@@ -74,15 +74,21 @@ class Context:
     # -- jax mapping -------------------------------------------------------
     def jax_device(self) -> "jax.Device":
         """Resolve this context to a concrete jax.Device."""
-        if self.device_type == "cpu" or self.device_type in ("cpu_pinned", "cpu_shared"):
+        if self.device_type in ("cpu", "cpu_pinned", "cpu_shared"):
             devs = _devices_by_platform("cpu")
         else:
+            # an accelerator context names an accelerator or nothing: a
+            # host fallback would let ``mx.tpu(0)`` be a CPU array on a
+            # machine whose chip failed to initialise
             devs = _accelerator_devices()
-            if not devs:  # no accelerator present: fall back to host
-                devs = _devices_by_platform("cpu")
-        if not devs:
-            devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                "%s: no such device — jax reports %d %s device(s) in this "
+                "process (backend %r)"
+                % (self, len(devs),
+                   "cpu" if self.device_type.startswith("cpu")
+                   else "accelerator", jax.default_backend()))
+        return devs[self.device_id]
 
     def empty_cache(self):
         """Parity with reference Context.empty_cache; XLA manages HBM pools."""

@@ -165,7 +165,7 @@ class Preempted(MXNetError):
 
 class StallError(MXNetError):
     """No step progress within ``MXNET_ELASTIC_STALL_SECS`` — the hang
-    class of failure (wedged accelerator tunnel, deadlocked input
+    class of failure (wedged device wait, deadlocked input
     pipeline) surfaced as a restartable error instead of an eternal
     wedge."""
 

@@ -20,7 +20,7 @@ piece                 what it gives you
                       contiguous per-dtype buckets through the kvstore
                       aggregate phase (``MXNET_KVSTORE_BUCKET_MB``)
 :mod:`.cache`         persistent XLA compilation cache
-                      (``MXNET_COMPILE_CACHE_DIR``) with hit/miss counters
+                      (``JAX_COMPILATION_CACHE_DIR``) with hit/miss counters
                       feeding the PR-3 recompile accounting
 :mod:`.zero`          ZeRO-1/2 sharded state plane (``MXNET_ZERO``):
                       optimizer state (and fp32 masters at level 2) lives

@@ -1,28 +1,55 @@
-"""Persistent XLA compilation cache (``MXNET_COMPILE_CACHE_DIR``).
+"""Persistent XLA compilation cache, placed from outside.
 
 Every process restart of the pre-fastpath stack recompiled the entire
 program set from scratch — minutes of XLA work to rebuild executables that
-were byte-identical to yesterday's. Pointing ``MXNET_COMPILE_CACHE_DIR``
-at a directory wires jax's persistent compilation cache under it: the
-first process pays the compiles and writes the executables; every later
+were byte-identical to yesterday's. With jax's persistent compilation cache
+the first process pays the compiles and writes the executables; every later
 process (restarts, elastic replacements, the second bench run) deserializes
 them instead.
+
+Where the cache lives is decided in exactly one of two ways:
+
+* ``JAX_COMPILATION_CACHE_DIR`` is set — jax reads it itself and this module
+  sets NO directory in code (an explicit ``configure(path)`` loses to it:
+  the machine's owner placed the cache, and the directory is part of the
+  cache key, so moving it would never hit);
+* it is unset — ``configure()`` uses one fixed directory inside the
+  checkout (``<repo>/.jax_cache``, git-ignored), ``configure(path)`` the
+  given one. Never a temp dir, a pid or a timestamp.
+
+Import-time wiring is driven by the environment only: with the variable
+unset, ``import mxnet_tpu`` writes no cache anywhere (the test suite must
+not grow one inside the checkout); entry points that compile for minutes
+(``chip_smoke.py``, ``bench.py``) call :func:`configure` before their first
+compile.
 
 Hit/miss traffic is surfaced through the PR-3 recompile accounting:
 jax's monitoring events ``/jax/compilation_cache/cache_hits`` /
 ``cache_misses`` increment ``mxnet_compile_cache_hits_total`` /
 ``mxnet_compile_cache_misses_total``, so a scrape (or the bench JSON line)
 shows whether a restart actually started warm.
-
-Configured once at package import when the env var is set; tests call
-:func:`configure` with an explicit path.
 """
 from __future__ import annotations
+
+import os
 
 from .. import telemetry
 from ..base import get_env
 
-__all__ = ["configure", "configured", "cache_counts"]
+__all__ = ["configure", "configured", "cache_counts", "DEFAULT_DIR"]
+
+#: the one in-checkout location used when nothing outside placed the cache
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+
+def _env_dir():
+    """jax's own variable (not an ``MXNET_*`` knob): read per call so a
+    test's monkeypatched environment is seen."""
+    return get_env("JAX_COMPILATION_CACHE_DIR", None, str, cache=False)
+
 
 _CONFIGURED = {"dir": None, "listener": False}
 
@@ -38,33 +65,28 @@ def _on_event(event, **_kw):
 
 
 def configure(path=None):
-    """Enable the persistent cache under ``path`` (or
-    ``MXNET_COMPILE_CACHE_DIR``). Returns True when active. Thresholds are
-    zeroed so every executable is eligible — the point is warm restarts,
-    not only the multi-second monsters."""
-    path = path or get_env("MXNET_COMPILE_CACHE_DIR", None, str, cache=False)
-    if not path:
-        return False
-    import jax
+    """Enable the persistent cache and return its directory.
 
-    jax.config.update("jax_compilation_cache_dir", str(path))
+    The directory is ``JAX_COMPILATION_CACHE_DIR`` when that is set (left
+    to jax — nothing here overrides it), else ``path``, else
+    :data:`DEFAULT_DIR`. Thresholds are zeroed so every executable is
+    eligible — the point is warm restarts, not only the multi-second
+    monsters."""
+    import jax
+    from jax._src import monitoring
+
+    if _env_dir():
+        active = jax.config.jax_compilation_cache_dir
+    else:
+        active = str(path or DEFAULT_DIR)
+        jax.config.update("jax_compilation_cache_dir", active)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     if not _CONFIGURED["listener"]:
-        try:
-            from jax._src import monitoring
-
-            monitoring.register_event_listener(_on_event)
-            _CONFIGURED["listener"] = True
-        except Exception:  # noqa: BLE001 - counters are best-effort; the
-            # cache itself works without them (jax internal API moved)
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "compile-cache hit/miss counters unavailable "
-                "(jax monitoring API not found); cache stays active")
-    _CONFIGURED["dir"] = str(path)
-    return True
+        monitoring.register_event_listener(_on_event)
+        _CONFIGURED["listener"] = True
+    _CONFIGURED["dir"] = active
+    return active
 
 
 def configured():
@@ -79,6 +101,8 @@ def cache_counts():
             int(telemetry.COMPILE_CACHE_MISSES.value()))
 
 
-# wire at import: a restart must start warm without anyone remembering to
-# call configure() (no-op when MXNET_COMPILE_CACHE_DIR is unset)
-configure()
+# wire at import ONLY when the environment placed the cache: a restart on a
+# machine that has one starts warm without anyone calling configure(), and
+# a process without the variable never writes a cache it was not asked for
+if _env_dir():
+    configure()

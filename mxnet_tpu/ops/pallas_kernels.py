@@ -20,11 +20,13 @@ Layout notes (see /opt/skills/guides/pallas_guide.md):
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 _ROW_X1, _ROW_Y1, _ROW_X2, _ROW_Y2, _ROW_CLS, _ROW_KEEP = range(6)
@@ -32,17 +34,12 @@ _PACK_ROWS = 8  # float32 sublane tile
 
 
 def _interpret() -> bool:
+    """The ONE platform decision of this module: Mosaic-compiled kernels on
+    a TPU backend, Pallas interpret mode (or, for the paged dispatchers, the
+    dense reference) everywhere else. Nothing else — no shape gate, no
+    try/except — picks a path, so on a TPU a kernel either runs or the call
+    fails with the compiler's message."""
     return jax.default_backend() != "tpu"
-
-
-def _compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` across the rename: jax >= 0.5 calls it
-    ``CompilerParams``, 0.4.x ``TPUCompilerParams`` — same fields."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
 
 
 def _pad_up(n: int, m: int) -> int:
@@ -177,10 +174,6 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 def _flash_forward(q, k, v, scale, causal, block_q=128, block_k=128):
     """q/k/v: (B, H, S, D) -> (B, H, S, D)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    import math
-
     b, h, s_len, d = q.shape
     bq = min(block_q, _pad_up(s_len, 8))
     bk = min(block_k, _pad_up(s_len, 128))
@@ -211,7 +204,7 @@ def _flash_forward(q, k, v, scale, causal, block_q=128, block_k=128):
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(qp, kp, vp)
@@ -270,9 +263,11 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # ---------------------------------------------------------------------------
 #
 # The decode-plane attention of mxnet_tpu.serving.decode: each of S decode
-# slots holds ONE new query token that must attend to that sequence's whole
-# KV history, which lives scattered across fixed-size pages of a static
-# device pool (serving.kvcache). Shapes are static in (S, max_pages,
+# slots holds W new query tokens (1 on a classic tick or a chunked-prefill
+# row, K+1 on a speculative verify tick — ONE kernel, W is a trace-time
+# constant) that must attend to that sequence's whole KV history, which
+# lives scattered across fixed-size pages of a static device pool
+# (serving.kvcache). Shapes are static in (S, max_pages,
 # page_size) regardless of how many sequences are live or how long each
 # one is — membership churn and ragged lengths never retrace (the Ragged
 # Paged Attention argument, PAPERS.md).
@@ -291,13 +286,20 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 def _paged_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, page_size, max_pages, groups,
-                  scale, causal):
-    """One (slot, page) cell of ragged paged attention.
+                  width, scale, causal):
+    """One (slot, page) cell of ragged paged attention, ``width`` query
+    tokens per slot (1 = classic decode tick / chunked-prefill row, K+1 =
+    speculative verify tick).
 
-    q_ref: (1, Hp, D) — the slot's single query token (heads padded to the
-    sublane tile); k_ref/v_ref: (1, page_size, KH, D) — the page named by
-    the slot's page table; o_ref: (1, Hp, D). Scratch m/l: (Hp, LANES),
-    acc: (Hp, D).
+    q_ref/o_ref: (1, KH, Rp, D) — the slot's query rows grouped by the kv
+    head they read: row ``r = w*groups + g`` of kv head ``kh`` is query
+    token ``w``, head ``kh*groups + g``; Rp pads ``width*groups`` to the
+    sublane tile. k_ref/v_ref: (1, page_size, KH, D) — the page named by
+    the slot's page table. Scratch m/l: (KH, Rp, LANES), acc: (KH, Rp, D).
+    sl_ref/qp_ref are (S*width,): PER-QUERY-TOKEN seq_len and (when
+    ``causal``) query position. Every per-kv-head access indexes a LEADING
+    ref axis or loads one kv head straight from the page ref — no value
+    slicing, which Mosaic refuses (dynamic_slice) or relayouts.
     """
     s = pl.program_id(0)
     j = pl.program_id(1)
@@ -308,59 +310,130 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)            # (Hp, D)
-    k = k_ref[0].astype(jnp.float32)            # (page_size, KH, D)
-    v = v_ref[0].astype(jnp.float32)
-    hp = q.shape[0]
-    kh = k.shape[1]
+    n_kv, rp = m_scr.shape[0], m_scr.shape[1]
 
-    # scores (Hp, page_size): head h attends kv head h // groups. Per-kv-
-    # head 2D matmuls keep the MXU fed without a batched einsum; kh is a
-    # small trace-time constant so the python loop unrolls.
-    scores = jnp.zeros((hp, page_size), jnp.float32)
-    for khi in range(kh):
-        qh = lax.dynamic_slice_in_dim(q, khi * groups, groups, 0)
-        sk = jax.lax.dot_general(qh, k[:, khi, :], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        scores = lax.dynamic_update_slice_in_dim(scores, sk, khi * groups, 0)
-    scores = scores * scale
-
-    # ragged mask: token positions of this page vs the slot's length (and
-    # its query position when causal). Padded table entries point at page
+    # ragged mask, per query row: token positions of this page vs the
+    # row's length (and its query position when causal). The w of a row
+    # is its index // groups — the (Rp, 1) columns are an unrolled select
+    # over the width scalar-prefetch entries; pad rows match no w, keep
+    # length 0 and come out as zeros. Padded table entries point at page
     # 0; the position mask kills them, so the duplicate load is harmless.
+    row_w = lax.broadcasted_iota(jnp.int32, (rp, 1), 0) // groups
+    sl_rows = jnp.zeros((rp, 1), jnp.int32)
+    qp_rows = jnp.zeros((rp, 1), jnp.int32)
+    for w in range(width):
+        sl_rows = jnp.where(row_w == w, sl_ref[s * width + w], sl_rows)
+        if causal:
+            qp_rows = jnp.where(row_w == w, qp_ref[s * width + w], qp_rows)
     pos = j * page_size + lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-    valid = pos < sl_ref[s]
+    valid = pos < sl_rows
     if causal:
-        valid = jnp.logical_and(valid, pos <= qp_ref[s])
-    scores = jnp.where(valid, scores, _NEG_BIG)
+        valid = jnp.logical_and(valid, pos <= qp_rows)
 
-    m_prev = m_scr[:, :1]
-    l_prev = l_scr[:, :1]
-    m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)
-    l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-    pv = jnp.zeros_like(acc_scr[...])
-    for khi in range(kh):
-        ph = lax.dynamic_slice_in_dim(p, khi * groups, groups, 0)
-        av = jax.lax.dot_general(ph, v[:, khi, :], (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        pv = lax.dynamic_update_slice_in_dim(pv, av, khi * groups, 0)
-    acc_scr[...] = acc_scr[...] * alpha + pv
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    # per-kv-head 2D matmuls keep the MXU fed without a batched einsum;
+    # n_kv is a small trace-time constant so the python loop unrolls.
+    for khi in range(n_kv):
+        q = q_ref[0, khi].astype(jnp.float32)           # (Rp, D)
+        k = k_ref[0, :, khi, :].astype(jnp.float32)     # (page_size, D)
+        v = v_ref[0, :, khi, :].astype(jnp.float32)
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(valid, scores, _NEG_BIG)
+        m_prev = m_scr[khi][:, :1]
+        l_prev = l_scr[khi][:, :1]
+        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
+        acc_scr[khi] = acc_scr[khi] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[khi] = jnp.broadcast_to(m_new, (rp, LANES))
+        l_scr[khi] = jnp.broadcast_to(l_new, (rp, LANES))
 
     @pl.when(j == max_pages - 1)
     def _finish():
-        # a fully-masked row (inactive slot, seq_len 0) never raises the
-        # running max off the sentinel: its p = exp(NEG_BIG - NEG_BIG) = 1
-        # accumulates garbage the flash kernel tolerates only because it
-        # drops padded rows — here the row IS the slot's output, so gate
-        # on the max and emit zeros instead
-        seen = m_scr[:, :1] > _NEG_BIG * 0.5
+        # a fully-masked row (inactive slot, padded draft row, seq_len 0)
+        # never raises the running max off the sentinel: its p =
+        # exp(NEG_BIG - NEG_BIG) = 1 accumulates garbage the flash kernel
+        # tolerates only because it drops padded rows — here the row IS
+        # the slot's output, so gate on the max and emit zeros instead
+        seen = m_scr[:, :, :1] > _NEG_BIG * 0.5
         o = jnp.where(seen,
-                      acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30), 0.0)
+                      acc_scr[...] / jnp.maximum(l_scr[:, :, :1], 1e-30),
+                      0.0)
         o_ref[0] = o.astype(o_ref.dtype)
+
+
+def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
+                interpret, who):
+    """Shared launch of :func:`_paged_kernel`. q: (S, W, H, D); seq_lens
+    (and q_pos, when not None): (S*W,) per query token. Returns
+    (S, W, H, D)."""
+    s_slots, width, n_heads, d = q.shape
+    _, page_size, n_kv, _ = k_pool.shape
+    if n_heads % n_kv:
+        raise ValueError("%s: %d heads not divisible by %d kv heads"
+                         % (who, n_heads, n_kv))
+    if seq_lens.shape[0] != s_slots * width:
+        raise ValueError("%s: seq_lens %s != S*W = %d"
+                         % (who, seq_lens.shape, s_slots * width))
+    groups = n_heads // n_kv
+    max_pages = page_table.shape[1]
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    causal = q_pos is not None
+    if interpret is None:
+        interpret = _interpret()
+
+    # (S, W, KH, G, D) -> (S, KH, W*G, D): each kv head's query rows
+    # contiguous and padded to the f32 sublane tile. Pad rows carry
+    # seq_len 0 (see the kernel's row mask), each row's softmax state is
+    # independent, and they are sliced off on return — layout, not math.
+    rows = width * groups
+    rp = _pad_up(rows, _PACK_ROWS)
+    qk = q.reshape(s_slots, width, n_kv, groups, d).transpose(0, 2, 1, 3, 4)
+    qk = jnp.pad(qk.reshape(s_slots, n_kv, rows, d),
+                 ((0, 0), (0, 0), (0, rp - rows), (0, 0)))
+    kernel = functools.partial(
+        _paged_kernel, page_size=page_size, max_pages=max_pages,
+        groups=groups, width=width, scale=float(scale), causal=causal)
+    pt_flat = page_table.astype(jnp.int32).ravel()
+    sl = seq_lens.astype(jnp.int32)
+    qpos = q_pos.astype(jnp.int32) if causal else jnp.zeros_like(sl)
+
+    def q_map(s, j, pt, sl_, qp_):
+        return (s, 0, 0, 0)
+
+    def page_map(s, j, pt, sl_, qp_):
+        return (pt[s * max_pages + j], 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(s_slots, max_pages),
+        in_specs=[
+            pl.BlockSpec((1, n_kv, rp, d), q_map),
+            pl.BlockSpec((1, page_size, n_kv, d), page_map),
+            pl.BlockSpec((1, page_size, n_kv, d), page_map),
+        ],
+        out_specs=pl.BlockSpec((1, n_kv, rp, d), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((n_kv, rp, LANES), jnp.float32),
+            pltpu.VMEM((n_kv, rp, LANES), jnp.float32),
+            pltpu.VMEM((n_kv, rp, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((s_slots, n_kv, rp, d), q.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(pt_flat, sl, qpos, qk, k_pool, v_pool)
+    out = out[:, :, :rows].reshape(s_slots, n_kv, width, groups, d)
+    return out.transpose(0, 2, 1, 3, 4).reshape(s_slots, width, n_heads, d)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, page_table, seq_lens,
@@ -379,64 +452,9 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, seq_lens,
     Static in every shape — membership churn, ragged lengths and page
     reassignment never recompile. Returns (S, H, D).
     """
-    from jax.experimental.pallas import tpu as pltpu
-
-    s_slots, n_heads, d = q.shape
-    n_pages_pool, page_size, n_kv, _ = k_pool.shape
-    if n_heads % n_kv:
-        raise ValueError("ragged_paged_attention: %d heads not divisible "
-                         "by %d kv heads" % (n_heads, n_kv))
-    groups = n_heads // n_kv
-    max_pages = page_table.shape[1]
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
-    causal = q_pos is not None
-    if interpret is None:
-        interpret = _interpret()
-
-    # heads padded to the f32 sublane tile. Pad rows are never written by
-    # the per-kv-head loops (they cover exactly n_heads rows), each score
-    # row's softmax state is independent, and the pad rows are sliced off
-    # on return — so the padding is layout-only, not math.
-    hp = _pad_up(n_heads, _PACK_ROWS)
-    qp = jnp.pad(q, ((0, 0), (0, hp - n_heads), (0, 0)))
-    kernel = functools.partial(
-        _paged_kernel, page_size=page_size, max_pages=max_pages,
-        groups=groups, scale=float(scale), causal=causal)
-    pt_flat = page_table.astype(jnp.int32).ravel()
-    sl = seq_lens.astype(jnp.int32)
-    qpos = (q_pos.astype(jnp.int32) if causal
-            else jnp.zeros_like(sl))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(s_slots, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, hp, d), lambda s, j, pt, sl, qp_: (s, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv, d),
-                         lambda s, j, pt, sl, qp_:
-                         (pt[s * max_pages + j], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv, d),
-                         lambda s, j, pt, sl, qp_:
-                         (pt[s * max_pages + j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, hp, d),
-                               lambda s, j, pt, sl, qp_: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((hp, LANES), jnp.float32),
-            pltpu.VMEM((hp, LANES), jnp.float32),
-            pltpu.VMEM((hp, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((s_slots, hp, d), q.dtype),
-        grid_spec=grid_spec,
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(pt_flat, sl, qpos, qp, k_pool, v_pool)
-    return out[:, :n_heads]
+    return _paged_call(q[:, None], k_pool, v_pool, page_table, seq_lens,
+                       q_pos, scale, interpret,
+                       "ragged_paged_attention")[:, 0]
 
 
 def paged_attention_reference(q, k_pool, v_pool, page_table, seq_lens,
@@ -499,103 +517,18 @@ def paged_prefill_attention(q, k_pool, v_pool, page_row, start, length,
 
 def paged_attention(q, k_pool, v_pool, page_table, seq_lens, q_pos=None,
                     scale=None):
-    """Dispatcher the decode engine traces: the Pallas kernel on TPU (when
-    the pool meets the (8, 128) tiling), the jnp reference elsewhere —
-    same math, tested for parity in interpret mode."""
-    page_size = k_pool.shape[1]
-    d = k_pool.shape[3]
-    if jax.default_backend() == "tpu" and page_size % 8 == 0 \
-            and d % LANES == 0:
+    """Dispatcher the decode engine traces: the Pallas kernel on a TPU
+    backend, the jnp reference elsewhere — same math, tested for parity in
+    interpret mode. The platform is the ONLY gate: there is no shape gate
+    (the kernel's blocks span the pool's full (KH, D) minor dims, so any
+    page_size/head_dim lowers — tests/test_chip_compile.py), and a
+    compiler refusal on a TPU propagates instead of demoting."""
+    if not _interpret():
         return ragged_paged_attention(q, k_pool, v_pool, page_table,
                                       seq_lens, q_pos=q_pos, scale=scale,
                                       interpret=False)
     return paged_attention_reference(q, k_pool, v_pool, page_table,
                                      seq_lens, q_pos=q_pos, scale=scale)
-
-
-def _paged_spec_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_scr, l_scr, acc_scr, *, page_size, max_pages,
-                       groups, width, hp, scale):
-    """One (slot, page) cell of multi-query ragged paged attention — the
-    speculative verify tick: each slot carries ``width`` = K+1 query rows
-    (last committed token + up to K draft tokens) instead of one.
-
-    q_ref: (1, width*Hp, D) with row layout ``row = w*Hp + h`` (each
-    query's heads contiguous, so the per-kv-head slices of the decode
-    kernel still work per w); k_ref/v_ref: (1, page_size, KH, D);
-    o_ref: (1, width*Hp, D). Scratch m/l: (width*Hp, LANES), acc:
-    (width*Hp, D). sl_ref is (S*width,): per-ROW seq_lens — query w of
-    slot s sits at position sl[s*width+w]-1 and sees everything below
-    it, so the ragged mask alone encodes causality between draft rows
-    (no q_pos operand needed; a padded row carries seq_len 0 and emits
-    zeros exactly like an inactive slot in the single-query kernel).
-    """
-    s = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0].astype(jnp.float32)            # (width*Hp, D)
-    k = k_ref[0].astype(jnp.float32)            # (page_size, KH, D)
-    v = v_ref[0].astype(jnp.float32)
-    whp = q.shape[0]
-    kh = k.shape[1]
-
-    # scores (width*Hp, page_size): within each w block, head h attends
-    # kv head h // groups — width*kh small unrolled 2D matmuls.
-    scores = jnp.zeros((whp, page_size), jnp.float32)
-    for w in range(width):
-        for khi in range(kh):
-            row0 = w * hp + khi * groups
-            qh = lax.dynamic_slice_in_dim(q, row0, groups, 0)
-            sk = jax.lax.dot_general(qh, k[:, khi, :],
-                                     (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            scores = lax.dynamic_update_slice_in_dim(scores, sk, row0, 0)
-    scores = scores * scale
-
-    # per-row ragged mask: row w's length is sl[s*width + w]. The w of a
-    # row is its index // hp — build the (whp, 1) length column by an
-    # unrolled select over the width scalar-prefetch entries.
-    row_w = lax.broadcasted_iota(jnp.int32, (whp, 1), 0) // hp
-    sl_rows = jnp.zeros((whp, 1), jnp.int32)
-    for w in range(width):
-        sl_rows = jnp.where(row_w == w, sl_ref[s * width + w], sl_rows)
-    pos = j * page_size + lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-    valid = pos < sl_rows
-    scores = jnp.where(valid, scores, _NEG_BIG)
-
-    m_prev = m_scr[:, :1]
-    l_prev = l_scr[:, :1]
-    m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)
-    l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-    pv = jnp.zeros_like(acc_scr[...])
-    for w in range(width):
-        for khi in range(kh):
-            row0 = w * hp + khi * groups
-            ph = lax.dynamic_slice_in_dim(p, row0, groups, 0)
-            av = jax.lax.dot_general(ph, v[:, khi, :],
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            pv = lax.dynamic_update_slice_in_dim(pv, av, row0, 0)
-    acc_scr[...] = acc_scr[...] * alpha + pv
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(j == max_pages - 1)
-    def _finish():
-        # same fully-masked-row gate as the single-query kernel: a padded
-        # draft row (seq_len 0) is the row's OWN output — emit zeros.
-        seen = m_scr[:, :1] > _NEG_BIG * 0.5
-        o = jnp.where(seen,
-                      acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30), 0.0)
-        o_ref[0] = o.astype(o_ref.dtype)
 
 
 def ragged_spec_attention(q, k_pool, v_pool, page_table, seq_lens,
@@ -608,67 +541,17 @@ def ragged_spec_attention(q, k_pool, v_pool, page_table, seq_lens,
     residency); seq_lens: (S*W,) int32, PER ROW: row w of slot s has
     seq_len = its absolute position + 1, so each draft row attends the
     committed prefix plus the earlier draft rows already written below
-    it, and a padded/inactive row carries 0 and returns zeros.
+    it (the ragged mask alone encodes causality between draft rows — no
+    q_pos operand), and a padded/inactive row carries 0 and returns
+    zeros. The same kernel as :func:`ragged_paged_attention`, which is
+    its W = 1 case.
 
     Shapes are static in (S, W, max_pages, page_size): speculation depth
     and per-slot acceptance vary the seq_lens DATA only — membership
     churn, rejection, ragged drafts never recompile. Returns (S, W, H, D).
     """
-    from jax.experimental.pallas import tpu as pltpu
-
-    s_slots, width, n_heads, d = q.shape
-    n_pages_pool, page_size, n_kv, _ = k_pool.shape
-    if n_heads % n_kv:
-        raise ValueError("ragged_spec_attention: %d heads not divisible "
-                         "by %d kv heads" % (n_heads, n_kv))
-    if seq_lens.shape[0] != s_slots * width:
-        raise ValueError("ragged_spec_attention: seq_lens %s != S*W = %d"
-                         % (seq_lens.shape, s_slots * width))
-    groups = n_heads // n_kv
-    max_pages = page_table.shape[1]
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
-    if interpret is None:
-        interpret = _interpret()
-
-    hp = _pad_up(n_heads, _PACK_ROWS)
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, hp - n_heads), (0, 0)))
-    qp = qp.reshape(s_slots, width * hp, d)
-    kernel = functools.partial(
-        _paged_spec_kernel, page_size=page_size, max_pages=max_pages,
-        groups=groups, width=width, hp=hp, scale=float(scale))
-    pt_flat = page_table.astype(jnp.int32).ravel()
-    sl = seq_lens.astype(jnp.int32)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s_slots, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, width * hp, d), lambda s, j, pt, sl: (s, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv, d),
-                         lambda s, j, pt, sl:
-                         (pt[s * max_pages + j], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv, d),
-                         lambda s, j, pt, sl:
-                         (pt[s * max_pages + j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, width * hp, d),
-                               lambda s, j, pt, sl: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((width * hp, LANES), jnp.float32),
-            pltpu.VMEM((width * hp, LANES), jnp.float32),
-            pltpu.VMEM((width * hp, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((s_slots, width * hp, d), q.dtype),
-        grid_spec=grid_spec,
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(pt_flat, sl, qp, k_pool, v_pool)
-    return out.reshape(s_slots, width, hp, d)[:, :, :n_heads]
+    return _paged_call(q, k_pool, v_pool, page_table, seq_lens, None,
+                       scale, interpret, "ragged_spec_attention")
 
 
 def paged_spec_attention_reference(q, k_pool, v_pool, page_table, seq_lens,
@@ -690,14 +573,11 @@ def paged_spec_attention(q, k_pool, v_pool, page_table, seq_lens,
     """Dispatcher for the widened (speculative) decode step: q is the
     flattened (S*W, H, D) query block — W derived from the page-table row
     count at trace time, so the engine's model code needs no signature
-    change. Pallas kernel on TPU (same tiling bar as `paged_attention`),
-    dense reference elsewhere."""
+    change. Pallas kernel on a TPU backend (platform-only gate, as
+    `paged_attention`), dense reference elsewhere."""
     s_slots = page_table.shape[0]
     width = q.shape[0] // s_slots
-    page_size = k_pool.shape[1]
-    d = k_pool.shape[3]
-    if jax.default_backend() == "tpu" and page_size % 8 == 0 \
-            and d % LANES == 0:
+    if not _interpret():
         out = ragged_spec_attention(
             q.reshape(s_slots, width, q.shape[1], q.shape[2]),
             k_pool, v_pool, page_table, seq_lens, scale=scale,

@@ -104,7 +104,7 @@ _UNARY = {
     "rint": jnp.rint,
     "fix": jnp.trunc,  # fix == round-toward-zero; jnp.fix is deprecated in jax 0.9
     "trunc": jnp.trunc,
-    "gamma": getattr(jax.scipy.special, "gamma", lambda x: jnp.exp(jax.scipy.special.gammaln(x))),
+    "gamma": jax.scipy.special.gamma,
     "gammaln": jax.scipy.special.gammaln,
     "erf": jax.scipy.special.erf,
     "erfinv": jax.scipy.special.erfinv,

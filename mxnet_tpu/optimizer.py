@@ -174,7 +174,12 @@ class Optimizer(object):
         weight_master_copy = None
         if self.multi_precision and _is_mp_dtype(weight.dtype):
             weight_master_copy = jnp.asarray(_as_jax(weight), dtype=jnp.float32)
-            return (weight_master_copy, self.create_state(index, weight))
+            # the base state is built from the MASTER (reference
+            # optimizer.py does the same): the kernel returns fp32 state,
+            # so a state born in the weight's dtype changed type after the
+            # first update and compiled every jit over it a second time
+            return (weight_master_copy,
+                    self.create_state(index, weight_master_copy))
         return self.create_state(index, weight)
 
     # ------------------------------------------------------------------

@@ -25,12 +25,9 @@ import numpy as np
 
 from .base import MXNetError, np_dtype
 from .ndarray.ndarray import NDArray
+from .ops.pallas_kernels import _interpret  # THE platform decision, one copy
 
 __all__ = ["PallasModule", "Kernel", "CudaModule"]
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 class Kernel(object):

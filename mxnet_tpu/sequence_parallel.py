@@ -34,10 +34,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exports it at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # the 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from .base import MXNetError
 from .ndarray.ndarray import NDArray

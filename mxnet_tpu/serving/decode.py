@@ -1891,6 +1891,7 @@ class TinyDecoder(PagedDecodeModel):
         self.embed_dim = self.num_heads * self.head_dim
         self.mlp_dim = self.embed_dim * int(mlp_ratio)
         self.scale = 1.0 / float(self.head_dim) ** 0.5
+        self._reference_jit = None  # built on first reference_generate
 
     # -- params ---------------------------------------------------------
     def init_params(self, seed: int = 0):
@@ -2042,34 +2043,46 @@ class TinyDecoder(PagedDecodeModel):
         return logits, k_pool, v_pool
 
     # -- oracle ---------------------------------------------------------
+    def _reference_next(self, params, arr):
+        """Greedy next token of the dense no-cache forward over ``arr``."""
+        import jax.numpy as jnp
+
+        t = arr.shape[0]
+        h, kh, d = self.num_heads, self.num_kv_heads, self.head_dim
+        positions = jnp.arange(t, dtype=jnp.int32)
+        x = params["embed"][arr] + self._pe(positions)
+        for layer in params["layers"]:
+            hx = self._norm(x, layer["ln1"])
+            q = (hx @ layer["wq"]).reshape(t, h, d)
+            k = (hx @ layer["wk"]).reshape(t, kh, d)
+            v = (hx @ layer["wv"]).reshape(t, kh, d)
+            att = self._dense_causal(q, k, v, self.scale)
+            x = x + att.reshape(t, h * d) @ layer["wo"]
+            x = x + self._mlp(self._norm(x, layer["ln2"]), layer)
+        logits = self._norm(x, params["lnf"]) @ params["unembed"]
+        return jnp.argmax(logits[-1])
+
     def reference_generate(self, params, prompt, max_new_tokens,
                            eos_id=None):
         """No-cache greedy decode: re-runs the full dense forward per
         token. O(T^2) per token — the correctness oracle the engine's
-        paged path is tested against, never a serving path."""
+        paged path is tested against, never a serving path. The forward
+        is ONE jitted program per sequence length: run op by op it cost a
+        set of per-op compiles for every new length, 24 minutes for 100
+        tokens on a v5e chip (PR 22)."""
+        import jax
         import jax.numpy as jnp
 
+        if self._reference_jit is None:
+            self._reference_jit = jax.jit(self._reference_next)
         toks = [int(t) for t in np.asarray(prompt).ravel()]
         out: List[int] = []
         for _ in range(int(max_new_tokens)):
             arr = jnp.asarray(np.asarray(toks, np.int32))
-            t = arr.shape[0]
-            h, kh, d = self.num_heads, self.num_kv_heads, self.head_dim
-            positions = jnp.arange(t, dtype=jnp.int32)
-            x = params["embed"][arr] + self._pe(positions)
-            for layer in params["layers"]:
-                hx = self._norm(x, layer["ln1"])
-                q = (hx @ layer["wq"]).reshape(t, h, d)
-                k = (hx @ layer["wk"]).reshape(t, kh, d)
-                v = (hx @ layer["wv"]).reshape(t, kh, d)
-                att = self._dense_causal(q, k, v, self.scale)
-                x = x + att.reshape(t, h * d) @ layer["wo"]
-                x = x + self._mlp(self._norm(x, layer["ln2"]), layer)
-            logits = self._norm(x, params["lnf"]) @ params["unembed"]
             # the batched-fetch idiom even for one value: the transfer is
             # explicit, and greedy decode is inherently per-token (the
             # fetched token IS the next input)
-            nxt = int(fetch_host([jnp.argmax(logits[-1])])[0])  # tpulint: disable=decode-host-sync -- correctness oracle, never a serving path; per-token fetch is the point
+            nxt = int(fetch_host([self._reference_jit(params, arr)])[0])  # tpulint: disable=decode-host-sync,unattributed-dispatch -- correctness oracle, never a serving path: per-token fetch is the point, and it stays off the chaos/attribution plane
             out.append(nxt)
             toks.append(nxt)
             if eos_id is not None and nxt == eos_id:

@@ -79,7 +79,7 @@ STEP_DISPATCHES = _registry.counter(
 COMPILE_CACHE_HITS = _registry.counter(
     "mxnet_compile_cache_hits_total",
     "XLA executables served from the persistent compilation cache "
-    "(MXNET_COMPILE_CACHE_DIR) instead of recompiled")
+    "(fastpath.cache) instead of recompiled")
 
 COMPILE_CACHE_MISSES = _registry.counter(
     "mxnet_compile_cache_misses_total",

@@ -268,7 +268,7 @@ def iter_bench_lines(path: str) -> Iterable[Dict[str, Any]]:
         doc = None
     if isinstance(doc, dict):
         line = _maybe_bench_line(doc)
-        if line is not None:  # a raw bench line (BENCH_CPU_QUICK shape)
+        if line is not None:  # a raw bench line
             yield line
             return
         if "parsed" in doc or "tail" in doc:  # driver wrapper

@@ -111,6 +111,18 @@ def _default_mesh(batch_size: int):
     return parallel.device_mesh(n)
 
 
+def _laid_out(x, target) -> bool:
+    """Whether ``x`` already IS a ``target``-laid-out array of the target's
+    mesh. Equivalence of placement is not enough: jax >= 0.9 carries the
+    mesh in an array's TYPE, so a single-device array "equivalent" to a
+    1-device-mesh ``target`` still keys a different trace than the
+    mesh-typed outputs the step hands back — a first step fed such arrays
+    compiled the whole step twice (once per type)."""
+    sh = getattr(x, "sharding", None)
+    return isinstance(sh, NamedSharding) and sh.mesh == target.mesh \
+        and sh.is_equivalent_to(target, x.ndim)
+
+
 def _aval(x):
     return jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)) \
         if not hasattr(x, "dtype") else jax.ShapeDtypeStruct(
@@ -119,6 +131,19 @@ def _aval(x):
 
 class _Ineligible(Exception):
     """Internal: the graph plane cannot serve this model/config."""
+
+
+def _backend_refusal(plane: str, exc: BaseException) -> bool:
+    """Whether ``exc`` must propagate instead of demoting a plane to the
+    eager path. The auto-fallback contract covers TRACE ineligibility (a
+    non-traceable model must train, not crash); an XLA/Mosaic compile or
+    runtime error is the device saying no, and an eager plane that quietly
+    trains anyway would hide it. The one exception is an OOM, which the
+    HBM governor records (``hbm.oom_survival``) before the demotion."""
+    from .resilience import hbm as hbm_mod
+
+    return isinstance(exc, jax.errors.JaxRuntimeError) \
+        and not hbm_mod.oom_survival(plane, exc, dump=True)
 
 
 class _PlaneBase(object):
@@ -304,6 +329,8 @@ class TrainPlane(_PlaneBase):
         except Exception as exc:  # noqa: BLE001 - auto-fallback contract:
             # a non-traceable model (host-sync in hybrid_forward, shape-
             # dependent python control flow, ...) must train, not crash
+            if _backend_refusal("trainplane.prepare", exc):
+                raise
             self._demote("trace: %s" % type(exc).__name__)
 
     # -- graph plane ----------------------------------------------------
@@ -314,8 +341,13 @@ class TrainPlane(_PlaneBase):
             for p in params.values():
                 p.data(self._trainer._contexts[0])
         except Exception:  # DeferredInitializationError
-            with autograd.pause():
-                self._net(data_nd)
+            if hasattr(self._net, "infer_shape"):
+                # abstract: no forward runs (or compiles) just to learn
+                # the parameter shapes
+                self._net.infer_shape(data_nd)
+            else:
+                with autograd.pause():
+                    self._net(data_nd)
 
     def _prepare_graph(self, data_nd, label_nd, batch_size):
         """Resolve rows/mesh and PROBE the whole-step trace (eval_shape:
@@ -397,8 +429,7 @@ class TrainPlane(_PlaneBase):
 
         def repl_val(nd):
             v = nd._data
-            sh = getattr(v, "sharding", None)
-            if sh is None or not sh.is_equivalent_to(repl, v.ndim):
+            if not _laid_out(v, repl):
                 v = parallel.fresh_replicate(v, self._mesh)
                 nd._data = v
             return v
@@ -408,8 +439,7 @@ class TrainPlane(_PlaneBase):
         out = {"diff": diff, "const": const}
         if with_states:
             states = [jax.tree_util.tree_map(
-                lambda x: x if getattr(x, "sharding", None) is not None
-                and x.sharding.is_equivalent_to(repl, x.ndim)
+                lambda x: x if _laid_out(x, repl)
                 else parallel.fresh_replicate(x, self._mesh),
                 updater.states[i]) for i, _ in self._rows]
             for (i, _), s in zip(self._rows, states):
@@ -1018,7 +1048,9 @@ def module_plane(module):
     ``Module`` — or return ``None`` when the eager executor path must run
     (``MXNET_TRAINSTEP=0``, multi-context, kvstore exchange, custom
     grad_req, non-traceable graph, ...). ``BaseModule.fit`` calls this once
-    per fit and falls back silently: routing must never break training."""
+    per fit and falls back silently: routing must never break training —
+    except on a backend compile/runtime error, which propagates
+    (:func:`_backend_refusal`)."""
     if mode() == "0":
         return None
     try:
@@ -1043,6 +1075,8 @@ def module_plane(module):
             return None
         return _ModulePlane(module)
     except Exception as exc:  # noqa: BLE001 - auto-fallback contract
+        if _backend_refusal("trainplane.module", exc):
+            raise
         FALLBACKS.inc(reason="module-trace: %s" % type(exc).__name__)
         if mode() == "1":
             _LOG.warning(

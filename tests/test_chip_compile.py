@@ -1,0 +1,113 @@
+"""Compile the Pallas kernels of the main path for the REAL chip, without
+the chip: the TPU compiler is installed here and compiles for a device that
+is described, not attached (``jax.experimental.topologies``). Interpret-mode
+parity tests prove the math; only this proves Mosaic accepts the kernels —
+both decode kernels passed every interpret test while being refused at
+lowering (``dynamic_slice`` on values) until PR 22.
+
+This is the ONLY file that describes the chip. The topology is asked for
+inside a module-scoped fixture, never at import / in ``skipif`` / in
+``parametrize``: libtpu belongs to one process, the suite runs under several
+xdist workers that each import every test file, and only the worker handed
+this file may load it. Nothing runs — a passing compile is not a chip run.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops import pallas_kernels as pk
+
+# chip_smoke.py's serve shapes (SERVE there): 16 slots, 4 heads x 128, the
+# engine's default 16-token pages, max_seq_len 1152 -> 72 pages per slot,
+# pool of slots * pages + the null page; speculation width spec_k + 1.
+SLOTS, HEADS, HEAD_DIM, PAGE, MAX_PAGES = 16, 4, 128, 16, 72
+POOL_PAGES = SLOTS * MAX_PAGES + 1
+SPEC_W = 4
+CHUNK = 32          # the largest prefill-bucket rung below max_seq_len
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compile_cache_off():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip (the next one warns and compiles
+    again): keep the cache out of these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _paged_operands(spec, dtype, rows, width=1):
+    q_shape = (rows, HEADS, HEAD_DIM) if width == 1 \
+        else (rows, width, HEADS, HEAD_DIM)
+    pool = spec((POOL_PAGES, PAGE, HEADS, HEAD_DIM), dtype)
+    return (spec(q_shape, dtype), pool, pool,
+            spec((rows, MAX_PAGES), jnp.int32),
+            spec((rows * width,), jnp.int32))
+
+
+def _case(name, spec, dtype):
+    """(function, abstract operands) for one kernel at chip_smoke's shapes;
+    every kernel is called with ``interpret=False`` (flash/NMS through the
+    test's ``_interpret`` patch) — the dispatchers ask
+    ``jax.default_backend()``, which is the CPU here."""
+    if name == "paged_decode":
+        return (lambda *a: pk.ragged_paged_attention(*a, interpret=False),
+                _paged_operands(spec, dtype, SLOTS))
+    if name == "paged_prefill_chunk":
+        # what paged_prefill_attention feeds the kernel: one row per chunk
+        # token, a broadcast page-table row, causal q_pos
+        ops = _paged_operands(spec, dtype, CHUNK)
+        return (lambda q, k, v, pt, sl, qp: pk.ragged_paged_attention(
+            q, k, v, pt, sl, q_pos=qp, interpret=False),
+            ops + (spec((CHUNK,), jnp.int32),))
+    if name == "paged_spec_verify":
+        return (lambda *a: pk.ragged_spec_attention(*a, interpret=False),
+                _paged_operands(spec, dtype, SLOTS, SPEC_W))
+    if name == "flash_attention":
+        qkv = spec((1, HEADS, 1152, HEAD_DIM), dtype)
+        return (lambda q, k, v: pk._flash_forward(q, k, v, 0.088, True),
+                (qkv, qkv, qkv))
+    if name == "nms":
+        n = 1000
+        return (lambda b, c, v: pk.nms_keep(b, c, v, 0.5, False),
+                (spec((n, 4), dtype), spec((n,), dtype),
+                 spec((n,), jnp.bool_)))
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", ["paged_decode", "paged_prefill_chunk",
+                                  "paged_spec_verify", "flash_attention",
+                                  "nms"])
+def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
+                               monkeypatch):
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    fn, operands = _case(name, spec, jnp.dtype(dtype))
+    compiled = jax.jit(fn).lower(*operands).compile()
+    assert "tpu_custom_call" in compiled.as_text()

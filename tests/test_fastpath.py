@@ -614,11 +614,54 @@ print(json.dumps({"hits": hits, "misses": misses,
 """
 
 
+_CACHE_DIR_PROBE = r"""
+import json
+import mxnet_tpu as mx
+import jax
+from mxnet_tpu.fastpath import cache
+at_import = jax.config.jax_compilation_cache_dir
+wired_at_import = cache.configured()
+print(json.dumps({"at_import": at_import, "wired_at_import": wired_at_import,
+                  "configure": cache.configure(),
+                  "after": jax.config.jax_compilation_cache_dir,
+                  "explicit": cache.configure("/explicit/path"),
+                  "after_explicit": jax.config.jax_compilation_cache_dir,
+                  "default_dir": cache.DEFAULT_DIR}))
+"""
+
+
+@pytest.mark.parametrize("env_dir", ["/x", None])
+def test_compile_cache_directory_is_placed_from_outside(env_dir):
+    """The cache-path rule: with JAX_COMPILATION_CACHE_DIR set no code path
+    sets another directory (not at import, not configure(), not
+    configure(path)); unset, import wires nothing and configure() uses the
+    one fixed in-checkout path."""
+    env = subprocess_env()
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", _CACHE_DIR_PROBE],
+                         capture_output=True, text=True, env=env,
+                         timeout=300, cwd=repo)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    if env_dir:
+        assert {got[k] for k in ("at_import", "wired_at_import", "configure",
+                                 "after", "explicit", "after_explicit")} \
+            == {env_dir}
+    else:
+        assert got["at_import"] is None and got["wired_at_import"] is None
+        assert got["configure"] == got["after"] == got["default_dir"] \
+            == os.path.join(repo, ".jax_cache")
+        assert got["explicit"] == got["after_explicit"] == "/explicit/path"
+
+
 @pytest.mark.slow
 def test_compile_cache_hits_on_second_process(tmp_path):
     """ISSUE-5 acceptance: a restarted process deserializes executables
-    from MXNET_COMPILE_CACHE_DIR instead of recompiling."""
-    env = subprocess_env(MXNET_COMPILE_CACHE_DIR=str(tmp_path))
+    from JAX_COMPILATION_CACHE_DIR instead of recompiling."""
+    env = subprocess_env(JAX_COMPILATION_CACHE_DIR=str(tmp_path))
 
     def probe():
         out = subprocess.run([sys.executable, "-c", _CACHE_PROBE],

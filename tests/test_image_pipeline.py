@@ -68,9 +68,20 @@ def test_mp_pipeline_padding(rec_file):
         it.close()
 
 
-def test_io_wiring_selects_mp(rec_file):
+def test_io_wiring_selects_mp(rec_file, monkeypatch):
     from mxnet_tpu.image_pipeline import MPImageRecordIter
 
+    # the factory picks the multi-process pipeline only under a
+    # re-importable ``__main__`` (one with a ``__file__``). An xdist worker's
+    # ``__main__`` is execnet's bootstrap, which has none — so this test
+    # passed alone and failed under ``-n 6``. Give it one: a spawned worker
+    # then runs that file as ``__mp_main__`` — so a trivially import-safe
+    # one, the stdlib's ``keyword.py``.
+    import keyword
+    import sys
+
+    monkeypatch.setattr(sys.modules["__main__"], "__file__",
+                        keyword.__file__, raising=False)
     it = mxio.ImageRecordIter(path_imgrec=rec_file, data_shape=(3, 32, 32),
                               batch_size=8, preprocess_threads=2,
                               prefetch_buffer=2)

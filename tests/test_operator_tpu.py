@@ -14,10 +14,15 @@ import pytest
 
 import mxnet_tpu as mx
 
-_ACCEL = [d for d in jax.devices() if d.platform != "cpu"]
 
-pytestmark = pytest.mark.skipif(
-    not _ACCEL, reason="no TPU device visible (CPU test mesh)")
+@pytest.fixture(scope="module")
+def tpu_ctx():
+    """Asks jax for its devices only once a test of this module runs —
+    never at import, where every xdist worker would initialise a backend
+    just to collect."""
+    if all(d.platform == "cpu" for d in jax.devices()):
+        pytest.skip("no TPU device visible (CPU test mesh)")
+    return mx.tpu()
 
 
 def _op_test_functions():
@@ -50,7 +55,7 @@ except ImportError:  # tests not importable as a package: fall back
 
 
 @pytest.mark.parametrize("name,fn", _CASES, ids=[n for n, _ in _CASES])
-def test_operator_on_tpu(name, fn):
-    with mx.tpu():
+def test_operator_on_tpu(name, fn, tpu_ctx):
+    with tpu_ctx:
         assert mx.current_context().device_type in ("tpu", "gpu")
         fn()

@@ -64,13 +64,24 @@ def test_modern_json_roundtrip(tmp_path):
 
 def test_group2ctx_model_parallel_placement():
     """group2ctx maps ctx_group attrs to device placement constraints
-    (reference graph_executor.cc:1577; the v1.0 fixture carries
-    stage1/stage2 groups). Same numerics as unplaced execution."""
+    (reference graph_executor.cc:1577). The symbol is the v1.0 fixture's
+    two-group MLP, built here with AttrScope so the test runs where the
+    reference checkout is absent. Same numerics as unplaced execution."""
     import jax
 
     if len(jax.devices()) < 2:
         pytest.skip("needs >=2 devices")
-    sym = mx.sym.load(LEGACY_JSON)
+    with mx.AttrScope(ctx_group="stage1"):
+        data = mx.sym.var("data")
+        fc1 = mx.sym.FullyConnected(data, num_hidden=128, name="fc1")
+        act1 = mx.sym.Activation(fc1, act_type="relu", name="relu1")
+    with mx.AttrScope(ctx_group="stage2"):
+        fc2 = mx.sym.FullyConnected(act1, num_hidden=64, name="fc2")
+        act2 = mx.sym.Activation(fc2, act_type="relu", name="relu2")
+        fc3 = mx.sym.FullyConnected(act2, num_hidden=10, name="fc3")
+        sym = mx.sym.SoftmaxOutput(fc3, name="softmax")
+    assert {n: a.get("ctx_group") for n, a in sym.attr_dict().items()
+            if n in ("fc1", "fc3")} == {"fc1": "stage1", "fc3": "stage2"}
     rng = np.random.RandomState(0)
     x = rng.rand(4, 10).astype(np.float32)
 

@@ -609,3 +609,73 @@ def test_trainstep_net_params_survive_donating_steps():
     for n, p in net.collect_params().items():
         np.testing.assert_array_equal(np.asarray(p.data()._data),
                                       before[n], err_msg=n)
+
+
+# ---------------------------------------------------------------------------
+# one compile per plane; backend refusals are not demotions (PR 22)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype,ndev", [("fp32", 1), ("bf16", 1),
+                                        ("fp32", 2)])
+def test_whole_step_compiles_exactly_once(monkeypatch, dtype, ndev):
+    """The step's inputs must have ONE type from the first call on: a
+    first step fed single-device params (mesh-less type under jax >= 0.9)
+    or a bf16-born momentum (the kernel returns fp32) compiled the whole
+    program a second time at step 2 — minutes, on the chip."""
+    if len(jax.devices()) < ndev:
+        pytest.skip("needs %d devices" % ndev)
+    monkeypatch.setenv("MXNET_TRAINSTEP", "1")
+    monkeypatch.setenv("MXNET_TRAIN_DTYPE", dtype)
+    xs, ys = _data()
+    net = _make_mlp("once_%s%d_" % (dtype, ndev))
+    net.initialize()
+    net.hybridize()
+    tr = gluon.Trainer(net.collect_params(), "sgd",
+                       {"learning_rate": 0.1, "momentum": 0.9})
+    plane = trainplane.TrainPlane(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                                  tr, mesh=parallel.device_mesh(ndev))
+    before = telemetry.RECOMPILES.value(site="trainplane.step")
+    for k in range(3):
+        plane.step(nd.array(xs[k * B:(k + 1) * B]),
+                   nd.array(ys[k * B:(k + 1) * B]))
+    assert plane.plane == "graph"
+    assert telemetry.RECOMPILES.value(site="trainplane.step") - before == 1
+
+
+def test_backend_error_propagates_trace_error_demotes(monkeypatch):
+    """Demotion to the eager plane is for models that cannot be TRACED; a
+    backend (XLA/Mosaic) compile or runtime error is the device saying no
+    and must surface, not train quietly op-by-op."""
+    monkeypatch.setenv("MXNET_TRAINSTEP", "auto")
+    xs, ys = _data()
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+
+    def plane_failing_with(exc, tag):
+        net = _make_mlp(tag)
+        _init(net, xs)
+        net.hybridize()
+        tr = gluon.Trainer(net.collect_params(), "sgd",
+                           {"learning_rate": 0.1})
+        plane = trainplane.TrainPlane(net, loss_fn, tr)
+
+        def boom(*_a, **_k):
+            raise exc
+        monkeypatch.setattr(plane, "_prepare_graph", boom)
+        return plane
+
+    x, y = nd.array(xs[:B]), nd.array(ys[:B])
+    before = sum(s["value"] for s in trainplane.FALLBACKS.series())
+    refused = plane_failing_with(jax.errors.JaxRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel"), "refuse_")
+    with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+        refused.step(x, y)
+    assert refused.plane == "undecided"
+    assert sum(s["value"] for s in trainplane.FALLBACKS.series()) == before
+
+    untraceable = plane_failing_with(
+        jax.errors.ConcretizationTypeError.__new__(
+            jax.errors.ConcretizationTypeError), "untrace_")
+    loss = untraceable.step(x, y)
+    assert untraceable.plane == "eager"
+    assert np.isfinite(np.asarray(loss._data)).all()

@@ -8,7 +8,7 @@ line would have received at the moment it landed — the same
 verdict-then-absorb sequence ``bench.py`` runs live. Three uses:
 
 * **post-mortem**: rerun after a round to see which trajectories moved
-  (``BENCH_r03``'s dead rounds show up as ``no_value`` lines carrying
+  (dead rounds show up as ``no_value`` lines carrying
   their error, not as silent gaps);
 * **pre-merge**: point it at a candidate bench line (``--line file``)
   to judge it against committed history before the file is committed;
