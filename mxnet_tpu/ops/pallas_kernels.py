@@ -207,6 +207,7 @@ def _flash_forward(q, k, v, scale, causal, block_q=128, block_k=128):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="mx_flash_attn",  # what a device trace is searched for
     )(qp, kp, vp)
     return out.reshape(b, h, sp, d)[:, :, :s_len]
 
@@ -431,6 +432,7 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="mx_paged_attn",  # what a device trace is searched for
     )(pt_flat, sl, qpos, qk, k_pool, v_pool)
     out = out[:, :, :rows].reshape(s_slots, n_kv, width, groups, d)
     return out.transpose(0, 2, 1, 3, 4).reshape(s_slots, width, n_heads, d)

@@ -130,6 +130,10 @@ _DEFAULT_QUEUE_DEPTH = 256
 _DEFAULT_PREFIX_CACHE = 1  # sharing is exact by construction: default on
 _DEFAULT_PREFILL_CHUNK = 0  # 0 = monolithic prefill (one rung per prompt)
 
+#: category of the worker's ``telemetry.span`` regions (``mx.decode.*`` in
+#: a ``jax.profiler`` trace; docs/observability.md lists them)
+_SPAN_CAT = "serving"
+
 _T_TOKENS = telemetry.counter(
     "mxnet_decode_tokens_total",
     "output tokens generated by the decode plane",
@@ -423,7 +427,7 @@ class DecodeEngine:
         # (5, S*W) array — one host->device put per tick instead of five;
         # the page table rides a version-keyed device cache (below), so a
         # steady tick pays exactly one put + one fetch
-        def _step_fn(params, packed, k_pool, v_pool, page_tables):
+        def mx_decode_step(params, packed, k_pool, v_pool, page_tables):
             tokens, positions, seq_lens, write_pages, write_offsets = packed
             logits, k_pool, v_pool = model.decode(
                 params, tokens, positions, k_pool, v_pool, page_tables,
@@ -433,11 +437,13 @@ class DecodeEngine:
 
         # same packing for prefill: tokens + write pages + offsets share
         # the rung shape, so they travel as one (3, rung) array
-        def _prefill_fn(params, packed, length, k_pool, v_pool):
+        # (one jit, one program a rung: the scope names the rung's ops)
+        def mx_prefill(params, packed, length, k_pool, v_pool):
             tokens, write_pages, write_offsets = packed
-            last, k_pool, v_pool = model.prefill(
-                params, tokens, length, k_pool, v_pool, write_pages,
-                write_offsets)
+            with jax.named_scope("mx_prefill_%d" % tokens.shape[0]):
+                last, k_pool, v_pool = model.prefill(
+                    params, tokens, length, k_pool, v_pool, write_pages,
+                    write_offsets)
             return jnp.argmax(last).astype(jnp.int32), k_pool, v_pool
 
         # one prefill CHUNK: same (3, rung) packing plus the absolute
@@ -445,8 +451,8 @@ class DecodeEngine:
         # attends through the pages (earlier chunks' and shared prefix
         # KV included), so start/length are traced and one compile
         # serves every chunk of a rung
-        def _chunk_fn(params, packed, start, length, page_row, k_pool,
-                      v_pool):
+        def mx_prefill_chunk(params, packed, start, length, page_row,
+                             k_pool, v_pool):
             tokens, write_pages, write_offsets = packed
             last, k_pool, v_pool = model.prefill_chunk(
                 params, tokens, start, length, k_pool, v_pool, page_row,
@@ -457,7 +463,7 @@ class DecodeEngine:
         # into a fresh page so a sequence diverging inside a shared page
         # writes into its own copy; src/dst are traced scalars — ONE
         # compile, pre-warmed against the null page
-        def _cow_fn(k_pool, v_pool, src, dst):
+        def mx_kv_cow(k_pool, v_pool, src, dst):
             k_pool = k_pool.at[:, dst].set(k_pool[:, src])
             v_pool = v_pool.at[:, dst].set(v_pool[:, src])
             return k_pool, v_pool
@@ -465,13 +471,15 @@ class DecodeEngine:
         # pools are donated through the jits (they are dead the moment
         # the step returns — swap_pools rebinds to the outputs), so the
         # cache costs ONE pool of HBM, not two per step
-        self._step = jax.jit(_step_fn,
+        # the functions' names are the XLA modules' (jit_mx_decode_step,
+        # ...): what a device trace is searched for
+        self._step = jax.jit(mx_decode_step,
                              donate_argnums=(2, 3) if donate else ())
-        self._prefill_jit = jax.jit(_prefill_fn, donate_argnums=donate)
+        self._prefill_jit = jax.jit(mx_prefill, donate_argnums=donate)
         self._chunk_jit = jax.jit(
-            _chunk_fn, donate_argnums=(5, 6) if donate else ())
+            mx_prefill_chunk, donate_argnums=(5, 6) if donate else ())
         self._cow_jit = jax.jit(
-            _cow_fn, donate_argnums=(0, 1) if donate else ())
+            mx_kv_cow, donate_argnums=(0, 1) if donate else ())
         self._pt_dev = None  # version-keyed device page table
         self._pt_version = -1
 
@@ -483,8 +491,8 @@ class DecodeEngine:
         self._tokens_total = 0
         self._prefills = 0
         self._evictions = 0
-        self._occ_sum = 0.0
-        self._ticks = 0
+        self._ticks = 0       # decode steps run
+        self._slot_ticks = 0  # decoding slots, summed over those steps
         # speculation accounting (worker-confined): draft tokens
         # proposed/accepted, and the accepted-per-tick numerator/
         # denominator over SPECULATING slot-ticks only
@@ -802,8 +810,13 @@ class DecodeEngine:
                 "prefills": self._prefills,
                 "evictions": self._evictions,
                 "deadline_evictions": self._deadline_evictions,
-                "slot_occupancy": (self._occ_sum / self._ticks
-                                   if self._ticks else 0.0),
+                "slot_occupancy": (
+                    self._slot_ticks / float(self._ticks * self.num_slots)
+                    if self._ticks else 0.0),
+                # monotonic: two scrapes give a windowed occupancy,
+                # d(slot_ticks) / (d(ticks) * slots)
+                "ticks": self._ticks,
+                "slot_ticks": self._slot_ticks,
                 "prefill_buckets": list(self._ladder),
                 "prefill_chunk": self._chunk,
                 "cow_copies": self._cow_copies,
@@ -944,83 +957,96 @@ class DecodeEngine:
                         and not self._any_active():
                     swaps, self._pending_swaps = self._pending_swaps, []
                     break
-            self._apply_pending_swaps()
-            self._expire_queued()
-            self._evict_expired()
-            self._shed_tenant_breakers()
-            has_work = False
-            with self._cv:
-                has_work = bool(self._wfq.total_queued()) \
-                    or self._any_active()
-            if not has_work:
-                continue
-            if not self._breaker.allow():
-                # open ENGINE breaker: answer all queued work explicitly
-                # (the PR-2 engine load-shed) instead of letting it age
-                # out; the reset timeout admits a half-open probe later
-                self._shed_open_breaker()
-                time.sleep(0.005)
-                continue
-            try:
-                # devprof tick scope: the sampling decision is drawn once
-                # for the whole tick so a timed tick's prefill/step/host-
-                # gap breakdown is coherent; one global read when off
-                tick_t0 = time.perf_counter()
-                tick_timed = _devprof.tick_begin()
-                toks_before = self._tokens_total
-                self._admit()
-                prefilling = [(i, r) for i, r in enumerate(self._slots)
-                              if r is not None and r.prefilling]
-                decoding = [(i, r) for i, r in enumerate(self._slots)
-                            if r is not None and not r.prefilling]
-                if prefilling:
-                    # ONE chunk per tick, ROUND-ROBIN over prefilling
-                    # slots (admission order, wrapping), then the tick
-                    # goes back to decoding. Round-robin — not oldest-
-                    # first — is what decouples TTFT from the longest
-                    # prompt: a 1-chunk prompt lands on its next turn
-                    # instead of waiting out a 100-chunk neighbour.
-                    cands = sorted(prefilling, key=lambda t: t[1].seq)
-                    slot, req = next(
-                        (t for t in cands if t[1].seq > self._rr_last),
-                        cands[0])
-                    self._rr_last = req.seq
-                    self._advance_prefill(slot, req)
-                if decoding:
-                    self._step_once(decoding)
-                elif not prefilling:
-                    # every queued tenant deferred (pages/rate/breaker)
-                    # with nothing in flight: yield instead of spinning
-                    if tick_timed:
-                        _devprof.tick_end()
-                    time.sleep(0.001)
-                    continue
-                if tick_timed:
-                    _devprof.note_decode_tick(
-                        self._name,
-                        (time.perf_counter() - tick_t0) * 1e3,
-                        self._tokens_total - toks_before)
-            except Exception as exc:  # noqa: BLE001 - engine must survive
-                _devprof.tick_end()  # don't leak the tick scope into the
-                # eviction/recovery path's dispatches
-                # belt-and-braces (the PR-2 batcher discipline): NO
-                # exception may kill the engine thread — that would hang
-                # every in-flight and queued future forever. Evict
-                # whatever was in flight and keep serving. This is also a
-                # black-box moment: something unexpected reached the
-                # catch-all, so commit the ring before state is torn down.
-                _flightrec.record("decode.engine_exception",
-                                  server=self._name, error=repr(exc))
-                _flightrec.dump("decode engine catch-all: %r" % (exc,))
-                self._breaker.on_failure()
-                self._evict([(i, r) for i, r in enumerate(self._slots)
-                             if r is not None], exc)
+            with telemetry.span("decode.tick", _SPAN_CAT) as tick:
+                self._tick(tick)
         # drained close: resolve any swap still pending so its waiter
         # does not hang on a dead worker
         exc = ServerClosedError("engine closed before the swap applied")
         for _params, _variant, fut in swaps:
             if fut.set_running_or_notify_cancel():
                 fut.set_exception(exc)
+
+    def _tick(self, tick):
+        """One pass of the worker: housekeeping, admission, at most one
+        prefill chunk, one decode step. ``tick`` is the pass's span."""
+        with telemetry.span("decode.housekeep", _SPAN_CAT):
+            self._apply_pending_swaps()
+            self._expire_queued()
+            self._evict_expired()
+            self._shed_tenant_breakers()
+            with self._cv:
+                has_work = bool(self._wfq.total_queued()) \
+                    or self._any_active()
+            if not has_work:
+                return
+            if not self._breaker.allow():
+                # open ENGINE breaker: answer all queued work explicitly
+                # (the PR-2 engine load-shed) instead of letting it age
+                # out; the reset timeout admits a half-open probe later
+                self._shed_open_breaker()
+                time.sleep(0.005)
+                return
+        try:
+            # devprof tick scope: the sampling decision is drawn once
+            # for the whole tick so a timed tick's prefill/step/host-
+            # gap breakdown is coherent; one global read when off
+            tick_t0 = time.perf_counter()
+            tick_timed = _devprof.tick_begin()
+            toks_before = self._tokens_total
+            with telemetry.span("decode.admit", _SPAN_CAT):
+                self._admit()
+            prefilling = [(i, r) for i, r in enumerate(self._slots)
+                          if r is not None and r.prefilling]
+            decoding = [(i, r) for i, r in enumerate(self._slots)
+                        if r is not None and not r.prefilling]
+            with self._cv:
+                queued = self._wfq.total_queued()
+            tick.set_args(active=len(decoding), prefilling=len(prefilling),
+                          queued=queued)
+            if prefilling:
+                # ONE chunk per tick, ROUND-ROBIN over prefilling
+                # slots (admission order, wrapping), then the tick
+                # goes back to decoding. Round-robin — not oldest-
+                # first — is what decouples TTFT from the longest
+                # prompt: a 1-chunk prompt lands on its next turn
+                # instead of waiting out a 100-chunk neighbour.
+                cands = sorted(prefilling, key=lambda t: t[1].seq)
+                slot, req = next(
+                    (t for t in cands if t[1].seq > self._rr_last),
+                    cands[0])
+                self._rr_last = req.seq
+                with telemetry.span("decode.prefill", _SPAN_CAT,
+                                    chunk=self._chunk):
+                    self._advance_prefill(slot, req)
+            if decoding:
+                self._step_once(decoding)
+            elif not prefilling:
+                # every queued tenant deferred (pages/rate/breaker)
+                # with nothing in flight: yield instead of spinning
+                if tick_timed:
+                    _devprof.tick_end()
+                time.sleep(0.001)
+                return
+            if tick_timed:
+                _devprof.note_decode_tick(
+                    self._name,
+                    (time.perf_counter() - tick_t0) * 1e3,
+                    self._tokens_total - toks_before)
+        except Exception as exc:  # noqa: BLE001 - engine must survive
+            _devprof.tick_end()  # don't leak the tick scope into the
+            # eviction/recovery path's dispatches
+            # belt-and-braces (the PR-2 batcher discipline): NO
+            # exception may kill the engine thread — that would hang
+            # every in-flight and queued future forever. Evict
+            # whatever was in flight and keep serving. This is also a
+            # black-box moment: something unexpected reached the
+            # catch-all, so commit the ring before state is torn down.
+            _flightrec.record("decode.engine_exception",
+                              server=self._name, error=repr(exc))
+            _flightrec.dump("decode engine catch-all: %r" % (exc,))
+            self._breaker.on_failure()
+            self._evict([(i, r) for i, r in enumerate(self._slots)
+                         if r is not None], exc)
 
     def _apply_pending_swaps(self):
         """Tick-boundary weight swap: rebind ``self._params`` between
@@ -1313,7 +1339,8 @@ class DecodeEngine:
         # the tenant's budget pays for its exclusive tail + CoW copies
         req.tenant.charge_pages(self._cache.exclusive_pages(slot))
         if cow_src is not None:
-            self._run_cow(cow_src, cow_dst)
+            with telemetry.span("decode.prefill", _SPAN_CAT, cow=1):
+                self._run_cow(cow_src, cow_dst)
         req.kv_cached = matched
         _tracing.event(req.trace, "admit", slot=slot, ring=ring,
                        queue_wait_ms=round(
@@ -1336,12 +1363,13 @@ class DecodeEngine:
             self._slots[slot] = req
             _T_EVENTS.inc(server=self._name, event="admitted")
             return
-        if matched == 0:
-            tok = self._run_full_prefill(req, slot, ring=ring)
-        else:
-            rung = select_bucket(p - req.filled, self._ladder)
-            tok = self._run_chunk(slot, req, req.filled, p, rung)
-        self._finish_prefill(req, slot, tok)
+        rung = select_bucket(p - req.filled, self._ladder)
+        with telemetry.span("decode.prefill", _SPAN_CAT, rung=rung):
+            if matched == 0:
+                tok = self._run_full_prefill(req, slot, ring=ring)
+            else:
+                tok = self._run_chunk(slot, req, req.filled, p, rung)
+            self._finish_prefill(req, slot, tok)
 
     def _run_full_prefill(self, req: _DecodeRequest, slot: int,
                           ring: bool = False):
@@ -1552,9 +1580,57 @@ class DecodeEngine:
 
     # -- the decode tick ------------------------------------------------
     def _step_once(self, active):
+        """One decode step for the ``active`` (slot, request) pairs: the
+        packed operand, the dispatch, the wait for the sampled tokens and
+        their commit, each under its span."""
         from .. import resilience
 
         jnp = self._jnp
+        with telemetry.span("decode.pack", _SPAN_CAT):
+            packed, drafts, pages_before = self._pack_step(active)
+        policy = self._retry or resilience.default_policy()
+
+        def attempt():
+            chaos.maybe_fail("serving.decode")
+            if self._pools_dead():
+                raise MXNetError(  # not transient: stop the retry loop
+                    "KV pools consumed by a failed step (donation); "
+                    "eviction required")
+            return telemetry.jit_call(
+                "serving.decode_step", self._step, self._params,
+                jnp.asarray(packed), self._cache.k_pool,
+                self._cache.v_pool, self._device_page_table())
+
+        try:
+            with telemetry.span("decode.dispatch", _SPAN_CAT):
+                sampled, kp, vp = policy.call(attempt,
+                                              site="serving.decode")
+            with telemetry.span("decode.fetch", _SPAN_CAT):
+                self._cache.swap_pools(kp, vp)
+                # the one per-token device->host sync of the plane: the
+                # sampled token ids must reach the host for EOS/stop
+                # checks and feedback. Inside the try: a wedged transfer
+                # evicts the tick like a failed step instead of killing
+                # the worker.
+                toks = fetch_host([sampled])[0]
+        except Exception as exc:  # noqa: BLE001 - evict, don't die
+            # OOM first: a classified RESOURCE_EXHAUSTED (or injected
+            # action=oom) additionally latches the governor red and arms
+            # governed re-admission before the same full-eviction path
+            # below reclaims every page
+            self._on_oom("serving.decode", exc)
+            self._breaker.on_failure()
+            # the pool re-zero kills EVERY in-flight sequence's KV —
+            # chunked-prefilling slots included, not just this tick's
+            self._evict([(i, r) for i, r in enumerate(self._slots)
+                         if r is not None], exc)
+            return
+        with telemetry.span("decode.commit", _SPAN_CAT):
+            self._commit_step(active, toks, drafts, pages_before)
+
+    def _pack_step(self, active):
+        """The step's packed operand, the drafts by slot and (under the
+        KV audit) the pages in use before the step."""
         s = self.num_slots
         w = self._spec_w
         ps = self._cache.page_size
@@ -1603,39 +1679,13 @@ class DecodeEngine:
                 reqs=[[req.rid, req.tenant.tenant_id,
                        "prefill" if req.prefilling else "decode"]
                       for req in self._slots if req is not None])
-        policy = self._retry or resilience.default_policy()
+        return packed, drafts, pages_before
 
-        def attempt():
-            chaos.maybe_fail("serving.decode")
-            if self._pools_dead():
-                raise MXNetError(  # not transient: stop the retry loop
-                    "KV pools consumed by a failed step (donation); "
-                    "eviction required")
-            return telemetry.jit_call(
-                "serving.decode_step", self._step, self._params,
-                jnp.asarray(packed), self._cache.k_pool,
-                self._cache.v_pool, self._device_page_table())
-
-        try:
-            sampled, kp, vp = policy.call(attempt, site="serving.decode")
-            self._cache.swap_pools(kp, vp)
-            # the one per-token device->host sync of the plane: the
-            # sampled token ids must reach the host for EOS/stop checks
-            # and feedback. Inside the try: a wedged transfer evicts the
-            # tick like a failed step instead of killing the worker.
-            toks = fetch_host([sampled])[0]
-        except Exception as exc:  # noqa: BLE001 - evict, don't die
-            # OOM first: a classified RESOURCE_EXHAUSTED (or injected
-            # action=oom) additionally latches the governor red and arms
-            # governed re-admission before the same full-eviction path
-            # below reclaims every page
-            self._on_oom("serving.decode", exc)
-            self._breaker.on_failure()
-            # the pool re-zero kills EVERY in-flight sequence's KV —
-            # chunked-prefilling slots included, not just this tick's
-            self._evict([(i, r) for i, r in enumerate(self._slots)
-                         if r is not None], exc)
-            return
+    def _commit_step(self, active, toks, drafts, pages_before):
+        """Accept the step's tokens slot by slot, complete what finished,
+        and book the tick."""
+        s = self.num_slots
+        w = self._spec_w
         self._breaker.on_success()
         now = time.perf_counter()
         tpots = []
@@ -1719,9 +1769,8 @@ class DecodeEngine:
         self._tokens_total += total_new
         _T_TOKENS.inc(total_new, server=self._name)
         self._ticks += 1
-        occ = len(active) / float(s)
-        self._occ_sum += occ
-        _T_OCCUPANCY.set(occ, server=self._name)
+        self._slot_ticks += len(active)
+        _T_OCCUPANCY.set(len(active) / float(s), server=self._name)
         # MXNET_KVCACHE_AUDIT: re-prove the page refcount invariant at
         # every tick boundary, not just on cache mutations — seq_lens
         # advances and slot completion both ran above without a page-map

@@ -12,8 +12,10 @@ piece                 what it gives you
                       bounded-reservoir percentiles; free when
                       ``MXNET_TELEMETRY=0``
 :mod:`.spans`         ``telemetry.span("x")`` context manager/decorator —
-                      duration histograms in the registry AND chrome-trace
-                      events in the profiler buffer from one call site
+                      duration histograms in the registry, chrome-trace
+                      events in the profiler buffer AND ``mx.x``
+                      annotations in a live ``jax.profiler`` trace (the
+                      device timeline's clock) from one call site
 :mod:`.accounting`    the TPU-truth numbers: recompiles + compile seconds
                       per jit call site, device->host transfer count/bytes
                       per path, the serving steady-state-recompile gauge

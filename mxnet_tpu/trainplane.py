@@ -68,6 +68,10 @@ __all__ = ["TrainPlane", "fit", "module_plane", "mode", "train_dtype",
 
 _LOG = logging.getLogger(__name__)
 
+#: category of the planes' ``telemetry.span`` regions (``mx.train.*`` in a
+#: ``jax.profiler`` trace; docs/observability.md lists them)
+_SPAN_CAT = "trainplane"
+
 #: why planes fell back to eager, by coarse reason — the operator-visible
 #: record that MXNET_TRAINSTEP=auto quietly declined to compile something
 FALLBACKS = telemetry.counter(
@@ -458,8 +462,8 @@ class TrainPlane(_PlaneBase):
         loss_fn = self._loss
         cast = self._cast
 
-        def step(diff_vals, const_vals, states, ts, lrs, wds, extras,
-                 data, label, rng):
+        def mx_train_step(diff_vals, const_vals, states, ts, lrs, wds,
+                          extras, data, label, rng):
             if cast is not None and jnp.issubdtype(data.dtype, jnp.floating):
                 data = data.astype(cast)
 
@@ -480,7 +484,7 @@ class TrainPlane(_PlaneBase):
                 list(diff_vals), grads, states, ts, lrs, wds, extras)
             return loss, new_ws, new_sts, aux
 
-        return step
+        return mx_train_step
 
     # -- ZeRO: the sharded state plane inside the step jit ---------------
     def _zero_acquire(self, opt, updater):
@@ -524,8 +528,8 @@ class TrainPlane(_PlaneBase):
         loss_fn = self._loss
         cast = self._cast
 
-        def step(diff_vals, const_vals, buckets, tvs, lrvs, wdvs,
-                 data, label, rng):
+        def mx_train_step(diff_vals, const_vals, buckets, tvs, lrvs, wdvs,
+                          data, label, rng):
             if cast is not None and jnp.issubdtype(data.dtype, jnp.floating):
                 data = data.astype(cast)
 
@@ -546,7 +550,7 @@ class TrainPlane(_PlaneBase):
                 tvs, lrvs, wdvs)
             return loss, new_ws, new_buckets, aux
 
-        return step
+        return mx_train_step
 
     def _zero_graph_call(self, zp, opt, updater, fts, flrs, fwds,
                          d, l, rng):
@@ -554,38 +558,42 @@ class TrainPlane(_PlaneBase):
         weights replicated back onto the params, state buckets staying in
         their dp shards (``updater.states`` keeps the handles)."""
         ctx = self._trainer._contexts[0]
-        args = self._gather(updater, with_states=False)
-        tvs, lrvs, wdvs = zp.expand_scalars(fts, flrs, fwds)
-        argnums, consumed = self._donation(args["diff"], zp.buckets)
-        # zp.sig carries indices/plan/level/mesh/mp — the sharded twin of
-        # the replicated key's mp_flags: a row added after activation (or
-        # any relayout) must miss here, not reuse a jit whose closure
-        # holds the OLD plane's diff names and bucket layout
-        key = ("zero", zp.sig, tuple(d.shape), str(d.dtype),
-               tuple(l.shape), str(l.dtype), opt.rescale_grad,
-               opt.clip_gradient, argnums)
-        fn = self._jits.get(key)
-        if fn is None:
-            repl = NamedSharding(self._mesh, P())
-            fn = jax.jit(
-                self._build_zero_step(opt, zp),
-                out_shardings=(repl, [repl] * len(self._rows),
-                               zp.sharding_tree(), repl),
-                donate_argnums=(0, 2) if argnums else ())
-            self._jits[key] = fn
-        loss, new_ws, new_buckets, aux = telemetry.jit_call(
-            "trainplane.step", fn, args["diff"], args["const"],
-            zp.buckets, tvs, lrvs, wdvs, d, l, rng)
-        zp.buckets = new_buckets
-
-        params = self._net.collect_params()
-        for (_i, p), nw in zip(self._rows, new_ws):
-            p.data(ctx)._data = nw
-        for name, val in aux.items():
-            params[name].data(ctx)._data = val
-        self._invalidate_consumed(consumed, (new_ws, new_buckets))
-        telemetry.STEP_DISPATCHES.inc(plane="graph")
-        telemetry.sample_hbm()
+        with telemetry.span("train.gather", _SPAN_CAT):
+            args = self._gather(updater, with_states=False)
+            tvs, lrvs, wdvs = zp.expand_scalars(fts, flrs, fwds)
+            argnums, consumed = self._donation(args["diff"], zp.buckets)
+            # zp.sig carries indices/plan/level/mesh/mp — the sharded twin
+            # of the replicated key's mp_flags: a row added after
+            # activation (or any relayout) must miss here, not reuse a jit
+            # whose closure holds the OLD plane's diff names and bucket
+            # layout
+            key = ("zero", zp.sig, tuple(d.shape), str(d.dtype),
+                   tuple(l.shape), str(l.dtype), opt.rescale_grad,
+                   opt.clip_gradient, argnums)
+            fn = self._jits.get(key)
+            if fn is None:
+                repl = NamedSharding(self._mesh, P())
+                fn = jax.jit(
+                    self._build_zero_step(opt, zp),
+                    out_shardings=(repl, [repl] * len(self._rows),
+                                   zp.sharding_tree(), repl),
+                    donate_argnums=(0, 2) if argnums else ())
+                self._jits[key] = fn
+        with telemetry.span("train.dispatch", _SPAN_CAT):
+            loss, new_ws, new_buckets, aux = telemetry.jit_call(
+                "trainplane.step", fn, args["diff"], args["const"],
+                zp.buckets, tvs, lrvs, wdvs, d, l, rng)
+        with telemetry.span("train.commit", _SPAN_CAT):
+            zp.buckets = new_buckets
+            params = self._net.collect_params()
+            for (_i, p), nw in zip(self._rows, new_ws):
+                p.data(ctx)._data = nw
+            for name, val in aux.items():
+                params[name].data(ctx)._data = val
+            self._invalidate_consumed(consumed, (new_ws, new_buckets))
+            telemetry.STEP_DISPATCHES.inc(plane="graph")
+        with telemetry.span("train.hbm_sample", _SPAN_CAT):
+            telemetry.sample_hbm()
         return NDArray(loss, ctx)
 
     def _graph_step(self, data_nd, label_nd, batch_size):
@@ -596,28 +604,33 @@ class TrainPlane(_PlaneBase):
         from . import parallel
         from .fastpath import zero as zero_mod
 
-        opt.rescale_grad = tr._scale / batch_size  # Trainer.step parity
-        for i, p in self._rows:  # states for rows added after activation
-            if i not in updater.states:
-                updater.states[i] = opt.create_state_multi_precision(
-                    i, p.data(ctx))
-                updater.states_synced[i] = True
-        d = parallel.shard_to_mesh(data_nd, self._mesh, self._batch_axis)
-        l = parallel.shard_to_mesh(label_nd, self._mesh, self._batch_axis)
-        rng = _global_key()
-        indices = [i for i, _ in self._rows]
-
-        zp = self._zero_acquire(opt, updater)
+        with telemetry.span("train.shard", _SPAN_CAT):
+            d = parallel.shard_to_mesh(data_nd, self._mesh, self._batch_axis)
+            l = parallel.shard_to_mesh(label_nd, self._mesh,
+                                       self._batch_axis)
+        with telemetry.span("train.prologue", _SPAN_CAT):
+            opt.rescale_grad = tr._scale / batch_size  # Trainer.step parity
+            for i, p in self._rows:  # states for rows added after activation
+                if i not in updater.states:
+                    updater.states[i] = opt.create_state_multi_precision(
+                        i, p.data(ctx))
+                    updater.states_synced[i] = True
+            rng = _global_key()
+            indices = [i for i, _ in self._rows]
+            zp = self._zero_acquire(opt, updater)
+            if zp is not None:
+                # zero's float prologue — the SAME count/scalars sequence,
+                # plain floats for expand_scalars (no device scalar bounce)
+                fts, flrs, fwds = [], [], []
+                for i in indices:
+                    opt._update_count(i)
+                    lr, wd, _ex = opt._host_scalars(i)
+                    fts.append(float(opt._index_update_count[i]))
+                    flrs.append(float(lr))
+                    fwds.append(float(wd))
+            else:
+                ts, lrs, wds, extras = self._host_prologue(opt, indices)
         if zp is not None:
-            # zero's float prologue — the SAME count/scalars sequence,
-            # plain floats for expand_scalars (no device scalar bounce)
-            fts, flrs, fwds = [], [], []
-            for i in indices:
-                opt._update_count(i)
-                lr, wd, _ex = opt._host_scalars(i)
-                fts.append(float(opt._index_update_count[i]))
-                flrs.append(float(lr))
-                fwds.append(float(wd))
             try:
                 return self._zero_graph_call(zp, opt, updater,
                                              fts, flrs, fwds, d, l, rng)
@@ -647,35 +660,35 @@ class TrainPlane(_PlaneBase):
                 lrs = [_f32(x) for x in flrs]
                 wds = [_f32(x) for x in fwds]
                 extras = [() for _ in indices]
-        else:
-            ts, lrs, wds, extras = self._host_prologue(opt, indices)
-        mp_flags = tuple(self._mp_flags(opt, updater))
-        args = self._gather(updater)
-
-        argnums, consumed = self._donation(args["diff"], args["states"])
-        key = (tuple(d.shape), str(d.dtype), tuple(l.shape), str(l.dtype),
-               opt.rescale_grad, opt.clip_gradient, mp_flags, argnums,
-               tuple(len(e) for e in extras))
-        fn = self._jits.get(key)
-        if fn is None:
-            repl = NamedSharding(self._mesh, P())
-            fn = jax.jit(self._build_step(opt, mp_flags),
-                         out_shardings=(repl, repl, repl, repl),
-                         donate_argnums=(0, 2) if argnums else ())
-            self._jits[key] = fn
-        loss, new_ws, new_sts, aux = telemetry.jit_call(
-            "trainplane.step", fn, args["diff"], args["const"],
-            args["states"], ts, lrs, wds, extras, d, l, rng)
-
-        params = self._net.collect_params()
-        for (i, p), nw, ns in zip(self._rows, new_ws, new_sts):
-            p.data(ctx)._data = nw
-            updater.states[i] = ns
-        for name, val in aux.items():
-            params[name].data(ctx)._data = val
-        self._invalidate_consumed(consumed, (new_ws, new_sts))
-        telemetry.STEP_DISPATCHES.inc(plane="graph")
-        telemetry.sample_hbm()
+        with telemetry.span("train.gather", _SPAN_CAT):
+            mp_flags = tuple(self._mp_flags(opt, updater))
+            args = self._gather(updater)
+            argnums, consumed = self._donation(args["diff"], args["states"])
+            key = (tuple(d.shape), str(d.dtype), tuple(l.shape),
+                   str(l.dtype), opt.rescale_grad, opt.clip_gradient,
+                   mp_flags, argnums, tuple(len(e) for e in extras))
+            fn = self._jits.get(key)
+            if fn is None:
+                repl = NamedSharding(self._mesh, P())
+                fn = jax.jit(self._build_step(opt, mp_flags),
+                             out_shardings=(repl, repl, repl, repl),
+                             donate_argnums=(0, 2) if argnums else ())
+                self._jits[key] = fn
+        with telemetry.span("train.dispatch", _SPAN_CAT):
+            loss, new_ws, new_sts, aux = telemetry.jit_call(
+                "trainplane.step", fn, args["diff"], args["const"],
+                args["states"], ts, lrs, wds, extras, d, l, rng)
+        with telemetry.span("train.commit", _SPAN_CAT):
+            params = self._net.collect_params()
+            for (i, p), nw, ns in zip(self._rows, new_ws, new_sts):
+                p.data(ctx)._data = nw
+                updater.states[i] = ns
+            for name, val in aux.items():
+                params[name].data(ctx)._data = val
+            self._invalidate_consumed(consumed, (new_ws, new_sts))
+            telemetry.STEP_DISPATCHES.inc(plane="graph")
+        with telemetry.span("train.hbm_sample", _SPAN_CAT):
+            telemetry.sample_hbm()
         return NDArray(loss, ctx)
 
     # -- eager plane ----------------------------------------------------
@@ -704,7 +717,9 @@ class TrainPlane(_PlaneBase):
         if self._plane is None:
             self._activate(data_nd, label_nd, batch_size)
         self.step_count += 1
-        if self._plane == "graph":
+        with telemetry.span("train.step", _SPAN_CAT):
+            if self._plane != "graph":
+                return self._eager_step(data_nd, label_nd, batch_size)
             # devprof step scope: one coherent sampling decision for the
             # whole step so its device/host_gap split is honest. Eager
             # plane is deliberately unscoped — it dispatches op-by-op
@@ -718,7 +733,6 @@ class TrainPlane(_PlaneBase):
                     _devprof.note_train_step(
                         (time.perf_counter() - t0) * 1e3)
             return self._graph_step_guarded(data_nd, label_nd, batch_size)
-        return self._eager_step(data_nd, label_nd, batch_size)
 
     def _graph_step_guarded(self, data_nd, label_nd, batch_size):
         """Never-a-crash at the graph plane's own dispatch: a step
@@ -954,8 +968,8 @@ class _ModulePlane(_PlaneBase):
                 _global.set_train(prev)
             return tuple(outs), aux_updates
 
-        def step(diff_vals, const_vals, aux_vals, states, ts, lrs, wds,
-                 extras, rng):
+        def mx_train_step(diff_vals, const_vals, aux_vals, states, ts, lrs,
+                          wds, extras, rng):
             def f(dv):
                 av = dict(const_vals)
                 av.update(zip(diff_names, dv))
@@ -970,18 +984,20 @@ class _ModulePlane(_PlaneBase):
                 list(diff_vals), grads, states, ts, lrs, wds, extras)
             return outs, aux_updates, new_ws, new_sts
 
-        return step
+        return mx_train_step
 
     def step(self, batch):
         """One whole-graph training step for a DataBatch; fills the
         executor's outputs so ``update_metric`` reads them as usual."""
-        if _devprof.tick_begin():
-            t0 = time.perf_counter()
-            try:
-                return self._step(batch)
-            finally:
-                _devprof.note_train_step((time.perf_counter() - t0) * 1e3)
-        return self._step(batch)
+        with telemetry.span("train.step", _SPAN_CAT):
+            if _devprof.tick_begin():
+                t0 = time.perf_counter()
+                try:
+                    return self._step(batch)
+                finally:
+                    _devprof.note_train_step(
+                        (time.perf_counter() - t0) * 1e3)
+            return self._step(batch)
 
     def _step(self, batch):
         m = self._m
@@ -989,57 +1005,63 @@ class _ModulePlane(_PlaneBase):
         opt = m._optimizer
         updater = m._updater
         group = m._exec_group
-        # stage the batch into the (traced-operand) arg values
-        for name, arr in zip(group.data_names, batch.data):
-            exec_.arg_dict[name]._data = arr._data
-        if group.label_names and batch.label:
-            for name, arr in zip(group.label_names, batch.label):
+        with telemetry.span("train.shard", _SPAN_CAT):
+            # stage the batch into the (traced-operand) arg values
+            for name, arr in zip(group.data_names, batch.data):
                 exec_.arg_dict[name]._data = arr._data
-        for idx, name in self._entries:
-            if idx not in updater.states:
-                updater.states[idx] = opt.create_state_multi_precision(
-                    idx, exec_.arg_dict[name])
-                updater.states_synced[idx] = True
-        ts, lrs, wds, extras = self._host_prologue(
-            opt, [i for i, _ in self._entries])
-        mp_flags = tuple(self._mp_flags(opt))
-        args = self._args()
-        rng = _global_key()
-        argnums, consumed = self._donation(args["diff"], args["states"])
-        # const = fixed params + the staged batch; only the batch arrays
-        # can change shape between steps, so the sorted full-signature walk
-        # (O(n log n) host work on the one-dispatch hot path) is rebuilt
-        # only when the batch signature does
-        batch_sig = tuple((tuple(a.shape), str(a.dtype))
-                          for b in (batch.data, batch.label or ())
-                          for a in b)
-        if batch_sig != self._sig_batch:
-            self._sig = tuple(sorted((n, tuple(v.shape), str(v.dtype))
-                                     for n, v in args["const"].items()))
-            self._sig_batch = batch_sig
-        key = (self._sig, opt.rescale_grad, opt.clip_gradient, mp_flags,
-               argnums, tuple(len(e) for e in extras))
-        fn = self._jits.get(key)
-        if fn is None:
-            fn = jax.jit(self._build_step(opt, mp_flags),
-                         donate_argnums=(0, 3) if argnums else ())
-            self._jits[key] = fn
-        outs, aux_updates, new_ws, new_sts = telemetry.jit_call(
-            "trainplane.module_step", fn, args["diff"], args["const"],
-            args["aux"], args["states"], ts, lrs, wds, extras, rng)
-
-        for (i, n), nw, ns in zip(self._entries, new_ws, new_sts):
-            exec_.arg_dict[n]._data = nw
-            updater.states[i] = ns
-        for name, val in aux_updates.items():
-            if name in exec_.aux_dict:
-                exec_.aux_dict[name]._data = val
-        exec_.outputs = [NDArray(o, self._ctx) for o in outs]
-        exec_._output_shapes = [o.shape for o in outs]
-        exec_._residuals = None
-        m._params_dirty = True
-        self._invalidate_consumed(consumed, (new_ws, new_sts))
-        telemetry.STEP_DISPATCHES.inc(plane="graph")
+            if group.label_names and batch.label:
+                for name, arr in zip(group.label_names, batch.label):
+                    exec_.arg_dict[name]._data = arr._data
+        with telemetry.span("train.prologue", _SPAN_CAT):
+            for idx, name in self._entries:
+                if idx not in updater.states:
+                    updater.states[idx] = opt.create_state_multi_precision(
+                        idx, exec_.arg_dict[name])
+                    updater.states_synced[idx] = True
+            ts, lrs, wds, extras = self._host_prologue(
+                opt, [i for i, _ in self._entries])
+            rng = _global_key()
+        with telemetry.span("train.gather", _SPAN_CAT):
+            mp_flags = tuple(self._mp_flags(opt))
+            args = self._args()
+            argnums, consumed = self._donation(args["diff"], args["states"])
+            # const = fixed params + the staged batch; only the batch
+            # arrays can change shape between steps, so the sorted
+            # full-signature walk (O(n log n) host work on the
+            # one-dispatch hot path) is rebuilt only when the batch
+            # signature does
+            batch_sig = tuple((tuple(a.shape), str(a.dtype))
+                              for b in (batch.data, batch.label or ())
+                              for a in b)
+            if batch_sig != self._sig_batch:
+                self._sig = tuple(sorted(
+                    (n, tuple(v.shape), str(v.dtype))
+                    for n, v in args["const"].items()))
+                self._sig_batch = batch_sig
+            key = (self._sig, opt.rescale_grad, opt.clip_gradient, mp_flags,
+                   argnums, tuple(len(e) for e in extras))
+            fn = self._jits.get(key)
+            if fn is None:
+                fn = jax.jit(self._build_step(opt, mp_flags),
+                             donate_argnums=(0, 3) if argnums else ())
+                self._jits[key] = fn
+        with telemetry.span("train.dispatch", _SPAN_CAT):
+            outs, aux_updates, new_ws, new_sts = telemetry.jit_call(
+                "trainplane.module_step", fn, args["diff"], args["const"],
+                args["aux"], args["states"], ts, lrs, wds, extras, rng)
+        with telemetry.span("train.commit", _SPAN_CAT):
+            for (i, n), nw, ns in zip(self._entries, new_ws, new_sts):
+                exec_.arg_dict[n]._data = nw
+                updater.states[i] = ns
+            for name, val in aux_updates.items():
+                if name in exec_.aux_dict:
+                    exec_.aux_dict[name]._data = val
+            exec_.outputs = [NDArray(o, self._ctx) for o in outs]
+            exec_._output_shapes = [o.shape for o in outs]
+            exec_._residuals = None
+            m._params_dirty = True
+            self._invalidate_consumed(consumed, (new_ws, new_sts))
+            telemetry.STEP_DISPATCHES.inc(plane="graph")
         return exec_.outputs
 
 

@@ -111,3 +111,25 @@ def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
     fn, operands = _case(name, spec, jnp.dtype(dtype))
     compiled = jax.jit(fn).lower(*operands).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,kernel", [
+    ("paged_decode", "mx_paged_attn"),
+    ("paged_spec_verify", "mx_paged_attn"),
+    ("flash_attention", "mx_flash_attn"),
+])
+def test_kernel_keeps_its_name_in_the_compiled_program(
+        name, kernel, one_chip, compile_cache_off, monkeypatch):
+    """The custom call of a named ``pallas_call`` is the instruction
+    ``%<name>.<n>``: what a device trace's events are named by, and what
+    ``benchmark/layer_metrics/paged_attn_ms_per_tick.py`` looks for."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    fn, operands = _case(name, spec, jnp.dtype("float32"))
+    text = jax.jit(fn).lower(*operands).compile().as_text()
+    calls = [ln.split(" = ", 1)[0].split("%")[-1] for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert calls and all(c.startswith(kernel) for c in calls), calls

@@ -1,0 +1,356 @@
+"""``telemetry.span``'s third sink: the ``jax.profiler`` trace.
+
+A span under a live trace comes back from the xplane as ``mx.<name>`` with
+its arguments, from any thread; the train planes and the decode worker write
+the spans docs/observability.md lists, nested as it says; with telemetry
+off and no trace a span still makes no clock call. CPU only: the host plane
+is the same on every backend.
+"""
+import glob
+import os
+import threading
+
+import numpy as np
+import pytest
+
+import jax
+
+import mxnet_tpu as mx
+from mxnet_tpu import gluon, nd, serving, telemetry, trainplane
+from mxnet_tpu.gluon import nn
+from mxnet_tpu.telemetry import spans as spans_mod
+
+B = 8
+TRAIN_CHILDREN = ["mx.train.shard", "mx.train.prologue", "mx.train.gather",
+                  "mx.train.dispatch", "mx.train.commit",
+                  "mx.train.hbm_sample"]
+
+
+def _traced(tmp_path, fn):
+    """Run ``fn`` under a ``jax.profiler`` trace; the ``mx.*`` events of the
+    host planes as ``[(name, start_ns, end_ns, args, thread)]`` by start."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    out, thread = [], 0
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            thread += 1
+            for ev in line.events:
+                if ev.name.startswith(spans_mod.TRACE_PREFIX):
+                    out.append((ev.name, int(ev.start_ns),
+                                int(ev.start_ns + ev.duration_ns),
+                                dict(ev.stats), thread))
+    return sorted(out, key=lambda e: e[1])
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and child[2] <= parent[2] \
+        and child[4] == parent[4]
+
+
+def _assert_children_tile(events, parent_name, child_names, steps):
+    """Each child once per parent, inside it, in order, none overlapping."""
+    parents = _named(events, parent_name)
+    assert len(parents) == steps
+    for parent in parents:
+        kids = [e for e in events if e[0] != parent_name
+                and _inside(e, parent)]
+        assert [k[0] for k in kids] == child_names
+        for a, b in zip(kids, kids[1:]):
+            assert a[2] <= b[1], (a, b)
+
+
+# ---------------------------------------------------------------------------
+# the sink itself
+# ---------------------------------------------------------------------------
+
+
+def test_span_comes_back_from_the_trace_with_its_arguments(tmp_path):
+    def work():
+        with telemetry.span("t_trace_region", "t_cat", rung=128):
+            pass
+
+    (ev,) = _named(_traced(tmp_path, work), "mx.t_trace_region")
+    assert ev[3] == {"rung": 128}
+    assert ev[2] >= ev[1]
+
+
+def test_span_from_a_worker_thread_lands_on_its_own_line(tmp_path):
+    def work():
+        def worker():
+            with telemetry.span("t_trace_worker", slot=3):
+                pass
+
+        with telemetry.span("t_trace_main"):
+            th = threading.Thread(target=worker)
+            th.start()
+            th.join(timeout=30)
+            assert not th.is_alive()
+
+    events = _traced(tmp_path, work)
+    (main,) = _named(events, "mx.t_trace_main")
+    (worker,) = _named(events, "mx.t_trace_worker")
+    assert worker[3] == {"slot": 3}
+    assert worker[4] != main[4]
+
+
+def test_span_set_args_adds_what_is_known_inside_the_region(tmp_path):
+    def work():
+        with telemetry.span("t_trace_late", queued=1) as sp:
+            sp.set_args(active=5)
+
+    (ev,) = _named(_traced(tmp_path, work), "mx.t_trace_late")
+    assert ev[3] == {"queued": 1, "active": 5}
+    with telemetry.span("t_trace_late") as sp:  # no trace: dropped, no error
+        sp.set_args(active=5)
+
+
+def test_span_decorator_and_traced_annotate_with_the_registry_off(tmp_path):
+    @telemetry.span("t_trace_deco", "t_cat", k=2)
+    def deco():
+        return 1
+
+    @telemetry.traced("t_cat", lambda x: "t_trace_dyn_%d" % x)
+    def dyn(x):
+        return x
+
+    def work():
+        telemetry.set_enabled(False)
+        try:
+            assert deco() == 1 and dyn(7) == 7
+        finally:
+            telemetry.set_enabled(True)
+
+    events = _traced(tmp_path, work)
+    assert _named(events, "mx.t_trace_deco")[0][3] == {"k": 2}
+    assert len(_named(events, "mx.t_trace_dyn_7")) == 1
+    assert spans_mod.SPAN_MS.count(category="t_cat",
+                                   span="t_trace_deco") == 0
+
+
+def test_span_with_everything_off_makes_no_clock_call(monkeypatch):
+    def no_clock():
+        raise AssertionError("a disabled span read the clock")
+
+    @telemetry.span("t_off_deco")
+    def deco():
+        return 1
+
+    telemetry.set_enabled(False)
+    try:
+        monkeypatch.setattr(spans_mod.time, "perf_counter", no_clock)
+        with telemetry.span("t_off_region", rung=1) as sp:
+            sp.set_args(active=1)
+        assert deco() == 1
+    finally:
+        monkeypatch.undo()
+        telemetry.set_enabled(True)
+    assert spans_mod.SPAN_MS.count(category="span",
+                                   span="t_off_region") == 0
+
+
+# ---------------------------------------------------------------------------
+# the train planes
+# ---------------------------------------------------------------------------
+
+
+def _gluon_plane(prefix, hybridize=True):
+    rs = np.random.RandomState(11)
+    xs = rs.rand(4 * B, 6).astype(np.float32)
+    ys = rs.randint(0, 8, (4 * B,))
+    net = nn.HybridSequential(prefix=prefix)
+    with net.name_scope():
+        net.add(nn.Dense(16, activation="relu"))
+        net.add(nn.Dense(8))
+    net.initialize()
+    with mx.autograd.pause():
+        net(nd.array(xs[:B]))
+    if hybridize:
+        net.hybridize()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1, "momentum": 0.9})
+    plane = trainplane.TrainPlane(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                                  trainer)
+
+    def step(i):
+        lo = (i % 4) * B
+        return plane.step(nd.array(xs[lo:lo + B]), nd.array(ys[lo:lo + B]))
+
+    return plane, step
+
+
+def test_trainplane_children_tile_each_step(tmp_path, monkeypatch):
+    monkeypatch.setenv("MXNET_TRAINSTEP", "1")
+    plane, step = _gluon_plane("spg_")
+    step(0)  # activation and the compile stay outside the trace
+    assert plane.plane == "graph"
+    events = _traced(tmp_path, lambda: [step(i) for i in range(1, 4)])
+    _assert_children_tile(events, "mx.train.step", TRAIN_CHILDREN, steps=3)
+
+
+def test_eager_plane_gets_the_step_span_alone(tmp_path, monkeypatch):
+    monkeypatch.setenv("MXNET_TRAINSTEP", "0")
+    plane, step = _gluon_plane("spe_")
+    step(0)
+    assert plane.plane == "eager"
+    events = _traced(tmp_path, lambda: [step(i) for i in range(1, 3)])
+    assert len(_named(events, "mx.train.step")) == 2
+    assert not [e for e in events if e[0] in TRAIN_CHILDREN]
+
+
+def test_module_plane_children_tile_each_step(tmp_path, monkeypatch):
+    from mxnet_tpu import io as io_mod
+    from mxnet_tpu.module import Module
+
+    monkeypatch.setenv("MXNET_TRAINSTEP", "1")
+    rs = np.random.RandomState(13)
+    xs = rs.rand(4 * B, 6).astype(np.float32)
+    ys = rs.randint(0, 4, (4 * B,)).astype(np.float32)
+    data = mx.sym.var("data")
+    fc1 = mx.sym.FullyConnected(data, num_hidden=16, name="fc1")
+    act = mx.sym.Activation(fc1, act_type="relu")
+    fc2 = mx.sym.FullyConnected(act, num_hidden=4, name="fc2")
+    it = io_mod.NDArrayIter(xs, ys, batch_size=B)
+    mod = Module(mx.sym.SoftmaxOutput(fc2, name="softmax"),
+                 context=mx.cpu())
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params()
+    mod.init_optimizer(optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1})
+    plane = trainplane.module_plane(mod)
+    assert plane is not None
+    batches = list(it)
+    plane.step(batches[0])  # the compile stays outside the trace
+    events = _traced(tmp_path, lambda: [plane.step(b) for b in batches[1:]])
+    # the module plane stages the batch where the gluon plane shards it,
+    # and samples no HBM
+    _assert_children_tile(events, "mx.train.step", TRAIN_CHILDREN[:-1],
+                          steps=3)
+
+
+# ---------------------------------------------------------------------------
+# the decode worker
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    model = serving.TinyDecoder(vocab_size=32, num_layers=2, num_heads=4,
+                                head_dim=8, num_kv_heads=2)
+    return model, model.init_params(0)
+
+
+def _engine(tiny, **kw):
+    model, params = tiny
+    kw.setdefault("num_slots", 3)
+    kw.setdefault("max_seq_len", 48)
+    kw.setdefault("prefill_buckets", (8, 16))
+    kw.setdefault("timeout_ms", 0)
+    kw.setdefault("prefix_cache", False)
+    return serving.DecodeEngine(model, params, **kw)
+
+
+def _prompts(n, seed=5):
+    rng = np.random.RandomState(seed)
+    return [(rng.randint(1, 32, int(rng.randint(2, 12))).astype(np.int32),
+             int(rng.randint(3, 9))) for _ in range(n)]
+
+
+def test_decode_tick_spans_carry_the_slots_in_use(tiny, tmp_path):
+    with _engine(tiny, name="spans_tick") as eng:
+        eng.warmup()
+
+        def work():
+            futs = [eng.submit(p, m) for p, m in _prompts(7)]
+            for f in futs:
+                f.result(timeout=120)
+
+        events = _traced(tmp_path, work)
+        stats = eng.stats()
+    ticks = _named(events, "mx.decode.tick")
+    stepped = [t for t in ticks if t[3].get("active")]
+    assert stepped and len(stepped) <= stats["ticks"]
+    for t in ticks:
+        kids = [e for e in events if e is not t and _inside(e, t)]
+        names = [k[0] for k in kids]
+        assert names[0] == "mx.decode.housekeep"
+        if "active" not in t[3]:
+            continue
+        assert set(t[3]) == {"active", "prefilling", "queued"}
+        assert 0 <= t[3]["active"] <= eng.num_slots
+        step = ["mx.decode.pack", "mx.decode.dispatch", "mx.decode.fetch",
+                "mx.decode.commit"]
+        assert [n for n in names if n in step] == \
+            (step if t[3]["active"] else [])
+        assert "mx.decode.admit" in names
+    # every span of the worker lies inside one of its passes
+    for e in events:
+        if e[0].startswith("mx.decode.") and e[0] != "mx.decode.tick":
+            assert any(_inside(e, t) for t in ticks), e
+    # monolithic prefill runs inside the admission pass, under its rung
+    prefills = _named(events, "mx.decode.prefill")
+    assert len(prefills) == 7
+    assert {p[3]["rung"] for p in prefills} <= {8, 16}
+    admits = _named(events, "mx.decode.admit")
+    assert all(any(_inside(p, a) for a in admits) for p in prefills)
+    # `active` is what the step decoded: summed over the traced passes it
+    # is the engine's own slot_ticks (the whole soak ran under the trace)
+    assert sum(t[3]["active"] for t in stepped) == stats["slot_ticks"]
+
+
+def test_decode_chunked_prefill_span_carries_the_chunk(tiny, tmp_path):
+    with _engine(tiny, name="spans_chunk", prefill_chunk=8) as eng:
+        eng.warmup()
+        prompt = np.arange(1, 20, dtype=np.int32)
+        events = _traced(
+            tmp_path, lambda: eng.submit(prompt, 3).result(timeout=120))
+    prefills = _named(events, "mx.decode.prefill")
+    assert len(prefills) == 3  # 19 tokens, 8 a chunk, one chunk a pass
+    assert all(p[3] == {"chunk": 8} for p in prefills)
+    assert any(t[3].get("prefilling") == 1
+               for t in _named(events, "mx.decode.tick"))
+
+
+def test_stats_ticks_and_slot_ticks_give_the_occupancy(tiny):
+    with _engine(tiny, name="spans_stats") as eng:
+        eng.warmup()
+        seen = [(0, 0)]
+        for wave in range(3):
+            futs = [eng.submit(p, m) for p, m in _prompts(4, seed=wave)]
+            for f in futs:
+                f.result(timeout=120)
+            st = eng.stats()
+            seen.append((st["ticks"], st["slot_ticks"]))
+            assert st["slot_occupancy"] == pytest.approx(
+                st["slot_ticks"] / float(st["ticks"] * eng.num_slots),
+                abs=1e-12)
+            assert 0.0 < st["slot_occupancy"] <= 1.0
+    for (t0, s0), (t1, s1) in zip(seen, seen[1:]):
+        assert t1 > t0 and s1 > s0        # monotonic: a window is a delta
+        assert s1 - s0 <= (t1 - t0) * eng.num_slots
+
+
+def test_device_programs_carry_stable_names(tiny):
+    with _engine(tiny, name="spans_names") as eng:
+        names = [fn.__name__ for fn in (eng._step, eng._prefill_jit,
+                                        eng._chunk_jit, eng._cow_jit)]
+    assert names == ["mx_decode_step", "mx_prefill", "mx_prefill_chunk",
+                     "mx_kv_cow"]
+    plane, step = _gluon_plane("spn_")
+    step(0)
+    if plane.plane == "graph":
+        assert [fn.__name__ for fn in plane._jits.values()] == \
+            ["mx_train_step"]
