@@ -54,6 +54,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import autograd, telemetry
@@ -96,10 +97,6 @@ def sharded_feed() -> bool:
     """Whether :func:`fit` pre-shards batches over the mesh
     (``MXNET_SHARDED_FEED``, default on)."""
     return bool(get_env("MXNET_SHARDED_FEED", 1, int, cache=False))
-
-
-def _f32(x):
-    return jnp.asarray(x, dtype=jnp.float32)
 
 
 def _default_mesh(batch_size: int):
@@ -150,9 +147,29 @@ def _backend_refusal(plane: str, exc: BaseException) -> bool:
         and not hbm_mod.oom_survival(plane, exc, dump=True)
 
 
+def _unpack_scalars(scalars):
+    """In-trace inverse of :meth:`_PlaneBase._host_prologue`'s packing: the
+    per-row ``ts, lrs, wds`` lists ``fastpath.tree_kernel`` takes, each
+    element a float32 scalar sliced by STATIC index out of the ``(3, n)``
+    operand — the same bits the eager plane's per-row scalars carry."""
+    return tuple([row[k] for k in range(scalars.shape[1])]
+                 for row in scalars)
+
+
 class _PlaneBase(object):
     """Shared jit plumbing of the gluon and Module planes: host prologue,
-    donation bookkeeping, dispatch accounting."""
+    donation bookkeeping, dispatch accounting.
+
+    The per-row optimizer scalars (update count, learning rate, weight
+    decay) reach the step program as ONE host ``numpy`` array of shape
+    ``(3, rows)``, an ordinary operand of the jit call: the dispatch
+    transfers it (and, on a mesh, replicates it) once, where one device
+    scalar per value was three host->device puts a row — 483 a step for
+    ResNet-50, most of the step's host time on the chip. The values change
+    every step and the operand's shape never does, so a schedule never
+    retraces. ``extras`` (Nadam's schedule scalars, SGLD's key) stay
+    outside the pack: they are the optimizer's own device values, of the
+    optimizer's own dtypes."""
 
     @staticmethod
     def _probe_optimizer(opt):
@@ -173,18 +190,38 @@ class _PlaneBase(object):
 
     def _host_prologue(self, optimizer, indices):
         """Per-index counting + scalar prologue — EXACTLY the sequence the
-        eager ``fastpath.fused_apply`` runs, in the same order, so the
-        in-graph update consumes bit-identical scalars (Adam's host f64
-        bias correction included)."""
+        eager ``fastpath.fused_apply`` runs, in the same order
+        (``_update_count(i)`` then ``_host_scalars(i)``, index by index:
+        Nadam's ``m_schedule`` recurrence and SGLD's key draws depend on
+        it), so the in-graph update consumes bit-identical scalars (Adam's
+        host f64 bias correction included).
+
+        Returns ``(scalars, extras)``. ``scalars`` is one host
+        ``numpy.ndarray`` of shape ``(3, len(indices))``, float32, rows
+        ``t, lr, wd``: Python numbers rounded to float32 exactly as the
+        eager plane's ``jnp.asarray(x, float32)`` rounds them, and no
+        device put — the array travels as an operand of the step's jit
+        call and :func:`_unpack_scalars` slices it in-trace (the ZeRO
+        branch reads its rows on the host). ``extras`` is the per-index
+        list of the optimizer's own extra operands, untouched: empty for
+        SGD, Adam, Adamax, ...; Nadam's four device scalars and SGLD's key
+        are made by the optimizer (its own puts) and are not folded in."""
         ts, lrs, wds, extras = [], [], [], []
         for i in indices:
             optimizer._update_count(i)
             lr, wd, ex = optimizer._host_scalars(i)
-            ts.append(_f32(optimizer._index_update_count[i]))
-            lrs.append(_f32(lr))
-            wds.append(_f32(wd))
+            ts.append(optimizer._index_update_count[i])
+            lrs.append(lr)
+            wds.append(wd)
             extras.append(tuple(ex))
-        return ts, lrs, wds, extras
+        return np.array([ts, lrs, wds], dtype=np.float32), extras
+
+    @staticmethod
+    def _prologue_puts(extras):
+        """Host->device transfers the prologue issued: none for the packed
+        scalars, one per device value the optimizer put in a row's
+        ``extras`` (the ``puts`` argument of ``mx.train.prologue``)."""
+        return sum(isinstance(x, jax.Array) for ex in extras for x in ex)
 
     def _donation(self, diff_vals, states):
         """(argnums_ok, consumed) — the shared ``fastpath.fused`` donation
@@ -389,7 +426,7 @@ class TrainPlane(_PlaneBase):
 
         probe_opt = self._probe_optimizer(opt)
         probe_opt.rescale_grad = tr._scale / batch_size
-        ts, lrs, wds, extras = self._host_prologue(
+        scalars, extras = self._host_prologue(
             probe_opt, [i for i, _ in rows])
         step_fn = self._build_step(probe_opt, tuple(
             self._mp_flags(probe_opt, updater)))
@@ -408,7 +445,7 @@ class TrainPlane(_PlaneBase):
             else jnp.asarray(label_nd)
         avals = jax.tree_util.tree_map(
             _aval, (raw_diff, raw_const, raw_states,
-                    ts, lrs, wds, extras, d, l, _global_key()))
+                    scalars, extras, d, l, _global_key()))
         jax.eval_shape(step_fn, *avals)
 
     def _mp_flags(self, optimizer, updater):
@@ -462,8 +499,8 @@ class TrainPlane(_PlaneBase):
         loss_fn = self._loss
         cast = self._cast
 
-        def mx_train_step(diff_vals, const_vals, states, ts, lrs, wds,
-                          extras, data, label, rng):
+        def mx_train_step(diff_vals, const_vals, states, scalars, extras,
+                          data, label, rng):
             if cast is not None and jnp.issubdtype(data.dtype, jnp.floating):
                 data = data.astype(cast)
 
@@ -480,6 +517,7 @@ class TrainPlane(_PlaneBase):
             loss, vjp_fn, aux = jax.vjp(f, list(diff_vals), has_aux=True)
             # the same all-ones cotangent loss.backward() seeds eagerly
             (grads,) = vjp_fn(jnp.ones(loss.shape, loss.dtype))
+            ts, lrs, wds = _unpack_scalars(scalars)
             new_ws, new_sts = kernel(
                 list(diff_vals), grads, states, ts, lrs, wds, extras)
             return loss, new_ws, new_sts, aux
@@ -552,15 +590,14 @@ class TrainPlane(_PlaneBase):
 
         return mx_train_step
 
-    def _zero_graph_call(self, zp, opt, updater, fts, flrs, fwds,
-                         d, l, rng):
+    def _zero_graph_call(self, zp, opt, updater, scalars, d, l, rng):
         """Dispatch one sharded whole-step jit and commit its outputs:
         weights replicated back onto the params, state buckets staying in
         their dp shards (``updater.states`` keeps the handles)."""
         ctx = self._trainer._contexts[0]
         with telemetry.span("train.gather", _SPAN_CAT):
             args = self._gather(updater, with_states=False)
-            tvs, lrvs, wdvs = zp.expand_scalars(fts, flrs, fwds)
+            tvs, lrvs, wdvs = zp.expand_scalars(*scalars)
             argnums, consumed = self._donation(args["diff"], zp.buckets)
             # zp.sig carries indices/plan/level/mesh/mp — the sharded twin
             # of the replicated key's mp_flags: a row added after
@@ -608,7 +645,7 @@ class TrainPlane(_PlaneBase):
             d = parallel.shard_to_mesh(data_nd, self._mesh, self._batch_axis)
             l = parallel.shard_to_mesh(label_nd, self._mesh,
                                        self._batch_axis)
-        with telemetry.span("train.prologue", _SPAN_CAT):
+        with telemetry.span("train.prologue", _SPAN_CAT) as prologue:
             opt.rescale_grad = tr._scale / batch_size  # Trainer.step parity
             for i, p in self._rows:  # states for rows added after activation
                 if i not in updater.states:
@@ -616,27 +653,17 @@ class TrainPlane(_PlaneBase):
                         i, p.data(ctx))
                     updater.states_synced[i] = True
             rng = _global_key()
-            indices = [i for i, _ in self._rows]
             zp = self._zero_acquire(opt, updater)
-            if zp is not None:
-                # zero's float prologue — the SAME count/scalars sequence,
-                # plain floats for expand_scalars (no device scalar bounce)
-                fts, flrs, fwds = [], [], []
-                for i in indices:
-                    opt._update_count(i)
-                    lr, wd, _ex = opt._host_scalars(i)
-                    fts.append(float(opt._index_update_count[i]))
-                    flrs.append(float(lr))
-                    fwds.append(float(wd))
-            else:
-                ts, lrs, wds, extras = self._host_prologue(opt, indices)
+            scalars, extras = self._host_prologue(
+                opt, [i for i, _ in self._rows])
+            prologue.set_args(puts=self._prologue_puts(extras))
         if zp is not None:
             try:
-                return self._zero_graph_call(zp, opt, updater,
-                                             fts, flrs, fwds, d, l, rng)
+                return self._zero_graph_call(zp, opt, updater, scalars,
+                                             d, l, rng)
             except Exception as exc:  # noqa: BLE001 - never-a-crash: the
                 # sharded trace failing must not kill training; the
-                # replicated step below reuses the SAME prologue scalars
+                # replicated step below reuses the SAME prologue values
                 # (counters already advanced — no double count)
                 from .resilience import hbm as hbm_mod
 
@@ -656,10 +683,6 @@ class TrainPlane(_PlaneBase):
                         updater.states[i] = \
                             opt.create_state_multi_precision(i, p.data(ctx))
                         updater.states_synced[i] = True
-                ts = [_f32(t) for t in fts]
-                lrs = [_f32(x) for x in flrs]
-                wds = [_f32(x) for x in fwds]
-                extras = [() for _ in indices]
         with telemetry.span("train.gather", _SPAN_CAT):
             mp_flags = tuple(self._mp_flags(opt, updater))
             args = self._gather(updater)
@@ -677,7 +700,7 @@ class TrainPlane(_PlaneBase):
         with telemetry.span("train.dispatch", _SPAN_CAT):
             loss, new_ws, new_sts, aux = telemetry.jit_call(
                 "trainplane.step", fn, args["diff"], args["const"],
-                args["states"], ts, lrs, wds, extras, d, l, rng)
+                args["states"], scalars, extras, d, l, rng)
         with telemetry.span("train.commit", _SPAN_CAT):
             params = self._net.collect_params()
             for (i, p), nw, ns in zip(self._rows, new_ws, new_sts):
@@ -922,13 +945,13 @@ class _ModulePlane(_PlaneBase):
                 updater.states[idx] = m._optimizer \
                     .create_state_multi_precision(idx, exec_.arg_dict[name])
                 updater.states_synced[idx] = True
-        ts, lrs, wds, extras = self._host_prologue(
+        scalars, extras = self._host_prologue(
             opt, [i for i, _ in self._entries])
         step_fn = self._build_step(opt, tuple(self._mp_flags(opt)))
         args = self._args()
         avals = jax.tree_util.tree_map(
             _aval, (args["diff"], args["const"], args["aux"],
-                    args["states"], ts, lrs, wds, extras, _global_key()))
+                    args["states"], scalars, extras, _global_key()))
         jax.eval_shape(step_fn, *avals)
 
     def _mp_flags(self, optimizer):
@@ -968,8 +991,8 @@ class _ModulePlane(_PlaneBase):
                 _global.set_train(prev)
             return tuple(outs), aux_updates
 
-        def mx_train_step(diff_vals, const_vals, aux_vals, states, ts, lrs,
-                          wds, extras, rng):
+        def mx_train_step(diff_vals, const_vals, aux_vals, states, scalars,
+                          extras, rng):
             def f(dv):
                 av = dict(const_vals)
                 av.update(zip(diff_names, dv))
@@ -980,6 +1003,7 @@ class _ModulePlane(_PlaneBase):
             # backward(out_grads=None) parity: all-ones head gradients
             (grads,) = vjp_fn(tuple(
                 jnp.ones(o.shape, o.dtype) for o in outs))
+            ts, lrs, wds = _unpack_scalars(scalars)
             new_ws, new_sts = kernel(
                 list(diff_vals), grads, states, ts, lrs, wds, extras)
             return outs, aux_updates, new_ws, new_sts
@@ -1012,14 +1036,15 @@ class _ModulePlane(_PlaneBase):
             if group.label_names and batch.label:
                 for name, arr in zip(group.label_names, batch.label):
                     exec_.arg_dict[name]._data = arr._data
-        with telemetry.span("train.prologue", _SPAN_CAT):
+        with telemetry.span("train.prologue", _SPAN_CAT) as prologue:
             for idx, name in self._entries:
                 if idx not in updater.states:
                     updater.states[idx] = opt.create_state_multi_precision(
                         idx, exec_.arg_dict[name])
                     updater.states_synced[idx] = True
-            ts, lrs, wds, extras = self._host_prologue(
+            scalars, extras = self._host_prologue(
                 opt, [i for i, _ in self._entries])
+            prologue.set_args(puts=self._prologue_puts(extras))
             rng = _global_key()
         with telemetry.span("train.gather", _SPAN_CAT):
             mp_flags = tuple(self._mp_flags(opt))
@@ -1048,7 +1073,7 @@ class _ModulePlane(_PlaneBase):
         with telemetry.span("train.dispatch", _SPAN_CAT):
             outs, aux_updates, new_ws, new_sts = telemetry.jit_call(
                 "trainplane.module_step", fn, args["diff"], args["const"],
-                args["aux"], args["states"], ts, lrs, wds, extras, rng)
+                args["aux"], args["states"], scalars, extras, rng)
         with telemetry.span("train.commit", _SPAN_CAT):
             for (i, n), nw, ns in zip(self._entries, new_ws, new_sts):
                 exec_.arg_dict[n]._data = nw

@@ -167,7 +167,7 @@ def test_span_with_everything_off_makes_no_clock_call(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _gluon_plane(prefix, hybridize=True):
+def _gluon_plane(prefix, hybridize=True, opt="sgd", opt_params=None):
     rs = np.random.RandomState(11)
     xs = rs.rand(4 * B, 6).astype(np.float32)
     ys = rs.randint(0, 8, (4 * B,))
@@ -180,7 +180,7 @@ def _gluon_plane(prefix, hybridize=True):
         net(nd.array(xs[:B]))
     if hybridize:
         net.hybridize()
-    trainer = gluon.Trainer(net.collect_params(), "sgd",
+    trainer = gluon.Trainer(net.collect_params(), opt, opt_params or
                             {"learning_rate": 0.1, "momentum": 0.9})
     plane = trainplane.TrainPlane(net, gluon.loss.SoftmaxCrossEntropyLoss(),
                                   trainer)
@@ -199,6 +199,31 @@ def test_trainplane_children_tile_each_step(tmp_path, monkeypatch):
     assert plane.plane == "graph"
     events = _traced(tmp_path, lambda: [step(i) for i in range(1, 4)])
     _assert_children_tile(events, "mx.train.step", TRAIN_CHILDREN, steps=3)
+
+
+@pytest.mark.parametrize("opt,opt_params,puts_per_row", [
+    ("sgd", {"learning_rate": 0.1, "momentum": 0.9}, 0),
+    # Nadam's four schedule scalars a row are the optimizer's own puts
+    ("nadam", {"learning_rate": 0.01}, 4),
+])
+def test_train_prologue_span_carries_its_puts(tmp_path, monkeypatch, opt,
+                                              opt_params, puts_per_row):
+    """``puts``: the host->device transfers the prologue issued — none for
+    the packed t/lr/wd operand, one per device value in ``extras``."""
+    monkeypatch.setenv("MXNET_TRAINSTEP", "1")
+    plane, step = _gluon_plane("spp_%s_" % opt, opt=opt,
+                               opt_params=opt_params)
+    step(0)
+    assert plane.plane == "graph"
+    events = _traced(tmp_path, lambda: [step(i) for i in range(1, 4)])
+    prologues = _named(events, "mx.train.prologue")
+    assert len(prologues) == 3
+    for ev in prologues:
+        assert ev[3] == {"puts": puts_per_row * len(plane._rows)}
+    # no other child of the step carries an argument
+    for name in TRAIN_CHILDREN:
+        if name != "mx.train.prologue":
+            assert all(not e[3] for e in _named(events, name))
 
 
 def test_eager_plane_gets_the_step_span_alone(tmp_path, monkeypatch):
@@ -239,6 +264,8 @@ def test_module_plane_children_tile_each_step(tmp_path, monkeypatch):
     # and samples no HBM
     _assert_children_tile(events, "mx.train.step", TRAIN_CHILDREN[:-1],
                           steps=3)
+    assert [e[3] for e in _named(events, "mx.train.prologue")] == \
+        [{"puts": 0}] * 3
 
 
 # ---------------------------------------------------------------------------

@@ -59,6 +59,9 @@ def _data(seed=3):
 @pytest.mark.parametrize("opt,opt_params,ndev,bitwise", [
     ("sgd", {"learning_rate": 0.1, "momentum": 0.9}, 1, True),
     ("adam", {"learning_rate": 0.01}, 1, True),
+    # a stateful host prologue (the m_schedule recurrence) and four device
+    # scalars of its own in ``extras``, beside the packed t/lr/wd operand
+    ("nadam", {"learning_rate": 0.01}, 1, True),
     # on a sharded mesh the dp-partial gradient reduction (per-device
     # matmul + psum) can differ from the single-device contraction order
     # by 1 ulp — the update math itself is still the identical kernel, so
@@ -120,6 +123,14 @@ def test_graph_plane_matches_eager_fastpath(monkeypatch, opt, opt_params,
     st_g = tr_g._updaters[0].states
     st_e = tr_e._updaters[0].states
     assert set(st_g) == set(st_e)
+    if bitwise:
+        for k in st_e:
+            le_, lg_ = (jax.tree_util.tree_leaves(st[k])
+                        for st in (st_e, st_g))
+            assert len(le_) == len(lg_)
+            for a, b in zip(le_, lg_):
+                np.testing.assert_array_equal(np.asarray(b), np.asarray(a),
+                                              err_msg="state %s" % k)
 
 
 def test_graph_plane_one_dispatch_per_step(monkeypatch):
@@ -143,6 +154,248 @@ def test_graph_plane_one_dispatch_per_step(monkeypatch):
     assert telemetry.STEP_DISPATCHES.value(plane="graph") - g0 == 3
     assert (telemetry.OPT_DISPATCHES.value(path="perparam")
             + telemetry.OPT_DISPATCHES.value(path="fused")) - o0 == 0
+
+
+# ---------------------------------------------------------------------------
+# the packed scalar operand (PR 26): one host array, no device put
+# ---------------------------------------------------------------------------
+
+
+def _mlp_plane(monkeypatch, prefix, opt="sgd", opt_params=None, ndev=1):
+    monkeypatch.setenv("MXNET_TRAINSTEP", "1")
+    xs, ys = _data(17)
+    net = _make_mlp(prefix)
+    _init(net, xs)
+    tr = gluon.Trainer(net.collect_params(), opt, dict(
+        opt_params or {"learning_rate": 0.1, "momentum": 0.9, "wd": 1e-3}))
+    plane = trainplane.TrainPlane(net, gluon.loss.SoftmaxCrossEntropyLoss(),
+                                  tr, mesh=parallel.device_mesh(ndev))
+
+    def step(s):
+        return plane.step(nd.array(xs[s * B:(s + 1) * B]),
+                          nd.array(ys[s * B:(s + 1) * B]))
+
+    return plane, tr, step
+
+
+def test_host_prologue_packs_one_float32_host_array(monkeypatch):
+    """``_host_prologue`` hands back ONE numpy (3, rows) float32 array —
+    t, lr, wd, rounded as the eager plane's ``jnp.asarray(x, float32)``
+    rounds them — and counts each index exactly once."""
+    plane, tr, step = _mlp_plane(monkeypatch, "pack_")
+    step(0)
+    opt = tr._optimizer
+    indices = [i for i, _ in plane._rows]
+    before = dict(opt._index_update_count)
+    scalars, extras = plane._host_prologue(opt, indices)
+    assert type(scalars) is np.ndarray
+    assert scalars.shape == (3, len(indices))
+    assert scalars.dtype == np.float32
+    assert extras == [()] * len(indices)
+    assert plane._prologue_puts(extras) == 0
+    for k, i in enumerate(indices):
+        assert opt._index_update_count[i] == before[i] + 1
+        want = [jnp.asarray(v, dtype=jnp.float32) for v in (
+            opt._index_update_count[i], opt._get_lr(i), opt._get_wd(i))]
+        np.testing.assert_array_equal(scalars[:, k], np.asarray(want))
+    # in-trace unpacking: the per-row lists tree_kernel takes, same bits
+    ts, lrs, wds = jax.jit(trainplane._unpack_scalars)(scalars)
+    assert len(ts) == len(lrs) == len(wds) == len(indices)
+    np.testing.assert_array_equal(np.asarray([ts, lrs, wds]), scalars)
+
+
+@pytest.mark.parametrize("opt,opt_params,puts_per_row", [
+    ("sgd", {"learning_rate": 0.1, "momentum": 0.9}, 0),
+    ("adam", {"learning_rate": 0.01}, 0),
+    # Nadam's four schedule scalars are the optimizer's own device values
+    ("nadam", {"learning_rate": 0.01}, 4),
+])
+def test_prologue_span_issues_no_device_put(monkeypatch, opt, opt_params,
+                                            puts_per_row):
+    """Inside ``train.prologue`` the plane itself makes no host->device
+    transfer: ``jnp.asarray`` and ``jax.device_put`` are not called from
+    ``trainplane`` at all, and the span's ``puts`` argument counts only
+    what the optimizer put in ``extras``."""
+    plane, tr, step = _mlp_plane(monkeypatch, "noput_%s_" % opt, opt,
+                                 opt_params)
+    step(0)  # activation, probe and compile stay outside the count
+    assert plane.plane == "graph"
+    seen = {"open": False, "calls": 0, "puts": []}
+
+    class _Watch(telemetry.span):
+        __slots__ = ()
+
+        def __enter__(self):
+            if self.name == "train.prologue":
+                seen["open"] = True
+            return super().__enter__()
+
+        def set_args(self, **args):
+            if self.name == "train.prologue":
+                seen["puts"].append(args["puts"])
+            return super().set_args(**args)
+
+        def __exit__(self, *exc):
+            if self.name == "train.prologue":
+                seen["open"] = False
+            return super().__exit__(*exc)
+
+    def counting(real):
+        def wrapper(*a, **k):
+            seen["calls"] += seen["open"]
+            return real(*a, **k)
+        return wrapper
+
+    monkeypatch.setattr(telemetry, "span", _Watch)
+    monkeypatch.setattr(trainplane.jnp, "asarray",
+                        counting(jnp.asarray))
+    monkeypatch.setattr(trainplane.jax, "device_put",
+                        counting(jax.device_put))
+    for s in range(1, 4):
+        step(s)
+    rows = len(plane._rows)
+    assert seen["puts"] == [puts_per_row * rows] * 3
+    if puts_per_row == 0:
+        assert seen["calls"] == 0
+    else:  # the counter is live: the optimizer's own puts pass through it
+        assert seen["calls"] >= 3 * puts_per_row * rows
+
+
+def test_changing_lr_travels_in_the_operand_without_retrace(monkeypatch):
+    """A learning-rate schedule changes the VALUES of the packed operand
+    every step and nothing of its type: one jit entry, one trace of it, no
+    recompile — and the weights still follow the eager loop bit for bit,
+    so the step reads this step's values."""
+    from mxnet_tpu import lr_scheduler
+
+    xs, ys = _data(23)
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+
+    def make(prefix):
+        net = _make_mlp(prefix)
+        _init(net, xs)
+        opt = mx.optimizer.create(
+            "sgd", learning_rate=0.4, momentum=0.9,
+            lr_scheduler=lr_scheduler.FactorScheduler(step=1, factor=0.7))
+        return net, opt, gluon.Trainer(net.collect_params(), opt)
+
+    net_e, opt_e, tr_e = make("lre_")
+    net_e.hybridize()
+    net_g, opt_g, tr_g = make("lrg_")
+    _copy_params(net_e, net_g)
+    monkeypatch.setenv("MXNET_TRAINSTEP", "1")
+    plane = trainplane.TrainPlane(net_g, loss_fn, tr_g,
+                                  mesh=parallel.device_mesh(1))
+
+    def both(s):
+        x, y = nd.array(xs[(s % 5) * B:(s % 5 + 1) * B]), \
+            nd.array(ys[(s % 5) * B:(s % 5 + 1) * B])
+        with mx.autograd.record():
+            le = loss_fn(net_e(x), y)
+        le.backward()
+        tr_e.step(B)
+        plane.step(x, y)
+
+    both(0)  # activate + the one compile
+    assert plane.plane == "graph"
+    r0 = telemetry.RECOMPILES.value(site="trainplane.step")
+    lrs = []
+    for s in range(1, 6):
+        both(s)
+        lrs.append(opt_g.learning_rate)
+    assert len(set(lrs)) == 5  # the schedule moved on every step
+    assert telemetry.RECOMPILES.value(site="trainplane.step") - r0 == 0
+    assert len(plane._jits) == 1
+    (fn,) = plane._jits.values()
+    assert fn._cache_size() == 1
+    pe, pg = net_e.collect_params(), net_g.collect_params()
+    for name, p in pg.items():
+        ref = pe["lre_" + name.split("_", 1)[1]]
+        np.testing.assert_array_equal(np.asarray(p.data()._data),
+                                      np.asarray(ref.data()._data),
+                                      err_msg=name)
+
+
+def test_zero_branch_and_its_fallback_share_one_prologue(monkeypatch):
+    """The ZeRO branch reads rows of the SAME packed array the replicated
+    step takes as its operand: when the sharded step fails, the fallback
+    dispatches the very array that step was handed, the counters have
+    advanced once, and training tracks the eager loop."""
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 devices")
+    from mxnet_tpu.fastpath import zero
+
+    xs, ys = _data(29)
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    net_e = _make_mlp("zfe_")
+    _init(net_e, xs)
+    net_e.hybridize()
+    tr_e = gluon.Trainer(net_e.collect_params(), "adam",
+                         {"learning_rate": 0.01})
+    net_g = _make_mlp("zfg_")
+    _init(net_g, xs)
+    _copy_params(net_e, net_g)
+    monkeypatch.setenv("MXNET_TRAINSTEP", "1")
+    tr_g = gluon.Trainer(net_g.collect_params(), "adam",
+                         {"learning_rate": 0.01})
+    plane = trainplane.TrainPlane(net_g, loss_fn, tr_g,
+                                  mesh=parallel.device_mesh(2))
+
+    prologues, zero_got, step_got = [], [], []
+    real_prologue = plane._host_prologue
+    real_zero_call = plane._zero_graph_call
+    real_jit_call = telemetry.jit_call
+
+    def prologue(opt, indices):
+        out = real_prologue(opt, indices)
+        if opt is tr_g._optimizer:  # the probe counts on a throwaway copy
+            prologues.append(out[0])
+        return out
+
+    def zero_call(zp, opt, updater, scalars, d, l, rng):
+        zero_got.append(scalars)
+        if len(zero_got) == 2:
+            raise RuntimeError("sharded trace refused")
+        return real_zero_call(zp, opt, updater, scalars, d, l, rng)
+
+    def jit_call(site, fn, *args, **kwargs):
+        if site == "trainplane.step" and plane._zero_broken:
+            step_got.append(args[3])
+        return real_jit_call(site, fn, *args, **kwargs)
+
+    monkeypatch.setattr(plane, "_host_prologue", prologue)
+    monkeypatch.setattr(plane, "_zero_graph_call", zero_call)
+    monkeypatch.setattr(telemetry, "jit_call", jit_call)
+    for s in range(3):
+        x, y = nd.array(xs[s * B:(s + 1) * B]), \
+            nd.array(ys[s * B:(s + 1) * B])
+        monkeypatch.setenv("MXNET_ZERO", "0")
+        with mx.autograd.record():
+            le = loss_fn(net_e(x), y)
+        le.backward()
+        tr_e.step(B)
+        monkeypatch.setenv("MXNET_ZERO", "1")
+        lg = plane.step(x, y)
+        np.testing.assert_allclose(lg.asnumpy(), le.asnumpy(),
+                                   rtol=1e-5, atol=1e-6)
+    assert plane.plane == "graph" and plane._zero_broken == "RuntimeError"
+    assert zero.plane_of(tr_g._updaters[0]) is None
+    # one prologue a step; step 1 sharded, step 2 sharded-then-replicated
+    # on ONE array, step 3 replicated
+    assert len(prologues) == 3 and len(zero_got) == 2
+    assert zero_got[0] is prologues[0] and zero_got[1] is prologues[1]
+    assert len(step_got) == 2
+    assert step_got[0] is prologues[1] and step_got[1] is prologues[2]
+    np.testing.assert_array_equal(prologues[1][0], 2.0)  # t, counted once
+    opt = tr_g._optimizer
+    assert opt.num_update == 3
+    assert all(opt._index_update_count[i] == 3 for i, _ in plane._rows)
+    pe, pg = net_e.collect_params(), net_g.collect_params()
+    for name, p in pg.items():
+        ref = pe["zfe_" + name.split("_", 1)[1]]
+        np.testing.assert_allclose(np.asarray(p.data()._data),
+                                   np.asarray(ref.data()._data),
+                                   rtol=1e-5, atol=1e-6, err_msg=name)
 
 
 # ---------------------------------------------------------------------------
