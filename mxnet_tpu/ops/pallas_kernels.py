@@ -285,9 +285,9 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # is faster than interpreting and bit-comparable within fp tolerance.
 
 
-def _paged_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, page_size, max_pages, groups,
-                  width, scale, causal):
+def _paged_kernel(pt_ref, sl_ref, qp_ref, *rest, page_size, max_pages,
+                  groups, width, scale, causal, window=0, ring=False,
+                  precision=None):
     """One (slot, page) cell of ragged paged attention, ``width`` query
     tokens per slot (1 = classic decode tick / chunked-prefill row, K+1 =
     speculative verify tick).
@@ -301,7 +301,19 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
     ``causal``) query position. Every per-kv-head access indexes a LEADING
     ref axis or loads one kv head straight from the page ref — no value
     slicing, which Mosaic refuses (dynamic_slice) or relayouts.
+
+    ``window`` > 0 (static) also masks keys at or below ``query - window``
+    (the query is the row's ``q_pos`` when causal, else its last token).
+    ``ring`` (static): the table's columns are a ring and a fourth
+    scalar-prefetch operand ``blk_ref`` ``(S * max_pages,)`` names the
+    page-sized block of the sequence each column holds (-1: none yet).
+    ``precision``: of the two products (None: the compiler's default, one
+    bfloat16 pass over float32 operands; ``HIGHEST``: float32 products).
     """
+    if ring:
+        blk_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = rest
     s = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -326,10 +338,17 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
         sl_rows = jnp.where(row_w == w, sl_ref[s * width + w], sl_rows)
         if causal:
             qp_rows = jnp.where(row_w == w, qp_ref[s * width + w], qp_rows)
-    pos = j * page_size + lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+    first = blk_ref[s * max_pages + j] if ring else j
+    pos = first * page_size \
+        + lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
     valid = pos < sl_rows
     if causal:
         valid = jnp.logical_and(valid, pos <= qp_rows)
+    if window or ring:
+        # a ring column that holds no block yet has first = -1: pos < 0
+        low = (qp_rows if causal else sl_rows - 1) - window + 1 \
+            if window else 0
+        valid = jnp.logical_and(valid, pos >= jnp.maximum(low, 0))
 
     # per-kv-head 2D matmuls keep the MXU fed without a batched einsum;
     # n_kv is a small trace-time constant so the python loop unrolls.
@@ -338,7 +357,7 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
         k = k_ref[0, :, khi, :].astype(jnp.float32)     # (page_size, D)
         v = v_ref[0, :, khi, :].astype(jnp.float32)
         scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32) * scale
         scores = jnp.where(valid, scores, _NEG_BIG)
         m_prev = m_scr[khi][:, :1]
@@ -348,7 +367,7 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
         p = jnp.exp(scores - m_new)
         l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
         acc_scr[khi] = acc_scr[khi] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (0,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32)
         m_scr[khi] = jnp.broadcast_to(m_new, (rp, LANES))
         l_scr[khi] = jnp.broadcast_to(l_new, (rp, LANES))
@@ -367,11 +386,26 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = o.astype(o_ref.dtype)
 
 
+def ring_blocks(seq_lens, columns, page_size):
+    """Which page-sized block of its sequence each column of a RING page
+    table holds: ``(S, columns)`` int32, -1 where the column holds none yet.
+    Position ``p`` lives in column ``(p // page_size) % columns`` (the
+    contract of ``serving.kvcache.RingKVCache``), so with the last token in
+    block ``b`` column ``j`` holds the latest block at or below ``b`` that
+    is congruent to ``j``."""
+    last = (seq_lens.astype(jnp.int32) - 1) // page_size      # -1: empty
+    col = jnp.arange(columns, dtype=jnp.int32)[None, :]
+    return last[:, None] - (last[:, None] - col + columns) % columns
+
+
 def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
-                interpret, who):
+                interpret, who, window=0, ring=False, precise=False):
     """Shared launch of :func:`_paged_kernel`. q: (S, W, H, D); seq_lens
-    (and q_pos, when not None): (S*W,) per query token. Returns
-    (S, W, H, D)."""
+    (and q_pos, when not None): (S*W,) per query token. ``window`` (static)
+    masks keys at or below ``query - window``; ``ring`` (static) reads
+    ``page_table`` as a ring of columns (one query token a slot);
+    ``precise`` (static) asks for float32 products where the compiler's
+    default is one bfloat16 pass. Returns (S, W, H, D)."""
     s_slots, width, n_heads, d = q.shape
     _, page_size, n_kv, _ = k_pool.shape
     if n_heads % n_kv:
@@ -397,21 +431,32 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
     qk = q.reshape(s_slots, width, n_kv, groups, d).transpose(0, 2, 1, 3, 4)
     qk = jnp.pad(qk.reshape(s_slots, n_kv, rows, d),
                  ((0, 0), (0, 0), (0, rp - rows), (0, 0)))
+    more = {}
+    if window or ring:  # a model without either keeps its program as it was
+        more = {"window": int(window), "ring": bool(ring)}
+    if precise:
+        more["precision"] = lax.Precision.HIGHEST
     kernel = functools.partial(
         _paged_kernel, page_size=page_size, max_pages=max_pages,
-        groups=groups, width=width, scale=float(scale), causal=causal)
+        groups=groups, width=width, scale=float(scale), causal=causal,
+        **more)
     pt_flat = page_table.astype(jnp.int32).ravel()
     sl = seq_lens.astype(jnp.int32)
     qpos = q_pos.astype(jnp.int32) if causal else jnp.zeros_like(sl)
+    scalars = (pt_flat, sl, qpos)
+    if ring:
+        if width != 1:
+            raise ValueError("%s: a ring table serves one query token a "
+                             "slot, got %d" % (who, width))
+        scalars += (ring_blocks(sl, max_pages, page_size).ravel(),)
 
-    def q_map(s, j, pt, sl_, qp_):
+    def q_map(s, j, pt, *_):
         return (s, 0, 0, 0)
 
-    def page_map(s, j, pt, sl_, qp_):
+    def page_map(s, j, pt, *_):
         return (pt[s * max_pages + j], 0, 0, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+    spec = dict(
         grid=(s_slots, max_pages),
         in_specs=[
             pl.BlockSpec((1, n_kv, rp, d), q_map),
@@ -425,6 +470,10 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
             pltpu.VMEM((n_kv, rp, d), jnp.float32),
         ],
     )
+    # page table, lengths, query positions (+ the ring's block numbers)
+    grid_spec = pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=4, **spec) \
+        if ring else pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=3,
+                                                  **spec)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((s_slots, n_kv, rp, d), q.dtype),
@@ -433,13 +482,14 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="mx_paged_attn",  # what a device trace is searched for
-    )(pt_flat, sl, qpos, qk, k_pool, v_pool)
+    )(*scalars, qk, k_pool, v_pool)
     out = out[:, :, :rows].reshape(s_slots, n_kv, width, groups, d)
     return out.transpose(0, 2, 1, 3, 4).reshape(s_slots, width, n_heads, d)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, page_table, seq_lens,
-                           q_pos=None, scale=None, interpret=None):
+                           q_pos=None, scale=None, interpret=None,
+                           precise=False):
     """Ragged paged-attention for decode: one query token per slot.
 
     q: (S, H, D); k_pool/v_pool: (P, page_size, KH, D) static pools;
@@ -455,8 +505,33 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, seq_lens,
     reassignment never recompile. Returns (S, H, D).
     """
     return _paged_call(q[:, None], k_pool, v_pool, page_table, seq_lens,
-                       q_pos, scale, interpret,
-                       "ragged_paged_attention")[:, 0]
+                       q_pos, scale, interpret, "ragged_paged_attention",
+                       precise=precise)[:, 0]
+
+
+def _dense_paged(q, k_pool, v_pool, page_table, valid, scale):
+    """Dense attention of one query a slot over the keys its table's pages
+    hold, ``valid`` (S, columns * page_size) saying which of them count; a
+    slot with none comes back zeros. The body of both paged references."""
+    s_slots, n_heads, d = q.shape
+    _, page_size, n_kv, _ = k_pool.shape
+    groups = n_heads // n_kv
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    t = page_table.shape[1] * page_size
+    # (S, columns, page_size, KH, D) -> (S, T, KH, D)
+    k = k_pool[page_table].reshape(s_slots, t, n_kv, d)
+    v = v_pool[page_table].reshape(s_slots, t, n_kv, d)
+    if groups > 1:
+        k = jnp.repeat(k, groups, axis=2)
+        v = jnp.repeat(v, groups, axis=2)
+    scores = jnp.einsum("shd,sthd->sht", q.astype(jnp.float32),
+                        k.astype(jnp.float32)) * scale
+    scores = jnp.where(valid[:, None, :], scores, _NEG_BIG)
+    p = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("sht,sthd->shd", p, v.astype(jnp.float32))
+    return jnp.where(valid.any(axis=1)[:, None, None], out,
+                     0.0).astype(q.dtype)
 
 
 def paged_attention_reference(q, k_pool, v_pool, page_table, seq_lens,
@@ -465,29 +540,12 @@ def paged_attention_reference(q, k_pool, v_pool, page_table, seq_lens,
     the decode path on non-TPU backends (faster than interpret mode;
     gathers (S, max_pages*page_size) KV views, so it trades the kernel's
     O(page) VMEM residency for plain XLA gathers)."""
-    s_slots, n_heads, d = q.shape
-    _, page_size, n_kv, _ = k_pool.shape
-    groups = n_heads // n_kv
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
-    t = page_table.shape[1] * page_size
-    # (S, max_pages, page_size, KH, D) -> (S, T, KH, D)
-    k = k_pool[page_table].reshape(s_slots, t, n_kv, d)
-    v = v_pool[page_table].reshape(s_slots, t, n_kv, d)
-    if groups > 1:
-        k = jnp.repeat(k, groups, axis=2)
-        v = jnp.repeat(v, groups, axis=2)
-    scores = jnp.einsum("shd,sthd->sht", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
+    t = page_table.shape[1] * k_pool.shape[1]
     pos = jnp.arange(t, dtype=jnp.int32)
     valid = pos[None, :] < seq_lens.astype(jnp.int32)[:, None]
     if q_pos is not None:
         valid = valid & (pos[None, :] <= q_pos.astype(jnp.int32)[:, None])
-    scores = jnp.where(valid[:, None, :], scores, _NEG_BIG)
-    any_valid = valid.any(axis=1)[:, None, None]
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("sht,sthd->shd", p, v.astype(jnp.float32))
-    return jnp.where(any_valid, out, 0.0).astype(q.dtype)
+    return _dense_paged(q, k_pool, v_pool, page_table, valid, scale)
 
 
 def paged_prefill_attention(q, k_pool, v_pool, page_row, start, length,
@@ -518,7 +576,7 @@ def paged_prefill_attention(q, k_pool, v_pool, page_row, start, length,
 
 
 def paged_attention(q, k_pool, v_pool, page_table, seq_lens, q_pos=None,
-                    scale=None):
+                    scale=None, precise=False):
     """Dispatcher the decode engine traces: the Pallas kernel on a TPU
     backend, the jnp reference elsewhere — same math, tested for parity in
     interpret mode. The platform is the ONLY gate: there is no shape gate
@@ -528,7 +586,7 @@ def paged_attention(q, k_pool, v_pool, page_table, seq_lens, q_pos=None,
     if not _interpret():
         return ragged_paged_attention(q, k_pool, v_pool, page_table,
                                       seq_lens, q_pos=q_pos, scale=scale,
-                                      interpret=False)
+                                      interpret=False, precise=precise)
     return paged_attention_reference(q, k_pool, v_pool, page_table,
                                      seq_lens, q_pos=q_pos, scale=scale)
 
@@ -587,6 +645,198 @@ def paged_spec_attention(q, k_pool, v_pool, page_table, seq_lens,
         return out.reshape(q.shape)
     return paged_spec_attention_reference(q, k_pool, v_pool, page_table,
                                           seq_lens, scale=scale)
+
+
+# ---------------------------------------------------------------------------
+# Window layers: decode through a ring table, prefill over the band
+# ---------------------------------------------------------------------------
+#
+# A sliding-window layer attends to the last ``window`` positions only, so
+# its cache holds at most ``window`` tokens (+ a page of slack) a sequence:
+# the slot's page-table row is a RING of ``window / page_size + 1`` columns
+# (serving.kvcache.RingKVCache) and the decode kernel walks those columns
+# and no more. Prefill computes the causal band blockwise (the flash idiom
+# above) for all the query heads of a kv head at once, and never visits a
+# block that lies wholly outside the band.
+
+
+def ragged_window_attention(q, k_pool, v_pool, page_table, seq_lens, window,
+                            scale=None, interpret=None, precise=False):
+    """Decode attention of a sliding-window layer: one query token a slot
+    over a RING page table. q: (S, H, D); page_table: (S, columns) — column
+    ``(p // page_size) % columns`` holds position ``p``; seq_lens: (S,)
+    tokens live (the query is the last); keys at or below ``query -
+    window`` are masked. Returns (S, H, D)."""
+    return _paged_call(q[:, None], k_pool, v_pool, page_table, seq_lens,
+                       None, scale, interpret, "ragged_window_attention",
+                       window=window, ring=True, precise=precise)[:, 0]
+
+
+def paged_window_attention_reference(q, k_pool, v_pool, page_table,
+                                     seq_lens, window, scale=None):
+    """Dense jnp form of :func:`ragged_window_attention`: the kernel's
+    parity oracle and the path off the TPU."""
+    s_slots, columns = page_table.shape
+    page_size = k_pool.shape[1]
+    sl = seq_lens.astype(jnp.int32)
+    pos = (ring_blocks(sl, columns, page_size)[:, :, None] * page_size
+           + jnp.arange(page_size, dtype=jnp.int32)
+           ).reshape(s_slots, columns * page_size)
+    valid = (pos < sl[:, None]) \
+        & (pos >= jnp.maximum(sl - window, 0)[:, None])
+    return _dense_paged(q, k_pool, v_pool, page_table, valid, scale)
+
+
+def paged_window_attention(q, k_pool, v_pool, page_table, seq_lens, window,
+                           scale=None, precise=False):
+    """Dispatcher (as :func:`paged_attention`): the kernel on a TPU, the
+    dense reference elsewhere."""
+    if not _interpret():
+        return ragged_window_attention(q, k_pool, v_pool, page_table,
+                                       seq_lens, window, scale=scale,
+                                       interpret=False, precise=precise)
+    return paged_window_attention_reference(q, k_pool, v_pool, page_table,
+                                            seq_lens, window, scale=scale)
+
+
+def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                 scale, window, block, n_band, groups, precision):
+    """One (kv head, query block, band step) cell of causal (+ window)
+    prefill attention. q_ref/o_ref: (1, G, B, D) — the G query heads of the
+    kv head; k_ref/v_ref: (1, B, D) — kv block ``first + j`` of the band
+    (``first = max(qi - n_band + 1, 0)``); steps past the diagonal are not
+    computed (their index map names the diagonal block again: no new
+    copy)."""
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    kb = jnp.maximum(qi - (n_band - 1), 0) + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(kb <= qi)
+    def _block():
+        k = k_ref[0].astype(jnp.float32)          # (B, D)
+        v = v_ref[0].astype(jnp.float32)
+        rows = qi * block + lax.broadcasted_iota(jnp.int32,
+                                                 (block, block), 0)
+        cols = kb * block + lax.broadcasted_iota(jnp.int32,
+                                                 (block, block), 1)
+        valid = cols <= rows
+        if window:
+            valid = jnp.logical_and(valid, cols > rows - window)
+        for g in range(groups):
+            q = q_ref[0, g].astype(jnp.float32)   # (B, D)
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=precision,
+                                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, _NEG_BIG)
+            m_prev = m_scr[g][:, :1]
+            l_prev = l_scr[g][:, :1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
+            acc_scr[g] = acc_scr[g] * alpha + lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32)
+            m_scr[g] = jnp.broadcast_to(m_new, (block, LANES))
+            l_scr[g] = jnp.broadcast_to(l_new, (block, LANES))
+
+    @pl.when(j == n_band - 1)
+    def _finish():
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :, :1], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def band_attention(q, k, v, scale=None, window=0, block=128,
+                   interpret=None, precise=False):
+    """Causal prefill attention of ONE sequence with grouped queries and an
+    optional sliding window, never an ``(H, T, T)`` tensor.
+
+    q: (T, H, D); k/v: (T, KH, D), ``H % KH == 0``; ``window`` (static) > 0
+    masks keys at or below ``query - window``. Rows of padding at the end
+    come back finite and meaningless (a causal row never sees what follows
+    it). Grid (KH, T/B, band): a query block visits the ``window / B + 1``
+    kv blocks of its band (all blocks up to the diagonal without a window)
+    — blocks outside are not visited. ``precise`` (static): float32
+    products where the compiler's default is one bfloat16 pass over float32
+    operands. Returns (T, H, D). On a TPU the
+    kernel ``mx_prefill_attn``; elsewhere :func:`band_attention_reference`
+    unless ``interpret`` asks for the kernel."""
+    if interpret is None:
+        if _interpret():
+            return band_attention_reference(q, k, v, scale=scale,
+                                            window=window)
+        interpret = False
+    t, n_heads, d = q.shape
+    n_kv = k.shape[1]
+    groups = n_heads // n_kv
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    block = min(block, _pad_up(t, 8))
+    tp = _pad_up(t, block)
+    n_q = tp // block
+    n_band = n_q if not window else min(n_q, -(-window // block) + 1)
+    pad = ((0, tp - t), (0, 0), (0, 0))
+    # (T, KH, G, D) -> (KH, G, T, D); k/v -> (KH, T, D)
+    qg = jnp.pad(q, pad).reshape(tp, n_kv, groups, d).transpose(1, 2, 0, 3)
+    kk = jnp.pad(k, pad).transpose(1, 0, 2)
+    vv = jnp.pad(v, pad).transpose(1, 0, 2)
+
+    def q_map(h, qi, j):
+        return (h, 0, qi, 0)
+
+    def kv_map(h, qi, j):
+        first = jnp.maximum(qi - (n_band - 1), 0)
+        return (h, jnp.minimum(first + j, qi), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_band_kernel, scale=float(scale),
+                          window=int(window), block=block, n_band=n_band,
+                          groups=groups,
+                          precision=lax.Precision.HIGHEST if precise
+                          else None),
+        grid=(n_kv, n_q, n_band),
+        in_specs=[pl.BlockSpec((1, groups, block, d), q_map),
+                  pl.BlockSpec((1, block, d), kv_map),
+                  pl.BlockSpec((1, block, d), kv_map)],
+        out_specs=pl.BlockSpec((1, groups, block, d), q_map),
+        out_shape=jax.ShapeDtypeStruct((n_kv, groups, tp, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((groups, block, LANES), jnp.float32),
+                        pltpu.VMEM((groups, block, LANES), jnp.float32),
+                        pltpu.VMEM((groups, block, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="mx_prefill_attn",  # what a device trace is searched for
+    )(qg, kk, vv)
+    return out.transpose(2, 0, 1, 3).reshape(tp, n_heads, d)[:t]
+
+
+def band_attention_reference(q, k, v, scale=None, window=0):
+    """Dense jnp form of :func:`band_attention` (materialises the scores:
+    tests and the CPU path only)."""
+    t, n_heads, d = q.shape
+    groups = n_heads // k.shape[1]
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    if groups > 1:
+        k = jnp.repeat(k, groups, axis=1)
+        v = jnp.repeat(v, groups, axis=1)
+    scores = jnp.einsum("qhd,khd->hqk", q.astype(jnp.float32),
+                        k.astype(jnp.float32)) * scale
+    rows = jnp.arange(t)[:, None]
+    cols = jnp.arange(t)[None, :]
+    valid = cols <= rows
+    if window:
+        valid = valid & (cols > rows - window)
+    p = jax.nn.softmax(jnp.where(valid[None], scores, _NEG_BIG), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", p,
+                      v.astype(jnp.float32)).astype(q.dtype)
 
 
 def _register_flash_attention_op():

@@ -65,10 +65,12 @@ from .batcher import (EngineUnavailableError, QueueFullError,
                       RequestTimeoutError, Server, ServerClosedError,
                       ServingError)
 from .buckets import bucket_ladder, pad_to_bucket, select_bucket
+from .afmoe import AfmoeDecoder
 from .decode import DecodeEngine, PagedDecodeModel, TinyDecoder
 from .engine import BlockEngine, Engine, StableHLOEngine
 from .fleet import FleetRouter
-from .kvcache import OutOfPagesError, PagedKVCache, PrefixMatch
+from .kvcache import (GroupedKVCache, OutOfPagesError, PagedKVCache,
+                      PrefixMatch, RingKVCache)
 from .speculative import (DraftProposer, ModelDraft, PromptLookupDraft,
                           available_drafts, make_draft, register_draft)
 from .stats import ServingStats, TenantStats
@@ -83,7 +85,8 @@ __all__ = [
     "ServingStats", "TenantStats",
     "bucket_ladder", "select_bucket", "pad_to_bucket",
     "serve_block", "serve_stablehlo",
-    "DecodeEngine", "PagedDecodeModel", "TinyDecoder", "FleetRouter",
+    "DecodeEngine", "PagedDecodeModel", "TinyDecoder", "AfmoeDecoder",
+    "GroupedKVCache", "RingKVCache", "FleetRouter",
     "PagedKVCache", "OutOfPagesError", "PrefixMatch",
     "DraftProposer", "PromptLookupDraft", "ModelDraft",
     "register_draft", "make_draft", "available_drafts",

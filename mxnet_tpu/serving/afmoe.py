@@ -1,0 +1,280 @@
+"""``afmoe`` (Arcee Trinity) through the paged-decode contract: one chip's
+share of an expert-parallel deployment.
+
+Per layer (the equations, with every departure from the published model, are
+at the head of :mod:`mxnet_tpu.serving.afmoe_reference`): sandwich RMS norms,
+grouped-query attention with per-head QK-norm and a sigmoid output gate;
+``sliding_attention`` layers rotate q and k (RoPE) and see the last
+``sliding_window`` positions, ``full_attention`` layers see every position
+and no position signal at all; the MLP is a dense SwiGLU in the first
+``num_dense_layers`` layers and an expert layer after them
+(:mod:`mxnet_tpu.ops.moe`: a router over all ``num_experts``, the grouped
+product over the ``held_experts`` that live here, one shared expert).
+
+What it declares to :class:`~mxnet_tpu.serving.DecodeEngine`:
+
+``kv_groups``
+    its full layers keep every page, its window layers a ring of
+    ``sliding_window / page_size + 1`` pages a slot
+    (:class:`~mxnet_tpu.serving.kvcache.GroupedKVCache`); pools, page tables
+    and write pages arrive as ``(full, window)`` pairs;
+``moe_counters``
+    ``decode`` and ``prefill`` return the rows routed to each held expert of
+    each expert layer (last column: to experts held elsewhere) behind the
+    logits.
+
+Decode attends through :func:`~mxnet_tpu.ops.pallas_kernels.paged_attention`
+(full) and :func:`~mxnet_tpu.ops.pallas_kernels.paged_window_attention`
+(window, its own ring table); prefill through
+:func:`~mxnet_tpu.ops.pallas_kernels.band_attention` (blocked, causal +
+window, never an ``(H, T, T)`` tensor). Activations are float32 for real:
+every product against bfloat16 weights takes them as two bfloat16 terms
+(:func:`mxnet_tpu.ops.moe.matmul`) and the attention kernels multiply at
+float32 precision (``precise=True``), because the router amplifies rounding:
+with one bfloat16 pass a product, 3 % of the tokens pick another expert than
+the float32 reference does (PERF.md section 6, PR 27). ``prefill_chunk`` is not offered: a
+chunk would have to read earlier chunks back through a ring that the prompt's
+own tail is overwriting, so the engine serves this model with
+``prefix_cache=False, prefill_chunk=0``.
+"""
+from __future__ import annotations
+
+from typing import Sequence
+
+import numpy as np
+
+from ..base import MXNetError
+from .decode import PagedDecodeModel
+from .kvcache import write_kv
+
+__all__ = ["AfmoeDecoder"]
+
+KINDS = ("sliding_attention", "full_attention")
+
+
+def _mm(x, w):
+    """Float32 activations times weights in their served type
+    (:func:`mxnet_tpu.ops.moe.matmul`: two bfloat16 terms against bfloat16
+    weights, so the activations really are float32)."""
+    from ..ops import moe
+
+    return moe.matmul(x, w)
+
+
+class AfmoeDecoder(PagedDecodeModel):
+    """See the module. Arguments are the keys of the model's ``config.json``
+    (``layer_types`` lists the layers held here) plus ``held_experts =
+    (first, count)``, the experts of each layer that live on this chip."""
+
+    def __init__(self, vocab_size: int, hidden_size: int,
+                 num_attention_heads: int, num_key_value_heads: int,
+                 head_dim: int, intermediate_size: int,
+                 moe_intermediate_size: int, layer_types: Sequence[str],
+                 num_dense_layers: int, num_experts: int,
+                 num_experts_per_tok: int, sliding_window: int,
+                 held_experts=None, num_shared_experts: int = 1,
+                 rope_theta: float = 10000.0, rms_norm_eps: float = 1e-5,
+                 route_norm: bool = True, route_scale: float = 1.0,
+                 mup_enabled: bool = False):
+        if num_attention_heads % num_key_value_heads:
+            raise MXNetError("num_attention_heads %d %% num_key_value_heads "
+                             "%d != 0" % (num_attention_heads,
+                                          num_key_value_heads))
+        bad = sorted(set(layer_types) - set(KINDS))
+        if bad:
+            raise MXNetError("unknown layer_types %s (known: %s)"
+                             % (bad, list(KINDS)))
+        if num_shared_experts != 1:
+            raise MXNetError("afmoe has one shared expert, got %d"
+                             % num_shared_experts)
+        held = (0, num_experts) if held_experts is None \
+            else tuple(int(x) for x in held_experts)
+        if held[0] < 0 or held[1] < 1 or held[0] + held[1] > num_experts:
+            raise MXNetError("held_experts %s outside 0..%d"
+                             % (held, num_experts))
+        self.cfg = {
+            "vocab_size": int(vocab_size), "hidden_size": int(hidden_size),
+            "num_attention_heads": int(num_attention_heads),
+            "num_key_value_heads": int(num_key_value_heads),
+            "head_dim": int(head_dim),
+            "intermediate_size": int(intermediate_size),
+            "moe_intermediate_size": int(moe_intermediate_size),
+            "layer_types": list(layer_types),
+            "num_dense_layers": int(num_dense_layers),
+            "num_experts": int(num_experts),
+            "num_experts_per_tok": int(num_experts_per_tok),
+            "held_experts": list(held),
+            "sliding_window": int(sliding_window),
+            "rope_theta": float(rope_theta),
+            "rms_norm_eps": float(rms_norm_eps),
+            "route_norm": bool(route_norm),
+            "route_scale": float(route_scale),
+            "mup_enabled": bool(mup_enabled),
+        }
+        self.vocab_size = int(vocab_size)
+        self.num_layers = len(layer_types)
+        self.num_heads = int(num_attention_heads)
+        self.num_kv_heads = int(num_key_value_heads)
+        self.head_dim = int(head_dim)
+        self.scale = float(head_dim) ** -0.5
+        # layer -> (group, index inside the group's pools)
+        self._place = []
+        groups = {"full": [], "window": []}
+        for li, kind in enumerate(layer_types):
+            name = "window" if kind == "sliding_attention" else "full"
+            self._place.append((0 if name == "full" else 1,
+                                len(groups[name])))
+            groups[name].append(li)
+        if not groups["full"] or not groups["window"]:
+            raise MXNetError("AfmoeDecoder needs layers of both kinds, got "
+                             "%s" % list(layer_types))
+        self.kv_groups = dict(groups, window_tokens=int(sliding_window))
+        n_expert_layers = self.num_layers - int(num_dense_layers)
+        self.moe_counters = (n_expert_layers, held[1] + 1) \
+            if n_expert_layers > 0 else None
+
+    def init_params(self, seed: int = 0, dtype="float32"):
+        from . import afmoe_reference
+
+        return afmoe_reference.init_params(self.cfg, seed, dtype)
+
+    # -- shared pieces --------------------------------------------------
+    def _rms(self, x, g):
+        import jax.numpy as jnp
+
+        return x * g / jnp.sqrt(jnp.mean(jnp.square(x), axis=-1,
+                                         keepdims=True)
+                                + self.cfg["rms_norm_eps"])
+
+    def _rope(self, x, positions):
+        import jax.numpy as jnp
+
+        d = self.head_dim
+        inv = self.cfg["rope_theta"] ** (
+            -jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
+        cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[:, None, :]
+        sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[:, None, :]
+        x1, x2 = x[..., :d // 2], x[..., d // 2:]
+        return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+    def _embed(self, params, tokens):
+        import jax.numpy as jnp
+
+        x = params["embed"][tokens].astype(jnp.float32)
+        if self.cfg["mup_enabled"]:
+            x = x * self.cfg["hidden_size"] ** 0.5
+        return x
+
+    def _qkv(self, layer, hx, positions, sliding):
+        n = hx.shape[0]
+        h, kh, d = self.num_heads, self.num_kv_heads, self.head_dim
+        q = self._rms(_mm(hx, layer["wq"]).reshape(n, h, d), layer["q_norm"])
+        k = self._rms(_mm(hx, layer["wk"]).reshape(n, kh, d),
+                      layer["k_norm"])
+        v = _mm(hx, layer["wv"]).reshape(n, kh, d)
+        if sliding:
+            q, k = self._rope(q, positions), self._rope(k, positions)
+        return q, k, v
+
+    def _mlp(self, layer, hx, valid):
+        """``(mlp(hx), rows or None)``: dense SwiGLU or the expert layer."""
+        import jax
+
+        from ..ops import moe
+
+        if "router" not in layer:
+            return _mm(jax.nn.silu(_mm(hx, layer["w1"]))
+                       * _mm(hx, layer["w3"]), layer["w2"]), None
+        cfg = self.cfg
+        picks = moe.route(hx, layer["router"], layer["expert_bias"],
+                          cfg["num_experts_per_tok"], cfg["route_norm"],
+                          cfg["route_scale"])
+        return moe.expert_layer(hx, picks, layer["experts"],
+                                tuple(cfg["held_experts"]),
+                                shared=layer["shared"], valid=valid)
+
+    def _forward(self, params, tokens, positions, k_pool, v_pool,
+                 write_pages, write_offsets, valid, attend):
+        """The layers over ``tokens`` rows; ``attend(li, sliding, q, k, v,
+        pools)`` is the one thing prefill and decode do differently."""
+        import jax
+        import jax.numpy as jnp
+
+        k_pool, v_pool = list(k_pool), list(v_pool)
+        x = self._embed(params, tokens)
+        rows = []
+        for li, layer in enumerate(params["layers"]):
+            sliding = self.cfg["layer_types"][li] == "sliding_attention"
+            grp, gi = self._place[li]
+            hx = self._rms(x, layer["ln_in"])
+            q, k, v = self._qkv(layer, hx, positions, sliding)
+            k_pool[grp], v_pool[grp] = write_kv(
+                k_pool[grp], v_pool[grp], gi, k, v, write_pages[grp],
+                write_offsets)
+            att = attend(sliding, q, k, v, k_pool[grp][gi], v_pool[grp][gi])
+            att = att.reshape(att.shape[0], -1) \
+                * jax.nn.sigmoid(_mm(hx, layer["wg"]))
+            x = x + self._rms(_mm(att, layer["wo"]), layer["ln_post_attn"])
+            m, n_rows = self._mlp(layer, self._rms(x, layer["ln_pre_mlp"]),
+                                  valid)
+            if n_rows is not None:
+                rows.append(n_rows)
+            x = x + self._rms(m, layer["ln_post_mlp"])
+        counters = (jnp.stack(rows),) if rows else ()
+        return x, tuple(k_pool), tuple(v_pool), counters
+
+    # -- contract -------------------------------------------------------
+    def prefill(self, params, tokens, length, k_pool, v_pool, write_pages,
+                write_offsets, attn=None):
+        import jax.numpy as jnp
+
+        from ..ops import pallas_kernels
+
+        if attn is not None:
+            raise MXNetError("AfmoeDecoder has no ring-attention prefill")
+        t = tokens.shape[0]
+        positions = jnp.arange(t, dtype=jnp.int32)
+        window = self.cfg["sliding_window"]
+
+        def attend(sliding, q, k, v, _kp, _vp):
+            return pallas_kernels.band_attention(
+                q, k, v, scale=self.scale, window=window if sliding else 0,
+                precise=True)
+
+        x, k_pool, v_pool, counters = self._forward(
+            params, tokens, positions, k_pool, v_pool, write_pages,
+            write_offsets, positions < length, attend)
+        last = _mm(self._rms(x[length - 1], params["ln_f"])[None],
+                   params["head"])[0]
+        return (last, k_pool, v_pool) + counters
+
+    def prefill_chunk(self, params, tokens, start, length, k_pool, v_pool,
+                      page_table_row, write_pages, write_offsets):
+        raise MXNetError(
+            "AfmoeDecoder offers no chunked prefill: serve it with "
+            "prefix_cache=False, prefill_chunk=0")
+
+    def decode(self, params, tokens, positions, k_pool, v_pool, page_tables,
+               seq_lens, write_pages, write_offsets):
+        from ..ops import pallas_kernels
+
+        if tokens.shape[0] != page_tables[0].shape[0]:
+            raise MXNetError("AfmoeDecoder decodes one token a slot "
+                             "(spec_k=0)")
+        window = self.cfg["sliding_window"]
+
+        def attend(sliding, q, _k, _v, kp, vp):
+            if sliding:
+                return pallas_kernels.paged_window_attention(
+                    q, kp, vp, page_tables[1], seq_lens, window,
+                    scale=self.scale, precise=True)
+            return pallas_kernels.paged_attention(
+                q, kp, vp, page_tables[0], seq_lens, scale=self.scale,
+                precise=True)
+
+        x, k_pool, v_pool, counters = self._forward(
+            params, tokens, positions, k_pool, v_pool, write_pages,
+            write_offsets, seq_lens > 0, attend)
+        logits = _mm(self._rms(x, params["ln_f"]), params["head"])
+        return (logits, k_pool, v_pool) + counters

@@ -114,7 +114,8 @@ from ..resilience import hbm as _hbm
 from .batcher import (EngineUnavailableError, QueueFullError,
                       RequestTimeoutError, ServerClosedError)
 from .buckets import select_bucket
-from .kvcache import OutOfPagesError, PagedKVCache, PrefixMatch, write_kv
+from .kvcache import (GroupedKVCache, OutOfPagesError, PagedKVCache,
+                      PrefixMatch, write_kv)
 from .stats import ServingStats
 from .tenancy import (PRIORITY_CLASSES, SHARED_TENANT, Tenant,
                       TenantRegistry, TenantUnavailableError,
@@ -142,6 +143,17 @@ _T_OCCUPANCY = telemetry.gauge(
     "mxnet_decode_slot_occupancy",
     "active decode slots over total slots, most recent tick",
     labels=("server",))
+_T_MOE_ROWS = telemetry.counter(
+    "mxnet_moe_rows_total",
+    "(token, pick) rows the router sent to experts held on this chip "
+    "(where=held) and to experts held elsewhere (where=absent), summed "
+    "over the expert layers; prefills and decode ticks",
+    labels=("server", "where"))
+_T_MOE_LOAD = telemetry.gauge(
+    "mxnet_moe_expert_load_max_over_mean",
+    "rows of the busiest held expert over the mean of the held experts, "
+    "over everything served so far (1.0 = even load)",
+    labels=("server",))
 _T_EVENTS = telemetry.counter(
     "mxnet_decode_events_total",
     "decode engine lifecycle events (prefill, admitted, completed, "
@@ -167,6 +179,25 @@ class PagedDecodeModel:
 
     Attributes the engine sizes the cache from: ``num_layers``,
     ``num_heads``, ``num_kv_heads``, ``head_dim``, ``vocab_size``.
+
+    Two optional declarations (a model without them, like
+    :class:`TinyDecoder`, is served exactly as before):
+
+    ``kv_groups``
+        ``{"full": [layer, ...], "window": [layer, ...], "window_tokens":
+        n}`` — the model's layers are of two kinds. The engine then keeps a
+        :class:`~mxnet_tpu.serving.kvcache.GroupedKVCache` and every
+        ``k_pool`` / ``v_pool`` / ``page_tables`` / ``write_pages``
+        argument below is a ``(full, window)`` PAIR: pools ``(layers of the
+        group, P, page, KH, D)``, the window table a ring of ``n /
+        page_size + 1`` columns. Such a model is served with
+        ``prefix_cache=False``, ``prefill_chunk=0``, ``spec_k=0``.
+    ``moe_counters``
+        ``(expert layers, held experts + 1)`` — ``decode`` and ``prefill``
+        return a fourth value, an int32 array of that shape: the (token,
+        pick) rows each held expert received in each expert layer and, in
+        the last column, the rows routed to experts held elsewhere. It
+        rides the tick's one fetch behind the sampled tokens.
     """
 
     num_layers: int
@@ -362,11 +393,33 @@ class DecodeEngine:
             self._chunk_rungs = self._ladder
         else:
             self._chunk_rungs = ()
-        self._cache = PagedKVCache(
-            self.num_slots, self.max_seq_len, model.num_layers,
-            model.num_kv_heads, model.head_dim, page_size=page_size,
-            num_pages=num_pages, dtype=dtype, name=name,
-            prefix_cache=self._prefix_cache)
+        kv_groups = getattr(model, "kv_groups", None)
+        self._grouped = bool(kv_groups)
+        if self._grouped:
+            if self._prefix_cache or self._chunk or self._spec_k \
+                    or self._ring_len:
+                raise MXNetError(
+                    "a model that declares kv_groups is served with "
+                    "prefix_cache=False, prefill_chunk=0, spec_k=0 and no "
+                    "ring prefill: a window layer's pages are a ring that "
+                    "is rewritten in place (no page to share, no chunk or "
+                    "draft row to read back through it)")
+            self._cache = GroupedKVCache(
+                self.num_slots, self.max_seq_len, kv_groups,
+                model.num_kv_heads, model.head_dim, page_size=page_size,
+                num_pages=num_pages, dtype=dtype, name=name)
+        else:
+            self._cache = PagedKVCache(
+                self.num_slots, self.max_seq_len, model.num_layers,
+                model.num_kv_heads, model.head_dim, page_size=page_size,
+                num_pages=num_pages, dtype=dtype, name=name,
+                prefix_cache=self._prefix_cache)
+        # rows of the packed operands: a second group adds its write pages
+        self._extra_rows = 1 if self._grouped else 0
+        # a model with experts returns its load counters beside the tokens
+        moe_shape = getattr(model, "moe_counters", None)
+        self._moe_rows = (np.zeros(moe_shape, np.int64)
+                          if moe_shape else None)
         self._stats = ServingStats(name)
         self._name = name
         self._retry = retry_policy
@@ -397,8 +450,9 @@ class DecodeEngine:
         # every queued request may reserve up to max_seq_len of pages
         # (total_queued() reads one int, safe from any thread).
         self._governor = _hbm.governor()
-        pool_bytes = int(self._cache.k_pool.nbytes
-                         + self._cache.v_pool.nbytes)
+        pool_bytes = int(sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(
+                (self._cache.k_pool, self._cache.v_pool))))
         self._governor.register_bound("serving.%s.kv_pool" % name,
                                       pool_bytes)
         page_bytes = pool_bytes // max(1, self._cache.num_pages)
@@ -427,24 +481,47 @@ class DecodeEngine:
         # (5, S*W) array — one host->device put per tick instead of five;
         # the page table rides a version-keyed device cache (below), so a
         # steady tick pays exactly one put + one fetch
+        grouped = self._grouped
+
+        def with_counters(sampled, out):
+            """The model's counters (if it returns any) behind the sampled
+            token(s): one array, one fetch."""
+            if len(out) == 3:
+                return sampled
+            return jnp.concatenate([sampled.reshape(-1),
+                                    out[3].reshape(-1).astype(jnp.int32)])
+
         def mx_decode_step(params, packed, k_pool, v_pool, page_tables):
-            tokens, positions, seq_lens, write_pages, write_offsets = packed
-            logits, k_pool, v_pool = model.decode(
+            if grouped:
+                tokens, positions, seq_lens, full_pages, write_offsets, \
+                    window_pages = packed
+                write_pages = (full_pages, window_pages)
+            else:
+                tokens, positions, seq_lens, write_pages, write_offsets = \
+                    packed
+            out = model.decode(
                 params, tokens, positions, k_pool, v_pool, page_tables,
                 seq_lens, write_pages, write_offsets)
+            logits, k_pool, v_pool = out[:3]
             sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return sampled, k_pool, v_pool
+            return with_counters(sampled, out), k_pool, v_pool
 
         # same packing for prefill: tokens + write pages + offsets share
         # the rung shape, so they travel as one (3, rung) array
         # (one jit, one program a rung: the scope names the rung's ops)
         def mx_prefill(params, packed, length, k_pool, v_pool):
-            tokens, write_pages, write_offsets = packed
+            if grouped:
+                tokens, full_pages, write_offsets, window_pages = packed
+                write_pages = (full_pages, window_pages)
+            else:
+                tokens, write_pages, write_offsets = packed
             with jax.named_scope("mx_prefill_%d" % tokens.shape[0]):
-                last, k_pool, v_pool = model.prefill(
+                out = model.prefill(
                     params, tokens, length, k_pool, v_pool, write_pages,
                     write_offsets)
-            return jnp.argmax(last).astype(jnp.int32), k_pool, v_pool
+            last, k_pool, v_pool = out[:3]
+            first = jnp.argmax(last).astype(jnp.int32)
+            return with_counters(first, out), k_pool, v_pool
 
         # one prefill CHUNK: same (3, rung) packing plus the absolute
         # start position and the slot's page-table row — the chunk
@@ -482,6 +559,7 @@ class DecodeEngine:
             mx_kv_cow, donate_argnums=(0, 1) if donate else ())
         self._pt_dev = None  # version-keyed device page table
         self._pt_version = -1
+        self._pt_groups = [[-1, None], [-1, None]]  # the same, by group
 
         self._warm_compiles: Optional[int] = None
         self._slots: List[Optional[_DecodeRequest]] = \
@@ -525,13 +603,22 @@ class DecodeEngine:
         off). A retry must not re-pass dead buffers, and a prefill
         failure that killed the pools has destroyed EVERY live sequence's
         KV — the caller escalates to a full eviction + fresh pools."""
-        dead = getattr(self._cache.k_pool, "is_deleted", None)
+        pool = self._cache.k_pool
+        dead = getattr(pool[0] if self._grouped else pool, "is_deleted",
+                       None)
         return bool(dead and dead())
 
     def _device_page_table(self):
         """The page table's device copy, re-put only when the allocator
         mutated it (admission/free) — steady ticks with stable membership
         skip the transfer entirely."""
+        if self._grouped:
+            # one copy a group, each re-put when ITS allocator moved
+            for held, (ver, table) in zip(self._pt_groups,
+                                          self._cache.tables):
+                if held[0] != ver:
+                    held[:] = [ver, self._jnp.asarray(table)]
+            return tuple(held[1] for held in self._pt_groups)
         ver = self._cache.version
         if self._pt_dev is None or self._pt_version != ver:
             self._pt_dev = self._jnp.asarray(self._cache.page_table)
@@ -738,8 +825,9 @@ class DecodeEngine:
             params = self._params
         # the step's packed operand carries W = spec_k+1 rows per slot;
         # warming at that width anchors the widened tick too
-        packed = np.zeros((5, s * self._spec_w), np.int32)
-        packed[3], packed[4] = self._cache.null_write_slots(s * self._spec_w)
+        packed = np.zeros((5 + self._extra_rows, s * self._spec_w), np.int32)
+        # every row of write pages stays 0, the null page
+        packed[4] = self._cache.null_write_slots(s * self._spec_w)[1]
         sampled, kp, vp = self._step(
             params, jnp.asarray(packed), self._cache.k_pool,
             self._cache.v_pool, self._device_page_table())
@@ -748,8 +836,8 @@ class DecodeEngine:
             # chunked mode never dispatches the monolithic rungs — every
             # prompt runs through the one chunk rung compiled below
             for rung in self._ladder:
-                pre = np.zeros((3, rung), np.int32)
-                pre[1], pre[2] = self._cache.null_write_slots(rung)
+                pre = np.zeros((3 + self._extra_rows, rung), np.int32)
+                pre[2] = self._cache.null_write_slots(rung)[1]
                 _tok, kp, vp = self._prefill_jit(
                     params, jnp.asarray(pre),
                     jnp.asarray(1, jnp.int32), self._cache.k_pool,
@@ -841,6 +929,18 @@ class DecodeEngine:
                 },
             })
             governed = self._governed_limit
+            moe_rows = None if self._moe_rows is None \
+                else self._moe_rows.copy()
+        if moe_rows is not None:
+            held = moe_rows[:, :-1]
+            out["moe"] = {
+                "expert_layers": int(held.shape[0]),
+                "experts_held": int(held.shape[1]),
+                "rows_held": int(held.sum()),
+                "rows_absent": int(moe_rows[:, -1].sum()),
+                "rows_by_expert": [[int(n) for n in row] for row in held],
+                "load_max_over_mean": self._moe_load(held),
+            }
         out["tenants"] = self._tenants.snapshot()
         out["kvcache"] = self._cache.stats()
         # the governor's verdict rides every stats snapshot (the fleet's
@@ -1364,12 +1464,12 @@ class DecodeEngine:
             _T_EVENTS.inc(server=self._name, event="admitted")
             return
         rung = select_bucket(p - req.filled, self._ladder)
-        with telemetry.span("decode.prefill", _SPAN_CAT, rung=rung):
+        with telemetry.span("decode.prefill", _SPAN_CAT, rung=rung) as span:
             if matched == 0:
                 tok = self._run_full_prefill(req, slot, ring=ring)
             else:
                 tok = self._run_chunk(slot, req, req.filled, p, rung)
-            self._finish_prefill(req, slot, tok)
+            self._finish_prefill(req, slot, tok, span)
 
     def _run_full_prefill(self, req: _DecodeRequest, slot: int,
                           ring: bool = False):
@@ -1384,12 +1484,17 @@ class DecodeEngine:
         rung = select_bucket(p, self._ladder)
         _tracing.event(req.trace, "prefill", rung=rung, tokens=p,
                        ring=ring)
-        pre = np.zeros((3, rung), np.int32)  # tokens, write pages, offsets
+        # tokens, write pages, offsets (+ a second group's write pages);
+        # the padding's pages stay 0, the null page
+        pre = np.zeros((3 + self._extra_rows, rung), np.int32)
         pre[0, :p] = req.prompt
         wpg, woff = self._cache.write_slots(slot, 0, p)
-        npg, noff = self._cache.null_write_slots(rung - p)
-        pre[1] = np.concatenate([wpg, npg])
-        pre[2] = np.concatenate([woff, noff])
+        if self._grouped:
+            pre[1, :p], pre[3, :p] = wpg
+        else:
+            pre[1, :p] = wpg
+        pre[2] = np.concatenate(
+            [woff, self._cache.null_write_slots(rung - p)[1]])
         policy = self._retry or resilience.default_policy()
 
         def attempt():
@@ -1505,7 +1610,8 @@ class DecodeEngine:
         if end >= p:
             self._finish_prefill(req, slot, tok)
 
-    def _finish_prefill(self, req: _DecodeRequest, slot: int, tok):
+    def _finish_prefill(self, req: _DecodeRequest, slot: int, tok,
+                        span=None):
         """Prefill complete (monolithic, tail or final chunk): index the
         prompt's pages for future sharers, deliver the first token and
         hand the slot to the decode tick."""
@@ -1525,7 +1631,12 @@ class DecodeEngine:
         _T_EVENTS.inc(server=self._name, event="prefill")
         # first token: ONE scalar fetch per admitted sequence (prefill
         # rate, not token rate — outside the decode-host-sync budget)
-        first = int(fetch_host([tok])[0])
+        fetched = fetch_host([tok])[0].reshape(-1)
+        first = int(fetched[0])    # a model's counters ride behind it
+        if span is not None and (self._grouped
+                                 or self._moe_rows is not None):
+            span.set_args(**self._layer_args(
+                fetched[1:] if self._moe_rows is not None else None, [p]))
         now = time.perf_counter()
         ttft = (now - req.t_submit) * 1e3
         _tracing.event(req.trace, "first_token", ttft_ms=round(ttft, 3))
@@ -1613,6 +1724,10 @@ class DecodeEngine:
                 # evicts the tick like a failed step instead of killing
                 # the worker.
                 toks = fetch_host([sampled])[0]
+                counters = None
+                if self._moe_rows is not None:
+                    n_rows = self.num_slots * self._spec_w
+                    toks, counters = toks[:n_rows], toks[n_rows:]
         except Exception as exc:  # noqa: BLE001 - evict, don't die
             # OOM first: a classified RESOURCE_EXHAUSTED (or injected
             # action=oom) additionally latches the governor red and arms
@@ -1625,7 +1740,12 @@ class DecodeEngine:
             self._evict([(i, r) for i, r in enumerate(self._slots)
                          if r is not None], exc)
             return
-        with telemetry.span("decode.commit", _SPAN_CAT):
+        with telemetry.span("decode.commit", _SPAN_CAT) as span:
+            if self._grouped or counters is not None:
+                # before the commit moves the lengths and frees slots
+                span.set_args(**self._layer_args(
+                    counters, [int(self._cache.seq_lens[slot]) + 1
+                               for slot, _req in active]))
             self._commit_step(active, toks, drafts, pages_before)
 
     def _pack_step(self, active):
@@ -1643,7 +1763,7 @@ class DecodeEngine:
         # unused draft rows keep seq_len 0 and the null write page (row 3
         # stays 0); their offsets cycle the page so scatter indices stay
         # in range.
-        packed = np.zeros((5, s * w), np.int32)
+        packed = np.zeros((5 + self._extra_rows, s * w), np.int32)
         packed[4] = np.arange(s * w) % ps
         drafts: dict = {}
         pages_before = self._cache.pages_in_use if self._cache.audit else 0
@@ -1668,6 +1788,9 @@ class DecodeEngine:
                 packed[3, base + j] = \
                     self._cache.page_table[slot, (pos + j) // ps]
                 packed[4, base + j] = (pos + j) % ps
+                if self._grouped:
+                    packed[5, base + j] = \
+                        self._cache.window.page_at(slot, pos + j)
         # black box: the in-flight set BEFORE the step executes, so a
         # mid-tick death's dump names the failing tick's sequences and
         # their tenants (the post-mortem acceptance contract). One event
@@ -1797,6 +1920,43 @@ class DecodeEngine:
                             "page_budget %d after a speculative tick"
                             % (tenant.tenant_id, tenant.pages_in_use,
                                tenant.page_budget))
+
+    @staticmethod
+    def _moe_load(held) -> float:
+        """Busiest expert's rows over the mean (0.0 before any row)."""
+        total = held.sum()
+        return float(held.max() * held.size / total) if total else 0.0
+
+    def _layer_args(self, counters, live) -> dict:
+        """Span arguments of a prefill or a decode tick of a model that
+        declares experts or cache groups (what the benchmark's per-layer
+        readers are handed), and the expert-load counters' bookkeeping.
+        ``counters``: the model's flat ``moe_counters`` of this program run
+        (or None); ``live``: tokens each sequence of the run holds."""
+        args = {}
+        if counters is not None:
+            rows = np.asarray(counters, np.int64).reshape(
+                self._moe_rows.shape)
+            held = rows[:, :-1]
+            with self._cv:      # stats() reads it from caller threads
+                self._moe_rows += rows
+                load = self._moe_load(self._moe_rows[:, :-1])
+            n_held, n_absent = int(held.sum()), int(rows[:, -1].sum())
+            _T_MOE_ROWS.inc(n_held, server=self._name, where="held")
+            _T_MOE_ROWS.inc(n_absent, server=self._name, where="absent")
+            _T_MOE_LOAD.set(load, server=self._name)
+            args.update(moe_rows_held=n_held,
+                        moe_experts_hit=int(np.count_nonzero(held)),
+                        moe_load_max=int(held.max()))
+        if self._grouped:
+            window = self._cache.window
+            args.update(
+                kv_rows_full=int(sum(live)),
+                kv_rows_window=int(sum(min(n, window.window_tokens)
+                                       for n in live)),
+                kv_window_pages=window.pages_in_use,
+                kv_window_capacity=window.num_pages - 1)
+        return args
 
     def _propose(self, req: _DecodeRequest, slot: int, pos: int):
         """Draft tokens for one slot's verify tick, clamped so the tick

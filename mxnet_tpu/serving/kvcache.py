@@ -59,7 +59,8 @@ import numpy as np
 from .. import telemetry
 from ..base import MXNetError, get_env
 
-__all__ = ["PagedKVCache", "OutOfPagesError", "PrefixMatch", "write_kv"]
+__all__ = ["PagedKVCache", "RingKVCache", "GroupedKVCache",
+           "OutOfPagesError", "PrefixMatch", "write_kv"]
 
 _DEFAULT_PAGE_SIZE = 16
 
@@ -97,11 +98,32 @@ _T_PRESSURE_SHEDS = telemetry.counter(
     labels=("cache",))
 
 
+_T_GROUP_PAGES = telemetry.gauge(
+    "mxnet_kvcache_group_pages_in_use",
+    "KV pages in use in a further group of layers of a cache whose model "
+    "declares kv_groups (the first, full-attention group keeps "
+    "mxnet_kvcache_pages_in_use)",
+    labels=("cache", "group"))
+_T_GROUP_CAPACITY = telemetry.gauge(
+    "mxnet_kvcache_group_pages_capacity",
+    "allocatable KV pages of a further group of layers (excludes its "
+    "null page)",
+    labels=("cache", "group"))
+
+
 class OutOfPagesError(MXNetError):
     """The free list (plus every reclaimable cached page) cannot cover
     the requested reservation; the caller (the decode engine's admission
     loop) defers the sequence instead of growing the pool — static
     shapes are the contract."""
+
+
+def _page_size(page_size: Optional[int]) -> int:
+    """Tokens a page (``None``: ``MXNET_KVCACHE_PAGE_SIZE``)."""
+    if page_size is None:
+        page_size = get_env("MXNET_KVCACHE_PAGE_SIZE", _DEFAULT_PAGE_SIZE,
+                            int, cache=False)
+    return max(1, int(page_size))
 
 
 def write_kv(k_pool, v_pool, layer: int, k_new, v_new, pages, offsets):
@@ -194,12 +216,9 @@ class PagedKVCache:
 
         from ..base import np_dtype
 
-        if page_size is None:
-            page_size = get_env("MXNET_KVCACHE_PAGE_SIZE",
-                                _DEFAULT_PAGE_SIZE, int, cache=False)
         if num_pages is None:
             num_pages = get_env("MXNET_KVCACHE_PAGES", 0, int, cache=False)
-        self.page_size = max(1, int(page_size))
+        self.page_size = _page_size(page_size)
         self.num_slots = int(num_slots)
         self.max_seq_len = int(max_seq_len)
         self.max_pages = -(-self.max_seq_len // self.page_size)
@@ -734,4 +753,230 @@ class PagedKVCache:
                 "shared_pages": self.shared_pages,
                 "index_entries": len(self._page_entry),
             })
+        return out
+
+
+class RingKVCache(PagedKVCache):
+    """The pools of a model's SLIDING-WINDOW layers: a slot's page-table row
+    is a ring.
+
+    A window layer attends to the last ``window_tokens`` positions only, so
+    a sequence never needs more than ``window_tokens / page_size + 1`` pages
+    in it however long it grows (the ``+ 1``: a window that does not start
+    on a page boundary touches one page more). The row has exactly that
+    many columns and position ``p`` lives in column ``(p // page_size) %
+    columns``: once the sequence is longer than the ring, a new page-sized
+    block overwrites the column of the block that just left every window.
+    Every shape stays static; nothing is allocated or freed mid-sequence.
+
+    ``reserve(slot, n)`` takes ``min(n, ring)`` tokens' worth of pages at
+    admission (the engine's worst-case discipline: an admitted sequence can
+    always finish). A prompt longer than the ring writes only its last
+    ``columns`` blocks (:meth:`write_slots` sends the earlier rows to the
+    null page — two rows of one scatter may not name one cell). No prefix
+    sharing: a ring page is rewritten in place.
+    """
+
+    group = "window"
+
+    def __init__(self, num_slots: int, max_seq_len: int, num_layers: int,
+                 num_kv_heads: int, head_dim: int, window_tokens: int,
+                 page_size: Optional[int] = None,
+                 num_pages: Optional[int] = None, dtype="float32",
+                 name: str = "decode"):
+        page_size = _page_size(page_size)
+        if window_tokens < 1 or window_tokens % page_size:
+            raise MXNetError("window of %d tokens is not a whole number of "
+                             "%d-token pages" % (window_tokens, page_size))
+        ring = window_tokens + page_size
+        # the base class sizes a row (and the default pool) from the
+        # longest sequence: here that is the ring
+        super().__init__(num_slots, min(int(max_seq_len), ring), num_layers,
+                         num_kv_heads, head_dim, page_size=page_size,
+                         num_pages=num_pages or 0, dtype=dtype, name=name)
+        self.window_tokens = int(window_tokens)
+        self.ring_tokens = self.max_pages * self.page_size
+        self.max_seq_len = int(max_seq_len)
+        if self.num_pages - 1 < self.max_pages:
+            raise MXNetError(
+                "kvcache %r: the window group needs at least one whole "
+                "ring (%d pages + the null page), got %d pages"
+                % (name, self.max_pages, self.num_pages))
+        _T_GROUP_CAPACITY.set(self.num_pages - 1, cache=self.name,
+                              group=self.group)
+
+    def ring_pages_for(self, n_tokens: int) -> int:
+        """Pages a sequence of ``n_tokens`` holds in this group."""
+        return self.pages_for(min(int(n_tokens), self.ring_tokens))
+
+    def can_admit(self, n_tokens: int) -> bool:
+        return self.ring_pages_for(n_tokens) <= self.pages_available
+
+    def reserve(self, slot: int, n_tokens: int, _pin=()) -> None:
+        if n_tokens > self.max_seq_len:
+            raise MXNetError(
+                "sequence of %d tokens exceeds max_seq_len %d"
+                % (n_tokens, self.max_seq_len))
+        super().reserve(slot, min(int(n_tokens), self.ring_tokens))
+
+    def reserved_tokens(self, slot: int) -> int:
+        owned = self._owned[int(slot)]
+        return self.max_seq_len if owned == self.max_pages \
+            else owned * self.page_size
+
+    def write_slots(self, slot: int, start: int,
+                    n_tokens: int) -> Tuple[np.ndarray, np.ndarray]:
+        pos = np.arange(start, start + n_tokens)
+        offsets = (pos % self.page_size).astype(np.int32)
+        if not n_tokens:
+            return np.zeros(0, np.int32), offsets
+        if pos[-1] >= self.reserved_tokens(slot):
+            raise MXNetError(
+                "write past slot %d's reservation (pos %d, %d pages)"
+                % (slot, int(pos[-1]), self._owned[slot]))
+        block = pos // self.page_size
+        pages = self.page_table[slot, block % self.max_pages]
+        # of the blocks that share a column only the last one is written
+        keep = block > block[-1] - self.max_pages
+        return np.where(keep, pages, 0).astype(np.int32), offsets
+
+    def page_at(self, slot: int, pos: int) -> int:
+        """The page that holds position ``pos`` of ``slot`` (the decode
+        tick's one write a slot)."""
+        return int(self.page_table[
+            slot, (pos // self.page_size) % self.max_pages])
+
+    def _publish(self) -> None:
+        _T_GROUP_PAGES.set(self.pages_in_use, cache=self.name,
+                           group=self.group)
+        if self.audit:
+            self.audit_check()
+
+    def stats(self) -> dict:
+        out = super().stats()
+        out["window_tokens"] = self.window_tokens
+        return out
+
+
+class GroupedKVCache:
+    """The cache of a model that declares ``kv_groups``: its full-attention
+    layers in a :class:`PagedKVCache` (``full``) and its sliding-window
+    layers in a :class:`RingKVCache` (``window``), side by side — two sets
+    of pools, two page tables, two free lists, one slot numbering.
+
+    What the decode engine asks of a cache it asks of this one; pools,
+    tables and write pages come back as ``(full, window)`` pairs, which is
+    how the model's ``decode`` / ``prefill`` receive them. Page counts
+    without a group's name (``pages_in_use``, ``num_pages``,
+    ``pages_for``, a tenant's page budget) are the FULL group's: it is the
+    one that grows with the sequence and gates admission first; the window
+    group reports under ``stats()["window"]`` and its own gauges. A
+    reservation takes pages in both groups or in neither.
+    """
+
+    prefix_cache = False
+
+    def __init__(self, num_slots: int, max_seq_len: int, kv_groups: dict,
+                 num_kv_heads: int, head_dim: int,
+                 page_size: Optional[int] = None, num_pages=None,
+                 dtype="float32", name: str = "decode"):
+        pages = dict(num_pages) if isinstance(num_pages, dict) \
+            else {"full": num_pages}
+        self.full = PagedKVCache(
+            num_slots, max_seq_len, len(kv_groups["full"]), num_kv_heads,
+            head_dim, page_size=page_size, num_pages=pages.get("full"),
+            dtype=dtype, name=name)
+        self.window = RingKVCache(
+            num_slots, max_seq_len, len(kv_groups["window"]), num_kv_heads,
+            head_dim, int(kv_groups["window_tokens"]),
+            page_size=self.full.page_size, num_pages=pages.get("window"),
+            dtype=dtype, name=name)
+        # the ring's base class published ITS capacity under this name
+        _T_CAPACITY.set(self.full.num_pages - 1, cache=name)
+        self.name = name
+        self.page_size = self.full.page_size
+        self.num_slots = self.full.num_slots
+        self.max_seq_len = self.full.max_seq_len
+        self.max_pages = self.full.max_pages
+        self.num_pages = self.full.num_pages
+        self.audit = self.full.audit
+        self.seq_lens = self.full.seq_lens
+        self.pressure_sheds = 0
+
+    # -- what a PagedKVCache answers, for the full group -----------------
+    pages_in_use = property(lambda self: self.full.pages_in_use)
+    page_table = property(lambda self: self.full.page_table)
+
+    def pages_for(self, n_tokens: int) -> int:
+        return self.full.pages_for(n_tokens)
+
+    def exclusive_pages(self, slot: int) -> int:
+        return self.full.exclusive_pages(slot)
+
+    # -- both groups -----------------------------------------------------
+    @property
+    def tables(self):
+        """``((version, host table), ...)``, the full group's first."""
+        return ((self.full.version, self.full.page_table),
+                (self.window.version, self.window.page_table))
+
+    @property
+    def k_pool(self):
+        return (self.full.k_pool, self.window.k_pool)
+
+    @property
+    def v_pool(self):
+        return (self.full.v_pool, self.window.v_pool)
+
+    def swap_pools(self, k_pool, v_pool) -> None:
+        self.full.swap_pools(k_pool[0], v_pool[0])
+        self.window.swap_pools(k_pool[1], v_pool[1])
+
+    def reset_pools(self) -> None:
+        self.full.reset_pools()
+        self.window.reset_pools()
+
+    def can_admit_prefix(self, n_tokens: int, match=None) -> bool:
+        return self.full.can_admit(n_tokens) \
+            and self.window.can_admit(n_tokens)
+
+    def reserve(self, slot: int, n_tokens: int) -> None:
+        if self.window.ring_pages_for(n_tokens) \
+                - self.window.pages_owned(slot) > self.window.pages_free:
+            raise OutOfPagesError(
+                "kvcache %r: the window group cannot cover %d tokens "
+                "(%d pages free)" % (self.name, n_tokens,
+                                     self.window.pages_free))
+        self.full.reserve(slot, n_tokens)   # raises before any mutation
+        self.window.reserve(slot, n_tokens)
+
+    def free(self, slot: int) -> None:
+        self.full.free(slot)
+        self.window.free(slot)
+
+    def shed_cached(self, n=None) -> int:
+        return 0  # no prefix index, so no cached page to shed
+
+    def insert_prefix(self, slot: int, prompt) -> None:
+        """No prefix index: a ring page is rewritten in place."""
+
+    def write_slots(self, slot: int, start: int, n_tokens: int):
+        """``(pages (2, n), offsets (n,))``: one row of destination pages a
+        group, the full group's first; a page's offsets are the same in
+        both (one page size)."""
+        pages, offsets = self.full.write_slots(slot, start, n_tokens)
+        ring, _ = self.window.write_slots(slot, start, n_tokens)
+        return np.stack([pages, ring]), offsets
+
+    def null_write_slots(self, n_tokens: int):
+        pages, offsets = self.full.null_write_slots(n_tokens)
+        return np.stack([pages, pages]), offsets
+
+    def audit_check(self) -> None:
+        self.full.audit_check()
+        self.window.audit_check()
+
+    def stats(self) -> dict:
+        out = self.full.stats()
+        out["window"] = self.window.stats()
         return out

@@ -1,0 +1,345 @@
+"""``serving.AfmoeDecoder`` through ``DecodeEngine`` and both cache groups
+against the repo's plain reference (``serving/afmoe_reference.py``, float32
+``highest``), at a tiny size that keeps the shape of the thing: 12 query / 2
+kv heads, window 32 with sequences to 100, 8-token pages (a ring of 5 pages
+= 40 tokens, so a 100-token sequence wraps it twice), 16 experts top-4 of
+which 4 are held, 5 layers ``s | s, s, s, f``.
+
+Tolerances. ``LOGIT_TOL`` 2e-4 on logits of standard deviation about 1: both
+sides are float32; the program reads its K/V back through pages, sums a
+token's picks in another order and (prefill) pads to a rung — rounding
+differences of order 1e-6 a layer. A run whose activations are rounded to
+bfloat16 at every norm misses it by two orders of magnitude (asserted). The
+engine's greedy tokens are compared by where the reference puts them: a
+served token's reference logit may lie at most ``GAP_TOL`` = 1e-3 row standard
+deviations below the reference's best (random weights put near-ties in a
+96-way argmax; a flipped near-tie is rounding, a wrong cache row is not).
+"""
+import glob
+import os
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu import serving, telemetry
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.serving import afmoe_reference as ref
+from mxnet_tpu.serving import decode as decode_mod
+from mxnet_tpu.serving.kvcache import (GroupedKVCache, OutOfPagesError,
+                                       RingKVCache)
+
+LOGIT_TOL = 2e-4
+GAP_TOL = 1e-3
+WINDOW, PAGE, VOCAB = 32, 8, 96
+TINY = dict(vocab_size=VOCAB, hidden_size=48, num_attention_heads=12,
+            num_key_value_heads=2, head_dim=8, intermediate_size=96,
+            moe_intermediate_size=32,
+            layer_types=["sliding_attention"] * 4 + ["full_attention"],
+            num_dense_layers=1, num_experts=16, num_experts_per_tok=4,
+            sliding_window=WINDOW, held_experts=[4, 4], route_scale=2.448,
+            mup_enabled=True)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    model = serving.AfmoeDecoder(**TINY)
+    return model, model.init_params(0)
+
+
+def _engine(tiny, **kw):
+    model, params = tiny
+    kw.setdefault("num_slots", 3)
+    kw.setdefault("max_seq_len", 128)
+    kw.setdefault("page_size", PAGE)
+    kw.setdefault("prefill_buckets", (16, 64))
+    kw.setdefault("timeout_ms", 0)
+    kw.setdefault("prefix_cache", False)
+    kw.setdefault("prefill_chunk", 0)
+    kw.setdefault("name", "af%d" % np.random.randint(1 << 30))
+    return serving.DecodeEngine(model, params, **kw)
+
+
+def _prompt(n, seed):
+    return np.random.RandomState(seed).randint(1, VOCAB, n).astype(np.int32)
+
+
+def _direct(model, params, prompt, steps, rung=64):
+    """Prefill then ``steps`` teacher-forced decode steps through a
+    ``GroupedKVCache``, as the engine drives them; the logits of every
+    position from the prompt's last on."""
+    cache = GroupedKVCache(2, 128, model.kv_groups, model.num_kv_heads,
+                           model.head_dim, page_size=PAGE)
+    slot, p = 1, prompt.size
+    seq = np.concatenate([prompt, _prompt(steps, 99)])
+    cache.reserve(slot, p + steps)
+    pages, offs = cache.write_slots(slot, 0, p)
+    pad = rung - p
+    tokens = np.concatenate([prompt, np.zeros(pad, np.int32)])
+    wp = tuple(jnp.asarray(np.concatenate([g, np.zeros(pad, np.int32)]))
+               for g in pages)
+    wo = jnp.asarray(np.concatenate([offs, np.zeros(pad, np.int32)]))
+    out = model.prefill(params, jnp.asarray(tokens), jnp.asarray(p),
+                        cache.k_pool, cache.v_pool, wp, wo)
+    logits = [np.asarray(out[0])]
+    k_pool, v_pool = out[1], out[2]
+    tables = tuple(jnp.asarray(t) for _v, t in cache.tables)
+    for i in range(steps):
+        pos = p + i
+        rows = np.zeros((2,), np.int32)
+        toks, poss, lens = rows.copy(), rows.copy(), rows.copy()
+        toks[slot], poss[slot], lens[slot] = seq[pos], pos, pos + 1
+        pages, offs = cache.write_slots(slot, pos, 1)
+        wp = tuple(jnp.asarray(np.where(np.arange(2) == slot, g[0], 0)
+                               .astype(np.int32)) for g in pages)
+        wo = jnp.asarray(np.where(np.arange(2) == slot, offs[0], 0)
+                         .astype(np.int32))
+        out = model.decode(params, jnp.asarray(toks), jnp.asarray(poss),
+                           k_pool, v_pool, tables, jnp.asarray(lens), wp, wo)
+        logits.append(np.asarray(out[0])[slot])
+        k_pool, v_pool = out[1], out[2]
+    return seq, np.stack(logits)
+
+
+@pytest.mark.parametrize("p,steps", [(5, 30), (30, 12), (47, 60)])
+def test_prefill_then_decode_logits_equal_the_reference(tiny, p, steps):
+    """Across the window edge (position 32) and the ring's wrap (position
+    40, and again at 80): logits, not tokens."""
+    model, params = tiny
+    seq, got = _direct(model, params, _prompt(p, p), steps)
+    want = np.asarray(ref.forward_logits(model.cfg, params, seq))[p - 1:]
+    assert want.std() > 0.5
+    np.testing.assert_allclose(got, want[:got.shape[0]], atol=LOGIT_TOL)
+
+
+def test_bfloat16_activations_miss_the_logit_tolerance(tiny, monkeypatch):
+    model, params = tiny
+    rms = serving.AfmoeDecoder._rms
+    monkeypatch.setattr(
+        serving.AfmoeDecoder, "_rms", lambda self, x, g:
+        rms(self, x, g).astype(jnp.bfloat16).astype(jnp.float32))
+    seq, got = _direct(model, params, _prompt(30, 30), 12)
+    want = np.asarray(ref.forward_logits(model.cfg, params, seq))[29:]
+    assert np.abs(got - want[:got.shape[0]]).max() > 50 * LOGIT_TOL
+
+
+def _gap_sd(model, params, prompt, out):
+    seq = np.concatenate([prompt, out[:-1]])
+    rows = np.asarray(ref.forward_logits(model.cfg, params, seq))
+    rows = rows[prompt.size - 1:]
+    got = rows[np.arange(out.size), out]
+    return float(((rows.max(-1) - got) / rows.std(-1)).max())
+
+
+def test_engine_serves_what_the_reference_puts_first_under_churn(
+        tiny, monkeypatch):
+    """More requests than slots, prompts on both sides of the window and
+    past the ring, pages audited at every mutation and every tick: every
+    served token is the reference's best (to a near-tie), the window group
+    never holds more than its ring a slot, both groups free to zero, and
+    nothing compiles after warm-up."""
+    monkeypatch.setenv("MXNET_KVCACHE_AUDIT", "1")
+    model, params = tiny
+    sizes = [(5, 20), (40, 24), (70, 30), (100, 28), (12, 9), (33, 40),
+             (64, 8), (90, 12)]
+    ring_pages = WINDOW // PAGE + 1
+    with _engine(tiny) as eng:
+        assert eng._cache.audit and eng._cache.window.audit
+        assert eng.warmup() == 4      # the step and three prefill rungs
+        prompts = [_prompt(n, i) for i, (n, _m) in enumerate(sizes)]
+        futs = [eng.submit(p, m) for p, (_n, m) in zip(prompts, sizes)]
+        peak = 0
+        while not all(f.done() for f in futs):
+            win = eng._cache.window
+            assert max(win.pages_owned(s) for s in range(3)) <= ring_pages
+            peak = max(peak, eng.kvcache_stats()["window"]["pages_in_use"])
+            time.sleep(0.01)
+        outs = [f.result(timeout=300) for f in futs]
+        stats = eng.stats()
+        eng._cache.audit_check()
+    assert 0 < peak <= 3 * ring_pages
+    assert stats["steady_state_recompiles"] == 0
+    assert stats["kvcache"]["pages_in_use"] == 0
+    assert stats["kvcache"]["window"]["pages_in_use"] == 0
+    assert stats["kvcache"]["window"]["pages_capacity"] == 3 * ring_pages
+    for prompt, out, (_n, m) in zip(prompts, outs, sizes):
+        assert out.size == m
+        assert _gap_sd(model, params, prompt, out) <= GAP_TOL
+    # the expert-load counters: every (token, pick) row of every expert
+    # layer is either held here or absent
+    tokens = sum(n + m - 1 for n, m in sizes)
+    moe = stats["moe"]
+    assert moe["expert_layers"] == 4 and moe["experts_held"] == 4
+    assert moe["rows_held"] + moe["rows_absent"] == tokens * 4 * 4
+    assert moe["rows_held"] == sum(map(sum, moe["rows_by_expert"]))
+    assert moe["load_max_over_mean"] >= 1.0
+    assert "mxnet_moe_expert_load_max_over_mean" in \
+        telemetry.render_prometheus()
+    assert decode_mod._T_MOE_ROWS.value(server=eng.name, where="held") \
+        == moe["rows_held"]
+    assert decode_mod._T_MOE_ROWS.value(server=eng.name, where="absent") \
+        == moe["rows_absent"]
+
+
+def test_one_put_and_one_fetch_a_steady_tick(tiny, monkeypatch):
+    """The counters ride the tick's one fetch and both groups' write pages
+    its one packed operand."""
+    fetches, puts = [], []
+    real_fetch = decode_mod.fetch_host
+    monkeypatch.setattr(decode_mod, "fetch_host",
+                        lambda xs: fetches.append(len(xs)) or real_fetch(xs))
+    with _engine(tiny, num_slots=2) as eng:
+        eng.warmup()
+        real_asarray = eng._jnp.asarray
+
+        class Counting:
+            def __getattr__(self, name):
+                return getattr(jnp, name)
+
+            @staticmethod
+            def asarray(x, *a, **k):
+                puts.append(np.shape(x))
+                return real_asarray(x, *a, **k)
+
+        eng._jnp = Counting()
+        out = eng.generate(_prompt(20, 3), 16, timeout=300)
+        stats = eng.stats()
+    assert out.size == 16 and stats["ticks"] == 15
+    assert fetches == [1] * 16          # one prefill, fifteen ticks
+    # a tick puts its (6, slots) operand and nothing else: the tables were
+    # put once each after the admission, the prefill put two operands
+    assert puts.count((6, 2)) == 15
+    assert len(puts) == 15 + 2 + 2
+
+
+def test_ring_cache_reserves_a_ring_and_writes_the_last_window():
+    ring = RingKVCache(2, 128, 4, 2, 8, WINDOW, page_size=PAGE)
+    assert ring.max_pages == 5 and ring.ring_tokens == 40
+    assert ring.num_pages == 2 * 5 + 1
+    ring.reserve(0, 20)
+    assert ring.pages_owned(0) == 3 and ring.reserved_tokens(0) == 24
+    with pytest.raises(MXNetError):
+        ring.write_slots(0, 20, 8)            # past a short reservation
+    ring.reserve(0, 128)                      # grows to the ring, no more
+    assert ring.pages_owned(0) == 5 and ring.reserved_tokens(0) == 128
+    with pytest.raises(MXNetError):
+        ring.reserve(0, 129)
+    row = ring.page_table[0].copy()
+    assert len(set(row)) == 5 and 0 not in row
+    # a 100-token prompt: blocks 0..12, the last 5 (8..12) are written,
+    # each into column block % 5; earlier rows go to the null page
+    pages, offs = ring.write_slots(0, 0, 100)
+    block = np.arange(100) // PAGE
+    assert not pages[block <= 7].any()
+    assert np.array_equal(pages[block > 7], row[block[block > 7] % 5])
+    assert np.array_equal(offs, np.arange(100) % PAGE)
+    assert ring.page_at(0, 100) == row[(100 // PAGE) % 5]
+    ring.reserve(1, 40)
+    small = RingKVCache(3, 128, 4, 2, 8, WINDOW, page_size=PAGE, num_pages=8)
+    small.reserve(0, 128)                     # 5 of its 7 pages
+    assert small.can_admit(16) and not small.can_admit(17)
+    with pytest.raises(OutOfPagesError):
+        small.reserve(1, 24)
+    assert small.pages_owned(1) == 0
+    ring.free(0)
+    ring.free(1)
+    assert ring.pages_in_use == 0
+    ring.audit_check()
+    with pytest.raises(MXNetError):
+        RingKVCache(2, 128, 4, 2, 8, 30, page_size=PAGE)   # 30 % 8
+    with pytest.raises(MXNetError):
+        RingKVCache(2, 128, 4, 2, 8, WINDOW, page_size=PAGE, num_pages=4)
+
+
+def test_grouped_cache_takes_pages_in_both_groups_or_in_neither(tiny):
+    model, _params = tiny
+    cache = GroupedKVCache(3, 128, model.kv_groups, 2, 8, page_size=PAGE,
+                           num_pages={"full": 40, "window": 8})
+    assert cache.k_pool[0].shape == (1, 40, PAGE, 2, 8)
+    assert cache.k_pool[1].shape == (4, 8, PAGE, 2, 8)
+    cache.reserve(0, 100)
+    assert cache.full.pages_owned(0) == 13
+    assert cache.window.pages_owned(0) == 5
+    assert cache.can_admit_prefix(16) and not cache.can_admit_prefix(24)
+    with pytest.raises(OutOfPagesError):
+        cache.reserve(1, 24)          # 3 window pages, 2 free
+    assert cache.full.pages_owned(1) == 0 and cache.pages_in_use == 13
+    cache.reserve(1, 16)
+    stats = cache.stats()
+    assert stats["pages_in_use"] == 15 and stats["pages_capacity"] == 39
+    assert stats["window"] == dict(stats["window"], pages_in_use=7,
+                                   pages_capacity=7, window_tokens=WINDOW)
+    cache.free(0)
+    cache.free(1)
+    cache.audit_check()
+    assert cache.pages_in_use == 0 and cache.window.pages_in_use == 0
+    pages, offs = cache.null_write_slots(9)
+    assert pages.shape == (2, 9) and not pages.any() and offs.max() == 7
+
+
+def test_engine_admission_waits_for_pages_of_the_window_group(tiny):
+    """A window pool of two rings under three slots: the third long
+    request is admitted only when a ring frees."""
+    with _engine(tiny, num_pages={"full": 49, "window": 11}) as eng:
+        eng.warmup()
+        futs = [eng.submit(_prompt(60, i), 24) for i in range(3)]
+        outs = [f.result(timeout=300) for f in futs]
+        stats = eng.stats()
+    assert all(o.size == 24 for o in outs)
+    assert stats["kvcache"]["window"]["pages_capacity"] == 10
+    assert stats["kvcache"]["window"]["pages_in_use"] == 0
+    assert stats["steady_state_recompiles"] == 0
+
+
+def test_a_grouped_model_is_refused_the_prefix_cache_chunks_and_drafts(tiny):
+    model, params = tiny
+    for kw in ({"prefix_cache": True}, {"prefill_chunk": 8}, {"spec_k": 2}):
+        with pytest.raises(MXNetError, match="kv_groups"):
+            _engine(tiny, **kw)
+    with pytest.raises(MXNetError, match="chunked prefill"):
+        model.prefill_chunk(params, None, 0, 1, None, None, None, None, None)
+    with pytest.raises(MXNetError):
+        serving.AfmoeDecoder(**dict(TINY, layer_types=["full_attention"]))
+    with pytest.raises(MXNetError):
+        serving.AfmoeDecoder(**dict(TINY, held_experts=[14, 4]))
+
+
+def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
+    """``mx.decode.commit`` and ``mx.decode.prefill`` carry what the
+    benchmark's per-layer readers read, on the trace's own clock."""
+    keys = {"moe_rows_held", "moe_experts_hit", "moe_load_max",
+            "kv_rows_full", "kv_rows_window", "kv_window_pages",
+            "kv_window_capacity"}
+    with _engine(tiny, num_slots=2) as eng:
+        eng.warmup()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            eng.generate(_prompt(50, 5), 6, timeout=300)
+            eng.close()
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    events = [(ev.name, dict(ev.stats))
+              for plane in jax.profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name in ("mx.decode.commit", "mx.decode.prefill")]
+    prefill = [a for n, a in events if n == "mx.decode.prefill"]
+    commits = [a for n, a in events if n == "mx.decode.commit"]
+    assert len(prefill) == 1 and len(commits) == 5
+    assert set(prefill[0]) == keys | {"rung"}
+    assert prefill[0]["kv_rows_full"] == 50
+    assert prefill[0]["kv_rows_window"] == WINDOW
+    for i, args in enumerate(commits):
+        assert set(args) == keys
+        assert args["kv_rows_full"] == 51 + i
+        assert args["kv_rows_window"] == WINDOW
+        assert args["kv_window_pages"] == 5
+        assert args["kv_window_capacity"] == 10
+        assert 0 <= args["moe_load_max"] <= args["moe_rows_held"] <= 16

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from mxnet_tpu.ops import moe
 from mxnet_tpu.ops import pallas_kernels as pk
 
 # chip_smoke.py's serve shapes (SERVE there): 16 slots, 4 heads x 128, the
@@ -89,6 +90,27 @@ def _case(name, spec, dtype):
         qkv = spec((1, HEADS, 1152, HEAD_DIM), dtype)
         return (lambda q, k, v: pk._flash_forward(q, k, v, 0.088, True),
                 (qkv, qkv, qkv))
+    # Trinity-Large-Preview's attention (48 query / 8 kv heads x 128, window
+    # 4096, 16-token pages -> a ring of 257 pages a slot) and expert widths
+    if name == "paged_window_decode":
+        ring = 4096 // PAGE + 1
+        pool = spec((SLOTS * ring + 1, PAGE, 8, 128), dtype)
+        return (lambda q, k, v, pt, sl: pk.ragged_window_attention(
+            q, k, v, pt, sl, 4096, interpret=False),
+            (spec((SLOTS, 48, 128), dtype), pool, pool,
+             spec((SLOTS, ring), jnp.int32), spec((SLOTS,), jnp.int32)))
+    if name in ("band_prefill_8192", "band_prefill_8192_window"):
+        window = 4096 if name.endswith("window") else 0
+        kv = spec((8192, 8, 128), dtype)
+        return (lambda q, k, v: pk.band_attention(
+            q, k, v, window=window, interpret=False),
+            (spec((8192, 48, 128), dtype), kv, kv))
+    if name in ("moe_gmm_decode", "moe_gmm_prefill"):
+        rows = 64 if name.endswith("decode") else 8192 * 4
+        return (lambda x, w, g: moe.grouped_matmul(x, w, g, interpret=False),
+                (spec((rows, 3072), dtype),
+                 spec((32, 3072, 3072), jnp.bfloat16),
+                 spec((32,), jnp.int32)))
     if name == "nms":
         n = 1000
         return (lambda b, c, v: pk.nms_keep(b, c, v, 0.5, False),
@@ -100,7 +122,10 @@ def _case(name, spec, dtype):
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("name", ["paged_decode", "paged_prefill_chunk",
                                   "paged_spec_verify", "flash_attention",
-                                  "nms"])
+                                  "nms", "paged_window_decode",
+                                  "band_prefill_8192",
+                                  "band_prefill_8192_window",
+                                  "moe_gmm_decode", "moe_gmm_prefill"])
 def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
                                monkeypatch):
     monkeypatch.setattr(pk, "_interpret", lambda: False)
@@ -117,6 +142,9 @@ def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
     ("paged_decode", "mx_paged_attn"),
     ("paged_spec_verify", "mx_paged_attn"),
     ("flash_attention", "mx_flash_attn"),
+    ("paged_window_decode", "mx_paged_attn"),
+    ("band_prefill_8192_window", "mx_prefill_attn"),
+    ("moe_gmm_decode", "mx_moe_gmm"),
 ])
 def test_kernel_keeps_its_name_in_the_compiled_program(
         name, kernel, one_chip, compile_cache_off, monkeypatch):

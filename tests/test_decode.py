@@ -1136,3 +1136,117 @@ def test_engine_audit_shared_prefix_chaos_eviction(tiny, monkeypatch):
             eng.generate(prompt, 4),
             model.reference_generate(params, prompt, 4))
         eng._cache.audit_check()
+
+
+# ---------------------------------------------------------------------------
+# window layers: the ring table in decode, the band in prefill (PR 27)
+# ---------------------------------------------------------------------------
+
+def test_ring_blocks_names_the_block_each_column_holds():
+    got = np.asarray(pk.ring_blocks(jnp.asarray([0, 5, 33, 100]), 5, 8))
+    assert got.tolist() == [[-5, -4, -3, -2, -1], [0, -4, -3, -2, -1],
+                            [0, 1, 2, 3, 4], [10, 11, 12, 8, 9]]
+
+
+@pytest.mark.parametrize("h,kh", [(4, 4), (12, 2)])
+def test_window_kernel_over_a_ring_table_matches_dense_oracle(h, kh):
+    """Interpret mode: an idle slot, a sequence inside the window, one past
+    it and one that wrapped the ring twice, each against the dense form."""
+    rng = np.random.RandomState(7)
+    s, d, ps, cols, window = 4, 16, 8, 5, 32
+    pool = 1 + s * cols
+    k_pool = jnp.asarray(rng.randn(pool, ps, kh, d).astype(np.float32))
+    v_pool = jnp.asarray(rng.randn(pool, ps, kh, d).astype(np.float32))
+    pt = jnp.asarray(1 + rng.permutation(s * cols).reshape(s, cols),
+                     jnp.int32)
+    q = jnp.asarray(rng.randn(s, h, d).astype(np.float32))
+    lens = jnp.asarray([0, 5, 37, 100], jnp.int32)
+    got = pk.ragged_window_attention(q, k_pool, v_pool, pt, lens, window,
+                                     interpret=True)
+    want = pk.paged_window_attention_reference(q, k_pool, v_pool, pt, lens,
+                                               window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert not np.asarray(got)[0].any()
+    # the dense form itself, for the wrapped sequence: keys 68..99 and no
+    # other, each read from column (p // 8) % 5
+    pos = np.arange(100 - window, 100)
+    rows_k = np.asarray(k_pool)[np.asarray(pt)[3][(pos // ps) % cols],
+                                pos % ps]
+    rows_v = np.asarray(v_pool)[np.asarray(pt)[3][(pos // ps) % cols],
+                                pos % ps]
+    g = h // kh
+    sc = np.einsum("hd,thd->ht", np.asarray(q)[3],
+                   np.repeat(rows_k, g, 1)) / np.sqrt(d)
+    p = np.exp(sc - sc.max(1, keepdims=True))
+    p /= p.sum(1, keepdims=True)
+    np.testing.assert_allclose(
+        np.einsum("ht,thd->hd", p, np.repeat(rows_v, g, 1)),
+        np.asarray(want)[3], atol=2e-5)
+
+
+def test_paged_kernel_masks_a_window_over_an_ordinary_table():
+    rng = np.random.RandomState(8)
+    s, h, kh, d, ps, mp, window = 3, 4, 2, 16, 8, 6, 16
+    q, k_pool, v_pool, pt = _rand_pool(rng, s, h, kh, d, 1 + s * mp, ps, mp)
+    lens = jnp.asarray([3, 20, 48], jnp.int32)
+    got = pk._paged_call(q[:, None], k_pool, v_pool, pt, lens, None, None,
+                         True, "test", window=window)[:, 0]
+    # the dense oracle over the last `window` keys: gather them to the front
+    want = []
+    for i, n in enumerate(np.asarray(lens)):
+        lo = max(0, n - window)
+        pos = np.arange(lo, n)
+        kk = np.asarray(k_pool)[np.asarray(pt)[i][pos // ps], pos % ps]
+        vv = np.asarray(v_pool)[np.asarray(pt)[i][pos // ps], pos % ps]
+        sc = np.einsum("hd,thd->ht", np.asarray(q)[i],
+                       np.repeat(kk, h // kh, 1)) / np.sqrt(d)
+        p = np.exp(sc - sc.max(1, keepdims=True))
+        p /= p.sum(1, keepdims=True)
+        want.append(np.einsum("ht,thd->hd", p, np.repeat(vv, h // kh, 1)))
+    np.testing.assert_allclose(np.asarray(got), np.stack(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [0, 32, 40])
+@pytest.mark.parametrize("h,kh", [(4, 4), (12, 2)])
+def test_band_attention_kernel_matches_dense_oracle(window, h, kh):
+    """Interpret mode, 16-row blocks over 100 rows (padded to 112): causal,
+    a window of whole blocks and one that cuts a block."""
+    rng = np.random.RandomState(9)
+    t, d = 100, 16
+    q = jnp.asarray(rng.randn(t, h, d).astype(np.float32))
+    k = jnp.asarray(rng.randn(t, kh, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(t, kh, d).astype(np.float32))
+    got = pk.band_attention(q, k, v, window=window, block=16,
+                            interpret=True)
+    want = pk.band_attention_reference(q, k, v, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_a_model_without_kv_groups_keeps_its_one_group_and_its_operands(tiny):
+    """TinyDecoder declares neither ``kv_groups`` nor ``moe_counters``: one
+    PagedKVCache, a (5, S) step operand, a (3, rung) prefill operand, no
+    counters behind the tokens, no new key in its stats."""
+    from mxnet_tpu.serving.kvcache import PagedKVCache
+
+    with _engine(tiny, prefix_cache=False) as eng:
+        assert type(eng._cache) is PagedKVCache
+        assert eng._extra_rows == 0 and eng._moe_rows is None
+        eng.warmup()
+        shapes = []
+        real = eng._jnp.asarray
+
+        class Spy:
+            def __getattr__(self, name):
+                return getattr(jnp, name)
+
+            @staticmethod
+            def asarray(x, *a, **k):
+                shapes.append(np.shape(x))
+                return real(x, *a, **k)
+
+        eng._jnp = Spy()
+        out = eng.generate(np.arange(1, 7, dtype=np.int32), 5, timeout=120)
+        stats = eng.stats()
+    assert out.size == 5
+    assert (3, 8) in shapes and shapes.count((5, 3)) == 4
+    assert "moe" not in stats and "window" not in stats["kvcache"]

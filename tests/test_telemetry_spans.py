@@ -304,6 +304,10 @@ def test_decode_tick_spans_carry_the_slots_in_use(tiny, tmp_path):
             futs = [eng.submit(p, m) for p, m in _prompts(7)]
             for f in futs:
                 f.result(timeout=120)
+            # the pass that resolved the last future is still open: join
+            # the worker before the trace stops, or that pass's children
+            # are in the trace and the pass itself is not
+            eng.close()
 
         events = _traced(tmp_path, work)
         stats = eng.stats()
