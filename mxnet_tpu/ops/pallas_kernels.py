@@ -273,21 +273,28 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # one is — membership churn and ragged lengths never retrace (the Ragged
 # Paged Attention argument, PAPERS.md).
 #
-# Kernel layout: grid (S, max_pages); the page axis is the innermost
+# Kernel layout: grid (S, columns up to the longest slot's last live one —
+# a traced extent, at most max_pages); the page axis is the innermost
 # ("arbitrary") dimension and carries online-softmax state (running max m,
 # normalizer l, accumulator acc) in VMEM scratch, exactly the flash-kernel
-# idiom above. The page table and sequence lengths ride in as
-# scalar-prefetch operands (PrefetchScalarGridSpec), so the K/V BlockSpec
-# index_map dereferences the page table — the pool page is DMA'd straight
-# into VMEM with no gather op in the kernel body. Interpret mode runs the
-# same kernel on the CPU test mesh; `paged_attention` (the dispatcher the
-# decode engine calls) uses the dense jnp reference off-TPU instead, which
-# is faster than interpreting and bit-comparable within fp tolerance.
+# idiom above. The page table, the sequence lengths and each slot's LIVE
+# column range ``[c0, c1)`` (:func:`live_columns`: the table columns that
+# hold a key some query row of the slot may see) ride in as scalar-prefetch
+# operands (PrefetchScalarGridSpec), so the K/V BlockSpec index_map
+# dereferences the page table — the pool page is DMA'd straight into VMEM
+# with no gather op in the kernel body. The walk is bounded by that range:
+# a grid step outside it asks for the page that is already resident (the
+# launch clamps the table: no new DMA) and skips the products
+# (``pl.when``); inside it the ragged mask works position by position.
+# Interpret mode runs the same kernel on the CPU test mesh;
+# `paged_attention` (the dispatcher the decode engine calls) uses the dense
+# jnp reference off-TPU instead, which is faster than interpreting and
+# bit-comparable within fp tolerance.
 
 
-def _paged_kernel(pt_ref, sl_ref, qp_ref, *rest, page_size, max_pages,
-                  groups, width, scale, causal, window=0, ring=False,
-                  precision=None):
+def _paged_kernel(pt_ref, sl_ref, qp_ref, lc_ref, *rest, page_size,
+                  max_pages, groups, width, scale, causal, window=0,
+                  ring=False, precision=None):
     """One (slot, page) cell of ragged paged attention, ``width`` query
     tokens per slot (1 = classic decode tick / chunked-prefill row, K+1 =
     speculative verify tick).
@@ -298,13 +305,16 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, *rest, page_size, max_pages,
     sublane tile. k_ref/v_ref: (1, page_size, KH, D) — the page named by
     the slot's page table. Scratch m/l: (KH, Rp, LANES), acc: (KH, Rp, D).
     sl_ref/qp_ref are (S*width,): PER-QUERY-TOKEN seq_len and (when
-    ``causal``) query position. Every per-kv-head access indexes a LEADING
-    ref axis or loads one kv head straight from the page ref — no value
-    slicing, which Mosaic refuses (dynamic_slice) or relayouts.
+    ``causal``) query position. lc_ref is (S*2,): the slot's live columns
+    ``[c0, c1)`` (:func:`live_columns`) — a column outside them holds no
+    key any row may see, so it is neither fetched (``pt_ref`` names a live
+    column's page there) nor multiplied. Every per-kv-head access indexes a
+    LEADING ref axis or loads one kv head straight from the page ref — no
+    value slicing, which Mosaic refuses (dynamic_slice) or relayouts.
 
     ``window`` > 0 (static) also masks keys at or below ``query - window``
     (the query is the row's ``q_pos`` when causal, else its last token).
-    ``ring`` (static): the table's columns are a ring and a fourth
+    ``ring`` (static): the table's columns are a ring and a fifth
     scalar-prefetch operand ``blk_ref`` ``(S * max_pages,)`` names the
     page-sized block of the sequence each column holds (-1: none yet).
     ``precision``: of the two products (None: the compiler's default, one
@@ -325,60 +335,63 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, *rest, page_size, max_pages,
 
     n_kv, rp = m_scr.shape[0], m_scr.shape[1]
 
-    # ragged mask, per query row: token positions of this page vs the
-    # row's length (and its query position when causal). The w of a row
-    # is its index // groups — the (Rp, 1) columns are an unrolled select
-    # over the width scalar-prefetch entries; pad rows match no w, keep
-    # length 0 and come out as zeros. Padded table entries point at page
-    # 0; the position mask kills them, so the duplicate load is harmless.
-    row_w = lax.broadcasted_iota(jnp.int32, (rp, 1), 0) // groups
-    sl_rows = jnp.zeros((rp, 1), jnp.int32)
-    qp_rows = jnp.zeros((rp, 1), jnp.int32)
-    for w in range(width):
-        sl_rows = jnp.where(row_w == w, sl_ref[s * width + w], sl_rows)
+    @pl.when(jnp.logical_and(j >= lc_ref[2 * s], j < lc_ref[2 * s + 1]))
+    def _page():
+        # ragged mask, per query row: token positions of this page vs the
+        # row's length (and its query position when causal). The w of a row
+        # is its index // groups — the (Rp, 1) columns are an unrolled
+        # select over the width scalar-prefetch entries; pad rows match no
+        # w, keep length 0 and come out as zeros.
+        row_w = lax.broadcasted_iota(jnp.int32, (rp, 1), 0) // groups
+        sl_rows = jnp.zeros((rp, 1), jnp.int32)
+        qp_rows = jnp.zeros((rp, 1), jnp.int32)
+        for w in range(width):
+            sl_rows = jnp.where(row_w == w, sl_ref[s * width + w], sl_rows)
+            if causal:
+                qp_rows = jnp.where(row_w == w, qp_ref[s * width + w],
+                                    qp_rows)
+        first = blk_ref[s * max_pages + j] if ring else j
+        pos = first * page_size \
+            + lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+        valid = pos < sl_rows
         if causal:
-            qp_rows = jnp.where(row_w == w, qp_ref[s * width + w], qp_rows)
-    first = blk_ref[s * max_pages + j] if ring else j
-    pos = first * page_size \
-        + lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-    valid = pos < sl_rows
-    if causal:
-        valid = jnp.logical_and(valid, pos <= qp_rows)
-    if window or ring:
-        # a ring column that holds no block yet has first = -1: pos < 0
-        low = (qp_rows if causal else sl_rows - 1) - window + 1 \
-            if window else 0
-        valid = jnp.logical_and(valid, pos >= jnp.maximum(low, 0))
+            valid = jnp.logical_and(valid, pos <= qp_rows)
+        if window or ring:
+            # a ring column that holds no block yet has first = -1: pos < 0
+            low = (qp_rows if causal else sl_rows - 1) - window + 1 \
+                if window else 0
+            valid = jnp.logical_and(valid, pos >= jnp.maximum(low, 0))
 
-    # per-kv-head 2D matmuls keep the MXU fed without a batched einsum;
-    # n_kv is a small trace-time constant so the python loop unrolls.
-    for khi in range(n_kv):
-        q = q_ref[0, khi].astype(jnp.float32)           # (Rp, D)
-        k = k_ref[0, :, khi, :].astype(jnp.float32)     # (page_size, D)
-        v = v_ref[0, :, khi, :].astype(jnp.float32)
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), precision=precision,
-            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(valid, scores, _NEG_BIG)
-        m_prev = m_scr[khi][:, :1]
-        l_prev = l_scr[khi][:, :1]
-        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)
-        l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-        acc_scr[khi] = acc_scr[khi] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), precision=precision,
-            preferred_element_type=jnp.float32)
-        m_scr[khi] = jnp.broadcast_to(m_new, (rp, LANES))
-        l_scr[khi] = jnp.broadcast_to(l_new, (rp, LANES))
+        # per-kv-head 2D matmuls keep the MXU fed without a batched einsum;
+        # n_kv is a small trace-time constant so the python loop unrolls.
+        for khi in range(n_kv):
+            q = q_ref[0, khi].astype(jnp.float32)           # (Rp, D)
+            k = k_ref[0, :, khi, :].astype(jnp.float32)     # (page_size, D)
+            v = v_ref[0, :, khi, :].astype(jnp.float32)
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(valid, scores, _NEG_BIG)
+            m_prev = m_scr[khi][:, :1]
+            l_prev = l_scr[khi][:, :1]
+            m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(scores - m_new)
+            l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
+            acc_scr[khi] = acc_scr[khi] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32)
+            m_scr[khi] = jnp.broadcast_to(m_new, (rp, LANES))
+            l_scr[khi] = jnp.broadcast_to(l_new, (rp, LANES))
 
-    @pl.when(j == max_pages - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
         # a fully-masked row (inactive slot, padded draft row, seq_len 0)
-        # never raises the running max off the sentinel: its p =
-        # exp(NEG_BIG - NEG_BIG) = 1 accumulates garbage the flash kernel
-        # tolerates only because it drops padded rows — here the row IS
-        # the slot's output, so gate on the max and emit zeros instead
+        # never raises the running max off the sentinel — a slot with no
+        # live column never ran a product at all: gate on the max and emit
+        # zeros (the row IS the slot's output, there is no padding to drop
+        # as in the flash kernel, where such a row's p = exp(NEG_BIG -
+        # NEG_BIG) = 1 accumulates garbage)
         seen = m_scr[:, :, :1] > _NEG_BIG * 0.5
         o = jnp.where(seen,
                       acc_scr[...] / jnp.maximum(l_scr[:, :, :1], 1e-30),
@@ -398,6 +411,33 @@ def ring_blocks(seq_lens, columns, page_size):
     return last[:, None] - (last[:, None] - col + columns) % columns
 
 
+def live_columns(seq_lens, q_pos, columns, page_size, window=0, ring=False):
+    """The columns ``[c0, c1)`` of each slot's page table that hold a key
+    some query row of the slot may see — what :func:`_paged_kernel` walks.
+    seq_lens (and q_pos, or None): ``(S, W)`` per query row; ``columns``,
+    ``page_size``, ``window``, ``ring`` static. Returns ``(S, 2)`` int32.
+
+    A row sees positions ``[lo, hi)``: ``hi`` its length (cut at its query
+    position when causal), ``lo`` the window's lower edge (0 without one).
+    Ordinary table: from the column of the lowest ``lo`` to the column of
+    the highest ``hi`` over the rows that see anything. Ring table: before
+    it wraps the live columns are the prefix that holds a block; after it
+    every column does, and at most one of them lies below the window. A
+    slot whose rows see nothing: ``c0 = c1 = 0``. ``c0 < columns`` always
+    (the launch points a dead column at one in ``[c0, max(c1 - 1, c0)]``)."""
+    sl = seq_lens.astype(jnp.int32)
+    query = sl - 1 if q_pos is None else q_pos.astype(jnp.int32)
+    hi = jnp.minimum(sl, query + 1)
+    lo = jnp.zeros_like(hi)
+    if window and not ring:
+        lo = jnp.maximum(query - window + 1, 0)
+    sees = hi > lo
+    c1 = jnp.where(sees, -(-hi // page_size), 0).max(axis=1)
+    c1 = jnp.minimum(c1, columns)
+    c0 = jnp.where(sees, lo // page_size, columns).min(axis=1)
+    return jnp.stack([jnp.minimum(c0, jnp.maximum(c1 - 1, 0)), c1], axis=1)
+
+
 def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
                 interpret, who, window=0, ring=False, precise=False):
     """Shared launch of :func:`_paged_kernel`. q: (S, W, H, D); seq_lens
@@ -414,6 +454,9 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
     if seq_lens.shape[0] != s_slots * width:
         raise ValueError("%s: seq_lens %s != S*W = %d"
                          % (who, seq_lens.shape, s_slots * width))
+    if ring and width != 1:
+        raise ValueError("%s: a ring table serves one query token a slot, "
+                         "got %d" % (who, width))
     groups = n_heads // n_kv
     max_pages = page_table.shape[1]
     if scale is None:
@@ -432,7 +475,7 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
     qk = jnp.pad(qk.reshape(s_slots, n_kv, rows, d),
                  ((0, 0), (0, 0), (0, rp - rows), (0, 0)))
     more = {}
-    if window or ring:  # a model without either keeps its program as it was
+    if window or ring:
         more = {"window": int(window), "ring": bool(ring)}
     if precise:
         more["precision"] = lax.Precision.HIGHEST
@@ -440,24 +483,35 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
         _paged_kernel, page_size=page_size, max_pages=max_pages,
         groups=groups, width=width, scale=float(scale), causal=causal,
         **more)
-    pt_flat = page_table.astype(jnp.int32).ravel()
     sl = seq_lens.astype(jnp.int32)
     qpos = q_pos.astype(jnp.int32) if causal else jnp.zeros_like(sl)
-    scalars = (pt_flat, sl, qpos)
+    live = live_columns(
+        sl.reshape(s_slots, width),
+        qpos.reshape(s_slots, width) if causal else None,
+        max_pages, page_size, window=window, ring=ring)
+    # a column outside the slot's live range names the nearest live column's
+    # page: the block a grid step out there asks for is already resident, so
+    # the pipeline issues no copy for it (clamped here, not in the index
+    # map: its scalar work is paid once a column, dead ones too)
+    col = jnp.clip(jnp.arange(max_pages, dtype=jnp.int32)[None, :],
+                   live[:, :1], jnp.maximum(live[:, 1:] - 1, live[:, :1]))
+    pt = jnp.take_along_axis(page_table.astype(jnp.int32), col, axis=1)
+    # page table, lengths, query positions, live columns (+ the ring's
+    # block numbers): traced data all, so no length ever retraces
+    scalars = (pt.ravel(), sl, qpos, live.ravel())
     if ring:
-        if width != 1:
-            raise ValueError("%s: a ring table serves one query token a "
-                             "slot, got %d" % (who, width))
         scalars += (ring_blocks(sl, max_pages, page_size).ravel(),)
 
-    def q_map(s, j, pt, *_):
+    def q_map(s, j, *_):
         return (s, 0, 0, 0)
 
     def page_map(s, j, pt, *_):
         return (pt[s * max_pages + j], 0, 0, 0)
 
     spec = dict(
-        grid=(s_slots, max_pages),
+        # the page axis ends at the longest slot's last live column: a dead
+        # column beyond it would still cost its index maps (0.1 us each)
+        grid=(s_slots, jnp.maximum(live[:, 1].max(), 1)),
         in_specs=[
             pl.BlockSpec((1, n_kv, rp, d), q_map),
             pl.BlockSpec((1, page_size, n_kv, d), page_map),
@@ -470,9 +524,8 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
             pltpu.VMEM((n_kv, rp, d), jnp.float32),
         ],
     )
-    # page table, lengths, query positions (+ the ring's block numbers)
-    grid_spec = pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=4, **spec) \
-        if ring else pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=3,
+    grid_spec = pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=5, **spec) \
+        if ring else pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=4,
                                                   **spec)
     out = pl.pallas_call(
         kernel,
@@ -493,9 +546,11 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, seq_lens,
     """Ragged paged-attention for decode: one query token per slot.
 
     q: (S, H, D); k_pool/v_pool: (P, page_size, KH, D) static pools;
-    page_table: (S, max_pages) int32 page ids (unused entries MUST point
-    at a valid page — the ragged mask drops them); seq_lens: (S,) int32
-    tokens live per slot (0 = inactive slot, output row is zeros).
+    page_table: (S, max_pages) int32 page ids (the walk ends at a
+    sequence's last page, so later entries are not read — but a slot with
+    no token still names its column 0: keep every entry a valid page id);
+    seq_lens: (S,) int32 tokens live per slot (0 = inactive slot, output
+    row is zeros).
     q_pos: optional (S,) int32 — when given, the causal bound: positions
     > q_pos[s] are masked even if < seq_lens[s] (decode passes None: the
     new token sits at seq_len - 1 and sees the whole prefix).

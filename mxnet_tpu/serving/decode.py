@@ -149,6 +149,18 @@ _T_MOE_ROWS = telemetry.counter(
     "(where=held) and to experts held elsewhere (where=absent), summed "
     "over the expert layers; prefills and decode ticks",
     labels=("server", "where"))
+_T_KV_COLS_LIVE = telemetry.counter(
+    "mxnet_decode_kv_cols_live_total",
+    "page-table columns the decode ticks' paged-attention walks ran: the "
+    "columns that hold a live key (ops.pallas_kernels.live_columns), over "
+    "slots and layers",
+    labels=("server", "group"))
+_T_KV_COLS_GRID = telemetry.counter(
+    "mxnet_decode_kv_cols_grid_total",
+    "page-table columns those walks have to choose from: the tables' "
+    "columns x slots x layers (live / grid = the share of a table a tick "
+    "fetches and multiplies)",
+    labels=("server", "group"))
 _T_MOE_LOAD = telemetry.gauge(
     "mxnet_moe_expert_load_max_over_mean",
     "rows of the busiest held expert over the mean of the held experts, "
@@ -416,6 +428,14 @@ class DecodeEngine:
                 prefix_cache=self._prefix_cache)
         # rows of the packed operands: a second group adds its write pages
         self._extra_rows = 1 if self._grouped else 0
+        # the tables a decode tick's paged attention walks: (cache group,
+        # columns, layers that walk it)
+        self._walk_groups = tuple(
+            (group, c.max_pages, c.k_pool.shape[0]) for group, c in (
+                (("full", self._cache.full), ("window", self._cache.window))
+                if self._grouped else (("full", self._cache),)))
+        self._kv_cols_live = 0
+        self._kv_cols_grid = 0
         # a model with experts returns its load counters beside the tokens
         moe_shape = getattr(model, "moe_counters", None)
         self._moe_rows = (np.zeros(moe_shape, np.int64)
@@ -905,6 +925,10 @@ class DecodeEngine:
                 # d(slot_ticks) / (d(ticks) * slots)
                 "ticks": self._ticks,
                 "slot_ticks": self._slot_ticks,
+                # page-table columns the ticks' attention walks ran, of
+                # the tables' columns x slots x layers (the share that ran)
+                "kv_cols_live": self._kv_cols_live,
+                "kv_cols_grid": self._kv_cols_grid,
                 "prefill_buckets": list(self._ladder),
                 "prefill_chunk": self._chunk,
                 "cow_copies": self._cow_copies,
@@ -1741,11 +1765,13 @@ class DecodeEngine:
                          if r is not None], exc)
             return
         with telemetry.span("decode.commit", _SPAN_CAT) as span:
+            args = self._walk_args(packed[2])
             if self._grouped or counters is not None:
                 # before the commit moves the lengths and frees slots
-                span.set_args(**self._layer_args(
+                args.update(self._layer_args(
                     counters, [int(self._cache.seq_lens[slot]) + 1
                                for slot, _req in active]))
+            span.set_args(**args)
             self._commit_step(active, toks, drafts, pages_before)
 
     def _pack_step(self, active):
@@ -1926,6 +1952,30 @@ class DecodeEngine:
         """Busiest expert's rows over the mean (0.0 before any row)."""
         total = held.sum()
         return float(held.max() * held.size / total) if total else 0.0
+
+    def _walk_args(self, row_lens) -> dict:
+        """Span arguments of a decode tick's page walk, and its counters'
+        bookkeeping: the table columns the paged-attention kernel ran
+        (``kv_cols_live``; the host's form of
+        ``ops.pallas_kernels.live_columns`` for a tick: the columns up to
+        the slot's longest row, in a ring at most all of them) of the
+        tables' columns x slots (``kv_cols_grid``), over the layers of
+        every cache group. ``row_lens``: the step operand's per-row
+        lengths."""
+        cols = -(-row_lens.reshape(self.num_slots, -1).max(axis=1)
+                 // self._cache.page_size)
+        live = grid = 0
+        for group, columns, layers in self._walk_groups:
+            n_live = layers * sum(min(int(c), columns) for c in cols)
+            n_grid = layers * self.num_slots * columns
+            _T_KV_COLS_LIVE.inc(n_live, server=self._name, group=group)
+            _T_KV_COLS_GRID.inc(n_grid, server=self._name, group=group)
+            live += n_live
+            grid += n_grid
+        with self._cv:      # stats() reads them from caller threads
+            self._kv_cols_live += live
+            self._kv_cols_grid += grid
+        return {"kv_cols_live": live, "kv_cols_grid": grid}
 
     def _layer_args(self, counters, live) -> dict:
         """Span arguments of a prefill or a decode tick of a model that

@@ -313,6 +313,7 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
     keys = {"moe_rows_held", "moe_experts_hit", "moe_load_max",
             "kv_rows_full", "kv_rows_window", "kv_window_pages",
             "kv_window_capacity"}
+    walk = {"kv_cols_live", "kv_cols_grid"}     # a decode tick's alone
     with _engine(tiny, num_slots=2) as eng:
         eng.warmup()
         opts = jax.profiler.ProfileOptions()
@@ -337,7 +338,7 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
     assert prefill[0]["kv_rows_full"] == 50
     assert prefill[0]["kv_rows_window"] == WINDOW
     for i, args in enumerate(commits):
-        assert set(args) == keys
+        assert set(args) == keys | walk
         assert args["kv_rows_full"] == 51 + i
         assert args["kv_rows_window"] == WINDOW
         assert args["kv_window_pages"] == 5

@@ -99,6 +99,19 @@ def _case(name, spec, dtype):
             q, k, v, pt, sl, 4096, interpret=False),
             (spec((SLOTS, 48, 128), dtype), pool, pool,
              spec((SLOTS, ring), jnp.int32), spec((SLOTS,), jnp.int32)))
+    # the two benchmark cells' own tables: the widest scalar-prefetch
+    # operands (table, lengths, live columns) the decode step hands SMEM
+    if name == "paged_decode_opt1p3b":      # 16 slots x 128 columns, 32 x 64
+        pool = spec((385, PAGE, 32, 64), dtype)
+        return (lambda *a: pk.ragged_paged_attention(*a, interpret=False),
+                (spec((SLOTS, 32, 64), dtype), pool, pool,
+                 spec((SLOTS, 128), jnp.int32), spec((SLOTS,), jnp.int32)))
+    if name == "paged_decode_trinity_full":  # 512 columns, 48 / 8 x 128
+        pool = spec((4097, PAGE, 8, 128), dtype)
+        return (lambda *a: pk.ragged_paged_attention(
+            *a, interpret=False, precise=True),
+            (spec((SLOTS, 48, 128), dtype), pool, pool,
+             spec((SLOTS, 512), jnp.int32), spec((SLOTS,), jnp.int32)))
     if name in ("band_prefill_8192", "band_prefill_8192_window"):
         window = 4096 if name.endswith("window") else 0
         kv = spec((8192, 8, 128), dtype)
@@ -123,6 +136,8 @@ def _case(name, spec, dtype):
 @pytest.mark.parametrize("name", ["paged_decode", "paged_prefill_chunk",
                                   "paged_spec_verify", "flash_attention",
                                   "nms", "paged_window_decode",
+                                  "paged_decode_opt1p3b",
+                                  "paged_decode_trinity_full",
                                   "band_prefill_8192",
                                   "band_prefill_8192_window",
                                   "moe_gmm_decode", "moe_gmm_prefill"])
@@ -143,6 +158,8 @@ def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
     ("paged_spec_verify", "mx_paged_attn"),
     ("flash_attention", "mx_flash_attn"),
     ("paged_window_decode", "mx_paged_attn"),
+    ("paged_prefill_chunk", "mx_paged_attn"),
+    ("paged_decode_opt1p3b", "mx_paged_attn"),
     ("band_prefill_8192_window", "mx_prefill_attn"),
     ("moe_gmm_decode", "mx_moe_gmm"),
 ])

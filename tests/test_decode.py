@@ -1206,6 +1206,159 @@ def test_paged_kernel_masks_a_window_over_an_ordinary_table():
     np.testing.assert_allclose(np.asarray(got), np.stack(want), atol=2e-5)
 
 
+# ---------------------------------------------------------------------------
+# the bounded page walk: live_columns, and the kernel under it (PR 28)
+# ---------------------------------------------------------------------------
+
+_WALK_PS = 8
+_WALK_LAUNCHES = ["decode", "verify", "chunk", "ring", "window"]
+_WALK_LENGTHS = [0, 1, _WALK_PS, _WALK_PS + 1, "full"]
+
+
+def _walk_launch(kind, n):
+    """One of ``_paged_call``'s five launches at tiny sizes, slot 0 (the
+    chunk: the whole launch) holding ``n`` tokens (``"full"``: as many as
+    its table holds): ``run(pt)`` the kernel in interpret mode, ``ref(pt)``
+    its dense oracle, the table ``pt``, per-row lengths ``sl`` and query
+    positions ``qp`` ``(S, W)`` and the static ``cols/window/ring``."""
+    rng = np.random.RandomState(28)
+    ps, h, kh, d = _WALK_PS, 4, 2, 8
+    cols, window, ring = (5, 32, True) if kind == "ring" else \
+        (6, 16 if kind == "window" else 0, False)
+    if n == "full":
+        n = cols * ps
+    qp = None
+    if kind == "verify":        # W = 4: three rows in a chain, one padded
+        sl = np.asarray([[max(n - 2, 0), max(n - 1, 0), n, 0],
+                         [19, 20, 21, 22], [0, 0, 0, 0]], np.int32)
+    elif kind == "chunk":       # 4 chunk rows of ONE sequence of n tokens,
+        # each a slot of its own over one table row, the last one padding
+        start = max(n - 3, 0)
+        qp = (start + np.arange(4, dtype=np.int32))[:, None]
+        sl = np.where(np.arange(4)[:, None] < min(n, 3), n, 0).astype(
+            np.int32)
+    elif kind == "ring":        # beside it: inside the window, wrapped twice
+        sl = np.asarray([[n], [37], [100]], np.int32)
+    else:
+        sl = np.asarray([[n], [20], [48]], np.int32)
+    s, w = sl.shape
+    pool = 2 + s * cols         # page 0 the null page, the last one NaN
+    q = jnp.asarray(rng.randn(s, w, h, d).astype(np.float32))
+    kp = rng.randn(pool, ps, kh, d).astype(np.float32)
+    vp = rng.randn(pool, ps, kh, d).astype(np.float32)
+    kp[-1] = vp[-1] = np.nan
+    kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+    pt = 1 + rng.permutation(s * cols).reshape(s, cols).astype(np.int32)
+    if kind == "chunk":
+        pt[:] = pt[0]
+    flat = jnp.asarray(sl.ravel())
+    qflat = None if qp is None else jnp.asarray(qp.ravel())
+
+    def run(table):
+        return pk._paged_call(q, kp, vp, jnp.asarray(table), flat, qflat,
+                              None, True, "test", window=window, ring=ring)
+
+    def ref(table):
+        table = jnp.asarray(table)
+        if kind == "verify":
+            out = pk.paged_spec_attention_reference(
+                q.reshape(s * w, h, d), kp, vp, table, flat)
+        elif kind == "ring":
+            out = pk.paged_window_attention_reference(
+                q[:, 0], kp, vp, table, flat, window)
+        elif kind == "window":
+            pos = np.arange(cols * ps)[None, :]
+            out = pk._dense_paged(
+                q[:, 0], kp, vp, table,
+                jnp.asarray((pos < sl) & (pos >= sl - window)), None)
+        else:
+            out = pk.paged_attention_reference(q[:, 0], kp, vp, table, flat,
+                                               q_pos=qflat)
+        return out.reshape(s, w, h, d)
+
+    return dict(run=run, ref=ref, pt=pt, sl=sl, qp=qp, cols=cols, ps=ps,
+                window=window, ring=ring, nan_page=pool - 1)
+
+
+def _walk_live(launch):
+    """``live_columns`` of a launch, as the kernel is handed it."""
+    return np.asarray(pk.live_columns(
+        jnp.asarray(launch["sl"]),
+        None if launch["qp"] is None else jnp.asarray(launch["qp"]),
+        launch["cols"], launch["ps"], window=launch["window"],
+        ring=launch["ring"]))
+
+
+def _walk_mask(launch):
+    """The kernel's own ``valid`` mask by brute force: ``(S, cols)`` bool,
+    whether ANY row of the slot sees ANY position of the column."""
+    sl, qp, ps, cols = (launch[k] for k in ("sl", "qp", "ps", "cols"))
+    first = np.broadcast_to(np.arange(cols), (sl.shape[0], cols))
+    if launch["ring"]:
+        first = np.asarray(pk.ring_blocks(jnp.asarray(sl[:, 0]), cols, ps))
+    # (S, W, cols, ps)
+    pos = first[:, None, :, None] * ps + np.arange(ps)
+    rows = sl[:, :, None, None]
+    valid = pos < rows
+    query = rows - 1
+    if qp is not None:
+        query = qp[:, :, None, None]
+        valid &= pos <= query
+    if launch["window"]:
+        valid &= pos >= np.maximum(query - launch["window"] + 1, 0)
+    if launch["ring"]:
+        valid &= pos >= 0
+    return valid.any(axis=(1, 3))
+
+
+@pytest.mark.parametrize("n", _WALK_LENGTHS)
+@pytest.mark.parametrize("kind", _WALK_LAUNCHES)
+def test_live_columns_cover_the_kernels_mask_to_one_column(kind, n):
+    launch = _walk_launch(kind, n)
+    seen = _walk_mask(launch)
+    for (c0, c1), cols in zip(_walk_live(launch), seen):
+        assert 0 <= c0 <= c1 <= launch["cols"] and c0 < launch["cols"]
+        live = np.flatnonzero(cols)
+        if not live.size:
+            assert c0 == c1 == 0
+            continue
+        assert c0 <= live[0] and live[-1] < c1           # never narrower
+        if launch["ring"]:      # a prefix; wrapped, all but at most one
+            assert c0 == 0 and (c1 - c0) - live.size <= 1
+        else:
+            assert (c0, c1) == (live[0], live[-1] + 1)
+
+
+@pytest.mark.parametrize("n", _WALK_LENGTHS)
+@pytest.mark.parametrize("kind", _WALK_LAUNCHES)
+def test_bounded_walk_matches_the_dense_oracle(kind, n):
+    launch = _walk_launch(kind, n)
+    got = np.asarray(launch["run"](launch["pt"]))
+    np.testing.assert_allclose(got, np.asarray(launch["ref"](launch["pt"])),
+                               atol=2e-5, rtol=2e-5)
+    assert not got[~(launch["sl"] > 0)].any()    # a row that sees nothing
+
+
+@pytest.mark.parametrize("n", _WALK_LENGTHS)
+@pytest.mark.parametrize("kind", _WALK_LAUNCHES)
+def test_bounded_walk_never_reads_a_dead_column(kind, n):
+    """Every column outside ``[c0, c1)`` points at a page of NaN: the kernel
+    neither fetches nor multiplies it (a masked product would read ``0 *
+    NaN``), so its output is finite and equals the oracle's over the table
+    whose dead columns hold the null page."""
+    launch = _walk_launch(kind, n)
+    col = np.arange(launch["cols"])[None, :]
+    live = _walk_live(launch)
+    dead = (col < live[:, :1]) | (col >= live[:, 1:])
+    assert dead.any() or n == "full"
+    got = np.asarray(launch["run"](
+        np.where(dead, launch["nan_page"], launch["pt"])))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(
+        got, np.asarray(launch["ref"](np.where(dead, 0, launch["pt"]))),
+        atol=2e-5, rtol=2e-5)
+
+
 @pytest.mark.parametrize("window", [0, 32, 40])
 @pytest.mark.parametrize("h,kh", [(4, 4), (12, 2)])
 def test_band_attention_kernel_matches_dense_oracle(window, h, kh):

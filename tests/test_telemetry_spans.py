@@ -342,6 +342,72 @@ def test_decode_tick_spans_carry_the_slots_in_use(tiny, tmp_path):
     assert sum(t[3]["active"] for t in stepped) == stats["slot_ticks"]
 
 
+def _walk_model(kind):
+    """(model, engine keywords, the (columns, layers) of each table a tick's
+    attention walks): TinyDecoder's one table, an afmoe model's full table
+    and its window group's ring."""
+    if kind == "plain":
+        model = serving.TinyDecoder(vocab_size=32, num_layers=2, num_heads=4,
+                                    head_dim=8, num_kv_heads=2)
+        return model, dict(max_seq_len=48, page_size=8), [(6, 2)]
+    model = serving.AfmoeDecoder(
+        vocab_size=96, hidden_size=48, num_attention_heads=12,
+        num_key_value_heads=2, head_dim=8, intermediate_size=96,
+        moe_intermediate_size=32,
+        layer_types=["sliding_attention"] * 4 + ["full_attention"],
+        num_dense_layers=1, num_experts=16, num_experts_per_tok=4,
+        sliding_window=32, held_experts=[4, 4], route_scale=2.448,
+        mup_enabled=True)
+    return model, dict(max_seq_len=128, page_size=8, prefill_chunk=0), \
+        [(16, 1), (5, 4)]
+
+
+@pytest.mark.parametrize("kind", ["plain", "grouped"])
+def test_decode_commit_span_carries_the_page_walk(kind, tmp_path):
+    """``kv_cols_live`` / ``kv_cols_grid`` on every tick of every model: the
+    table columns the paged-attention walk ran (what
+    ``pallas_kernels.live_columns`` hands the kernel) of the tables'
+    columns x slots, over the layers; the two counters and ``stats()`` add
+    up to the spans."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.serving import decode as decode_mod
+
+    model, kw, tables = _walk_model(kind)
+    name = "spans_walk_%s" % kind
+    with _engine((model, model.init_params(0)), name=name, num_slots=2,
+                 prefill_buckets=(16, 64), **kw) as eng:
+        eng.warmup()
+        before = eng.stats()
+        prompt = np.arange(1, 41, dtype=np.int32)   # wraps the 40-token ring
+
+        def work():
+            eng.generate(prompt, 6, timeout=300)
+            eng.close()
+
+        events = _traced(tmp_path, work)
+        stats = eng.stats()
+    commits = [e[3] for e in _named(events, "mx.decode.commit")]
+    assert len(commits) == 5
+    grid = sum(2 * cols * layers for cols, layers in tables)
+    for i, args in enumerate(commits):
+        # one sequence of 41 + i tokens, the other slot idle
+        lens = np.asarray([[41 + i], [0]], np.int32)
+        want = sum(
+            layers * int(np.diff(np.asarray(pk.live_columns(
+                lens, None, cols, 8, ring=ring))).sum())
+            for (cols, layers), ring in zip(tables, (False, True)))
+        assert args["kv_cols_live"] == want
+        assert args["kv_cols_grid"] == grid
+        assert 0 < args["kv_cols_live"] <= args["kv_cols_grid"]
+    for key, counter in (("kv_cols_live", decode_mod._T_KV_COLS_LIVE),
+                         ("kv_cols_grid", decode_mod._T_KV_COLS_GRID)):
+        total = sum(a[key] for a in commits)
+        assert stats[key] - before[key] == total
+        assert sum(counter.value(server=name, group=g)
+                   for g in ("full", "window")[:len(tables)]) == stats[key]
+    assert "mxnet_decode_kv_cols_live_total" in telemetry.render_prometheus()
+
+
 def test_decode_chunked_prefill_span_carries_the_chunk(tiny, tmp_path):
     with _engine(tiny, name="spans_chunk", prefill_chunk=8) as eng:
         eng.warmup()
