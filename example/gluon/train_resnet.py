@@ -4,10 +4,9 @@ Counterpart of the reference's ResNet-50 training path
 (``example/image-classification/train_imagenet.py`` + Gluon model_zoo
 ``resnet.py``), re-engineered TPU-first: the whole step — forward + loss +
 backward + gradient allreduce + SGD-momentum update — compiles into ONE XLA
-module via ``mxnet_tpu.parallel.TrainStep`` over a ``dp`` device mesh (the
-same engine ``bench.py`` measures). With a real ImageRecordIter ``.rec``
-file pass ``--rec``; otherwise synthetic ImageNet-shaped data keeps it
-runnable with zero egress.
+module via ``mxnet_tpu.parallel.TrainStep`` over a ``dp`` device mesh.
+With a real ImageRecordIter ``.rec`` file pass ``--rec``; otherwise
+synthetic ImageNet-shaped data keeps it runnable with zero egress.
 
 Usage::
 

@@ -9,8 +9,8 @@ TPU-idiomatic notes: per-op host timings here measure *dispatch* (op
 submission + any blocking fetch), not device kernels — under whole-graph
 XLA the per-op device story lives in the ``jax.profiler`` xplane trace,
 which `profiler.set_config(jax_trace_dir=...)` captures alongside
-(bench.py records one on real hardware; tpu_profile_r05/ has a live
-chip's). Both views ship: MXNet-style aggregates for API parity, xplane
+(``python3 benchmark/run.py --workload <cell> --trace 1`` records one on
+the chip; tpu_profile_r05/ has a live chip's). Both views ship: MXNet-style aggregates for API parity, xplane
 for kernel truth.
 
 Run:  python example/profiler/profiler_demo.py

@@ -1,12 +1,14 @@
 """mxnet_tpu.fastpath — the dispatch-bound-regime killer.
 
-BENCH_TPU_PARTIAL_r05 measured ResNet-50 eager training at 0.18× a V100 at
-~0.6% MFU, and the PR-3 telemetry said why: the update path issued one
-jitted call *per parameter per step* (~160 dispatches/step), no jit
-boundary donated its buffers, and every process restart recompiled the
-world. This package is the hot-path rework (TVM's whole-graph-fusion
-lesson, arxiv 1802.04799, applied to the update/exchange plane; Axe,
-arxiv 2601.19092, motivates the device-resident parameter layout):
+The update path issued one jitted call *per parameter per step* (~160
+dispatches/step on ResNet-50), no jit boundary donated its buffers, and
+every process restart recompiled the world. What a host-bound step costs
+on the chip: 393.5 img/s at 73.8 % device idle while 483 scalar puts a
+step held the host, 1,341 img/s device-bound without them
+(PERF_LEDGER.jsonl, PR 24 and PR 26). This package is the hot-path rework
+(TVM's whole-graph-fusion lesson, arxiv 1802.04799, applied to the
+update/exchange plane; Axe, arxiv 2601.19092, motivates the
+device-resident parameter layout):
 
 ====================  =====================================================
 piece                 what it gives you

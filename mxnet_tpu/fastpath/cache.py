@@ -4,8 +4,8 @@ Every process restart of the pre-fastpath stack recompiled the entire
 program set from scratch — minutes of XLA work to rebuild executables that
 were byte-identical to yesterday's. With jax's persistent compilation cache
 the first process pays the compiles and writes the executables; every later
-process (restarts, elastic replacements, the second bench run) deserializes
-them instead.
+process (restarts, elastic replacements, the second benchmark run)
+deserializes them instead.
 
 Where the cache lives is decided in exactly one of two ways:
 
@@ -20,14 +20,14 @@ Where the cache lives is decided in exactly one of two ways:
 Import-time wiring is driven by the environment only: with the variable
 unset, ``import mxnet_tpu`` writes no cache anywhere (the test suite must
 not grow one inside the checkout); entry points that compile for minutes
-(``chip_smoke.py``, ``bench.py``) call :func:`configure` before their first
-compile.
+(``chip_smoke.py``, ``benchmark/run.py``) call :func:`configure` before
+their first compile.
 
 Hit/miss traffic is surfaced through the PR-3 recompile accounting:
 jax's monitoring events ``/jax/compilation_cache/cache_hits`` /
 ``cache_misses`` increment ``mxnet_compile_cache_hits_total`` /
-``mxnet_compile_cache_misses_total``, so a scrape (or the bench JSON line)
-shows whether a restart actually started warm.
+``mxnet_compile_cache_misses_total``, so a scrape (or the benchmark's
+``compile_cache_misses``) shows whether a restart actually started warm.
 """
 from __future__ import annotations
 
@@ -95,8 +95,8 @@ def configured():
 
 
 def cache_counts():
-    """(hits, misses) observed by this process — the numbers the bench
-    stamps on every JSON line."""
+    """(hits, misses) observed by this process — the benchmark's
+    ``compile_cache_misses`` reads them over set-up."""
     return (int(telemetry.COMPILE_CACHE_HITS.value()),
             int(telemetry.COMPILE_CACHE_MISSES.value()))
 

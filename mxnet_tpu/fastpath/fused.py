@@ -2,10 +2,9 @@
 
 The pre-fastpath update plane dispatched one jitted kernel *per parameter
 per step* (``Optimizer.update`` via ``Updater.__call__`` in a python loop —
-~160 dispatches/step on ResNet-50, the regime BENCH_TPU_PARTIAL_r05 died
-in). Here the SAME pure per-parameter kernel (``Optimizer._leaf_step``,
-shared with the per-param path so the two cannot drift numerically) is
-composed over the whole ``(params, grads, states)`` pytree and compiled as
+~160 dispatches/step on ResNet-50). Here the SAME pure per-parameter
+kernel (``Optimizer._leaf_step``, shared with the per-param path so the
+two cannot drift numerically) is composed over the whole ``(params, grads, states)`` pytree and compiled as
 ONE jit per optimizer: XLA sees every parameter's rescale → clip → wd →
 momentum → assign chain in a single module and the python loop disappears
 from the hot path.

@@ -14,8 +14,9 @@ Two halves:
 **The governor** (:class:`PressureGovernor`, one per process via
 :func:`governor`). Planes register worst-case byte *bounds* (the KV
 pool, pending-prefill worst case, ZeRO bucket bytes) with
-:meth:`~PressureGovernor.register_bound`; the devprof watermark ticks
-feed real device samples through
+:meth:`~PressureGovernor.register_bound`; the Emitter's
+:func:`~mxnet_tpu.telemetry.accounting.hbm_watermark` ticks feed real
+device samples through
 :meth:`~PressureGovernor.observe_device`. Pressure = max(device in-use,
 sum of registered bounds) over the capacity (``MXNET_HBM_CAPACITY_BYTES``
 or the backend's reported limit; unknown capacity = no tier pressure —
@@ -226,11 +227,11 @@ class PressureGovernor:
             self._capacity = int(nbytes) if nbytes else None
 
     def observe_device(self, stats: Dict[int, tuple],
-                       source: str = "devprof") -> None:
+                       source: str = "emitter") -> None:
         """Feed one :func:`~mxnet_tpu.telemetry.accounting.sample_hbm`
-        result (``{device_id: (in_use, peak)}``) — the devprof watermark
-        tick calls this, so real device usage joins the pressure signal
-        wherever the backend has memory stats."""
+        result (``{device_id: (in_use, peak)}``) — the Emitter's
+        ``hbm_watermark`` tick calls this, so real device usage joins the
+        pressure signal wherever the backend has memory stats."""
         if not stats:
             return
         with self._lock:

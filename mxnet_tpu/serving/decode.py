@@ -104,7 +104,6 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from .. import telemetry
-from ..telemetry import devprof as _devprof
 from ..telemetry import flightrec as _flightrec
 from ..telemetry import slo as _slo
 from ..telemetry import tracing as _tracing
@@ -784,9 +783,9 @@ class DecodeEngine:
         signature as the current one: the params enter the decode/prefill
         jits as a traced operand, so an equal signature is structurally
         guaranteed not to retrace (the steady-state-recompile gauge stays
-        at 0 across the swap — asserted by the live-swap test and the
-        BENCH_TENANT soak). In-flight sequences keep their slots and KV
-        pages and continue under the new weights from the next tick — the
+        at 0 across the swap — asserted by the live-swap tests).
+        In-flight sequences keep their slots and KV pages and continue
+        under the new weights from the next tick — the
         fleet-upgrade/A-B-rollout semantic: nothing is evicted, nothing
         re-prefills. Returns a Future resolving True once a tick boundary
         applied the swap (``wait=True`` blocks on it)."""
@@ -944,9 +943,8 @@ class DecodeEngine:
                     "acceptance_rate": (self._spec_accepted /
                                         self._spec_proposed
                                         if self._spec_proposed else 0.0),
-                    # tokens committed per SPECULATING slot-tick — the
-                    # >1.0 gate of the BENCH_DECODE soak (1.0 = drafts
-                    # never helped; k+1 = every draft accepted)
+                    # tokens committed per SPECULATING slot-tick (1.0 =
+                    # drafts never helped; k+1 = every draft accepted)
                     "accepted_per_tick": (self._spec_new /
                                           self._spec_slot_ticks
                                           if self._spec_slot_ticks else 0.0),
@@ -1111,12 +1109,6 @@ class DecodeEngine:
                 time.sleep(0.005)
                 return
         try:
-            # devprof tick scope: the sampling decision is drawn once
-            # for the whole tick so a timed tick's prefill/step/host-
-            # gap breakdown is coherent; one global read when off
-            tick_t0 = time.perf_counter()
-            tick_timed = _devprof.tick_begin()
-            toks_before = self._tokens_total
             with telemetry.span("decode.admit", _SPAN_CAT):
                 self._admit()
             prefilling = [(i, r) for i, r in enumerate(self._slots)
@@ -1147,18 +1139,8 @@ class DecodeEngine:
             elif not prefilling:
                 # every queued tenant deferred (pages/rate/breaker)
                 # with nothing in flight: yield instead of spinning
-                if tick_timed:
-                    _devprof.tick_end()
                 time.sleep(0.001)
-                return
-            if tick_timed:
-                _devprof.note_decode_tick(
-                    self._name,
-                    (time.perf_counter() - tick_t0) * 1e3,
-                    self._tokens_total - toks_before)
         except Exception as exc:  # noqa: BLE001 - engine must survive
-            _devprof.tick_end()  # don't leak the tick scope into the
-            # eviction/recovery path's dispatches
             # belt-and-braces (the PR-2 batcher discipline): NO
             # exception may kill the engine thread — that would hang
             # every in-flight and queued future forever. Evict

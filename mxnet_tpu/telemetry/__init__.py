@@ -35,15 +35,6 @@ piece                 what it gives you
 :mod:`.httpd`         stdlib introspection daemon: ``/metrics``,
                       ``/healthz``, ``/debug/state``,
                       ``/debug/trace/<id>`` (``MXNET_METRICS_PORT``)
-:mod:`.devprof`       device-time attribution: sampled per-site
-                      ``block_until_ready`` timing through ``jit_call``,
-                      decode-tick / train-step host-gap breakdowns, MFU
-                      and tokens-per-device-second gauges, HBM watermark
-                      timeline, chrome-trace device lane
-                      (``MXNET_DEVPROF_SAMPLE``-gated)
-:mod:`.regress`       bench-regression sentinel: per-(metric, config)
-                      trajectories over BENCH_*.json + emitter JSONL,
-                      median+MAD verdicts stamped as ``perf_verdict``
 ====================  =====================================================
 
 Publishers wired in-framework: ``serving.ServingStats``, ``profiler.
@@ -60,7 +51,6 @@ from __future__ import annotations
 
 from . import accounting, exporters, registry, spans
 from . import flightrec, httpd, slo, tracing
-from . import devprof, regress
 from .accounting import (CKPT_BYTES, CKPT_CORRUPTION, CKPT_RESTORE_MS,
                          CKPT_SAVE_MS, COMPILE_CACHE_HITS,
                          COMPILE_CACHE_MISSES,
@@ -69,8 +59,8 @@ from .accounting import (CKPT_BYTES, CKPT_CORRUPTION, CKPT_RESTORE_MS,
                          OPT_DISPATCHES, PREEMPTIONS, PROFILER_COUNTER,
                          RECOMPILES, STEADY_STATE_RECOMPILES, STEP_DISPATCHES,
                          TRANSFER_BYTES,
-                         TRANSFERS, jit_cache_size, jit_call, note_recompile,
-                         record_transfer, sample_hbm,
+                         TRANSFERS, hbm_watermark, jit_cache_size, jit_call,
+                         note_recompile, record_transfer, sample_hbm,
                          set_steady_state_recompiles)
 from .exporters import (Emitter, render_prometheus, snapshot, start_emitter,
                         stop_emitter)
@@ -85,7 +75,7 @@ __all__ = [
     "counter", "gauge", "histogram", "enabled", "set_enabled",
     "span", "traced",
     "jit_call", "jit_cache_size", "note_recompile", "record_transfer",
-    "sample_hbm", "set_steady_state_recompiles",
+    "sample_hbm", "hbm_watermark", "set_steady_state_recompiles",
     "RECOMPILES", "COMPILE_SECONDS", "STEADY_STATE_RECOMPILES",
     "TRANSFERS", "TRANSFER_BYTES", "PROFILER_COUNTER",
     "HBM_BYTES_IN_USE", "HBM_BYTES_PEAK",
@@ -95,7 +85,7 @@ __all__ = [
     "PREEMPTIONS", "CKPT_CORRUPTION", "ELASTIC_GOODPUT", "ELASTIC_RESTARTS",
     "render_prometheus", "snapshot", "Emitter", "start_emitter",
     "stop_emitter",
-    "tracing", "flightrec", "slo", "httpd", "devprof", "regress",
+    "tracing", "flightrec", "slo", "httpd",
     "start_trace", "get_trace", "start_httpd", "stop_httpd",
 ]
 

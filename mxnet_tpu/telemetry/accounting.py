@@ -14,13 +14,17 @@ patterns; this module measures what actually happened at runtime:
   (``fetch_host``, ``asnumpy``) — wired into ``base.fetch_host`` and the
   NDArray host-conversion methods;
 * :func:`set_steady_state_recompiles` is the serving-facing gauge: after
-  ``Server.warmup()`` it must stay 0, and the bench asserts exactly that.
+  ``Server.warmup()`` it must stay 0 (the benchmark's
+  ``decode_recompiles``).
 """
 from __future__ import annotations
 
+import logging
 import time
 
 from . import registry as _registry
+
+_LOG = logging.getLogger(__name__)
 
 __all__ = ["RECOMPILES", "COMPILE_SECONDS", "STEADY_STATE_RECOMPILES",
            "TRANSFERS", "TRANSFER_BYTES", "PROFILER_COUNTER",
@@ -31,7 +35,7 @@ __all__ = ["RECOMPILES", "COMPILE_SECONDS", "STEADY_STATE_RECOMPILES",
            "PREEMPTIONS", "CKPT_CORRUPTION", "ELASTIC_GOODPUT",
            "ELASTIC_RESTARTS",
            "jit_call", "jit_cache_size", "note_recompile",
-           "record_transfer", "sample_hbm",
+           "record_transfer", "sample_hbm", "hbm_watermark",
            "set_steady_state_recompiles"]
 
 RECOMPILES = _registry.counter(
@@ -166,12 +170,6 @@ def jit_cache_size(jitted) -> int:
         return -1
 
 
-#: devprof's dispatch hook (``(site, t0, out) -> None``), installed by
-#: :mod:`~mxnet_tpu.telemetry.devprof` only while its sampling rate is
-#: positive. ``None`` (the default) keeps the steady-state jit_call cost
-#: at ONE module-global pointer check — the tracing-plane discipline.
-_DEVPROF_HOOK = None
-
 _CHAOS = None
 
 
@@ -208,11 +206,9 @@ def jit_call(site: str, jitted, *args, **kwargs):
     before = jit_cache_size(jitted)
     t0 = time.perf_counter()
     out = jitted(*args, **kwargs)
-    grew = False
     if before >= 0:
         after = jit_cache_size(jitted)
         if after > before:
-            grew = True
             RECOMPILES.inc(after - before, site=site)
             COMPILE_SECONDS.inc(time.perf_counter() - t0, site=site)
             # black box: a steady-state recompile at a serving site is a
@@ -222,11 +218,6 @@ def jit_call(site: str, jitted, *args, **kwargs):
             flightrec.record("recompile", site=site,
                              count=after - before,
                              seconds=round(time.perf_counter() - t0, 4))
-    hook = _DEVPROF_HOOK
-    if hook is not None and not grew:
-        # recompiling dispatches stay out of the device-time histograms:
-        # their wall time is compile cost, attributed just above
-        hook(site, t0, out)
     return out
 
 
@@ -250,10 +241,9 @@ def sample_hbm(devices=None):
     """Sample per-device memory stats into the ``mxnet_hbm_bytes_*``
     gauges and return ``{device_id: (in_use, peak)}``. HBM — not compute
     — is what the ZeRO state plane trades for collectives, so the
-    training planes publish this per step and the bench stamps it on
-    every JSON line. Guarded no-op where the backend exposes no memory
-    stats (CPU devices return ``None``): the gauges stay unset rather
-    than lying a zero."""
+    training planes publish this per step. Guarded no-op where the
+    backend exposes no memory stats (CPU devices return ``None``): the
+    gauges stay unset rather than lying a zero."""
     if not _registry.ENABLED:
         return {}
     import jax
@@ -274,6 +264,35 @@ def sample_hbm(devices=None):
         HBM_BYTES_PEAK.set(int(peak), device=str(d.id))
         out[d.id] = (int(used), int(peak))
     return out
+
+
+def hbm_watermark(source: str = "emitter"):
+    """One :func:`sample_hbm` into the gauges AND the flight-recorder
+    ring, so a dump carries a device-memory timeline, and into the HBM
+    pressure governor. Guarded no-op on stat-less backends (CPU) and on
+    any probe failure — a watermark must never break the thread taking
+    it (the Emitter daemon calls this)."""
+    try:
+        stats = sample_hbm()
+    except Exception:  # noqa: BLE001 - never break the sampling thread
+        return {}
+    if stats:
+        from . import flightrec
+
+        flightrec.record(
+            "hbm.watermark", source=source,
+            devices={str(d): {"in_use": u, "peak": p}
+                     for d, (u, p) in stats.items()})
+        # feed the pressure governor: real device usage joins the
+        # plane-registered bounds in its tier computation (lazy import —
+        # telemetry loads before resilience)
+        try:
+            from ..resilience import hbm as _hbm
+
+            _hbm.governor().observe_device(stats, source=source)
+        except Exception:  # noqa: BLE001 - never break the sampler
+            _LOG.debug("hbm governor feed failed", exc_info=True)
+    return stats
 
 
 def record_transfer(path: str, arrays):

@@ -6,13 +6,12 @@ Three ways the same registry leaves the process:
   scrape expects; counters/gauges verbatim, histograms as summaries
   (``{quantile="0.5"}``/``_sum``/``_count``). Serve it from any HTTP
   handler, or dump it to a file for node-exporter's textfile collector.
-* :func:`snapshot` — a plain-dict point-in-time view for benches, tests
-  and ``bench.py``'s result line.
+* :func:`snapshot` — a plain-dict point-in-time view for tests and
+  ``/debug/state``.
 * :class:`Emitter` / :func:`start_emitter` — a daemon thread appending
   ``snapshot()`` lines to a JSONL file every ``MXNET_TELEMETRY_EMIT_SECS``
   seconds. This is the post-mortem channel: a run that hangs and gets
-  killed (the r05 bench stall) leaves its last-known recompile/transfer
-  state on disk even though no in-process consumer survived to ask.
+  killed leaves its last-known recompile/transfer state on disk even though no in-process consumer survived to ask.
 
 .. _text exposition format:
    https://prometheus.io/docs/instrumenting/exposition_formats/
@@ -26,6 +25,7 @@ import time
 from typing import Any, Dict, Optional
 
 from ..base import get_env
+from . import accounting as _accounting
 from . import registry as _registry
 
 __all__ = ["render_prometheus", "snapshot", "Emitter", "start_emitter",
@@ -128,18 +128,11 @@ class Emitter(threading.Thread):
     def emit_once(self) -> bool:
         """Append one snapshot line; False when the sink is unwritable."""
         try:
-            # HBM watermark rides the emit cadence: non-bench runs get a
+            # HBM watermark rides the emit cadence: a run gets a
             # device-memory timeline in the JSONL tail and the flight-
-            # recorder ring, not one number at bench-line boundaries.
-            # Lazy import (devprof loads after exporters); the probe
-            # itself is guarded inside hbm_watermark — a stat-less
-            # backend must not cost the snapshot line.
-            try:
-                from . import devprof as _devprof
-            except ImportError:
-                _devprof = None
-            if _devprof is not None:
-                _devprof.hbm_watermark("emitter")
+            # recorder ring. The probe is guarded inside hbm_watermark —
+            # a stat-less backend must not cost the snapshot line.
+            _accounting.hbm_watermark("emitter")
             line = json.dumps(snapshot(self._registry))
             with open(self.path, "a") as f:
                 f.write(line + "\n")

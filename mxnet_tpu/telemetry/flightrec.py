@@ -14,13 +14,12 @@ published by the planes the framework already instruments:
 * checkpoint commits, preemptions, elastic stalls,
 * HBM pressure-tier edges (``hbm.pressure``) and classified-OOM
   survival diagnostics (``hbm.oom``, carrying the governor's full
-  per-plane memory breakdown — the OOM post-mortem artifact),
-* bench backend-init steps.
+  per-plane memory breakdown — the OOM post-mortem artifact).
 
 On a death signal — watchdog stall, SIGTERM, the decode engine-thread
-catch-all, a bench error path — :func:`dump` commits the ring atomically
-(the elastic plane's tmp+fsync+rename helper) so the next r05-style
-death leaves a readable black box instead of a bare deadline message.
+catch-all — :func:`dump` commits the ring atomically (the elastic plane's
+tmp+fsync+rename helper) so such a death leaves a readable black box
+instead of a bare deadline message.
 
 Cost discipline: :func:`record` checks the ``MXNET_TELEMETRY`` master
 switch first (one module-global read, nothing else when off) and appends

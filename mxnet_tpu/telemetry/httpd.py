@@ -25,9 +25,7 @@ path                      payload
                           (:func:`~mxnet_tpu.telemetry.tracing.get_trace`)
 ``/debug/traces``         retained trace ids
 ``/debug/<view>``         any single registered debug view standalone —
-                          ``/debug/perf`` is devprof's device-time
-                          attribution summary + the latest bench-sentinel
-                          verdicts; ``/debug/fleet`` the serving fleet's
+                          ``/debug/fleet`` is the serving fleet's view
 ========================  ==================================================
 
 Security: the endpoint is **unauthenticated introspection** — metrics,
@@ -152,10 +150,10 @@ class _Handler(BaseHTTPRequestHandler):
             else:
                 self._json(200, trace)
         elif path.startswith("/debug/"):
-            # any registered debug view standalone: /debug/perf serves
-            # devprof's attribution summary + sentinel verdicts without
-            # the full /debug/state payload around it (same exception
-            # isolation — the provider's error renders, never a 500)
+            # any registered debug view standalone: /debug/fleet serves
+            # the fleet's view without the full /debug/state payload
+            # around it (same exception isolation — the provider's error
+            # renders, never a 500)
             name = path[len("/debug/"):]
             with _VIEWS_LOCK:
                 provider = _DEBUG_VIEWS.get(name)

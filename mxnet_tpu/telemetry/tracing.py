@@ -245,13 +245,6 @@ def export_chrome(path: Optional[str] = None) -> Dict[str, Any]:
     # perf_counter-based microsecond timeline, so the two interleave
     with _profiler._lock:
         out.extend(list(_profiler._events))
-    # device lane (tid 0): devprof's sampled dispatch slices on the same
-    # timeline — a request's hop gaps line up against where the device
-    # actually was. Lazy import: devprof loads after tracing in the
-    # package sequence; empty when nothing was sampled.
-    from . import devprof as _devprof
-
-    out.extend(_devprof.chrome_events(pid))
     doc = {"traceEvents": out, "displayTimeUnit": "ms"}
     if path:
         with open(path, "w") as f:

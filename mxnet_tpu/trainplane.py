@@ -1,7 +1,6 @@
 """The training plane: ONE compiled SPMD step behind the high-level APIs.
 
-BENCH_TPU_PARTIAL_r05 measured eager ResNet-50 training at 0.6% MFU on a
-v5e chip; PR 5 collapsed the *update* plane to one fused jit, but the
+PR 5 collapsed the *update* plane to one fused jit, but the
 forward/backward still ran outside ``parallel.TrainStep``. This module
 turns the fused update plane into a fused *step* plane: the whole training
 step — forward + loss + backward + data-parallel all-reduce + optimizer
@@ -49,7 +48,6 @@ same step spans the slice (GSPMD inserts the ICI collectives).
 from __future__ import annotations
 
 import logging
-import time
 from typing import Any, Dict, Optional
 
 import jax
@@ -58,7 +56,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import autograd, telemetry
-from .telemetry import devprof as _devprof
 from . import optimizer as opt_mod
 from .base import get_env
 from .context import cpu
@@ -743,18 +740,6 @@ class TrainPlane(_PlaneBase):
         with telemetry.span("train.step", _SPAN_CAT):
             if self._plane != "graph":
                 return self._eager_step(data_nd, label_nd, batch_size)
-            # devprof step scope: one coherent sampling decision for the
-            # whole step so its device/host_gap split is honest. Eager
-            # plane is deliberately unscoped — it dispatches op-by-op
-            # outside jit_call, so there is no device time to attribute.
-            if _devprof.tick_begin():
-                t0 = time.perf_counter()
-                try:
-                    return self._graph_step_guarded(data_nd, label_nd,
-                                                    batch_size)
-                finally:
-                    _devprof.note_train_step(
-                        (time.perf_counter() - t0) * 1e3)
             return self._graph_step_guarded(data_nd, label_nd, batch_size)
 
     def _graph_step_guarded(self, data_nd, label_nd, batch_size):
@@ -1014,13 +999,6 @@ class _ModulePlane(_PlaneBase):
         """One whole-graph training step for a DataBatch; fills the
         executor's outputs so ``update_metric`` reads them as usual."""
         with telemetry.span("train.step", _SPAN_CAT):
-            if _devprof.tick_begin():
-                t0 = time.perf_counter()
-                try:
-                    return self._step(batch)
-                finally:
-                    _devprof.note_train_step(
-                        (time.perf_counter() - t0) * 1e3)
             return self._step(batch)
 
     def _step(self, batch):

@@ -13,8 +13,8 @@ Tiering: every multi-replica warmup costs ~10 jit compiles on a 1-core
 CI box, so the soak-shaped tests ride the ``slow`` tier (the tier-1
 budget is already nearly spent by the rest of the suite); tier-1 keeps
 the surface smoke (oracle parity through a cold 2-replica fleet),
-submit validation, and the pure snapshot-merge unit. The BENCH_FLEET
-soak re-proves the slow tier's gates end to end on every bench run."""
+submit validation, the pure snapshot-merge unit, and a seconds-sized
+twin of the slow kill soak (tests/test_soak_gates.py)."""
 import time
 
 import numpy as np
@@ -76,8 +76,8 @@ def _routed(fl):
 def test_fleet_matches_oracle_through_router(tiny):
     # tier-1 smoke: a cold fleet (no warmup — lazy compiles, ONE prefill
     # rung) still answers oracle-exact through the router; the
-    # zero-recompile contract is proven by the slow rolling-swap test
-    # and the BENCH_FLEET gate, which do pay for warmup
+    # zero-recompile contract is proven by the slow rolling-swap test,
+    # which does pay for warmup
     model, params = tiny
     rng = np.random.RandomState(7)
     reqs = [(rng.randint(1, 32, int(rng.randint(9, 14))).astype(np.int32),

@@ -24,7 +24,7 @@ from mxnet_tpu import gluon, nd, parallel, serving, telemetry, trainplane
 from mxnet_tpu.gluon import nn
 from mxnet_tpu.resilience import FaultInjected, RetryPolicy, chaos, hbm
 from mxnet_tpu.serving.kvcache import PagedKVCache
-from mxnet_tpu.telemetry import flightrec
+from mxnet_tpu.telemetry import accounting, exporters, flightrec
 
 
 @pytest.fixture(autouse=True)
@@ -86,6 +86,62 @@ def test_pressure_is_max_of_device_and_bounds():
     assert gov.observe() == "yellow"          # bounds sum to 75
     gov.observe_device({0: (96, 96)})          # device watermark wins
     assert gov.tier() == "red"
+
+
+def test_hbm_watermark_records_flightrec(monkeypatch):
+    monkeypatch.setattr(accounting, "sample_hbm",
+                        lambda devices=None: {0: (1024, 4096)})
+    stats = accounting.hbm_watermark("test")
+    assert stats == {0: (1024, 4096)}
+    evs = [e for e in flightrec.tail(0) if e["kind"] == "hbm.watermark"]
+    assert evs and evs[-1]["source"] == "test"
+    assert evs[-1]["devices"]["0"] == {"in_use": 1024, "peak": 4096}
+    # ... and the process governor received the same sample
+    assert hbm.governor().oom_report()["device_used_bytes"] == 1024
+
+
+def test_hbm_watermark_survives_probe_failure(monkeypatch):
+    def boom(devices=None):
+        raise RuntimeError("no stats on this backend")
+
+    monkeypatch.setattr(accounting, "sample_hbm", boom)
+    assert accounting.hbm_watermark("test") == {}
+
+
+def test_emitter_rides_hbm_watermark(tmp_path, monkeypatch):
+    monkeypatch.setattr(accounting, "sample_hbm",
+                        lambda devices=None: {0: (7, 9)})
+    path = str(tmp_path / "emit.jsonl")
+    em = exporters.Emitter(60.0, path)
+    assert em.emit_once()
+    lines = open(path).read().splitlines()
+    assert len(lines) == 1 and json.loads(lines[0])["metrics"] is not None
+    evs = [e for e in flightrec.tail(0) if e["kind"] == "hbm.watermark"]
+    assert evs and evs[-1]["source"] == "emitter"
+
+
+@pytest.mark.parametrize("used,tier", [(75, "yellow"), (90, "orange"),
+                                       (96, "red")])
+def test_emitter_watermark_walks_the_process_governor(tmp_path, monkeypatch,
+                                                      used, tier):
+    """The whole safety path from its new home: the Emitter's tick takes
+    the device sample, and that sample alone — no plane has registered a
+    bound — puts the process governor on the ladder; a low sample lets it
+    down again one tier an observation."""
+    gov = hbm.governor()
+    gov.set_capacity(100)
+    sample = {"used": used}
+    monkeypatch.setattr(accounting, "sample_hbm",
+                        lambda devices=None: {0: (sample["used"], 100)})
+    em = exporters.Emitter(60.0, str(tmp_path / "emit.jsonl"))
+    assert gov.tier() == "green"
+    assert em.emit_once()
+    assert gov.tier() == tier
+    assert gov.oom_report()["watermarks"][-1]["source"] == "emitter"
+    sample["used"] = 10
+    for _ in range(4):
+        assert em.emit_once()
+    assert gov.tier() == "green"
 
 
 def test_unknown_capacity_means_no_tier_pressure():

@@ -178,8 +178,7 @@ def test_trainstep_loss_decreases():
     # amplify scan-vs-straight-line fusion rounding into update diffs
     ("adam", {"learning_rate": 0.01, "epsilon": 1e-3}, "float32"),
     # bf16 params with f32 master optimizer state: the scan carry must stay
-    # dtype-stable (weights cast back to bf16, state kept f32) — the dtype
-    # combination bench.py's train_bf16 phase runs on real hardware
+    # dtype-stable (weights cast back to bf16, state kept f32)
     ("sgd", {"learning_rate": 0.1, "momentum": 0.9}, "bfloat16"),
 ])
 def test_trainstep_multi_call_matches_sequential_steps(opt, opt_params,

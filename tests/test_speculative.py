@@ -6,9 +6,9 @@ The correctness bar is the same one every other decode test holds: the
 engine's output token ids are BITWISE equal to the no-cache dense
 oracle (`TinyDecoder.reference_generate`), whatever the draft proposed
 — accept-all, reject-all and mixed schedules all reduce to the model's
-own argmax chain. The perf bar (accepted-per-tick > 1.0) lives in
-bench.py's BENCH_DECODE soak; here we assert the accounting that
-proves it.
+own argmax chain. The accepted-per-tick > 1.0 bar of an accept-all
+draft is held by tests/test_soak_gates.py; here we assert the accounting
+that proves it.
 """
 import contextlib
 import os
@@ -476,7 +476,7 @@ def test_fleet_forwards_spec_caps_to_replicas(tiny):
 
 
 # ---------------------------------------------------------------------------
-# observability: counters, gauges, devprof goodput
+# observability: counters, gauges
 # ---------------------------------------------------------------------------
 
 def test_spec_counters_and_acceptance_gauge(tiny, eng4):
@@ -500,7 +500,7 @@ def test_spec_counters_and_acceptance_gauge(tiny, eng4):
         spec["accepted_tokens"] / spec["proposed_tokens"])
     assert stats["spec_acceptance_rate"] > 0.9
     # tokens_generated counts COMMITTED tokens (8 per request), not
-    # verify rows — the number devprof's tokens-per-device-second uses
+    # verify rows
     assert stats["tokens_generated"] == before["tokens_generated"] + 8
 
 

@@ -2864,22 +2864,6 @@ def test_lint_gate_script_syntax_and_exec_bit():
     assert check.returncode == 0, check.stderr
 
 
-def test_bench_lint_stamp_fields():
-    """bench.py stamps lint_clean/lint_findings on every JSON line."""
-    import importlib
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench_for_lint",
-                                                  str(REPO / "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    stamp = bench._lint_stamp()
-    assert stamp.get("lint_clean") is True, stamp
-    assert stamp.get("lint_findings") == 0, stamp
-    # memoized: the second call must not re-run the linter
-    assert bench._lint_stamp() is stamp
-
-
 # -- review hardening: pinned fixes -----------------------------------------
 
 def test_sharding_flow_axis_name_kwarg_does_not_self_define():

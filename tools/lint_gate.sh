@@ -17,8 +17,6 @@
 #
 # Wire into pre-commit with:
 #   ln -s ../../tools/lint_gate.sh .git/hooks/pre-commit
-# bench.py stamps the same verdict on every JSON line as
-# lint_clean/lint_findings (see docs/performance.md).
 set -u -o pipefail
 
 # resolve symlinks (the documented `ln -s .../lint_gate.sh

@@ -48,9 +48,8 @@ def lint_paths(paths: Sequence[str], baseline_path: Optional[Path] = DEFAULT_BAS
     means not covered by the baseline (all of them when ``baseline_path``
     is None). ``cache=True`` (default) shares the CLI's incremental
     cache — keyed by the baseline content like every other entry point —
-    so programmatic callers (the tier-1 gate test, bench.py's per-line
-    ``lint_clean`` stamp) pay ~20ms warm instead of a cold whole-program
-    run."""
+    so programmatic callers (the tier-1 gate test) pay ~20ms warm instead
+    of a cold whole-program run."""
     files = collect_files(paths)
     lc = LintCache(DEFAULT_CACHE_PATH,
                    extra_sig=baseline_sig(baseline_path)) if cache else None

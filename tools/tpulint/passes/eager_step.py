@@ -4,8 +4,7 @@ The training plane behind ``MXNET_TRAINSTEP`` (``mxnet_tpu.trainplane``)
 compiles the whole step — forward + loss + backward + allreduce + update —
 into ONE XLA module; an eager loop body that records a forward, runs
 ``.backward()`` and applies an optimizer step dispatches dozens of
-compiled calls per iteration instead (the regime BENCH_TPU_PARTIAL_r05
-measured at 0.6% MFU even after the update plane fused). This pass flags
+compiled calls per iteration instead. This pass flags
 the shape of code that bypasses the step plane inside ``mxnet_tpu/`` so
 framework-owned training loops route through ``trainplane``/``TrainStep``
 (or get explicitly baselined as the eager fallback they are).

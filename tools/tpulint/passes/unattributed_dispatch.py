@@ -1,13 +1,10 @@
 """unattributed-dispatch: jit dispatch sites invisible to the perf plane.
 
 The telemetry stack attributes everything that flows through
-``telemetry.jit_call``: recompiles + compile seconds per site (PR 3),
-chaos injection (PR 4) and — since the devprof plane — sampled
-``block_until_ready`` device time, the decode/train host-gap
-breakdowns, and the chrome-trace device lane. A jit/pallas dispatch
-that bypasses the wrapper gets NONE of that: its recompiles surface
-only as unexplained latency, and its device milliseconds are missing
-from exactly the per-site cost model the autotuner roadmap item needs.
+``telemetry.jit_call``: recompiles + compile seconds per site (PR 3)
+and chaos injection (PR 4). A jit/pallas dispatch that bypasses the
+wrapper gets NONE of that: its recompiles surface only as unexplained
+latency.
 
 This pass reuses the recompile-risk interpreter's dispatch-site finder
 (:class:`tools.tpulint.shapes.DispatchSite` — the same resolution that
@@ -35,7 +32,7 @@ class UnattributedDispatchPass(Pass):
     name = "unattributed-dispatch"
     description = ("jit/pallas dispatch sites not routed through "
                    "telemetry.jit_call — invisible to recompile "
-                   "accounting and devprof device-time attribution")
+                   "accounting and chaos injection")
     project = True
 
     def applies(self, relpath: str) -> bool:
@@ -57,8 +54,8 @@ class UnattributedDispatchPass(Pass):
                                    "directly")
             yield ctx.finding(
                 site.node, self.name,
-                "jit dispatch `%s` %s — its recompiles and (sampled) "
-                "device time are invisible to the perf attribution plane; "
+                "jit dispatch `%s` %s — its recompiles are invisible to "
+                "the accounting plane and chaos cannot reach it; "
                 "route it as telemetry.jit_call(\"<site>\", fn, ...) or "
                 "baseline it with the reason it must stay bare"
                 % (site.fn_label, how))
