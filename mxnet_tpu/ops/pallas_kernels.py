@@ -445,9 +445,15 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
     masks keys at or below ``query - window``; ``ring`` (static) reads
     ``page_table`` as a ring of columns (one query token a slot);
     ``precise`` (static) asks for float32 products where the compiler's
-    default is one bfloat16 pass. Returns (S, W, H, D)."""
-    s_slots, width, n_heads, d = q.shape
-    _, page_size, n_kv, _ = k_pool.shape
+    default is one bfloat16 pass. The pools may hold their rows wider than
+    D (zero lanes: ``serving.kvcache.pool_row_width``): the query grows to
+    them with zeros and the result is cut back. Returns (S, W, H, D)."""
+    s_slots, width, n_heads, d_q = q.shape
+    _, page_size, n_kv, d = k_pool.shape
+    if scale is None:
+        scale = 1.0 / (d_q ** 0.5)
+    if d != d_q:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, d - d_q)))
     if n_heads % n_kv:
         raise ValueError("%s: %d heads not divisible by %d kv heads"
                          % (who, n_heads, n_kv))
@@ -459,8 +465,6 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
                          "got %d" % (who, width))
     groups = n_heads // n_kv
     max_pages = page_table.shape[1]
-    if scale is None:
-        scale = 1.0 / (d ** 0.5)
     causal = q_pos is not None
     if interpret is None:
         interpret = _interpret()
@@ -536,8 +540,9 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
         interpret=interpret,
         name="mx_paged_attn",  # what a device trace is searched for
     )(*scalars, qk, k_pool, v_pool)
-    out = out[:, :, :rows].reshape(s_slots, n_kv, width, groups, d)
-    return out.transpose(0, 2, 1, 3, 4).reshape(s_slots, width, n_heads, d)
+    out = out[:, :, :rows, :d_q].reshape(s_slots, n_kv, width, groups, d_q)
+    return out.transpose(0, 2, 1, 3, 4).reshape(s_slots, width, n_heads,
+                                                d_q)
 
 
 def ragged_paged_attention(q, k_pool, v_pool, page_table, seq_lens,
@@ -574,9 +579,10 @@ def _dense_paged(q, k_pool, v_pool, page_table, valid, scale):
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     t = page_table.shape[1] * page_size
-    # (S, columns, page_size, KH, D) -> (S, T, KH, D)
-    k = k_pool[page_table].reshape(s_slots, t, n_kv, d)
-    v = v_pool[page_table].reshape(s_slots, t, n_kv, d)
+    # (S, columns, page_size, KH, D) -> (S, T, KH, D); a pool may hold its
+    # rows wider than D (zero lanes)
+    k = k_pool[page_table][..., :d].reshape(s_slots, t, n_kv, d)
+    v = v_pool[page_table][..., :d].reshape(s_slots, t, n_kv, d)
     if groups > 1:
         k = jnp.repeat(k, groups, axis=2)
         v = jnp.repeat(v, groups, axis=2)

@@ -160,6 +160,13 @@ _T_KV_COLS_GRID = telemetry.counter(
     "columns x slots x layers (live / grid = the share of a table a tick "
     "fetches and multiplies)",
     labels=("server", "group"))
+_T_STEP_TEMP = telemetry.gauge(
+    "mxnet_decode_step_temp_bytes",
+    "temporaries of the compiled decode step (its memory_analysis), set by "
+    "warmup(): activations only while the step updates the KV pools in "
+    "place, the pools' own size or more once it copies, converts or slices "
+    "them",
+    labels=("server",))
 _T_MOE_LOAD = telemetry.gauge(
     "mxnet_moe_expert_load_max_over_mean",
     "rows of the busiest held expert over the mean of the held experts, "
@@ -199,8 +206,8 @@ class PagedDecodeModel:
         n}`` — the model's layers are of two kinds. The engine then keeps a
         :class:`~mxnet_tpu.serving.kvcache.GroupedKVCache` and every
         ``k_pool`` / ``v_pool`` / ``page_tables`` / ``write_pages``
-        argument below is a ``(full, window)`` PAIR: pools ``(layers of the
-        group, P, page, KH, D)``, the window table a ring of ``n /
+        argument below is a ``(full, window)`` PAIR: a group's pools in
+        the order of its layers, the window table a ring of ``n /
         page_size + 1`` columns. Such a model is served with
         ``prefix_cache=False``, ``prefill_chunk=0``, ``spec_k=0``.
     ``moe_counters``
@@ -216,6 +223,15 @@ class PagedDecodeModel:
     num_kv_heads: int
     head_dim: int
     vocab_size: int
+
+    # ``k_pool`` / ``v_pool`` are SEQUENCES of per-layer arrays ``(P, page,
+    # KH, Dw)``: ``k_pool[layer]`` is the array that layer's kernel reads,
+    # :func:`~mxnet_tpu.serving.kvcache.write_kv` replaces exactly that
+    # element, and the methods return the sequences whole. ``Dw`` is
+    # ``head_dim`` or, where the device holds narrow rows otherwise (a TPU,
+    # head_dim under 128), the lane tile above it: ``write_kv`` zero-pads
+    # the rows it is handed and the ``ops.pallas_kernels.paged_*`` functions
+    # take ``(.., head_dim)`` queries and return ``(.., head_dim)``.
 
     def decode(self, params, tokens, positions, k_pool, v_pool,
                page_tables, seq_lens, write_pages, write_offsets):
@@ -430,7 +446,7 @@ class DecodeEngine:
         # the tables a decode tick's paged attention walks: (cache group,
         # columns, layers that walk it)
         self._walk_groups = tuple(
-            (group, c.max_pages, c.k_pool.shape[0]) for group, c in (
+            (group, c.max_pages, c.num_layers) for group, c in (
                 (("full", self._cache.full), ("window", self._cache.window))
                 if self._grouped else (("full", self._cache),)))
         self._kv_cols_live = 0
@@ -469,9 +485,12 @@ class DecodeEngine:
         # every queued request may reserve up to max_seq_len of pages
         # (total_queued() reads one int, safe from any thread).
         self._governor = _hbm.governor()
-        pool_bytes = int(sum(
-            x.nbytes for x in jax.tree_util.tree_leaves(
-                (self._cache.k_pool, self._cache.v_pool))))
+        pools = jax.tree_util.tree_leaves(
+            (self._cache.k_pool, self._cache.v_pool))
+        #: arrays a step is handed as the pools: one a layer for K and V
+        self._kv_pool_leaves = len(pools)
+        self._step_temp_bytes: Optional[int] = None  # set by warmup()
+        pool_bytes = int(sum(x.nbytes for x in pools))
         self._governor.register_bound("serving.%s.kv_pool" % name,
                                       pool_bytes)
         page_bytes = pool_bytes // max(1, self._cache.num_pages)
@@ -560,9 +579,8 @@ class DecodeEngine:
         # writes into its own copy; src/dst are traced scalars — ONE
         # compile, pre-warmed against the null page
         def mx_kv_cow(k_pool, v_pool, src, dst):
-            k_pool = k_pool.at[:, dst].set(k_pool[:, src])
-            v_pool = v_pool.at[:, dst].set(v_pool[:, src])
-            return k_pool, v_pool
+            return jax.tree_util.tree_map(
+                lambda pool: pool.at[dst].set(pool[src]), (k_pool, v_pool))
 
         # pools are donated through the jits (they are dead the moment
         # the step returns — swap_pools rebinds to the outputs), so the
@@ -623,8 +641,8 @@ class DecodeEngine:
         failure that killed the pools has destroyed EVERY live sequence's
         KV — the caller escalates to a full eviction + fresh pools."""
         pool = self._cache.k_pool
-        dead = getattr(pool[0] if self._grouped else pool, "is_deleted",
-                       None)
+        dead = getattr((pool[0] if self._grouped else pool)[0],
+                       "is_deleted", None)
         return bool(dead and dead())
 
     def _device_page_table(self):
@@ -847,9 +865,15 @@ class DecodeEngine:
         packed = np.zeros((5 + self._extra_rows, s * self._spec_w), np.int32)
         # every row of write pages stays 0, the null page
         packed[4] = self._cache.null_write_slots(s * self._spec_w)[1]
-        sampled, kp, vp = self._step(
-            params, jnp.asarray(packed), self._cache.k_pool,
-            self._cache.v_pool, self._device_page_table())
+        step_args = (params, jnp.asarray(packed), self._cache.k_pool,
+                     self._cache.v_pool, self._device_page_table())
+        # compiled here, found again by the dispatch below (one executable
+        # for operands of one type): the gauge costs no second compile
+        mem = self._step.lower(*step_args).compile().memory_analysis()
+        if mem is not None:
+            self._step_temp_bytes = int(mem.temp_size_in_bytes)
+            _T_STEP_TEMP.set(self._step_temp_bytes, server=self._name)
+        sampled, kp, vp = self._step(*step_args)
         self._cache.swap_pools(kp, vp)
         if not self._chunk:
             # chunked mode never dispatches the monolithic rungs — every
@@ -928,6 +952,8 @@ class DecodeEngine:
                 # the tables' columns x slots x layers (the share that ran)
                 "kv_cols_live": self._kv_cols_live,
                 "kv_cols_grid": self._kv_cols_grid,
+                "kv_pool_leaves": self._kv_pool_leaves,
+                "decode_step_temp_bytes": self._step_temp_bytes,
                 "prefill_buckets": list(self._ladder),
                 "prefill_chunk": self._chunk,
                 "cow_copies": self._cow_copies,
@@ -1957,7 +1983,8 @@ class DecodeEngine:
         with self._cv:      # stats() reads them from caller threads
             self._kv_cols_live += live
             self._kv_cols_grid += grid
-        return {"kv_cols_live": live, "kv_cols_grid": grid}
+        return {"kv_cols_live": live, "kv_cols_grid": grid,
+                "kv_pool_leaves": self._kv_pool_leaves}
 
     def _layer_args(self, counters, live) -> dict:
         """Span arguments of a prefill or a decode tick of a model that

@@ -129,17 +129,65 @@ def _page_size(page_size: Optional[int]) -> int:
 def write_kv(k_pool, v_pool, layer: int, k_new, v_new, pages, offsets):
     """Scatter one batch of new K/V rows into the layer's pool pages.
 
-    k_pool/v_pool: (L, P, page_size, KH, D) device pools (traced);
-    k_new/v_new: (N, KH, D) rows; pages/offsets: (N,) int32 destinations
-    (host-computed by :meth:`PagedKVCache.write_slots`). Returns the
-    updated pools. Pure — trace it inside the step jit; every shape is
-    static, so membership churn never recompiles. Rows whose destination
-    is the null page (inactive slots, prompt padding) overwrite garbage
-    with garbage by design.
+    k_pool/v_pool: sequences of per-layer ``(P, page_size, KH, Dw)`` device
+    pools (traced); k_new/v_new: (N, KH, D) rows, zero-padded here to the
+    width ``Dw >= D`` the pools hold their rows at (:func:`pool_row_width`);
+    pages/offsets: (N,) int32 destinations (host-computed by
+    :meth:`PagedKVCache.write_slots`). Returns the pools as tuples with
+    element ``layer`` replaced — every other layer's array is the SAME
+    object, so a step donates and returns it untouched. Pure — trace it
+    inside the step jit; every shape is static, so membership churn never
+    recompiles. Rows whose destination is the null page (inactive slots,
+    prompt padding) overwrite garbage with garbage by design.
     """
-    k_pool = k_pool.at[layer, pages, offsets].set(k_new)
-    v_pool = v_pool.at[layer, pages, offsets].set(v_new)
-    return k_pool, v_pool
+    import jax.numpy as jnp
+
+    def put(pool, new):
+        pad = pool[layer].shape[-1] - new.shape[-1]
+        if pad:
+            new = jnp.pad(new, ((0, 0), (0, 0), (0, pad)))
+        return pool[:layer] + (pool[layer].at[pages, offsets].set(new),) \
+            + pool[layer + 1:]
+
+    return put(tuple(k_pool), k_new), put(tuple(v_pool), v_new)
+
+
+def pool_row_width(shape, dtype, device) -> int:
+    """The width a ``(P, page_size, KH, head_dim)`` pool's rows are HELD at
+    on ``device``: ``head_dim`` where the device's own default layout for
+    that shape is row-major (every CPU array; a TPU pool whose head_dim
+    fills the 128 lanes), else ``head_dim`` rounded up to the lanes of the
+    device's tile.
+
+    The paged kernel reads a pool row-major — a page's rows contiguous. A
+    TPU lays an array with a minor dimension under 128 out otherwise by
+    default (the PAGE axis on the lanes, since head_dim 64 would pad to
+    128), and a program handed such a pool converts the whole of it on the
+    way in and again on the way out, every tick. A pool whose rows ARE a
+    lane tile wide is row-major by default, byte for byte the row-major
+    layout of the narrow one (the pad the tile would add, made of zeros the
+    products ignore), and needs no layout of its own. (A layout pinned with
+    ``jax.experimental.layout`` does not survive the persistent compile
+    cache: PERF.md, PR 30.)
+    """
+    from jax.experimental.layout import Layout
+
+    def default(dims):
+        return Layout.from_pjrt_layout(device.client.get_default_layout(
+            np.dtype(dtype), tuple(dims), device))
+
+    row_major = tuple(range(len(shape)))
+    layout = default(shape)
+    if tuple(layout.major_to_minor) == row_major:
+        return int(shape[-1])
+    lanes = int(layout.tiling[0][-1])
+    width = -(-int(shape[-1]) // lanes) * lanes
+    if tuple(default(tuple(shape[:-1]) + (width,)).major_to_minor) \
+            != row_major:
+        raise MXNetError(
+            "kvcache: %s holds no %s pool of shape %s row-major, at rows of "
+            "%d either" % (device, np.dtype(dtype), tuple(shape), width))
+    return width
 
 
 class _PrefixEntry:
@@ -192,11 +240,14 @@ def _common_prefix_len(a: np.ndarray, b: np.ndarray) -> int:
 class PagedKVCache:
     """Fixed-size paged KV pools for ``num_slots`` concurrent sequences.
 
-    Device state: ``k_pool``/``v_pool`` of shape ``(num_layers,
-    num_pages, page_size, num_kv_heads, head_dim)`` — allocated once,
+    Device state: ``k_pool``/``v_pool``, each a tuple of ``num_layers``
+    arrays ``(num_pages, page_size, num_kv_heads, row width)`` — ONE ARRAY
+    A LAYER, the operand the layer's scatter writes and its kernel reads,
+    its rows as wide as the device holds row-major (:func:`pool_row_width`:
+    ``head_dim``, or the lane tile above it), allocated once and
     shape-stable for the cache's lifetime. The decode engine threads the
-    pools through its jitted step (functional update) and stores the
-    returned arrays back via :meth:`swap_pools`.
+    pools through its jitted step (functional update, donated) and stores
+    the returned arrays back via :meth:`swap_pools`.
 
     Host state per slot: a fixed-width page-table row (``max_pages``
     entries, unused entries = the null page 0) and a token count. The
@@ -230,10 +281,15 @@ class PagedKVCache:
                              % num_pages)
         self.num_pages = int(num_pages)
         self.name = name
-        shape = (int(num_layers), self.num_pages, self.page_size,
-                 int(num_kv_heads), int(head_dim))
-        self.k_pool = jnp.zeros(shape, np_dtype(dtype))
-        self.v_pool = jnp.zeros(shape, np_dtype(dtype))
+        self.num_layers = int(num_layers)
+        shape = (self.num_pages, self.page_size, int(num_kv_heads),
+                 int(head_dim))
+        self._pool_dtype = np_dtype(dtype)
+        (device,) = jnp.zeros((), self._pool_dtype).devices()
+        #: one layer's pool: rows as wide as ``device`` holds row-major
+        self._pool_shape = shape[:-1] + (
+            pool_row_width(shape, self._pool_dtype, device),)
+        self._zero_pools()
         # LIFO free list over pages 1..P-1; page 0 is the null page
         self._free: List[int] = list(range(self.num_pages - 1, 0, -1))
         self.page_table = np.zeros((self.num_slots, self.max_pages),
@@ -657,12 +713,15 @@ class PagedKVCache:
         been consumed by the failed execution, and every future sequence
         rewrites its pages through prefill before reading them anyway.
         The prefix index dies with the content it described."""
+        self._zero_pools()
+        self.clear_prefix_index()
+
+    def _zero_pools(self) -> None:
         import jax.numpy as jnp
 
-        shape, dtype = self.k_pool.shape, self.k_pool.dtype
-        self.k_pool = jnp.zeros(shape, dtype)
-        self.v_pool = jnp.zeros(shape, dtype)
-        self.clear_prefix_index()
+        self.k_pool, self.v_pool = (
+            tuple(jnp.zeros(self._pool_shape, self._pool_dtype)
+                  for _ in range(self.num_layers)) for _ in range(2))
 
     def _publish(self) -> None:
         _T_PAGES.set(self.pages_in_use, cache=self.name)

@@ -258,8 +258,8 @@ def test_grouped_cache_takes_pages_in_both_groups_or_in_neither(tiny):
     model, _params = tiny
     cache = GroupedKVCache(3, 128, model.kv_groups, 2, 8, page_size=PAGE,
                            num_pages={"full": 40, "window": 8})
-    assert cache.k_pool[0].shape == (1, 40, PAGE, 2, 8)
-    assert cache.k_pool[1].shape == (4, 8, PAGE, 2, 8)
+    assert [x.shape for x in cache.k_pool[0]] == [(40, PAGE, 2, 8)]
+    assert [x.shape for x in cache.k_pool[1]] == [(8, PAGE, 2, 8)] * 4
     cache.reserve(0, 100)
     assert cache.full.pages_owned(0) == 13
     assert cache.window.pages_owned(0) == 5
@@ -313,7 +313,8 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
     keys = {"moe_rows_held", "moe_experts_hit", "moe_load_max",
             "kv_rows_full", "kv_rows_window", "kv_window_pages",
             "kv_window_capacity"}
-    walk = {"kv_cols_live", "kv_cols_grid"}     # a decode tick's alone
+    walk = {"kv_cols_live", "kv_cols_grid",     # a decode tick's alone
+            "kv_pool_leaves"}
     with _engine(tiny, num_slots=2) as eng:
         eng.warmup()
         opts = jax.profiler.ProfileOptions()
@@ -343,4 +344,5 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
         assert args["kv_rows_window"] == WINDOW
         assert args["kv_window_pages"] == 5
         assert args["kv_window_capacity"] == 10
+        assert args["kv_pool_leaves"] == 2 * len(TINY["layer_types"])
         assert 0 <= args["moe_load_max"] <= args["moe_rows_held"] <= 16

@@ -11,8 +11,11 @@ inside a module-scoped fixture, never at import / in ``skipif`` / in
 xdist workers that each import every test file, and only the worker handed
 this file may load it. Nothing runs — a passing compile is not a chip run.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -178,3 +181,101 @@ def test_kernel_keeps_its_name_in_the_compiled_program(
     calls = [ln.split(" = ", 1)[0].split("%")[-1] for ln in text.splitlines()
              if "custom-call(" in ln and "tpu_custom_call" in ln]
     assert calls and all(c.startswith(kernel) for c in calls), calls
+
+
+# ---------------------------------------------------------------------------
+# the decode step's KV pools: updated in place, never copied or converted
+# ---------------------------------------------------------------------------
+LAYERS = 4
+
+
+def _pool_step_case(name, one_chip):
+    """A 4-layer decode step as the models build it — ``write_kv`` into the
+    layer's pool, the paged kernel on that pool — over per-layer pools with
+    rows as wide as the cache would hold them on the described chip
+    (``kvcache.pool_row_width``): ``(step, abstract operands, donated
+    operands, elements of the smallest pool)``."""
+    from mxnet_tpu.serving import kvcache
+
+    (device,) = one_chip.device_set
+
+    def spec(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def pools(layers, shape):
+        width = kvcache.pool_row_width(shape, jnp.float32, device)
+        assert width == 128, width      # 64 grows to the lanes, 128 stays
+        return tuple(spec(shape[:-1] + (width,)) for _ in range(layers))
+
+    # pools, tables and (group, layer in it) by cache group, as a model
+    # with ``kv_groups`` receives them; group 1 is a window's ring
+    if name == "opt1p3b_32x64":         # the OPT cell: 385 pages, 32 x 64
+        heads, kv_heads, dim = 32, 32, 64
+        pool = (pools(LAYERS, (385, PAGE, kv_heads, dim)),)
+        tables = (spec((SLOTS, 128), jnp.int32),)
+        place = [(0, li) for li in range(LAYERS)]
+        smallest = 385 * PAGE * kv_heads * 128
+    else:                               # Trinity's: 8 x 128, a window group
+        heads, kv_heads, dim = 48, 8, 128
+        ring = 4096 // PAGE + 1
+        # (a pool small enough for the chip's 128 MiB of VMEM is prefetched
+        # there whole by the compiler: the cells' pools are not, nor this)
+        pool = (pools(1, (4097, PAGE, kv_heads, dim)),
+                pools(LAYERS - 1, (SLOTS * ring + 1, PAGE, kv_heads, dim)))
+        tables = (spec((SLOTS, 512), jnp.int32),
+                  spec((SLOTS, ring), jnp.int32))
+        place = [(1, 0), (1, 1), (1, 2), (0, 0)]
+        smallest = 4097 * PAGE * kv_heads * dim
+
+    def step(q, k_new, v_new, k_pool, v_pool, tables, lens, pages, offs):
+        k_pool, v_pool = list(k_pool), list(v_pool)
+        for grp, li in place:
+            k_pool[grp], v_pool[grp] = kvcache.write_kv(
+                k_pool[grp], v_pool[grp], li, k_new, v_new, pages, offs)
+            operands = (q, k_pool[grp][li], v_pool[grp][li], tables[grp],
+                        lens)
+            q = q + (pk.ragged_window_attention(*operands, 4096,
+                                                interpret=False) if grp
+                     else pk.ragged_paged_attention(*operands,
+                                                    interpret=False))
+            k_new, v_new = k_new + 1.0, v_new + 1.0
+        return q, tuple(k_pool), tuple(v_pool)
+
+    rows = spec((SLOTS, kv_heads, dim))
+    ints = spec((SLOTS,), jnp.int32)
+    return (step, (spec((SLOTS, heads, dim)), rows, rows, pool, pool, tables,
+                   ints, ints, ints), (3, 4), smallest)
+
+
+@pytest.mark.parametrize("name", ["opt1p3b_32x64", "trinity_8x128_window"])
+def test_decode_step_updates_its_kv_pools_in_place(name, one_chip,
+                                                   compile_cache_off):
+    """One array a layer, its rows as wide as the chip holds row-major: the
+    compiled step holds no copy of a pool (``%copy.*``: a pool converted to
+    the kernel's layout and back), no slice of one
+    (``%slice_bitcast_fusion.*``: a layer cut out of a stacked pool) and
+    next to no temporaries. Stacked ``(L, P, page, KH, D)`` pools read 1.214
+    GB of temporaries here at 32 x 64 (PR 30)."""
+    step, operands, donate, smallest = _pool_step_case(name, one_chip)
+    compiled = jax.jit(step, donate_argnums=donate).lower(
+        *operands).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    big = {}
+    for line in entry.splitlines():
+        m = re.match(r"\s+(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* ([\w\-]+)\(",
+                     line)
+        if m and np.prod([int(d) for d in m.group(2).split(",")]) >= smallest:
+            big.setdefault(m.group(3), []).append(m.group(1))
+    # a layer's K and V scatter, each a fusion that writes its operand
+    fusions = big.pop("fusion")
+    assert len(fusions) == 2 * LAYERS and not any(
+        "slice" in n for n in fusions), fusions
+    big.pop("parameter")
+    big.pop("bitcast", None)            # a view, not a buffer
+    assert not big, "pool-sized outputs: %r" % big
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    # in and out in the one layout the kernel reads: row-major
+    for leaf in jax.tree_util.tree_leaves(
+            (compiled.input_formats[0][3:5], compiled.output_formats[1:])):
+        assert tuple(leaf.layout.major_to_minor) == (0, 1, 2, 3)

@@ -234,7 +234,7 @@ def test_write_kv_scatters_rows():
     pages, offs = c.write_slots(0, 7, 2)  # straddles the page edge
     kp, vp = write_kv(c.k_pool, c.v_pool, 1, rows, rows * 2.0,
                       jnp.asarray(pages), jnp.asarray(offs))
-    got_k = np.asarray(kp[1, np.asarray(pages), np.asarray(offs)])
+    got_k = np.asarray(kp[1][np.asarray(pages), np.asarray(offs)])
     np.testing.assert_array_equal(got_k, np.asarray(rows))
     assert np.abs(np.asarray(kp[0])).sum() == 0  # other layer untouched
 

@@ -415,7 +415,7 @@ def _eager_zero_run(prefix, opt, steps=5):
 
 def _zero_fallbacks():
     fam = telemetry.REGISTRY.get("mxnet_zero_fallbacks_total")
-    return sum(v for _k, v in fam.series()) if fam else 0
+    return sum(s["value"] for s in fam.series()) if fam else 0
 
 
 @pytest.mark.parametrize("opt", sorted(_OPTS))

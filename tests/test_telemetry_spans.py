@@ -399,6 +399,8 @@ def test_decode_commit_span_carries_the_page_walk(kind, tmp_path):
         assert args["kv_cols_live"] == want
         assert args["kv_cols_grid"] == grid
         assert 0 < args["kv_cols_live"] <= args["kv_cols_grid"]
+        # the arrays the step was handed as pools: K and V, one a layer
+        assert args["kv_pool_leaves"] == 2 * sum(n for _c, n in tables)
     for key, counter in (("kv_cols_live", decode_mod._T_KV_COLS_LIVE),
                          ("kv_cols_grid", decode_mod._T_KV_COLS_GRID)):
         total = sum(a[key] for a in commits)
@@ -406,6 +408,14 @@ def test_decode_commit_span_carries_the_page_walk(kind, tmp_path):
         assert sum(counter.value(server=name, group=g)
                    for g in ("full", "window")[:len(tables)]) == stats[key]
     assert "mxnet_decode_kv_cols_live_total" in telemetry.render_prometheus()
+    assert stats["kv_pool_leaves"] == commits[0]["kv_pool_leaves"]
+    # warmup() read the compiled step's temporaries (what they must stay
+    # under at real widths: tests/test_chip_compile.py)
+    assert stats["decode_step_temp_bytes"] > 0
+    assert decode_mod._T_STEP_TEMP.value(server=name) \
+        == stats["decode_step_temp_bytes"]
+    assert before["decode_step_temp_bytes"] \
+        == stats["decode_step_temp_bytes"]
 
 
 def test_decode_chunked_prefill_span_carries_the_chunk(tiny, tmp_path):
