@@ -286,6 +286,16 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # a grid step outside it asks for the page that is already resident (the
 # launch clamps the table: no new DMA) and skips the products
 # (``pl.when``); inside it the ragged mask works position by position.
+#
+# A grid step multiplies its page ONCE for all the page's kv heads: the
+# block (page_size, KH, D) is read as one key matrix (page_size * KH, D) —
+# with KH a multiple of the sublane tile the same tiles in the same order,
+# elsewhere a relayout in the kernel — and the slot's query rows are one
+# matrix (R, D) of every head, R = pad_up(KH * W * groups, 8). Two products
+# a live page, (R, D) x (page_size * KH, D)^T and (R, page_size * KH) x
+# (page_size * KH, D), whatever KH; a score of a row against another kv
+# head's key is masked like a position out of the row's sight, so its
+# weight is exactly 0. Scratch: m, l (R, LANES), acc (R, D).
 # Interpret mode runs the same kernel on the CPU test mesh;
 # `paged_attention` (the dispatcher the decode engine calls) uses the dense
 # jnp reference off-TPU instead, which is faster than interpreting and
@@ -299,18 +309,24 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, lc_ref, *rest, page_size,
     tokens per slot (1 = classic decode tick / chunked-prefill row, K+1 =
     speculative verify tick).
 
-    q_ref/o_ref: (1, KH, Rp, D) — the slot's query rows grouped by the kv
-    head they read: row ``r = w*groups + g`` of kv head ``kh`` is query
-    token ``w``, head ``kh*groups + g``; Rp pads ``width*groups`` to the
-    sublane tile. k_ref/v_ref: (1, page_size, KH, D) — the page named by
-    the slot's page table. Scratch m/l: (KH, Rp, LANES), acc: (KH, Rp, D).
+    q_ref/o_ref: (1, R, D) — ALL the slot's query rows as one matrix, those
+    of a kv head contiguous: row ``r = (kh*width + w)*groups + g`` is query
+    token ``w``, head ``kh*groups + g``; R pads ``n_kv*width*groups`` to the
+    sublane tile. k_ref/v_ref: (1, page_size, KH, D) — the page named by the
+    slot's page table, read as ONE key matrix ``(page_size*KH, D)`` for all
+    its kv heads: key row ``c = t*KH + kh`` is token ``t`` of the page, kv
+    head ``kh``. Scratch m/l: (R, LANES), acc: (R, D).
     sl_ref/qp_ref are (S*width,): PER-QUERY-TOKEN seq_len and (when
     ``causal``) query position. lc_ref is (S*2,): the slot's live columns
     ``[c0, c1)`` (:func:`live_columns`) — a column outside them holds no
     key any row may see, so it is neither fetched (``pt_ref`` names a live
-    column's page there) nor multiplied. Every per-kv-head access indexes a
-    LEADING ref axis or loads one kv head straight from the page ref — no
-    value slicing, which Mosaic refuses (dynamic_slice) or relayouts.
+    column's page there) nor multiplied.
+
+    A live page costs two products, ``(R, D) x (page_size*KH, D)^T`` and
+    ``(R, page_size*KH) x (page_size*KH, D)``: a score whose row reads
+    another kv head than its column holds is masked like a position the row
+    may not see, ``exp`` makes it exactly 0, and the zeros pick each row's
+    own head out of the values.
 
     ``window`` > 0 (static) also masks keys at or below ``query - window``
     (the query is the row's ``q_pos`` when causal, else its last token).
@@ -333,27 +349,32 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, lc_ref, *rest, page_size,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    n_kv, rp = m_scr.shape[0], m_scr.shape[1]
+    r_pad, d = acc_scr.shape
+    n_kv = k_ref.shape[2]
+    rows = width * groups           # the query rows of one kv head
+    keys = page_size * n_kv
 
     @pl.when(jnp.logical_and(j >= lc_ref[2 * s], j < lc_ref[2 * s + 1]))
     def _page():
-        # ragged mask, per query row: token positions of this page vs the
-        # row's length (and its query position when causal). The w of a row
-        # is its index // groups — the (Rp, 1) columns are an unrolled
-        # select over the width scalar-prefetch entries; pad rows match no
-        # w, keep length 0 and come out as zeros.
-        row_w = lax.broadcasted_iota(jnp.int32, (rp, 1), 0) // groups
-        sl_rows = jnp.zeros((rp, 1), jnp.int32)
-        qp_rows = jnp.zeros((rp, 1), jnp.int32)
+        # the mask, per query row and key row: the key's kv head is the
+        # row's, and its token position lies under the row's length (and
+        # its query position when causal). The w of a row is an unrolled
+        # select over the width scalar-prefetch entries; a pad row reads kv
+        # head KH, which no key holds, and comes out as zeros.
+        row = lax.broadcasted_iota(jnp.int32, (r_pad, 1), 0)
+        row_kh = row // rows
+        row_w = (row - row_kh * rows) // groups
+        sl_rows = jnp.zeros((r_pad, 1), jnp.int32)
+        qp_rows = jnp.zeros((r_pad, 1), jnp.int32)
         for w in range(width):
             sl_rows = jnp.where(row_w == w, sl_ref[s * width + w], sl_rows)
             if causal:
                 qp_rows = jnp.where(row_w == w, qp_ref[s * width + w],
                                     qp_rows)
+        col = lax.broadcasted_iota(jnp.int32, (1, keys), 1)
         first = blk_ref[s * max_pages + j] if ring else j
-        pos = first * page_size \
-            + lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        valid = pos < sl_rows
+        pos = first * page_size + col // n_kv
+        valid = jnp.logical_and(col % n_kv == row_kh, pos < sl_rows)
         if causal:
             valid = jnp.logical_and(valid, pos <= qp_rows)
         if window or ring:
@@ -362,27 +383,24 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, lc_ref, *rest, page_size,
                 if window else 0
             valid = jnp.logical_and(valid, pos >= jnp.maximum(low, 0))
 
-        # per-kv-head 2D matmuls keep the MXU fed without a batched einsum;
-        # n_kv is a small trace-time constant so the python loop unrolls.
-        for khi in range(n_kv):
-            q = q_ref[0, khi].astype(jnp.float32)           # (Rp, D)
-            k = k_ref[0, :, khi, :].astype(jnp.float32)     # (page_size, D)
-            v = v_ref[0, :, khi, :].astype(jnp.float32)
-            scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), precision=precision,
-                preferred_element_type=jnp.float32) * scale
-            scores = jnp.where(valid, scores, _NEG_BIG)
-            m_prev = m_scr[khi][:, :1]
-            l_prev = l_scr[khi][:, :1]
-            m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(scores - m_new)
-            l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-            acc_scr[khi] = acc_scr[khi] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())), precision=precision,
-                preferred_element_type=jnp.float32)
-            m_scr[khi] = jnp.broadcast_to(m_new, (rp, LANES))
-            l_scr[khi] = jnp.broadcast_to(l_new, (rp, LANES))
+        q = q_ref[0].astype(jnp.float32)                        # (R, D)
+        k = k_ref[0].astype(jnp.float32).reshape(keys, d)
+        v = v_ref[0].astype(jnp.float32).reshape(keys, d)
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(valid, scores, _NEG_BIG)
+        m_prev = m_scr[:, :1]
+        l_prev = l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
@@ -392,9 +410,9 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, lc_ref, *rest, page_size,
         # zeros (the row IS the slot's output, there is no padding to drop
         # as in the flash kernel, where such a row's p = exp(NEG_BIG -
         # NEG_BIG) = 1 accumulates garbage)
-        seen = m_scr[:, :, :1] > _NEG_BIG * 0.5
+        seen = m_scr[:, :1] > _NEG_BIG * 0.5
         o = jnp.where(seen,
-                      acc_scr[...] / jnp.maximum(l_scr[:, :, :1], 1e-30),
+                      acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30),
                       0.0)
         o_ref[0] = o.astype(o_ref.dtype)
 
@@ -469,15 +487,16 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
     if interpret is None:
         interpret = _interpret()
 
-    # (S, W, KH, G, D) -> (S, KH, W*G, D): each kv head's query rows
-    # contiguous and padded to the f32 sublane tile. Pad rows carry
-    # seq_len 0 (see the kernel's row mask), each row's softmax state is
-    # independent, and they are sliced off on return — layout, not math.
-    rows = width * groups
-    rp = _pad_up(rows, _PACK_ROWS)
+    # (S, W, KH, G, D) -> (S, KH*W*G, D): one matrix of every query row,
+    # those of a kv head contiguous, padded as a whole to the f32 sublane
+    # tile. Pad rows read no kv head (see the kernel's mask), each row's
+    # softmax state is independent, and they are sliced off on return —
+    # layout, not math.
+    rows = n_kv * width * groups
+    r_pad = _pad_up(rows, _PACK_ROWS)
     qk = q.reshape(s_slots, width, n_kv, groups, d).transpose(0, 2, 1, 3, 4)
-    qk = jnp.pad(qk.reshape(s_slots, n_kv, rows, d),
-                 ((0, 0), (0, 0), (0, rp - rows), (0, 0)))
+    qk = jnp.pad(qk.reshape(s_slots, rows, d),
+                 ((0, 0), (0, r_pad - rows), (0, 0)))
     more = {}
     if window or ring:
         more = {"window": int(window), "ring": bool(ring)}
@@ -507,7 +526,7 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
         scalars += (ring_blocks(sl, max_pages, page_size).ravel(),)
 
     def q_map(s, j, *_):
-        return (s, 0, 0, 0)
+        return (s, 0, 0)
 
     def page_map(s, j, pt, *_):
         return (pt[s * max_pages + j], 0, 0, 0)
@@ -517,15 +536,15 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
         # column beyond it would still cost its index maps (0.1 us each)
         grid=(s_slots, jnp.maximum(live[:, 1].max(), 1)),
         in_specs=[
-            pl.BlockSpec((1, n_kv, rp, d), q_map),
+            pl.BlockSpec((1, r_pad, d), q_map),
             pl.BlockSpec((1, page_size, n_kv, d), page_map),
             pl.BlockSpec((1, page_size, n_kv, d), page_map),
         ],
-        out_specs=pl.BlockSpec((1, n_kv, rp, d), q_map),
+        out_specs=pl.BlockSpec((1, r_pad, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((n_kv, rp, LANES), jnp.float32),
-            pltpu.VMEM((n_kv, rp, LANES), jnp.float32),
-            pltpu.VMEM((n_kv, rp, d), jnp.float32),
+            pltpu.VMEM((r_pad, LANES), jnp.float32),
+            pltpu.VMEM((r_pad, LANES), jnp.float32),
+            pltpu.VMEM((r_pad, d), jnp.float32),
         ],
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=5, **spec) \
@@ -533,14 +552,14 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
                                                   **spec)
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((s_slots, n_kv, rp, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s_slots, r_pad, d), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="mx_paged_attn",  # what a device trace is searched for
     )(*scalars, qk, k_pool, v_pool)
-    out = out[:, :, :rows, :d_q].reshape(s_slots, n_kv, width, groups, d_q)
+    out = out[:, :rows, :d_q].reshape(s_slots, n_kv, width, groups, d_q)
     return out.transpose(0, 2, 1, 3, 4).reshape(s_slots, width, n_heads,
                                                 d_q)
 

@@ -95,11 +95,13 @@ def _case(name, spec, dtype):
                 (qkv, qkv, qkv))
     # Trinity-Large-Preview's attention (48 query / 8 kv heads x 128, window
     # 4096, 16-token pages -> a ring of 257 pages a slot) and expert widths
-    if name == "paged_window_decode":
+    # (``_trinity``: with float32 products, as afmoe.py launches it)
+    if name in ("paged_window_decode", "paged_window_decode_trinity"):
         ring = 4096 // PAGE + 1
         pool = spec((SLOTS * ring + 1, PAGE, 8, 128), dtype)
         return (lambda q, k, v, pt, sl: pk.ragged_window_attention(
-            q, k, v, pt, sl, 4096, interpret=False),
+            q, k, v, pt, sl, 4096, interpret=False,
+            precise=name.endswith("trinity")),
             (spec((SLOTS, 48, 128), dtype), pool, pool,
              spec((SLOTS, ring), jnp.int32), spec((SLOTS,), jnp.int32)))
     # the two benchmark cells' own tables: the widest scalar-prefetch
@@ -115,6 +117,26 @@ def _case(name, spec, dtype):
             *a, interpret=False, precise=True),
             (spec((SLOTS, 48, 128), dtype), pool, pool,
              spec((SLOTS, 512), jnp.int32), spec((SLOTS,), jnp.int32)))
+    # the body that multiplies a page once for all its kv heads (PR 34), at
+    # the cells' own shapes. OPT as the engine holds it on the chip: 32 kv
+    # heads x 64 in rows 128 wide (the key matrix of a page is 512 x 128),
+    # the decode tick, the verify tick (width 4) and the chunk program
+    if name.startswith("opt1p3b_wide_"):
+        pool = spec((385, PAGE, 32, 128), dtype)
+        table = spec((SLOTS, 128), jnp.int32)
+        if name.endswith("decode"):
+            return (lambda *a: pk.ragged_paged_attention(*a, interpret=False),
+                    (spec((SLOTS, 32, 64), dtype), pool, pool, table,
+                     spec((SLOTS,), jnp.int32)))
+        if name.endswith("spec"):
+            return (lambda *a: pk.ragged_spec_attention(*a, interpret=False),
+                    (spec((SLOTS, SPEC_W, 32, 64), dtype), pool, pool, table,
+                     spec((SLOTS * SPEC_W,), jnp.int32)))
+        return (lambda q, k, v, pt, sl, qp: pk.ragged_paged_attention(
+            q, k, v, pt, sl, q_pos=qp, interpret=False),
+            (spec((CHUNK, 32, 64), dtype), pool, pool,
+             spec((CHUNK, 128), jnp.int32), spec((CHUNK,), jnp.int32),
+             spec((CHUNK,), jnp.int32)))
     if name in ("band_prefill_8192", "band_prefill_8192_window"):
         window = 4096 if name.endswith("window") else 0
         kv = spec((8192, 8, 128), dtype)
@@ -141,6 +163,10 @@ def _case(name, spec, dtype):
                                   "nms", "paged_window_decode",
                                   "paged_decode_opt1p3b",
                                   "paged_decode_trinity_full",
+                                  "opt1p3b_wide_decode",
+                                  "opt1p3b_wide_spec",
+                                  "opt1p3b_wide_chunk",
+                                  "paged_window_decode_trinity",
                                   "band_prefill_8192",
                                   "band_prefill_8192_window",
                                   "moe_gmm_decode", "moe_gmm_prefill"])
