@@ -261,10 +261,11 @@ def serve_phase(device, seed, size):
             got = [f.result(timeout=600) for f in futs]
             wall = time.perf_counter() - t0
             stats = eng.stats()
-            packed = np.zeros((5, size["slots"] * (spec_k + 1)), np.int32)
+            packed = np.zeros(
+                (eng._packed_rows, size["slots"] * (spec_k + 1)), np.int32)
             step_text = eng._step.lower(
-                eng._params, packed, eng._cache.k_pool, eng._cache.v_pool,
-                eng._cache.page_table).as_text()
+                eng._params, packed, eng._no_prev, eng._cache.k_pool,
+                eng._cache.v_pool, eng._cache.page_table).as_text()
         finally:
             eng.close()
         exact = [bool(np.array_equal(g, w)) for g, w in zip(got, want)]
@@ -277,6 +278,7 @@ def serve_phase(device, seed, size):
             steady_state_recompiles=stats.get("steady_state_recompiles"),
             pages_in_use=stats["kvcache"]["pages_in_use"],
             accepted_per_tick=spec.get("accepted_per_tick"),
+            ticks=stats["ticks"], steps_overlapped=stats["steps_overlapped"],
             tpu_custom_call_in_decode_step="tpu_custom_call" in step_text,
             compile_cache_hits=hits1 - hits0,
             compile_cache_misses=misses1 - misses0)
@@ -291,6 +293,12 @@ def serve_phase(device, seed, size):
         # takes the dense reference by design)
         check("tpu_custom_call" in step_text or device.platform != "tpu",
               "%s: Pallas kernel in the lowered decode step" % tag)
+        # one step in flight without a draft; with one, a step is fetched
+        # before the next is packed
+        check((stats["steps_overlapped"] == 0) if spec_k
+              else (stats["steps_overlapped"] > stats["ticks"] // 2),
+              "%s: steps overlapped as the engine's order says" % tag,
+              ticks=stats["ticks"], overlapped=stats["steps_overlapped"])
         if spec_k:
             check(spec.get("accepted_per_tick", 0) > 1.0,
                   "%s: speculation accepts drafts" % tag, speculative=spec)

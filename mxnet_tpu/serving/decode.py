@@ -28,12 +28,15 @@ Anatomy of a request:
    long-context path, sequence axis sharded over the local mesh;
 4. **decode ticks** — one jitted step per tick regardless of membership
    churn: paged-attention over the page table, in-graph greedy sampling,
-   and exactly TWO host<->device crossings per tick — one packed (5, S)
+   and exactly TWO host<->device crossings per tick — one packed
    operand put (tokens/positions/lengths/write slots travel together;
    the page table rides a version-keyed device cache re-put only when
    admission or completion mutates it) and ONE fetch of the sampled
    tokens (the per-token sync the ``decode-host-sync`` tpulint pass
-   audits);
+   audits). One step is kept IN FLIGHT: step N+1 is dispatched before
+   step N is fetched and reads N's tokens on the device, so the fetch,
+   the commit and the next pass's admission run while the device works
+   (with a draft in play a step is fetched before the next is packed);
 5. **completion** — EOS or the token budget frees the pages (LIFO reuse)
    and the freed slot admits the next queued sequence on the same tick.
 
@@ -160,6 +163,11 @@ _T_KV_COLS_GRID = telemetry.counter(
     "columns x slots x layers (live / grid = the share of a table a tick "
     "fetches and multiplies)",
     labels=("server", "group"))
+_T_OVERLAPPED = telemetry.counter(
+    "mxnet_decode_steps_overlapped_total",
+    "decode steps dispatched while the step before them was still "
+    "un-fetched (the host's work for that step ran behind the device)",
+    labels=("server",))
 _T_STEP_TEMP = telemetry.gauge(
     "mxnet_decode_step_temp_bytes",
     "temporaries of the compiled decode step (its memory_analysis), set by "
@@ -189,6 +197,15 @@ def _tree_sig(tree):
     return jax.tree_util.tree_map(
         lambda x: (tuple(getattr(x, "shape", ())),
                    str(getattr(x, "dtype", type(x).__name__))), tree)
+
+
+def _placed_alike(a, b) -> bool:
+    """Whether a jit takes the two arrays for one signature: both left to
+    the default device, or both committed to equivalent shardings."""
+    if not (a.committed or b.committed):
+        return True
+    return a.committed == b.committed and \
+        a.sharding.is_equivalent_to(b.sharding, a.ndim)
 
 
 class PagedDecodeModel:
@@ -321,6 +338,24 @@ class _DecodeRequest:
         self.trace: Optional[_tracing.Trace] = None
 
 
+class _StepInFlight:
+    """A decode step the device has been handed and the host has not
+    fetched: its output array (the sampled tokens, a model's counters
+    behind them; the device->host copy already started), the ``(slot,
+    request)`` pairs and the per-row lengths as dispatched, the drafts by
+    slot. ``reqs`` is the requests by identity: a slot may have changed
+    hands by the time the step is retired."""
+
+    __slots__ = ("out", "active", "drafts", "lens", "reqs")
+
+    def __init__(self, out, active, drafts, lens):
+        self.out = out
+        self.active = active
+        self.drafts = drafts
+        self.lens = lens
+        self.reqs = frozenset(req for _slot, req in active)
+
+
 class DecodeEngine:
     """Continuous-batching decode service over one :class:`PagedDecodeModel`.
 
@@ -395,7 +430,7 @@ class DecodeEngine:
         # speculative decoding: the step carries a STATIC width of
         # spec_k+1 query rows per slot (committed token + up to k draft
         # rows). k=0 keeps the classic 1-row tick bit-for-bit (the
-        # packed operand is then (5, S) exactly as before). The width is
+        # packed operand is then one column a slot). The width is
         # a compile-time constant — per-tick draft depth, acceptance and
         # per-tenant caps vary only the DATA inside it.
         self._spec_k = max(0, int(spec_k))
@@ -443,6 +478,8 @@ class DecodeEngine:
                 prefix_cache=self._prefix_cache)
         # rows of the packed operands: a second group adds its write pages
         self._extra_rows = 1 if self._grouped else 0
+        # ... and the step's: `from_prev` last
+        self._packed_rows = 5 + self._extra_rows + 1
         # the tables a decode tick's paged attention walks: (cache group,
         # columns, layers that walk it)
         self._walk_groups = tuple(
@@ -516,9 +553,12 @@ class DecodeEngine:
         # the tick's five (S*W,) int32 operands (tokens, positions,
         # seq_lens, write pages, write offsets; W = spec_k+1 query rows
         # per slot, 1 when speculation is off) travel as ONE packed
-        # (5, S*W) array — one host->device put per tick instead of five;
-        # the page table rides a version-keyed device cache (below), so a
-        # steady tick pays exactly one put + one fetch
+        # array — one host->device put per tick instead of five — with a
+        # second group's write pages and, last, `from_prev`: the rows
+        # whose token the host has not seen yet, taken on the device from
+        # the previous step's output. The page table rides a version-keyed
+        # device cache (below), so a steady tick pays exactly one put +
+        # one fetch
         grouped = self._grouped
 
         def with_counters(sampled, out):
@@ -529,14 +569,20 @@ class DecodeEngine:
             return jnp.concatenate([sampled.reshape(-1),
                                     out[3].reshape(-1).astype(jnp.int32)])
 
-        def mx_decode_step(params, packed, k_pool, v_pool, page_tables):
+        def mx_decode_step(params, packed, prev, k_pool, v_pool,
+                           page_tables):
+            # `prev`: the previous step's own output, whole (or zeros of
+            # its shape before any step); its first S*W values are tokens
+            from_prev = packed[-1]
             if grouped:
                 tokens, positions, seq_lens, full_pages, write_offsets, \
-                    window_pages = packed
+                    window_pages = packed[:-1]
                 write_pages = (full_pages, window_pages)
             else:
                 tokens, positions, seq_lens, write_pages, write_offsets = \
-                    packed
+                    packed[:-1]
+            tokens = jnp.where(from_prev != 0, prev[:tokens.shape[0]],
+                               tokens)
             out = model.decode(
                 params, tokens, positions, k_pool, v_pool, page_tables,
                 seq_lens, write_pages, write_offsets)
@@ -587,13 +633,30 @@ class DecodeEngine:
         # cache costs ONE pool of HBM, not two per step
         # the functions' names are the XLA modules' (jit_mx_decode_step,
         # ...): what a device trace is searched for
+        # (`prev` is not donated: the host has yet to fetch it)
         self._step = jax.jit(mx_decode_step,
-                             donate_argnums=(2, 3) if donate else ())
+                             donate_argnums=(3, 4) if donate else ())
         self._prefill_jit = jax.jit(mx_prefill, donate_argnums=donate)
         self._chunk_jit = jax.jit(
             mx_prefill_chunk, donate_argnums=(5, 6) if donate else ())
         self._cow_jit = jax.jit(
             mx_kv_cow, donate_argnums=(0, 1) if donate else ())
+        # what a step with no step before it is handed as `prev`: the
+        # shape, dtype and placement of a step's output, so every mix of
+        # host-fed and device-fed rows runs ONE executable. Weights
+        # committed to one device commit the output there; across several
+        # warmup() asks the step where it puts it
+        n_out = self.num_slots * self._spec_w + (
+            int(np.prod(moe_shape)) if moe_shape else 0)
+        self._no_prev = jnp.zeros((n_out,), jnp.int32)
+        leaf = jax.tree_util.tree_leaves(params)[0]
+        if getattr(leaf, "committed", False) and \
+                len(leaf.sharding.device_set) == 1:
+            self._no_prev = jax.device_put(
+                self._no_prev, next(iter(leaf.sharding.device_set)))
+        #: the step dispatched and not yet fetched (worker-confined)
+        self._inflight: Optional[_StepInFlight] = None
+        self._steps_overlapped = 0
         self._pt_dev = None  # version-keyed device page table
         self._pt_version = -1
         self._pt_groups = [[-1, None], [-1, None]]  # the same, by group
@@ -855,6 +918,8 @@ class DecodeEngine:
         to the null page); anchors the steady-state-recompile gauge at 0
         — a cold first shared-prefix request compiles NOTHING. Returns
         the compile count."""
+        import jax
+
         jnp = self._jnp
         s = self.num_slots
         with self._cv:
@@ -862,19 +927,30 @@ class DecodeEngine:
             params = self._params
         # the step's packed operand carries W = spec_k+1 rows per slot;
         # warming at that width anchors the widened tick too
-        packed = np.zeros((5 + self._extra_rows, s * self._spec_w), np.int32)
+        packed = np.zeros((self._packed_rows, s * self._spec_w), np.int32)
         # every row of write pages stays 0, the null page
         packed[4] = self._cache.null_write_slots(s * self._spec_w)[1]
-        step_args = (params, jnp.asarray(packed), self._cache.k_pool,
-                     self._cache.v_pool, self._device_page_table())
+        def step_args():
+            return (params, jnp.asarray(packed), self._no_prev,
+                    self._cache.k_pool, self._cache.v_pool,
+                    self._device_page_table())
+
         # compiled here, found again by the dispatch below (one executable
         # for operands of one type): the gauge costs no second compile
-        mem = self._step.lower(*step_args).compile().memory_analysis()
+        mem = self._step.lower(*step_args()).compile().memory_analysis()
         if mem is not None:
             self._step_temp_bytes = int(mem.temp_size_in_bytes)
             _T_STEP_TEMP.set(self._step_temp_bytes, server=self._name)
-        sampled, kp, vp = self._step(*step_args)
-        self._cache.swap_pools(kp, vp)
+        for _ in range(2):
+            sampled, kp, vp = self._step(*step_args())
+            self._cache.swap_pools(kp, vp)
+            if _placed_alike(self._no_prev, sampled):
+                break
+            # weights sharded over several devices: the step's output is
+            # placed by them, so the stand-in takes that placement and the
+            # second pass warms the call every later step makes
+            self._no_prev = jax.device_put(
+                np.zeros(sampled.shape, sampled.dtype), sampled.sharding)
         if not self._chunk:
             # chunked mode never dispatches the monolithic rungs — every
             # prompt runs through the one chunk rung compiled below
@@ -948,6 +1024,8 @@ class DecodeEngine:
                 # d(slot_ticks) / (d(ticks) * slots)
                 "ticks": self._ticks,
                 "slot_ticks": self._slot_ticks,
+                # steps dispatched while the one before was un-fetched
+                "steps_overlapped": self._steps_overlapped,
                 # page-table columns the ticks' attention walks ran, of
                 # the tables' columns x slots x layers (the share that ran)
                 "kv_cols_live": self._kv_cols_live,
@@ -1097,12 +1175,16 @@ class DecodeEngine:
     def _worker(self):
         while True:
             with self._cv:
+                # (a step in flight is retired by the next pass: never
+                # waited on, never left behind at the exit)
                 while not self._wfq.total_queued() \
                         and not self._any_active() and not self._closed \
-                        and not self._pending_swaps:
+                        and not self._pending_swaps \
+                        and self._inflight is None:
                     self._cv.wait()
                 if self._closed and not self._wfq.total_queued() \
-                        and not self._any_active():
+                        and not self._any_active() \
+                        and self._inflight is None:
                     swaps, self._pending_swaps = self._pending_swaps, []
                     break
             with telemetry.span("decode.tick", _SPAN_CAT) as tick:
@@ -1116,7 +1198,10 @@ class DecodeEngine:
 
     def _tick(self, tick):
         """One pass of the worker: housekeeping, admission, at most one
-        prefill chunk, one decode step. ``tick`` is the pass's span."""
+        prefill chunk, then the decode step — dispatched BEFORE the step
+        of the pass before is fetched and committed, so that fetch, that
+        commit and the next pass's housekeeping and admission run while
+        the device computes. ``tick`` is the pass's span."""
         with telemetry.span("decode.housekeep", _SPAN_CAT):
             self._apply_pending_swaps()
             self._expire_queued()
@@ -1125,22 +1210,27 @@ class DecodeEngine:
             with self._cv:
                 has_work = bool(self._wfq.total_queued()) \
                     or self._any_active()
+        try:
             if not has_work:
+                self._step_pass(())     # (a step whose rows all left)
                 return
             if not self._breaker.allow():
                 # open ENGINE breaker: answer all queued work explicitly
                 # (the PR-2 engine load-shed) instead of letting it age
                 # out; the reset timeout admits a half-open probe later
+                self._step_pass(())
                 self._shed_open_breaker()
                 time.sleep(0.005)
                 return
-        try:
             with telemetry.span("decode.admit", _SPAN_CAT):
                 self._admit()
             prefilling = [(i, r) for i, r in enumerate(self._slots)
                           if r is not None and r.prefilling]
+            # the slots the step decodes: all but a sequence whose LAST
+            # token (by its budget) is the one in flight
             decoding = [(i, r) for i, r in enumerate(self._slots)
-                        if r is not None and not r.prefilling]
+                        if r is not None and not r.prefilling
+                        and len(r.tokens) + self._ahead(r) < r.max_new]
             with self._cv:
                 queued = self._wfq.total_queued()
             tick.set_args(active=len(decoding), prefilling=len(prefilling),
@@ -1160,8 +1250,8 @@ class DecodeEngine:
                 with telemetry.span("decode.prefill", _SPAN_CAT,
                                     chunk=self._chunk):
                     self._advance_prefill(slot, req)
-            if decoding:
-                self._step_once(decoding)
+            if decoding or self._inflight is not None:
+                self._step_pass(decoding)
             elif not prefilling:
                 # every queued tenant deferred (pages/rate/breaker)
                 # with nothing in flight: yield instead of spinning
@@ -1722,16 +1812,48 @@ class DecodeEngine:
         return jnp.argmax(last).astype(jnp.int32), kp, vp
 
     # -- the decode tick ------------------------------------------------
-    def _step_once(self, active):
-        """One decode step for the ``active`` (slot, request) pairs: the
-        packed operand, the dispatch, the wait for the sampled tokens and
-        their commit, each under its span."""
+    def _ahead(self, req: _DecodeRequest) -> int:
+        """1 while a token of ``req`` is in flight (sampled on the device,
+        not fetched): with no draft in play a decoding slot commits exactly
+        one token a step, so its next position is host arithmetic."""
+        rec = self._inflight
+        return 1 if rec is not None and req in rec.reqs else 0
+
+    def _step_pass(self, active):
+        """The decode half of a worker pass: dispatch the step for the
+        ``active`` (slot, request) pairs, THEN fetch and commit the step
+        the pass before left in flight — at most one step un-fetched. With
+        a draft in play the tokens a slot commits are only known after the
+        fetch, so the step just dispatched is retired at once: today's
+        order, through the same two halves."""
+        prev = self._inflight
+        # (a prefill that failed this pass may have evicted them all)
+        active = [(i, r) for i, r in active if self._slots[i] is r]
+        if active:
+            self._inflight = self._dispatch_step(active)
+            if self._inflight is None:
+                return      # failed: everything in flight was evicted
+        else:
+            self._inflight = None
+        if prev is not None:
+            self._retire_step(prev)     # (a failure clears _inflight)
+        if self._draft is not None and self._inflight is not None:
+            rec, self._inflight = self._inflight, None
+            self._retire_step(rec)
+
+    def _dispatch_step(self, active) -> Optional[_StepInFlight]:
+        """Pack and launch one decode step; its device->host copy starts at
+        once. Rows whose last token is still on the device read it from the
+        un-fetched step's output there. Returns the step in flight, or
+        None after a failure (which evicted every sequence)."""
         from .. import resilience
 
         jnp = self._jnp
+        prev = self._inflight
         with telemetry.span("decode.pack", _SPAN_CAT):
-            packed, drafts, pages_before = self._pack_step(active)
+            packed, drafts = self._pack_step(active)
         policy = self._retry or resilience.default_policy()
+        fed = self._no_prev if prev is None else prev.out
 
         def attempt():
             chaos.maybe_fail("serving.decode")
@@ -1741,74 +1863,97 @@ class DecodeEngine:
                     "eviction required")
             return telemetry.jit_call(
                 "serving.decode_step", self._step, self._params,
-                jnp.asarray(packed), self._cache.k_pool,
+                jnp.asarray(packed), fed, self._cache.k_pool,
                 self._cache.v_pool, self._device_page_table())
 
         try:
-            with telemetry.span("decode.dispatch", _SPAN_CAT):
-                sampled, kp, vp = policy.call(attempt,
-                                              site="serving.decode")
-            with telemetry.span("decode.fetch", _SPAN_CAT):
+            with telemetry.span("decode.dispatch", _SPAN_CAT,
+                                overlapped=int(prev is not None)):
+                out, kp, vp = policy.call(attempt, site="serving.decode")
                 self._cache.swap_pools(kp, vp)
+                out.copy_to_host_async()
+        except Exception as exc:  # noqa: BLE001 - evict, don't die
+            self._on_oom("serving.decode", exc)
+            self._step_failed(exc)
+            return None
+        if prev is not None:
+            # stats() reads it from caller threads: tpulint's
+            # shared-state-race wants the writer under the same lock
+            with self._cv:
+                self._steps_overlapped += 1
+            _T_OVERLAPPED.inc(server=self._name)
+        return _StepInFlight(out, active, drafts, packed[2])
+
+    def _retire_step(self, rec: _StepInFlight):
+        """Fetch a dispatched step's tokens and commit them, each under its
+        span. A row whose request has left its slot since the dispatch
+        (finished on the token before, evicted, timed out) is dropped."""
+        try:
+            with telemetry.span("decode.fetch", _SPAN_CAT):
                 # the one per-token device->host sync of the plane: the
                 # sampled token ids must reach the host for EOS/stop
                 # checks and feedback. Inside the try: a wedged transfer
                 # evicts the tick like a failed step instead of killing
                 # the worker.
-                toks = fetch_host([sampled])[0]
+                toks = fetch_host([rec.out])[0]
                 counters = None
                 if self._moe_rows is not None:
                     n_rows = self.num_slots * self._spec_w
                     toks, counters = toks[:n_rows], toks[n_rows:]
         except Exception as exc:  # noqa: BLE001 - evict, don't die
-            # OOM first: a classified RESOURCE_EXHAUSTED (or injected
-            # action=oom) additionally latches the governor red and arms
-            # governed re-admission before the same full-eviction path
-            # below reclaims every page
             self._on_oom("serving.decode", exc)
-            self._breaker.on_failure()
-            # the pool re-zero kills EVERY in-flight sequence's KV —
-            # chunked-prefilling slots included, not just this tick's
-            self._evict([(i, r) for i, r in enumerate(self._slots)
-                         if r is not None], exc)
+            self._step_failed(exc)
             return
         with telemetry.span("decode.commit", _SPAN_CAT) as span:
-            args = self._walk_args(packed[2])
+            args = self._walk_args(rec.lens)
             if self._grouped or counters is not None:
-                # before the commit moves the lengths and frees slots
                 args.update(self._layer_args(
-                    counters, [int(self._cache.seq_lens[slot]) + 1
-                               for slot, _req in active]))
+                    counters, [int(rec.lens[slot * self._spec_w])
+                               for slot, _req in rec.active]))
             span.set_args(**args)
-            self._commit_step(active, toks, drafts, pages_before)
+            self._commit_step(rec.active, toks, rec.drafts)
+
+    def _step_failed(self, exc: BaseException):
+        """A dispatch or a fetch failed after retries (the caller ran
+        ``_on_oom`` first: a classified RESOURCE_EXHAUSTED or injected
+        action=oom additionally latches the governor red and arms governed
+        re-admission before the full eviction reclaims every page). The
+        pool re-zero kills EVERY in-flight sequence's KV — chunked-
+        prefilling slots included, not just this step's — and a step still
+        in flight is forgotten with them."""
+        self._breaker.on_failure()
+        self._evict([(i, r) for i, r in enumerate(self._slots)
+                     if r is not None], exc)
 
     def _pack_step(self, active):
-        """The step's packed operand, the drafts by slot and (under the
-        KV audit) the pages in use before the step."""
+        """The step's packed operand and the drafts by slot."""
         s = self.num_slots
         w = self._spec_w
         ps = self._cache.page_size
-        # rows: tokens, positions, seq_lens, write pages, write offsets —
-        # ONE packed put per tick, W = spec_k+1 query rows per slot (slot
-        # s owns rows s*W .. s*W+W-1: row 0 the committed token, rows
-        # 1..k its draft guesses at the next positions). W is static —
-        # draft depth, acceptance and per-tenant caps vary only the data,
-        # so speculation can never retrace the step. Inactive slots and
-        # unused draft rows keep seq_len 0 and the null write page (row 3
-        # stays 0); their offsets cycle the page so scatter indices stay
-        # in range.
-        packed = np.zeros((5 + self._extra_rows, s * w), np.int32)
+        # rows: tokens, positions, seq_lens, write pages, write offsets
+        # (a second group's write pages), from_prev — ONE packed put per
+        # tick, W = spec_k+1 query rows per slot (slot s owns rows s*W ..
+        # s*W+W-1: row 0 the committed token, rows 1..k its draft guesses
+        # at the next positions). W is static — draft depth, acceptance
+        # and per-tenant caps vary only the data, so speculation can never
+        # retrace the step. Inactive slots and unused draft rows keep
+        # seq_len 0 and the null write page (row 3 stays 0); their offsets
+        # cycle the page so scatter indices stay in range.
+        packed = np.zeros((self._packed_rows, s * w), np.int32)
         packed[4] = np.arange(s * w) % ps
         drafts: dict = {}
-        pages_before = self._cache.pages_in_use if self._cache.audit else 0
         for slot, req in active:
-            pos = int(req.prompt.size) + len(req.tokens) - 1
+            # a token in flight is one position the host has not seen:
+            # the row reads it on the device (from_prev), one place on
+            ahead = self._ahead(req)
+            pos = int(req.prompt.size) + len(req.tokens) - 1 + ahead
             base = slot * w
             draft = (self._propose(req, slot, pos)
                      if self._draft is not None else ())
             drafts[slot] = draft
-            row_toks = [req.tokens[-1]]
+            row_toks = [0 if ahead else req.tokens[-1]]
             row_toks.extend(int(t) for t in draft)
+            packed[-1, base] = ahead
             for j, row_tok in enumerate(row_toks):
                 # row j carries the token at absolute position pos+j and
                 # attends up to itself (per-row seq_len) — rows below it
@@ -1836,13 +1981,16 @@ class DecodeEngine:
                 reqs=[[req.rid, req.tenant.tenant_id,
                        "prefill" if req.prefilling else "decode"]
                       for req in self._slots if req is not None])
-        return packed, drafts, pages_before
+        return packed, drafts
 
-    def _commit_step(self, active, toks, drafts, pages_before):
+    def _commit_step(self, active, toks, drafts):
         """Accept the step's tokens slot by slot, complete what finished,
         and book the tick."""
         s = self.num_slots
         w = self._spec_w
+        # (under the KV audit: admission may have taken pages since the
+        # dispatch, the commit itself may not)
+        pages_before = self._cache.pages_in_use if self._cache.audit else 0
         self._breaker.on_success()
         now = time.perf_counter()
         tpots = []
@@ -1853,6 +2001,12 @@ class DecodeEngine:
         tick_proposed = 0
         tick_accepted = 0
         for slot, req in active:
+            if self._slots[slot] is not req or req.future.done():
+                # gone since the dispatch (its token before this one was
+                # EOS, or it was evicted, timed out, closed): the row's
+                # token is dropped; its K/V write went to a page that was
+                # the request's own, before any later program's
+                continue
             base = slot * w
             draft = drafts.get(slot, ())
             k_eff = len(draft)
@@ -2101,6 +2255,9 @@ class DecodeEngine:
         TICK-level fault: it feeds the engine breaker (the caller), not
         the tenants' — the victims were bystanders of an engine failure,
         not misbehaving traffic."""
+        # a step still un-fetched computed on the pools that go below: its
+        # rows' requests fail here, its tokens are nobody's
+        self._inflight = None
         _flightrec.record(
             "decode.evict", server=self._name, error=repr(exc),
             reqs=[[req.rid, req.tenant.tenant_id]

@@ -209,9 +209,10 @@ def test_one_put_and_one_fetch_a_steady_tick(tiny, monkeypatch):
         stats = eng.stats()
     assert out.size == 16 and stats["ticks"] == 15
     assert fetches == [1] * 16          # one prefill, fifteen ticks
-    # a tick puts its (6, slots) operand and nothing else: the tables were
-    # put once each after the admission, the prefill put two operands
-    assert puts.count((6, 2)) == 15
+    # a tick puts its (7, slots) operand (`from_prev` last) and nothing
+    # else: the tables were put once each after the admission, the prefill
+    # put two operands
+    assert puts.count((7, 2)) == 15
     assert len(puts) == 15 + 2 + 2
 
 
@@ -309,7 +310,9 @@ def test_a_grouped_model_is_refused_the_prefix_cache_chunks_and_drafts(tiny):
 
 def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
     """``mx.decode.commit`` and ``mx.decode.prefill`` carry what the
-    benchmark's per-layer readers read, on the trace's own clock."""
+    benchmark's per-layer readers read, on the trace's own clock — on the
+    overlapped path too: a step's commit runs a pass after its dispatch
+    and still describes that step."""
     keys = {"moe_rows_held", "moe_experts_hit", "moe_load_max",
             "kv_rows_full", "kv_rows_window", "kv_window_pages",
             "kv_window_capacity"}
@@ -325,16 +328,26 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
             eng.close()
         finally:
             jax.profiler.stop_trace()
+        stats = eng.stats()
     (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
                                      "*", "*.xplane.pb"))
     events = [(ev.name, dict(ev.stats))
               for plane in jax.profiler.ProfileData.from_file(path).planes
               if plane.name.startswith("/host:")
               for line in plane.lines for ev in line.events
-              if ev.name in ("mx.decode.commit", "mx.decode.prefill")]
+              if ev.name.startswith("mx.decode.")]
     prefill = [a for n, a in events if n == "mx.decode.prefill"]
     commits = [a for n, a in events if n == "mx.decode.commit"]
     assert len(prefill) == 1 and len(commits) == 5
+    # the worker's other spans, by the names the benchmark reads them by:
+    # five steps, the first host-fed, four dispatched over an un-fetched one
+    for name in ("mx.decode.pack", "mx.decode.dispatch", "mx.decode.fetch"):
+        assert sum(n == name for n, _a in events) == 5, name
+    assert [a for n, a in events if n == "mx.decode.dispatch"] \
+        == [{"overlapped": 0}] + [{"overlapped": 1}] * 4
+    assert stats["steps_overlapped"] == 4 and stats["ticks"] == 5
+    assert all(set(a) == {"active", "prefilling", "queued"}
+               for n, a in events if n == "mx.decode.tick" and a)
     assert set(prefill[0]) == keys | {"rung"}
     assert prefill[0]["kv_rows_full"] == 50
     assert prefill[0]["kv_rows_window"] == WINDOW

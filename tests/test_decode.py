@@ -1377,8 +1377,9 @@ def test_band_attention_kernel_matches_dense_oracle(window, h, kh):
 
 def test_a_model_without_kv_groups_keeps_its_one_group_and_its_operands(tiny):
     """TinyDecoder declares neither ``kv_groups`` nor ``moe_counters``: one
-    PagedKVCache, a (5, S) step operand, a (3, rung) prefill operand, no
-    counters behind the tokens, no new key in its stats."""
+    PagedKVCache, a (6, S) step operand (five rows and ``from_prev``), a
+    (3, rung) prefill operand, no counters behind the tokens, no new key in
+    its stats."""
     from mxnet_tpu.serving.kvcache import PagedKVCache
 
     with _engine(tiny, prefix_cache=False) as eng:
@@ -1401,5 +1402,5 @@ def test_a_model_without_kv_groups_keeps_its_one_group_and_its_operands(tiny):
         out = eng.generate(np.arange(1, 7, dtype=np.int32), 5, timeout=120)
         stats = eng.stats()
     assert out.size == 5
-    assert (3, 8) in shapes and shapes.count((5, 3)) == 4
+    assert (3, 8) in shapes and shapes.count((6, 3)) == 4
     assert "moe" not in stats and "window" not in stats["kvcache"]

@@ -322,11 +322,30 @@ def test_decode_tick_spans_carry_the_slots_in_use(tiny, tmp_path):
             continue
         assert set(t[3]) == {"active", "prefilling", "queued"}
         assert 0 <= t[3]["active"] <= eng.num_slots
+        # a pass dispatches its step (pack, dispatch), THEN fetches and
+        # commits the step the pass before left in flight
         step = ["mx.decode.pack", "mx.decode.dispatch", "mx.decode.fetch",
                 "mx.decode.commit"]
-        assert [n for n in names if n in step] == \
-            (step if t[3]["active"] else [])
+        ran = [n for n in names if n in step]
+        assert ran in (step, step[:2], step[2:], []), ran
+        assert (ran[:2] == step[:2]) == bool(t[3]["active"])
         assert "mx.decode.admit" in names
+    # every step is dispatched once, fetched once and committed once; most
+    # are dispatched with the step before them un-fetched, and say so
+    dispatches = _named(events, "mx.decode.dispatch")
+    assert all(set(d[3]) == {"overlapped"} for d in dispatches)
+    assert len(dispatches) == stats["ticks"] \
+        == len(_named(events, "mx.decode.fetch")) \
+        == len(_named(events, "mx.decode.commit"))
+    assert sum(d[3]["overlapped"] for d in dispatches) \
+        == stats["steps_overlapped"] > 0
+    # ... and an overlapped dispatch ENDS before the fetch of its pass (of
+    # the step before) starts
+    for t in ticks:
+        kids = {e[0]: e for e in events if e is not t and _inside(e, t)}
+        d, f = kids.get("mx.decode.dispatch"), kids.get("mx.decode.fetch")
+        if d is not None and f is not None:
+            assert d[3]["overlapped"] == 1 and d[2] <= f[1]
     # every span of the worker lies inside one of its passes
     for e in events:
         if e[0].startswith("mx.decode.") and e[0] != "mx.decode.tick":

@@ -422,17 +422,17 @@ def test_deadline_expiring_mid_decode_evicts_at_tick_boundary(tiny):
         # pin the race: 5ms/tick makes a 100-token generation take
         # >= 500ms, so a 100ms deadline MUST expire mid-decode (warm
         # prefill admits in a few ms — far inside the deadline)
-        orig_step = eng._step_once
+        orig_step = eng._dispatch_step
 
         def slow_step(active):
             time.sleep(0.005)
             return orig_step(active)
 
-        eng._step_once = slow_step
+        eng._dispatch_step = slow_step
         fut = eng.submit([1, 2], 100, timeout_ms=100)
         with pytest.raises(serving.RequestTimeoutError, match="mid-decode"):
             fut.result(timeout=120)
-        eng._step_once = orig_step
+        eng._dispatch_step = orig_step
         stats = eng.stats()
         assert stats["deadline_evictions"] == 1  # evicted, not queue-aged
         assert stats["kvcache"]["pages_in_use"] == 0  # pages freed
