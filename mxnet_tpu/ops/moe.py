@@ -21,9 +21,11 @@ Two pure functions, traced inside a jitted step with static shapes:
 Device names a trace can be searched for: the grouped product and the shared
 expert run the Pallas kernel :func:`grouped_matmul` under the names
 ``mx_moe_gmm`` and ``mx_moe_shared`` (the custom call ``%mx_moe_gmm.<n>``);
-the router's XLA operations sit under ``jax.named_scope("mx_moe_route")``
-(in the compiled program's ``op_name`` metadata; XLA names its fusions
-itself).
+every operation of the layer sits under one of four ``jax.named_scope``s,
+parts of the program (``telemetry.PROGRAM_PARTS``): ``mx_moe_route`` (scores,
+top-k, the sort into groups), ``mx_moe_experts``, ``mx_moe_shared`` and
+``mx_moe_combine`` — in the compiled program's ``op_name`` metadata, from
+which ``telemetry.program_parts`` maps XLA's own instruction names.
 """
 from __future__ import annotations
 
@@ -253,26 +255,31 @@ def expert_layer(h, route, experts, held, shared=None, valid=None):
     first, count = held
     t, top_k = sel.shape
     n_rows = t * top_k
-    local = sel.reshape(n_rows) - first
-    here = jnp.logical_and(local >= 0, local < count)
-    # sort key: a held expert's own index, then the absent picks, then the
-    # rows that are no token at all
-    key = jnp.where(here, local, count)
-    if valid is not None:
-        key = jnp.where(jnp.repeat(valid, top_k), key, count + 1)
-    rows = jnp.zeros((count + 2,), jnp.int32).at[key].add(1)[:count + 1]
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    group_sizes = rows[:count]
-    x = h[order // top_k]
-    y = _swiglu(x, experts, group_sizes, "mx_moe_gmm")
-    # back to (token, pick) order; rows past the held groups are zero
-    back = jnp.zeros((n_rows,), jnp.int32).at[order].set(
-        jnp.arange(n_rows, dtype=jnp.int32))
-    y = y[back].reshape(t, top_k, -1)
-    out = jnp.einsum("tk,tke->te", weights.astype(jnp.float32), y)
+    with jax.named_scope("mx_moe_route"):
+        local = sel.reshape(n_rows) - first
+        here = jnp.logical_and(local >= 0, local < count)
+        # sort key: a held expert's own index, then the absent picks, then
+        # the rows that are no token at all
+        key = jnp.where(here, local, count)
+        if valid is not None:
+            key = jnp.where(jnp.repeat(valid, top_k), key, count + 1)
+        rows = jnp.zeros((count + 2,), jnp.int32).at[key].add(1)[:count + 1]
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        group_sizes = rows[:count]
+        x = h[order // top_k]
+    with jax.named_scope("mx_moe_experts"):
+        y = _swiglu(x, experts, group_sizes, "mx_moe_gmm")
+    with jax.named_scope("mx_moe_combine"):
+        # back to (token, pick) order; rows past the held groups are zero
+        back = jnp.zeros((n_rows,), jnp.int32).at[order].set(
+            jnp.arange(n_rows, dtype=jnp.int32))
+        y = y[back].reshape(t, top_k, -1)
+        out = jnp.einsum("tk,tke->te", weights.astype(jnp.float32), y)
     if shared is not None:
-        whole = jnp.asarray([t], jnp.int32)
-        out = out + _swiglu(
-            h, {k: v[None] for k, v in shared.items()}, whole,
-            "mx_moe_shared")
+        with jax.named_scope("mx_moe_shared"):
+            whole = jnp.asarray([t], jnp.int32)
+            alike = _swiglu(h, {k: v[None] for k, v in shared.items()},
+                            whole, "mx_moe_shared")
+        with jax.named_scope("mx_moe_combine"):
+            out = out + alike
     return out, rows

@@ -178,14 +178,16 @@ class AfmoeDecoder(PagedDecodeModel):
         return q, k, v
 
     def _mlp(self, layer, hx, valid):
-        """``(mlp(hx), rows or None)``: dense SwiGLU or the expert layer."""
+        """``(mlp(hx), rows or None)``: dense SwiGLU or the expert layer
+        (whose parts :mod:`mxnet_tpu.ops.moe` names itself)."""
         import jax
 
         from ..ops import moe
 
         if "router" not in layer:
-            return _mm(jax.nn.silu(_mm(hx, layer["w1"]))
-                       * _mm(hx, layer["w3"]), layer["w2"]), None
+            with jax.named_scope("mx_mlp"):
+                return _mm(jax.nn.silu(_mm(hx, layer["w1"]))
+                           * _mm(hx, layer["w3"]), layer["w2"]), None
         cfg = self.cfg
         picks = moe.route(hx, layer["router"], layer["expert_bias"],
                           cfg["num_experts_per_tok"], cfg["route_norm"],
@@ -196,37 +198,53 @@ class AfmoeDecoder(PagedDecodeModel):
 
     def _forward(self, params, tokens, positions, k_pool, v_pool,
                  write_pages, write_offsets, valid, attend):
-        """The layers over ``tokens`` rows; ``attend(li, sliding, q, k, v,
+        """The layers over ``tokens`` rows, each piece under its part of the
+        program (``telemetry.PROGRAM_PARTS``); ``attend(sliding, q, k, v,
         pools)`` is the one thing prefill and decode do differently."""
         import jax
         import jax.numpy as jnp
 
+        part = jax.named_scope
         k_pool, v_pool = list(k_pool), list(v_pool)
-        x = self._embed(params, tokens)
+        with part("mx_embed"):
+            x = self._embed(params, tokens)
         rows = []
         for li, layer in enumerate(params["layers"]):
             sliding = self.cfg["layer_types"][li] == "sliding_attention"
             grp, gi = self._place[li]
-            hx = self._rms(x, layer["ln_in"])
-            q, k, v = self._qkv(layer, hx, positions, sliding)
-            k_pool[grp], v_pool[grp] = write_kv(
-                k_pool[grp], v_pool[grp], gi, k, v, write_pages[grp],
-                write_offsets)
-            att = attend(sliding, q, k, v, k_pool[grp][gi], v_pool[grp][gi])
-            att = att.reshape(att.shape[0], -1) \
-                * jax.nn.sigmoid(_mm(hx, layer["wg"]))
-            x = x + self._rms(_mm(att, layer["wo"]), layer["ln_post_attn"])
-            m, n_rows = self._mlp(layer, self._rms(x, layer["ln_pre_mlp"]),
-                                  valid)
+            with part("mx_qkv"):
+                hx = self._rms(x, layer["ln_in"])
+                q, k, v = self._qkv(layer, hx, positions, sliding)
+            with part("mx_kv_write"):
+                k_pool[grp], v_pool[grp] = write_kv(
+                    k_pool[grp], v_pool[grp], gi, k, v, write_pages[grp],
+                    write_offsets)
+            with part("mx_attn"):
+                att = attend(sliding, q, k, v, k_pool[grp][gi],
+                             v_pool[grp][gi])
+            with part("mx_attn_out"):
+                att = att.reshape(att.shape[0], -1) \
+                    * jax.nn.sigmoid(_mm(hx, layer["wg"]))
+                x = x + self._rms(_mm(att, layer["wo"]),
+                                  layer["ln_post_attn"])
+            # an expert layer's norm in front goes with its router, the
+            # norm behind and the residual with its combine
+            dense = "router" not in layer
+            with part("mx_mlp" if dense else "mx_moe_route"):
+                hm = self._rms(x, layer["ln_pre_mlp"])
+            m, n_rows = self._mlp(layer, hm, valid)
             if n_rows is not None:
                 rows.append(n_rows)
-            x = x + self._rms(m, layer["ln_post_mlp"])
-        counters = (jnp.stack(rows),) if rows else ()
+            with part("mx_mlp" if dense else "mx_moe_combine"):
+                x = x + self._rms(m, layer["ln_post_mlp"])
+        with part("mx_head"):
+            counters = (jnp.stack(rows),) if rows else ()
         return x, tuple(k_pool), tuple(v_pool), counters
 
     # -- contract -------------------------------------------------------
     def prefill(self, params, tokens, length, k_pool, v_pool, write_pages,
                 write_offsets, attn=None):
+        import jax
         import jax.numpy as jnp
 
         from ..ops import pallas_kernels
@@ -234,7 +252,9 @@ class AfmoeDecoder(PagedDecodeModel):
         if attn is not None:
             raise MXNetError("AfmoeDecoder has no ring-attention prefill")
         t = tokens.shape[0]
-        positions = jnp.arange(t, dtype=jnp.int32)
+        with jax.named_scope("mx_embed"):
+            positions = jnp.arange(t, dtype=jnp.int32)
+            valid = positions < length
         window = self.cfg["sliding_window"]
 
         def attend(sliding, q, k, v, _kp, _vp):
@@ -244,9 +264,10 @@ class AfmoeDecoder(PagedDecodeModel):
 
         x, k_pool, v_pool, counters = self._forward(
             params, tokens, positions, k_pool, v_pool, write_pages,
-            write_offsets, positions < length, attend)
-        last = _mm(self._rms(x[length - 1], params["ln_f"])[None],
-                   params["head"])[0]
+            write_offsets, valid, attend)
+        with jax.named_scope("mx_head"):
+            last = _mm(self._rms(x[length - 1], params["ln_f"])[None],
+                       params["head"])[0]
         return (last, k_pool, v_pool) + counters
 
     def prefill_chunk(self, params, tokens, start, length, k_pool, v_pool,
@@ -257,6 +278,8 @@ class AfmoeDecoder(PagedDecodeModel):
 
     def decode(self, params, tokens, positions, k_pool, v_pool, page_tables,
                seq_lens, write_pages, write_offsets):
+        import jax
+
         from ..ops import pallas_kernels
 
         if tokens.shape[0] != page_tables[0].shape[0]:
@@ -273,8 +296,11 @@ class AfmoeDecoder(PagedDecodeModel):
                 q, kp, vp, page_tables[0], seq_lens, scale=self.scale,
                 precise=True)
 
+        with jax.named_scope("mx_embed"):
+            valid = seq_lens > 0
         x, k_pool, v_pool, counters = self._forward(
             params, tokens, positions, k_pool, v_pool, write_pages,
-            write_offsets, seq_lens > 0, attend)
-        logits = _mm(self._rms(x, params["ln_f"]), params["head"])
+            write_offsets, valid, attend)
+        with jax.named_scope("mx_head"):
+            logits = _mm(self._rms(x, params["ln_f"]), params["head"])
         return (logits, k_pool, v_pool) + counters

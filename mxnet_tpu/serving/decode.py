@@ -98,7 +98,10 @@ and recompiles nothing (same pytree signature = same jit signature).
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import itertools
+import json
 import threading
 import time
 from concurrent.futures import Future
@@ -136,6 +139,10 @@ _DEFAULT_PREFILL_CHUNK = 0  # 0 = monolithic prefill (one rung per prompt)
 #: category of the worker's ``telemetry.span`` regions (``mx.decode.*`` in
 #: a ``jax.profiler`` trace; docs/observability.md lists them)
 _SPAN_CAT = "serving"
+#: an empty engine waits for work in slices of this many seconds, each under
+#: its own ``mx.decode.idle`` span: a trace that starts inside the wait
+#: loses at most one slice of it
+_IDLE_SLICE_S = 0.05
 
 _T_TOKENS = telemetry.counter(
     "mxnet_decode_tokens_total",
@@ -167,6 +174,12 @@ _T_OVERLAPPED = telemetry.counter(
     "mxnet_decode_steps_overlapped_total",
     "decode steps dispatched while the step before them was still "
     "un-fetched (the host's work for that step ran behind the device)",
+    labels=("server",))
+_T_PREFILL_HELD = telemetry.counter(
+    "mxnet_decode_prefill_held_slot_ms_total",
+    "milliseconds decoding slots waited behind somebody else's prefill: "
+    "each prefill's duration times the slots that were decoding when it "
+    "was launched (over the tokens decoded: what a token loses to prefills)",
     labels=("server",))
 _T_STEP_TEMP = telemetry.gauge(
     "mxnet_decode_step_temp_bytes",
@@ -527,6 +540,14 @@ class DecodeEngine:
         #: arrays a step is handed as the pools: one a layer for K and V
         self._kv_pool_leaves = len(pools)
         self._step_temp_bytes: Optional[int] = None  # set by warmup()
+        #: telemetry.program_parts of every model program warmup() compiles
+        #: (the step; each prefill and chunk rung, with its `rung`), each
+        #: read from the object compiled for the warm-up call, with
+        #: `seconds`: what the text and the pass over it cost
+        self._programs: List[dict] = []
+        #: worker-confined: the rows the live trace has (None: no trace)
+        self._programs_traced: Optional[List[dict]] = None
+        self._prefill_held_slot_ms = 0.0
         pool_bytes = int(sum(x.nbytes for x in pools))
         self._governor.register_bound("serving.%s.kv_pool" % name,
                                       pool_bytes)
@@ -561,6 +582,20 @@ class DecodeEngine:
         # one fetch
         grouped = self._grouped
 
+        def head():
+            """The scope of a program's last operations (``mx_head``: what
+            is not the model's own is under that part of the program,
+            ``telemetry.PROGRAM_PARTS``), marked with the layout of the
+            parts the program was traced with — what keeps a compile cache
+            that another layout filled from serving this one its names."""
+            from jax.experimental import xla_metadata
+
+            stack = contextlib.ExitStack()
+            stack.enter_context(jax.named_scope("mx_head"))
+            stack.enter_context(xla_metadata.set_xla_metadata(
+                mx_parts=telemetry.PROGRAM_PARTS_VERSION))
+            return stack
+
         def with_counters(sampled, out):
             """The model's counters (if it returns any) behind the sampled
             token(s): one array, one fetch."""
@@ -573,39 +608,43 @@ class DecodeEngine:
                            page_tables):
             # `prev`: the previous step's own output, whole (or zeros of
             # its shape before any step); its first S*W values are tokens
-            from_prev = packed[-1]
-            if grouped:
-                tokens, positions, seq_lens, full_pages, write_offsets, \
-                    window_pages = packed[:-1]
-                write_pages = (full_pages, window_pages)
-            else:
-                tokens, positions, seq_lens, write_pages, write_offsets = \
-                    packed[:-1]
-            tokens = jnp.where(from_prev != 0, prev[:tokens.shape[0]],
-                               tokens)
+            with jax.named_scope("mx_head"):
+                from_prev = packed[-1]
+                if grouped:
+                    tokens, positions, seq_lens, full_pages, \
+                        write_offsets, window_pages = packed[:-1]
+                    write_pages = (full_pages, window_pages)
+                else:
+                    tokens, positions, seq_lens, write_pages, \
+                        write_offsets = packed[:-1]
+                tokens = jnp.where(from_prev != 0, prev[:tokens.shape[0]],
+                                   tokens)
             out = model.decode(
                 params, tokens, positions, k_pool, v_pool, page_tables,
                 seq_lens, write_pages, write_offsets)
             logits, k_pool, v_pool = out[:3]
-            sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return with_counters(sampled, out), k_pool, v_pool
+            with head():
+                sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return with_counters(sampled, out), k_pool, v_pool
 
         # same packing for prefill: tokens + write pages + offsets share
         # the rung shape, so they travel as one (3, rung) array
         # (one jit, one program a rung: the scope names the rung's ops)
         def mx_prefill(params, packed, length, k_pool, v_pool):
-            if grouped:
-                tokens, full_pages, write_offsets, window_pages = packed
-                write_pages = (full_pages, window_pages)
-            else:
-                tokens, write_pages, write_offsets = packed
+            with jax.named_scope("mx_head"):
+                if grouped:
+                    tokens, full_pages, write_offsets, window_pages = packed
+                    write_pages = (full_pages, window_pages)
+                else:
+                    tokens, write_pages, write_offsets = packed
             with jax.named_scope("mx_prefill_%d" % tokens.shape[0]):
                 out = model.prefill(
                     params, tokens, length, k_pool, v_pool, write_pages,
                     write_offsets)
             last, k_pool, v_pool = out[:3]
-            first = jnp.argmax(last).astype(jnp.int32)
-            return with_counters(first, out), k_pool, v_pool
+            with head():
+                first = jnp.argmax(last).astype(jnp.int32)
+                return with_counters(first, out), k_pool, v_pool
 
         # one prefill CHUNK: same (3, rung) packing plus the absolute
         # start position and the slot's page-table row — the chunk
@@ -618,7 +657,8 @@ class DecodeEngine:
             last, k_pool, v_pool = model.prefill_chunk(
                 params, tokens, start, length, k_pool, v_pool, page_row,
                 write_pages, write_offsets)
-            return jnp.argmax(last).astype(jnp.int32), k_pool, v_pool
+            with head():
+                return jnp.argmax(last).astype(jnp.int32), k_pool, v_pool
 
         # the copy-on-write copy: duplicate one page's K/V (all layers)
         # into a fresh page so a sequence diverging inside a shared page
@@ -935,14 +975,25 @@ class DecodeEngine:
                     self._cache.k_pool, self._cache.v_pool,
                     self._device_page_table())
 
-        # compiled here, found again by the dispatch below (one executable
-        # for operands of one type): the gauge costs no second compile
-        mem = self._step.lower(*step_args()).compile().memory_analysis()
-        if mem is not None:
-            self._step_temp_bytes = int(mem.temp_size_in_bytes)
-            _T_STEP_TEMP.set(self._step_temp_bytes, server=self._name)
+        # every model program is compiled ahead of its warm-up call, which
+        # finds that lowering and that executable again (the jit holds one
+        # of each for operands of one type: nothing is lowered or compiled
+        # twice, with a persistent cache or without); what the compiled
+        # object has to say is read behind the dispatch
+        programs = []
+
+        def mapped(compiled, **row):
+            """The map of a compiled program's parts, with what reading it
+            cost (``seconds``: the text and one pass over it)."""
+            t0 = time.perf_counter()
+            parts = telemetry.program_parts(compiled.as_text())
+            programs.append(
+                dict(parts, seconds=time.perf_counter() - t0, **row))
+
         for _ in range(2):
-            sampled, kp, vp = self._step(*step_args())
+            args = step_args()
+            compiled = self._step.lower(*args).compile()
+            sampled, kp, vp = self._step(*args)
             self._cache.swap_pools(kp, vp)
             if _placed_alike(self._no_prev, sampled):
                 break
@@ -951,37 +1002,72 @@ class DecodeEngine:
             # second pass warms the call every later step makes
             self._no_prev = jax.device_put(
                 np.zeros(sampled.shape, sampled.dtype), sampled.sharding)
+        mem = compiled.memory_analysis()
+        if mem is not None:
+            self._step_temp_bytes = int(mem.temp_size_in_bytes)
+            _T_STEP_TEMP.set(self._step_temp_bytes, server=self._name)
+        mapped(compiled)
         if not self._chunk:
             # chunked mode never dispatches the monolithic rungs — every
             # prompt runs through the one chunk rung compiled below
             for rung in self._ladder:
                 pre = np.zeros((3 + self._extra_rows, rung), np.int32)
                 pre[2] = self._cache.null_write_slots(rung)[1]
-                _tok, kp, vp = self._prefill_jit(
-                    params, jnp.asarray(pre),
-                    jnp.asarray(1, jnp.int32), self._cache.k_pool,
-                    self._cache.v_pool)
+                args = (params, jnp.asarray(pre), jnp.asarray(1, jnp.int32),
+                        self._cache.k_pool, self._cache.v_pool)
+                compiled = self._prefill_jit.lower(*args).compile()
+                _tok, kp, vp = self._prefill_jit(*args)
                 self._cache.swap_pools(kp, vp)
+                mapped(compiled, rung=rung)
         null_row = np.zeros((self._cache.max_pages,), np.int32)
         for rung in self._chunk_rungs:
             pre = np.zeros((3, rung), np.int32)
             pre[1], pre[2] = self._cache.null_write_slots(rung)
-            _tok, kp, vp = self._chunk_jit(
-                params, jnp.asarray(pre), jnp.asarray(0, jnp.int32),
-                jnp.asarray(1, jnp.int32), jnp.asarray(null_row),
-                self._cache.k_pool, self._cache.v_pool)
+            args = (params, jnp.asarray(pre), jnp.asarray(0, jnp.int32),
+                    jnp.asarray(1, jnp.int32), jnp.asarray(null_row),
+                    self._cache.k_pool, self._cache.v_pool)
+            compiled = self._chunk_jit.lower(*args).compile()
+            _tok, kp, vp = self._chunk_jit(*args)
             self._cache.swap_pools(kp, vp)
+            mapped(compiled, rung=rung)
         if self._prefix_cache:
             # null -> null: harmless, and the CoW copy is compiled
             kp, vp = self._cow_jit(
                 self._cache.k_pool, self._cache.v_pool,
                 jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32))
             self._cache.swap_pools(kp, vp)
+        with self._cv:
+            self._programs = programs
         count = self.compile_count
         self._warm_compiles = count if count >= 0 else None
         if self._warm_compiles is not None:
             telemetry.set_steady_state_recompiles("serving." + self._name, 0)
         return count
+
+    def _trace_programs(self):
+        """One zero-length ``mx.decode.programs`` span a program a
+        ``jax.profiler`` trace, carrying the map from its instructions'
+        names to its parts (``{part: [instruction]}`` as one JSON string)
+        for whoever reads the device's events of that trace: written by the
+        worker's first look (a pass, or a slice of an empty wait) that finds
+        the trace live. Without a trace: one compare a look."""
+        rows = self._programs if telemetry.trace_live() else None
+        if rows is not None and rows is not self._programs_traced:
+            for row in rows:
+                by_part = {}
+                for inst, part in row["parts"].items():
+                    by_part.setdefault(part, []).append(inst)
+                args = {"program": row["program"], "mixed": row["mixed"],
+                        "unnamed": len(row["unnamed"]),
+                        "parts": json.dumps(by_part, sort_keys=True),
+                        "map_us": int(row["seconds"] * 1e6)}
+                if "rung" in row:
+                    args["rung"] = row["rung"]
+                elif self._step_temp_bytes is not None:
+                    args["step_temp_bytes"] = self._step_temp_bytes
+                with telemetry.span("decode.programs", _SPAN_CAT, **args):
+                    pass
+        self._programs_traced = rows
 
     @property
     def compile_count(self) -> int:
@@ -1032,6 +1118,17 @@ class DecodeEngine:
                 "kv_cols_grid": self._kv_cols_grid,
                 "kv_pool_leaves": self._kv_pool_leaves,
                 "decode_step_temp_bytes": self._step_temp_bytes,
+                # per program, per part, the instructions the compiled
+                # text puts there: a refactor that lost a scope shows here
+                "program_parts": {
+                    row["program"] + ("/%d" % row["rung"]
+                                      if "rung" in row else ""):
+                    dict(collections.Counter(row["parts"].values()),
+                         unnamed=len(row["unnamed"]), mixed=row["mixed"])
+                    for row in self._programs},
+                # decoding slots x the prefills they waited behind, counted
+                # while telemetry or a jax.profiler trace is on
+                "prefill_held_slot_ms": self._prefill_held_slot_ms,
                 "prefill_buckets": list(self._ladder),
                 "prefill_chunk": self._chunk,
                 "cow_copies": self._cow_copies,
@@ -1181,7 +1278,10 @@ class DecodeEngine:
                         and not self._any_active() and not self._closed \
                         and not self._pending_swaps \
                         and self._inflight is None:
-                    self._cv.wait()
+                    with telemetry.span("decode.idle", _SPAN_CAT,
+                                        why="empty"):
+                        self._cv.wait(_IDLE_SLICE_S)
+                    self._trace_programs()
                 if self._closed and not self._wfq.total_queued() \
                         and not self._any_active() \
                         and self._inflight is None:
@@ -1210,6 +1310,7 @@ class DecodeEngine:
             with self._cv:
                 has_work = bool(self._wfq.total_queued()) \
                     or self._any_active()
+        self._trace_programs()
         try:
             if not has_work:
                 self._step_pass(())     # (a step whose rows all left)
@@ -1220,17 +1321,14 @@ class DecodeEngine:
                 # out; the reset timeout admits a half-open probe later
                 self._step_pass(())
                 self._shed_open_breaker()
-                time.sleep(0.005)
+                with telemetry.span("decode.idle", _SPAN_CAT, why="breaker"):
+                    time.sleep(0.005)
                 return
             with telemetry.span("decode.admit", _SPAN_CAT):
                 self._admit()
             prefilling = [(i, r) for i, r in enumerate(self._slots)
                           if r is not None and r.prefilling]
-            # the slots the step decodes: all but a sequence whose LAST
-            # token (by its budget) is the one in flight
-            decoding = [(i, r) for i, r in enumerate(self._slots)
-                        if r is not None and not r.prefilling
-                        and len(r.tokens) + self._ahead(r) < r.max_new]
+            decoding = self._decoding()
             with self._cv:
                 queued = self._wfq.total_queued()
             tick.set_args(active=len(decoding), prefilling=len(prefilling),
@@ -1247,15 +1345,16 @@ class DecodeEngine:
                     (t for t in cands if t[1].seq > self._rr_last),
                     cands[0])
                 self._rr_last = req.seq
-                with telemetry.span("decode.prefill", _SPAN_CAT,
-                                    chunk=self._chunk):
+                with self._prefill_span(chunk=self._chunk):
                     self._advance_prefill(slot, req)
             if decoding or self._inflight is not None:
                 self._step_pass(decoding)
             elif not prefilling:
                 # every queued tenant deferred (pages/rate/breaker)
                 # with nothing in flight: yield instead of spinning
-                time.sleep(0.001)
+                with telemetry.span("decode.idle", _SPAN_CAT,
+                                    why="deferred"):
+                    time.sleep(0.001)
         except Exception as exc:  # noqa: BLE001 - engine must survive
             # belt-and-braces (the PR-2 batcher discipline): NO
             # exception may kill the engine thread — that would hang
@@ -1269,6 +1368,36 @@ class DecodeEngine:
             self._breaker.on_failure()
             self._evict([(i, r) for i, r in enumerate(self._slots)
                          if r is not None], exc)
+
+    def _decoding(self):
+        """The (slot, request) pairs the step decodes: all but a sequence
+        whose LAST token (by its budget) is the one in flight."""
+        return [(i, r) for i, r in enumerate(self._slots)
+                if r is not None and not r.prefilling
+                and len(r.tokens) + self._ahead(r) < r.max_new]
+
+    @contextlib.contextmanager
+    def _prefill_span(self, **args):
+        """``mx.decode.prefill`` around one prefill (a rung or a chunk).
+        While somebody reads it (a ``jax.profiler`` trace, the registry) it
+        carries ``held``, the slots that are decoding as it is launched —
+        the sequences whose next token waits for it — and its duration
+        times ``held`` is added to what decoding slots lost to prefills."""
+        if not (telemetry.trace_live() or telemetry.enabled()):
+            with telemetry.span("decode.prefill", _SPAN_CAT, **args) as span:
+                yield span
+            return
+        held = len(self._decoding())
+        t0 = time.perf_counter()
+        try:
+            with telemetry.span("decode.prefill", _SPAN_CAT, held=held,
+                                **args) as span:
+                yield span
+        finally:
+            lost_ms = held * (time.perf_counter() - t0) * 1e3
+            with self._cv:
+                self._prefill_held_slot_ms += lost_ms
+            _T_PREFILL_HELD.inc(lost_ms, server=self._name)
 
     def _apply_pending_swaps(self):
         """Tick-boundary weight swap: rebind ``self._params`` between
@@ -1586,7 +1715,7 @@ class DecodeEngine:
             _T_EVENTS.inc(server=self._name, event="admitted")
             return
         rung = select_bucket(p - req.filled, self._ladder)
-        with telemetry.span("decode.prefill", _SPAN_CAT, rung=rung) as span:
+        with self._prefill_span(rung=rung) as span:
             if matched == 0:
                 tok = self._run_full_prefill(req, slot, ring=ring)
             else:
@@ -2382,90 +2511,88 @@ class TinyDecoder(PagedDecodeModel):
 
         return jax.nn.relu(x @ layer["w1"]) @ layer["w2"]
 
+    def _forward(self, params, tokens, positions, k_pool, v_pool,
+                 write_pages, write_offsets, attend):
+        """The layers over ``tokens`` rows, each piece under its part of the
+        program (``telemetry.PROGRAM_PARTS``); ``attend(li, q, k, v, k_pool,
+        v_pool)`` is the one thing prefill, chunk and decode do
+        differently. Returns the logits of every row and the pools."""
+        import jax
+
+        part = jax.named_scope
+        n = tokens.shape[0]
+        h, kh, d = self.num_heads, self.num_kv_heads, self.head_dim
+        with part("mx_embed"):
+            x = params["embed"][tokens] + self._pe(positions)
+        for li, layer in enumerate(params["layers"]):
+            with part("mx_qkv"):
+                hx = self._norm(x, layer["ln1"])
+                q = (hx @ layer["wq"]).reshape(n, h, d)
+                k = (hx @ layer["wk"]).reshape(n, kh, d)
+                v = (hx @ layer["wv"]).reshape(n, kh, d)
+            # scatter FIRST so a chunk's positions read their own K/V back
+            # through the pages like every earlier chunk's (already-cached
+            # positions write to the null page — their KV is in the
+            # shared/CoW pages, that pass only recomputes activations)
+            with part("mx_kv_write"):
+                k_pool, v_pool = write_kv(k_pool, v_pool, li, k, v,
+                                          write_pages, write_offsets)
+            with part("mx_attn"):
+                att = attend(li, q, k, v, k_pool, v_pool)
+            with part("mx_attn_out"):
+                x = x + att.reshape(n, h * d) @ layer["wo"]
+            with part("mx_mlp"):
+                x = x + self._mlp(self._norm(x, layer["ln2"]), layer)
+        with part("mx_head"):
+            logits = self._norm(x, params["lnf"]) @ params["unembed"]
+        return logits, k_pool, v_pool
+
     # -- contract -------------------------------------------------------
     def prefill(self, params, tokens, length, k_pool, v_pool,
                 write_pages, write_offsets, attn=None):
         import jax.numpy as jnp
 
-        t = tokens.shape[0]
-        h, kh, d = self.num_heads, self.num_kv_heads, self.head_dim
-        positions = jnp.arange(t, dtype=jnp.int32)
-        x = params["embed"][tokens] + self._pe(positions)
-        for li, layer in enumerate(params["layers"]):
-            hx = self._norm(x, layer["ln1"])
-            q = (hx @ layer["wq"]).reshape(t, h, d)
-            k = (hx @ layer["wk"]).reshape(t, kh, d)
-            v = (hx @ layer["wv"]).reshape(t, kh, d)
-            k_pool, v_pool = write_kv(k_pool, v_pool, li, k, v,
-                                      write_pages, write_offsets)
-            if attn is None:
-                att = self._dense_causal(q, k, v, self.scale)
-            else:
-                att = attn(q, k, v, self.scale)
-            x = x + att.reshape(t, h * d) @ layer["wo"]
-            x = x + self._mlp(self._norm(x, layer["ln2"]), layer)
-        logits = self._norm(x, params["lnf"]) @ params["unembed"]
+        attn = attn or self._dense_causal
+        logits, k_pool, v_pool = self._forward(
+            params, tokens, jnp.arange(tokens.shape[0], dtype=jnp.int32),
+            k_pool, v_pool, write_pages, write_offsets,
+            lambda li, q, k, v, kp, vp: attn(q, k, v, self.scale))
         return logits[length - 1], k_pool, v_pool
 
     def prefill_chunk(self, params, tokens, start, length, k_pool, v_pool,
                       page_table_row, write_pages, write_offsets):
+        import jax
         import jax.numpy as jnp
 
         from ..ops import pallas_kernels
 
-        c = tokens.shape[0]
-        h, kh, d = self.num_heads, self.num_kv_heads, self.head_dim
-        positions = start.astype(jnp.int32) + jnp.arange(c, dtype=jnp.int32)
-        x = params["embed"][tokens] + self._pe(positions)
-        for li, layer in enumerate(params["layers"]):
-            hx = self._norm(x, layer["ln1"])
-            q = (hx @ layer["wq"]).reshape(c, h, d)
-            k = (hx @ layer["wk"]).reshape(c, kh, d)
-            v = (hx @ layer["wv"]).reshape(c, kh, d)
-            # scatter FIRST so in-chunk positions read their own K/V back
-            # through the pages like every earlier chunk's (already-cached
-            # positions write to the null page — their KV is in the
-            # shared/CoW pages, this pass only recomputes activations)
-            k_pool, v_pool = write_kv(k_pool, v_pool, li, k, v,
-                                      write_pages, write_offsets)
-            att = pallas_kernels.paged_prefill_attention(
-                q, k_pool[li], v_pool[li], page_table_row, start, length,
-                scale=self.scale)
-            x = x + att.reshape(c, h * d) @ layer["wo"]
-            x = x + self._mlp(self._norm(x, layer["ln2"]), layer)
-        logits = self._norm(x, params["lnf"]) @ params["unembed"]
+        with jax.named_scope("mx_embed"):
+            positions = start.astype(jnp.int32) \
+                + jnp.arange(tokens.shape[0], dtype=jnp.int32)
+        logits, k_pool, v_pool = self._forward(
+            params, tokens, positions, k_pool, v_pool, write_pages,
+            write_offsets,
+            lambda li, q, k, v, kp, vp:
+            pallas_kernels.paged_prefill_attention(
+                q, kp[li], vp[li], page_table_row, start, length,
+                scale=self.scale))
         return logits[length - 1], k_pool, v_pool
 
     def decode(self, params, tokens, positions, k_pool, v_pool,
                page_tables, seq_lens, write_pages, write_offsets):
         from ..ops import pallas_kernels
 
-        s = tokens.shape[0]
         # the per-slot query width (1 = classic tick, K+1 = speculative
         # verify tick) falls out of trace-time shapes — the contract's
         # operands widen, the signature doesn't
-        w = s // page_tables.shape[0]
-        h, kh, d = self.num_heads, self.num_kv_heads, self.head_dim
-        x = params["embed"][tokens] + self._pe(positions)
-        for li, layer in enumerate(params["layers"]):
-            hx = self._norm(x, layer["ln1"])
-            q = (hx @ layer["wq"]).reshape(s, h, d)
-            k = (hx @ layer["wk"]).reshape(s, kh, d)
-            v = (hx @ layer["wv"]).reshape(s, kh, d)
-            k_pool, v_pool = write_kv(k_pool, v_pool, li, k, v,
-                                      write_pages, write_offsets)
-            if w > 1:
-                att = pallas_kernels.paged_spec_attention(
-                    q, k_pool[li], v_pool[li], page_tables, seq_lens,
-                    scale=self.scale)
-            else:
-                att = pallas_kernels.paged_attention(
-                    q, k_pool[li], v_pool[li], page_tables, seq_lens,
-                    scale=self.scale)
-            x = x + att.reshape(s, h * d) @ layer["wo"]
-            x = x + self._mlp(self._norm(x, layer["ln2"]), layer)
-        logits = self._norm(x, params["lnf"]) @ params["unembed"]
-        return logits, k_pool, v_pool
+        w = tokens.shape[0] // page_tables.shape[0]
+        paged = pallas_kernels.paged_spec_attention if w > 1 \
+            else pallas_kernels.paged_attention
+        return self._forward(
+            params, tokens, positions, k_pool, v_pool, write_pages,
+            write_offsets,
+            lambda li, q, k, v, kp, vp: paged(
+                q, kp[li], vp[li], page_tables, seq_lens, scale=self.scale))
 
     # -- oracle ---------------------------------------------------------
     def _reference_next(self, params, arr):

@@ -57,24 +57,26 @@ from .accounting import (CKPT_BYTES, CKPT_CORRUPTION, CKPT_RESTORE_MS,
                          COMPILE_SECONDS, ELASTIC_GOODPUT, ELASTIC_RESTARTS,
                          HBM_BYTES_IN_USE, HBM_BYTES_PEAK,
                          OPT_DISPATCHES, PREEMPTIONS, PROFILER_COUNTER,
+                         PROGRAM_PARTS, PROGRAM_PARTS_VERSION,
                          RECOMPILES, STEADY_STATE_RECOMPILES, STEP_DISPATCHES,
                          TRANSFER_BYTES,
                          TRANSFERS, hbm_watermark, jit_cache_size, jit_call,
-                         note_recompile, record_transfer, sample_hbm,
-                         set_steady_state_recompiles)
+                         note_recompile, program_parts, record_transfer,
+                         sample_hbm, set_steady_state_recompiles)
 from .exporters import (Emitter, render_prometheus, snapshot, start_emitter,
                         stop_emitter)
 from .httpd import start_httpd, stop_httpd
 from .registry import (Counter, Gauge, Histogram, Registry, REGISTRY,
                        counter, gauge, histogram, enabled, set_enabled)
-from .spans import span, traced
+from .spans import span, trace_live, traced
 from .tracing import get_trace, start_trace
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
     "counter", "gauge", "histogram", "enabled", "set_enabled",
-    "span", "traced",
+    "span", "traced", "trace_live",
     "jit_call", "jit_cache_size", "note_recompile", "record_transfer",
+    "PROGRAM_PARTS", "PROGRAM_PARTS_VERSION", "program_parts",
     "sample_hbm", "hbm_watermark", "set_steady_state_recompiles",
     "RECOMPILES", "COMPILE_SECONDS", "STEADY_STATE_RECOMPILES",
     "TRANSFERS", "TRANSFER_BYTES", "PROFILER_COUNTER",

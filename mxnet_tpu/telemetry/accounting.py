@@ -20,6 +20,7 @@ patterns; this module measures what actually happened at runtime:
 from __future__ import annotations
 
 import logging
+import re
 import time
 
 from . import registry as _registry
@@ -34,6 +35,7 @@ __all__ = ["RECOMPILES", "COMPILE_SECONDS", "STEADY_STATE_RECOMPILES",
            "CKPT_SAVE_MS", "CKPT_RESTORE_MS", "CKPT_BYTES",
            "PREEMPTIONS", "CKPT_CORRUPTION", "ELASTIC_GOODPUT",
            "ELASTIC_RESTARTS",
+           "PROGRAM_PARTS", "PROGRAM_PARTS_VERSION", "program_parts",
            "jit_call", "jit_cache_size", "note_recompile",
            "record_transfer", "sample_hbm", "hbm_watermark",
            "set_steady_state_recompiles"]
@@ -219,6 +221,133 @@ def jit_call(site: str, jitted, *args, **kwargs):
                              count=after - before,
                              seconds=round(time.perf_counter() - t0, 4))
     return out
+
+
+#: The closed vocabulary of ``jax.named_scope`` names the served models and
+#: the decode engine's programs put their operations under (what each
+#: covers: docs/observability.md "Parts of a program").
+PROGRAM_PARTS = frozenset((
+    "mx_embed", "mx_qkv", "mx_kv_write", "mx_attn", "mx_attn_out", "mx_mlp",
+    "mx_moe_route", "mx_moe_experts", "mx_moe_shared", "mx_moe_combine",
+    "mx_head"))
+
+#: Which layout of those scopes a program was traced with. jax's persistent
+#: compile cache keys a program WITHOUT its ``op_name`` metadata, so two
+#: programs that differ in their scopes alone share an entry and the second
+#: reads back the first's names (PERF.md section 6, PR 39: the parent's
+#: scope-less prefill served to this tree, its map empty). The engine's
+#: programs carry this value as an XLA frontend attribute
+#: (``jax.experimental.xla_metadata``), which the key does include: BUMP IT
+#: whenever a scope moves or the vocabulary changes. Forgetting is a wrong
+#: attribution where a cache is shared, not a silent one (the instruction
+#: names still match), so ``tests/test_program_parts.py`` pins a digest of
+#: the programs' scope paths beside this value: a scope cannot move without
+#: that test asking for the bump.
+PROGRAM_PARTS_VERSION = "1"
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OPCODE = re.compile(r"[\s)]([a-z][a-z0-9_\-]*)\(")
+_HLO_NAME = re.compile(r"%([\w.\-]+)")
+#: a computation named so is the inside of ONE instruction (a fusion's body,
+#: a reduction's or a sort's region): its instructions are no device events
+_HLO_INSIDE = re.compile(
+    r"\b(?:calls|to_apply|select|scatter|called_computations)=\{?%?([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+#: instructions that are a name for a value, not work: no device event
+_HLO_NO_WORK = frozenset(("parameter", "constant", "tuple", "bitcast",
+                          "get-tuple-element"))
+
+
+def _part_of(op_name):
+    """The innermost vocabulary name on a ``metadata={op_name=...}`` path."""
+    for scope in reversed(op_name.split("/")):
+        if scope in PROGRAM_PARTS:
+            return scope
+    return None
+
+
+def _first(parts):
+    """The first of ``parts`` that is one, else ``None``."""
+    return next((p for p in parts if p is not None), None)
+
+
+def program_parts(text: str) -> dict:
+    """Which part of the program each instruction of a compiled program
+    belongs to, from ``compiled.as_text()``.
+
+    A top-level ``fusion`` carries the ``op_name`` of its root, so an
+    instruction's part is the innermost :data:`PROGRAM_PARTS` name on its
+    own ``op_name``. What the compiler made itself carries no ``op_name``
+    (on a TPU: the ``slice-start`` / ``slice-done`` / ``copy-start`` pairs
+    that fetch a weight ahead of its product, layout copies, the pieces of a
+    split reduction) and takes the part of the first instruction that reads
+    it — a weight's fetch belongs to the product it was fetched for — or
+    else of the first operand that has one, or else of the first other
+    reader of its operands (the fetch of a weight for the program's NEXT run
+    has no reader in this one).
+
+    Returns ``{"program", "parts", "unnamed", "mixed"}``: the module's name
+    (an ``XLA Modules`` event starts with it); ``{instruction: part}`` over
+    the instructions that do work (no parameter, constant, tuple or bitcast)
+    of every computation that is not the inside of one instruction (a
+    fusion's body, a reduction's region); those among them that have no
+    part; and how many fusions hold instructions of more than one part — a
+    fusion XLA built across two scopes goes to its root's part whole, and
+    ``mixed`` says how often that happened. A text without scopes gives
+    empty ``parts``."""
+    module = re.match(r"HloModule ([\w.\-]+)", text)
+    comps, inside, rows = {}, set(), None
+    for line in text.splitlines():
+        if not line.startswith(" "):    # a computation opens or closes
+            head = _HLO_COMPUTATION.match(line)
+            rows = comps.setdefault(head.group(1), []) if head else None
+            continue
+        inst = _HLO_INSTRUCTION.match(line) if rows is not None else None
+        if not inst:
+            continue
+        rest = line[inst.end():]
+        opcode = _HLO_OPCODE.search(" " + rest)
+        opcode = opcode.group(1) if opcode else ""
+        if opcode != "call":
+            inside.update(_HLO_INSIDE.findall(rest))
+        op_name = _HLO_OP_NAME.search(rest)
+        rows.append([inst.group(1), opcode,
+                     _part_of(op_name.group(1)) if op_name else None,
+                     _HLO_NAME.findall(rest.split(", metadata=")[0])])
+    parts, unnamed = {}, []
+    for name, rows in comps.items():
+        if name in inside:
+            continue
+        part = {inst: p for inst, _o, p, _r in rows}
+        users = {}
+        for inst, _o, _p, refs in rows:
+            for ref in refs:
+                users.setdefault(ref, []).append(inst)
+        # the text is in schedule order, a definition before its uses: back
+        # to front every user is settled first, front to back every operand
+        # (by what made it, not by who else reads it)
+        made = dict(part)
+        for inst, _o, _p, _r in reversed(rows):
+            if part[inst] is None:
+                part[inst] = _first(part.get(u) for u in users.get(inst, ()))
+        for inst, _o, _p, refs in rows:
+            if part[inst] is None:
+                part[inst] = made[inst] = _first(made.get(r) for r in refs)
+            if part[inst] is None:      # ... or by who else reads the same
+                part[inst] = _first(part.get(r) for r in refs)
+        for inst, opcode, _p, _r in rows:
+            if opcode in _HLO_NO_WORK:
+                continue
+            if part[inst] is not None:
+                parts[inst] = part[inst]
+            else:
+                unnamed.append(inst)
+    mixed = sum(
+        1 for name in inside
+        if len({p for _i, _o, p, _r in comps.get(name, ()) if p}) > 1)
+    return {"program": module.group(1) if module else "", "parts": parts,
+            "unnamed": unnamed, "mixed": mixed}
 
 
 def note_recompile(site: str, count: int = 1, seconds: float = 0.0):

@@ -43,14 +43,14 @@ from jax.profiler import TraceAnnotation as _Annotation
 from .. import profiler as _profiler
 from . import registry as _registry
 
-__all__ = ["span", "traced", "SPAN_MS", "TRACE_PREFIX"]
+__all__ = ["span", "traced", "trace_live", "SPAN_MS", "TRACE_PREFIX"]
 
 #: What a span's annotation in the ``jax.profiler`` trace is named with:
 #: every host event of this framework starts with it.
 TRACE_PREFIX = "mx."
 
 #: whether a ``jax.profiler`` trace is being taken (one atomic read)
-_trace_live = _Annotation.is_enabled
+trace_live = _trace_live = _Annotation.is_enabled
 
 #: Every span's duration lands here; ``category`` groups related spans
 #: (executor/kvstore/serving/…), ``span`` is the specific region.

@@ -348,7 +348,8 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
     assert stats["steps_overlapped"] == 4 and stats["ticks"] == 5
     assert all(set(a) == {"active", "prefilling", "queued"}
                for n, a in events if n == "mx.decode.tick" and a)
-    assert set(prefill[0]) == keys | {"rung"}
+    assert set(prefill[0]) == keys | {"rung", "held"}
+    assert prefill[0]["held"] == 0          # nobody was decoding yet
     assert prefill[0]["kv_rows_full"] == 50
     assert prefill[0]["kv_rows_window"] == WINDOW
     for i, args in enumerate(commits):

@@ -346,9 +346,17 @@ def test_decode_tick_spans_carry_the_slots_in_use(tiny, tmp_path):
         d, f = kids.get("mx.decode.dispatch"), kids.get("mx.decode.fetch")
         if d is not None and f is not None:
             assert d[3]["overlapped"] == 1 and d[2] <= f[1]
-    # every span of the worker lies inside one of its passes
+    # every span of the worker lies inside one of its passes, but its wait
+    # for work between two of them
     for e in events:
-        if e[0].startswith("mx.decode.") and e[0] != "mx.decode.tick":
+        if e[0] == "mx.decode.idle":
+            assert e[3] == {"why": "empty"}
+            assert not any(_inside(e, t) for t in ticks), e
+        elif e[0] == "mx.decode.programs":
+            # zero-length, by the first look that finds the trace live: a
+            # pass, or the waiting worker between two slices
+            assert e[2] <= dispatches[0][1]
+        elif e[0].startswith("mx.decode.") and e[0] != "mx.decode.tick":
             assert any(_inside(e, t) for t in ticks), e
     # monolithic prefill runs inside the admission pass, under its rung
     prefills = _named(events, "mx.decode.prefill")
@@ -445,9 +453,256 @@ def test_decode_chunked_prefill_span_carries_the_chunk(tiny, tmp_path):
             tmp_path, lambda: eng.submit(prompt, 3).result(timeout=120))
     prefills = _named(events, "mx.decode.prefill")
     assert len(prefills) == 3  # 19 tokens, 8 a chunk, one chunk a pass
-    assert all(p[3] == {"chunk": 8} for p in prefills)
+    # (`held`: nobody was decoding while the one request prefilled)
+    assert all(p[3] == {"chunk": 8, "held": 0} for p in prefills)
     assert any(t[3].get("prefilling") == 1
                for t in _named(events, "mx.decode.tick"))
+
+
+def _afmoe():
+    return _walk_model("grouped")[0]
+
+
+def _parts_by_program(events):
+    """``{(program, rung): {instruction: part}}`` of a trace's
+    ``mx.decode.programs`` spans."""
+    import json
+
+    out = {}
+    for ev in _named(events, "mx.decode.programs"):
+        key = (ev[3]["program"], ev[3].get("rung"))
+        assert key not in out, "written twice in one trace: %r" % (key,)
+        out[key] = {inst: part
+                    for part, insts in json.loads(ev[3]["parts"]).items()
+                    for inst in insts}
+    return out
+
+
+def test_programs_span_is_written_once_a_trace_and_never_without(tiny,
+                                                                 tmp_path):
+    """One zero-length ``mx.decode.programs`` a program in every trace (the
+    maps of ``warmup()``, whole), by the worker's first look that finds the
+    trace live, ahead of the trace's first program; no trace, no span."""
+    import time
+
+    from mxnet_tpu.serving import decode as decode_mod
+
+    def soak(eng):
+        for f in [eng.submit(p, m) for p, m in _prompts(4)]:
+            f.result(timeout=120)
+
+    def count():
+        return spans_mod.SPAN_MS.count(category="serving",
+                                       span="decode.programs")
+
+    with _engine(tiny, name="spans_programs") as eng:
+        eng.warmup()
+        before = count()
+        soak(eng)                       # telemetry on, no trace
+        assert count() == before
+        for i in range(2):
+            # the second trace finds the engine idle since the first; the
+            # waiting worker looks once a slice whether a trace is live, and
+            # has to see none in between to take the next for a new one
+            time.sleep(3 * decode_mod._IDLE_SLICE_S)
+            events = _traced(tmp_path / str(i), lambda: soak(eng))
+            got = _parts_by_program(events)
+            assert sorted(got, key=str) == sorted(
+                [("jit_mx_decode_step", None)]
+                + [("jit_mx_prefill", r) for r in (8, 16, 48)], key=str)
+            for row in eng._programs:
+                assert got[(row["program"], row.get("rung"))] == row["parts"]
+            spans = _named(events, "mx.decode.programs")
+            assert all(s[1] == s[2] or s[2] - s[1] < 1e6 for s in spans)
+            assert max(s[2] for s in spans) <= min(
+                e[1] for e in _named(events, "mx.decode.prefill")
+                + _named(events, "mx.decode.dispatch"))
+            step = [s for s in spans if "rung" not in s[3]][0][3]
+            assert step["step_temp_bytes"] == \
+                eng.stats()["decode_step_temp_bytes"]
+            assert step["mixed"] >= 0 and step["unnamed"] == \
+                eng.stats()["program_parts"]["jit_mx_decode_step"]["unnamed"]
+        assert count() == before + 2 * 4
+
+
+def test_a_trace_that_is_live_before_warmup_still_gets_every_program(
+        tiny, tmp_path):
+    """``warmup()`` hands the worker every program's map at once; a trace
+    that was live before (the worker had looked and found nothing to
+    write) gets each program once, from the next look on."""
+    with _engine(tiny, name="spans_late") as eng:
+        def work():
+            eng.generate(np.arange(1, 7, dtype=np.int32), 4, timeout=120)
+            assert eng.stats()["program_parts"] == {}
+            eng.warmup()
+            eng.generate(np.arange(1, 7, dtype=np.int32), 4, timeout=120)
+
+        events = _traced(tmp_path, work)
+        stats = eng.stats()
+    got = _parts_by_program(events)     # (asserts: none written twice)
+    assert len(got) == len(stats["program_parts"]) == 4
+    assert all(got[key] for key in got)
+    assert stats["compile_count"] == 4
+
+
+def test_empty_engine_waits_under_idle_spans_a_late_trace_still_sees(
+        tiny, tmp_path):
+    """The worker of an engine with nothing to do waits in slices, each an
+    ``mx.decode.idle{why=empty}``: a trace that starts in the middle of the
+    wait loses one slice at most, and no pass runs."""
+    import time
+
+    from mxnet_tpu.serving import decode as decode_mod
+
+    with _engine(tiny, name="spans_idle") as eng:
+        eng.warmup()
+        time.sleep(2 * decode_mod._IDLE_SLICE_S)    # deep in the wait
+
+        def work():
+            with telemetry.span("t_idle_marker"):
+                pass
+            time.sleep(0.6)
+
+        events = _traced(tmp_path, work)
+    (marker,) = _named(events, "mx.t_idle_marker")
+    idle = _named(events, "mx.decode.idle")
+    assert not _named(events, "mx.decode.tick")
+    assert len(idle) >= 4 and all(e[3] == {"why": "empty"} for e in idle)
+    slice_ns = decode_mod._IDLE_SLICE_S * 1e9
+    # the slice the trace started in is lost, the next one is there
+    assert idle[0][1] - marker[1] < 3 * slice_ns
+    for a, b in zip(idle, idle[1:]):
+        assert a[2] <= b[1] and b[1] - a[2] < slice_ns   # back to back
+    assert sum(e[2] - e[1] for e in idle) > 0.5 * (idle[-1][2] - idle[0][1])
+
+
+def test_deferred_pass_sleeps_under_an_idle_span(tiny, tmp_path):
+    """Queued work that admission keeps deferring (here: a tenant without a
+    token budget) with nothing in flight: the pass yields under
+    ``mx.decode.idle{why=deferred}``, inside its ``mx.decode.tick``."""
+    with _engine(tiny, name="spans_deferred", timeout_ms=400,
+                 tenants="slow,rate=1,burst=12") as eng:
+        eng.warmup()
+        # the first request spends the budget, the second waits for it
+        eng.submit(np.arange(1, 6, dtype=np.int32), 4,
+                   tenant="slow").result(timeout=120)
+        fut = eng.submit(np.arange(1, 6, dtype=np.int32), 4, tenant="slow")
+
+        def work():
+            try:
+                fut.result(timeout=120)
+            except Exception:  # noqa: BLE001 - it may time out queued
+                pass
+
+        events = _traced(tmp_path, work)
+    idle = [e for e in _named(events, "mx.decode.idle")
+            if e[3] == {"why": "deferred"}]
+    ticks = _named(events, "mx.decode.tick")
+    assert idle and all(any(_inside(e, t) for t in ticks) for e in idle)
+
+
+@pytest.mark.parametrize("chunk", [0, 8])
+def test_prefill_span_carries_the_slots_it_holds_under_a_burst(tiny, tmp_path,
+                                                              chunk):
+    """``held`` on ``mx.decode.prefill``: the slots decoding as the prefill
+    is launched. A pass's ``active`` is taken after its monolithic prefills
+    (admission runs them), and a finished prefill's own slot joins the
+    decoding ones: the last prefill of a pass holds ``active`` - 1 (every
+    request here outlives its first token), and within a pass each prefill
+    holds one slot more than the one before. A pass's one chunk runs after
+    ``active`` is taken and holds just those. The counter and ``stats()``
+    carry duration x held."""
+    from mxnet_tpu.serving import decode as decode_mod
+
+    name = "spans_held_%d" % chunk
+    rng = np.random.RandomState(3)
+    burst = [(rng.randint(1, 32, 6).astype(np.int32), 8) for _ in range(6)]
+    with _engine(tiny, name=name, prefill_chunk=chunk) as eng:
+        eng.warmup()
+        assert eng.stats()["prefill_held_slot_ms"] == 0.0
+
+        def work():
+            for f in [eng.submit(p, m) for p, m in burst]:
+                f.result(timeout=120)
+            eng.close()
+
+        events = _traced(tmp_path, work)
+        stats = eng.stats()
+    prefills = _named(events, "mx.decode.prefill")
+    assert len(prefills) == 6
+    assert all(0 <= p[3]["held"] < eng.num_slots for p in prefills)
+    assert max(p[3]["held"] for p in prefills) >= 1
+    for t in _named(events, "mx.decode.tick"):
+        mine = [p for p in prefills if _inside(p, t)]
+        if mine:
+            assert mine[-1][3]["held"] == t[3]["active"] - (0 if chunk else 1)
+            assert [p[3]["held"] for p in mine] == list(range(
+                mine[0][3]["held"], mine[0][3]["held"] + len(mine)))
+    lost_ms = sum(p[3]["held"] * (p[2] - p[1]) / 1e6 for p in prefills)
+    assert stats["prefill_held_slot_ms"] == pytest.approx(lost_ms, rel=0.3,
+                                                          abs=0.5)
+    assert decode_mod._T_PREFILL_HELD.value(server=name) == pytest.approx(
+        stats["prefill_held_slot_ms"])
+    assert "mxnet_decode_prefill_held_slot_ms_total" \
+        in telemetry.render_prometheus()
+
+
+def test_held_is_not_computed_with_nobody_reading(tiny, monkeypatch):
+    """No trace and the registry off: a prefill counts no slot."""
+    with _engine(tiny, name="spans_held_off") as eng:
+        eng.warmup()
+        monkeypatch.setattr(
+            eng, "_decoding", lambda: pytest.fail("held was computed"),
+            raising=True)
+        telemetry.set_enabled(False)
+        try:
+            # (the pass itself asks once a pass, after admission: only the
+            # prefill's own count is under test, so admit and stop there)
+            with eng._prefill_span(rung=8) as span:
+                span.set_args(x=1)
+        finally:
+            telemetry.set_enabled(True)
+        monkeypatch.undo()
+        assert eng.stats()["prefill_held_slot_ms"] == 0.0
+
+
+@pytest.mark.parametrize("kind", ["plain", "grouped"])
+def test_tracing_the_parts_changes_nothing_served(kind, tmp_path):
+    """The same tokens, compile count, overlap and recompiles with the
+    programs' maps built and a trace live as the engine's contract gives
+    without: one program a rung and the step, most steps dispatched over the
+    one before, nothing compiled after ``warmup()``."""
+    model, kw, _tables = _walk_model(kind)
+    params = model.init_params(0)
+    prompts = [np.arange(1 + i, 8 + 3 * i, dtype=np.int32) for i in range(4)]
+    with _engine((model, params), name="spans_same_%s" % kind, num_slots=2,
+                 prefill_buckets=(16, 64), **kw) as eng:
+        assert eng.warmup() == 1 + len(eng.stats()["prefill_buckets"])
+        served = []
+
+        def work():
+            futs = [eng.submit(p, 6) for p in prompts]
+            served.extend(f.result(timeout=300) for f in futs)
+
+        events = _traced(tmp_path, work)
+        stats = eng.stats()
+    if kind == "plain":
+        for p, got in zip(prompts, served):
+            assert list(got) == list(model.reference_generate(params, p, 6))
+    else:
+        from mxnet_tpu.serving import afmoe_reference
+
+        for p, got in zip(prompts, served):
+            seq = list(p)
+            for tok in got:
+                logits = afmoe_reference.forward_logits(
+                    model.cfg, params, np.asarray(seq, np.int32))
+                assert int(np.argmax(logits[-1])) == int(tok)
+                seq.append(int(tok))
+    assert stats["steady_state_recompiles"] == 0
+    assert stats["compile_count"] == 1 + len(stats["prefill_buckets"])
+    assert stats["steps_overlapped"] >= stats["ticks"] - len(prompts) - 1
+    assert len(_named(events, "mx.decode.programs")) == stats["compile_count"]
 
 
 def test_stats_ticks_and_slot_ticks_give_the_occupancy(tiny):
