@@ -273,19 +273,24 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # one is — membership churn and ragged lengths never retrace (the Ragged
 # Paged Attention argument, PAPERS.md).
 #
-# Kernel layout: grid (S, columns up to the longest slot's last live one —
-# a traced extent, at most max_pages); the page axis is the innermost
-# ("arbitrary") dimension and carries online-softmax state (running max m,
-# normalizer l, accumulator acc) in VMEM scratch, exactly the flash-kernel
-# idiom above. The page table, the sequence lengths and each slot's LIVE
-# column range ``[c0, c1)`` (:func:`live_columns`: the table columns that
-# hold a key some query row of the slot may see) ride in as scalar-prefetch
-# operands (PrefetchScalarGridSpec), so the K/V BlockSpec index_map
-# dereferences the page table — the pool page is DMA'd straight into VMEM
-# with no gather op in the kernel body. The walk is bounded by that range:
-# a grid step outside it asks for the page that is already resident (the
-# launch clamps the table: no new DMA) and skips the products
-# (``pl.when``); inside it the ragged mask works position by position.
+# Kernel layout: ONE flat grid axis over the LIVE (slot, column) pairs,
+# slot-major and columns ascending — its extent a traced scalar, the sum
+# over slots of their live columns ``[c0, c1)`` (:func:`live_columns`: the
+# table columns that hold a key some query row of the slot may see). A slot
+# pays for its own columns and no other's; a slot with none is not visited
+# at all (the launch zeroes its rows behind the call). The axis is
+# "arbitrary" and carries online-softmax state (running max m, normalizer
+# l, accumulator acc) in VMEM scratch, exactly the flash-kernel idiom
+# above: a slot's first step resets it, its last one writes the slot's
+# rows. The walk's SCHEDULE (:func:`walk_schedule`, built in XLA once a
+# table: the launches of a step that share a table and lengths share it by
+# common-subexpression elimination) rides in as scalar-prefetch operands
+# (PrefetchScalarGridSpec) beside the per-row lengths: for a flat step its
+# pool page, its (slot, column) cell, and each slot's first step. So the
+# K/V BlockSpec index_map names the pool page directly — it is DMA'd
+# straight into VMEM with no gather op in the kernel body — and every grid
+# step fetches a page and multiplies it; the ragged mask works position by
+# position.
 #
 # A grid step multiplies its page ONCE for all the page's kv heads: the
 # block (page_size, KH, D) is read as one key matrix (page_size * KH, D) —
@@ -302,27 +307,33 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # bit-comparable within fp tolerance.
 
 
-def _paged_kernel(pt_ref, sl_ref, qp_ref, lc_ref, *rest, page_size,
+# a flat step's cell: its slot above _CELL_BITS, its table column below
+_CELL_BITS = 16
+_CELL_MASK = (1 << _CELL_BITS) - 1
+
+
+def _paged_kernel(pg_ref, cell_ref, st_ref, sl_ref, qp_ref, *rest, page_size,
                   max_pages, groups, width, scale, causal, window=0,
                   ring=False, precision=None):
-    """One (slot, page) cell of ragged paged attention, ``width`` query
-    tokens per slot (1 = classic decode tick / chunked-prefill row, K+1 =
-    speculative verify tick).
+    """One live (slot, column) pair of ragged paged attention, ``width``
+    query tokens per slot (1 = classic decode tick / chunked-prefill row,
+    K+1 = speculative verify tick). The grid is the flat walk of
+    :func:`walk_schedule`: step ``t`` is column ``cell_ref[t] & _CELL_MASK``
+    of slot ``cell_ref[t] >> _CELL_BITS``, the slot's steps are
+    ``[st_ref[slot], st_ref[slot + 1])`` and ``pg_ref[t]`` (the K/V index
+    map's) is the pool page the column names.
 
     q_ref/o_ref: (1, R, D) — ALL the slot's query rows as one matrix, those
     of a kv head contiguous: row ``r = (kh*width + w)*groups + g`` is query
     token ``w``, head ``kh*groups + g``; R pads ``n_kv*width*groups`` to the
-    sublane tile. k_ref/v_ref: (1, page_size, KH, D) — the page named by the
-    slot's page table, read as ONE key matrix ``(page_size*KH, D)`` for all
-    its kv heads: key row ``c = t*KH + kh`` is token ``t`` of the page, kv
-    head ``kh``. Scratch m/l: (R, LANES), acc: (R, D).
+    sublane tile. k_ref/v_ref: (1, page_size, KH, D) — the step's page, read
+    as ONE key matrix ``(page_size*KH, D)`` for all its kv heads: key row
+    ``c = t*KH + kh`` is token ``t`` of the page, kv head ``kh``. Scratch
+    m/l: (R, LANES), acc: (R, D).
     sl_ref/qp_ref are (S*width,): PER-QUERY-TOKEN seq_len and (when
-    ``causal``) query position. lc_ref is (S*2,): the slot's live columns
-    ``[c0, c1)`` (:func:`live_columns`) — a column outside them holds no
-    key any row may see, so it is neither fetched (``pt_ref`` names a live
-    column's page there) nor multiplied.
+    ``causal``) query position.
 
-    A live page costs two products, ``(R, D) x (page_size*KH, D)^T`` and
+    A step costs two products, ``(R, D) x (page_size*KH, D)^T`` and
     ``(R, page_size*KH) x (page_size*KH, D)``: a score whose row reads
     another kv head than its column holds is masked like a position the row
     may not see, ``exp`` makes it exactly 0, and the zeros pick each row's
@@ -330,20 +341,23 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, lc_ref, *rest, page_size,
 
     ``window`` > 0 (static) also masks keys at or below ``query - window``
     (the query is the row's ``q_pos`` when causal, else its last token).
-    ``ring`` (static): the table's columns are a ring and a fifth
+    ``ring`` (static): the table's columns are a ring and a sixth
     scalar-prefetch operand ``blk_ref`` ``(S * max_pages,)`` names the
     page-sized block of the sequence each column holds (-1: none yet).
     ``precision``: of the two products (None: the compiler's default, one
     bfloat16 pass over float32 operands; ``HIGHEST``: float32 products).
     """
+    del pg_ref                      # the index maps' own
     if ring:
         blk_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    s = pl.program_id(0)
-    j = pl.program_id(1)
+    t = pl.program_id(0)
+    cell = cell_ref[t]
+    s = cell >> _CELL_BITS
+    j = cell & _CELL_MASK
 
-    @pl.when(j == 0)
+    @pl.when(t == st_ref[s])
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -354,62 +368,58 @@ def _paged_kernel(pt_ref, sl_ref, qp_ref, lc_ref, *rest, page_size,
     rows = width * groups           # the query rows of one kv head
     keys = page_size * n_kv
 
-    @pl.when(jnp.logical_and(j >= lc_ref[2 * s], j < lc_ref[2 * s + 1]))
-    def _page():
-        # the mask, per query row and key row: the key's kv head is the
-        # row's, and its token position lies under the row's length (and
-        # its query position when causal). The w of a row is an unrolled
-        # select over the width scalar-prefetch entries; a pad row reads kv
-        # head KH, which no key holds, and comes out as zeros.
-        row = lax.broadcasted_iota(jnp.int32, (r_pad, 1), 0)
-        row_kh = row // rows
-        row_w = (row - row_kh * rows) // groups
-        sl_rows = jnp.zeros((r_pad, 1), jnp.int32)
-        qp_rows = jnp.zeros((r_pad, 1), jnp.int32)
-        for w in range(width):
-            sl_rows = jnp.where(row_w == w, sl_ref[s * width + w], sl_rows)
-            if causal:
-                qp_rows = jnp.where(row_w == w, qp_ref[s * width + w],
-                                    qp_rows)
-        col = lax.broadcasted_iota(jnp.int32, (1, keys), 1)
-        first = blk_ref[s * max_pages + j] if ring else j
-        pos = first * page_size + col // n_kv
-        valid = jnp.logical_and(col % n_kv == row_kh, pos < sl_rows)
+    # the mask, per query row and key row: the key's kv head is the row's,
+    # and its token position lies under the row's length (and its query
+    # position when causal). The w of a row is an unrolled select over the
+    # width scalar-prefetch entries; a pad row reads kv head KH, which no key
+    # holds, and comes out as zeros.
+    row = lax.broadcasted_iota(jnp.int32, (r_pad, 1), 0)
+    row_kh = row // rows
+    row_w = (row - row_kh * rows) // groups
+    sl_rows = jnp.zeros((r_pad, 1), jnp.int32)
+    qp_rows = jnp.zeros((r_pad, 1), jnp.int32)
+    for w in range(width):
+        sl_rows = jnp.where(row_w == w, sl_ref[s * width + w], sl_rows)
         if causal:
-            valid = jnp.logical_and(valid, pos <= qp_rows)
-        if window or ring:
-            # a ring column that holds no block yet has first = -1: pos < 0
-            low = (qp_rows if causal else sl_rows - 1) - window + 1 \
-                if window else 0
-            valid = jnp.logical_and(valid, pos >= jnp.maximum(low, 0))
+            qp_rows = jnp.where(row_w == w, qp_ref[s * width + w], qp_rows)
+    col = lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+    first = blk_ref[s * max_pages + j] if ring else j
+    pos = first * page_size + col // n_kv
+    valid = jnp.logical_and(col % n_kv == row_kh, pos < sl_rows)
+    if causal:
+        valid = jnp.logical_and(valid, pos <= qp_rows)
+    if window or ring:
+        # a ring column that holds no block yet has first = -1: pos < 0
+        low = (qp_rows if causal else sl_rows - 1) - window + 1 \
+            if window else 0
+        valid = jnp.logical_and(valid, pos >= jnp.maximum(low, 0))
 
-        q = q_ref[0].astype(jnp.float32)                        # (R, D)
-        k = k_ref[0].astype(jnp.float32).reshape(keys, d)
-        v = v_ref[0].astype(jnp.float32).reshape(keys, d)
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), precision=precision,
-            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(valid, scores, _NEG_BIG)
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)
-        l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), precision=precision,
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+    q = q_ref[0].astype(jnp.float32)                        # (R, D)
+    k = k_ref[0].astype(jnp.float32).reshape(keys, d)
+    v = v_ref[0].astype(jnp.float32).reshape(keys, d)
+    scores = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(valid, scores, _NEG_BIG)
+    m_prev = m_scr[:, :1]
+    l_prev = l_scr[:, :1]
+    m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(scores - m_new)
+    l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(t == st_ref[s + 1] - 1)
     def _finish():
-        # a fully-masked row (inactive slot, padded draft row, seq_len 0)
-        # never raises the running max off the sentinel — a slot with no
-        # live column never ran a product at all: gate on the max and emit
-        # zeros (the row IS the slot's output, there is no padding to drop
-        # as in the flash kernel, where such a row's p = exp(NEG_BIG -
-        # NEG_BIG) = 1 accumulates garbage)
+        # a fully-masked row (padded draft row, seq_len 0 beside a live row
+        # of its slot) never raises the running max off the sentinel: gate
+        # on the max and emit zeros (the row IS the slot's output, there is
+        # no padding to drop as in the flash kernel, where such a row's p =
+        # exp(NEG_BIG - NEG_BIG) = 1 accumulates garbage)
         seen = m_scr[:, :1] > _NEG_BIG * 0.5
         o = jnp.where(seen,
                       acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30),
@@ -441,8 +451,7 @@ def live_columns(seq_lens, q_pos, columns, page_size, window=0, ring=False):
     the highest ``hi`` over the rows that see anything. Ring table: before
     it wraps the live columns are the prefix that holds a block; after it
     every column does, and at most one of them lies below the window. A
-    slot whose rows see nothing: ``c0 = c1 = 0``. ``c0 < columns`` always
-    (the launch points a dead column at one in ``[c0, max(c1 - 1, c0)]``)."""
+    slot whose rows see nothing: ``c0 = c1 = 0``. ``c0 < columns`` always."""
     sl = seq_lens.astype(jnp.int32)
     query = sl - 1 if q_pos is None else q_pos.astype(jnp.int32)
     hi = jnp.minimum(sl, query + 1)
@@ -454,6 +463,44 @@ def live_columns(seq_lens, q_pos, columns, page_size, window=0, ring=False):
     c1 = jnp.minimum(c1, columns)
     c0 = jnp.where(sees, lo // page_size, columns).min(axis=1)
     return jnp.stack([jnp.minimum(c0, jnp.maximum(c1 - 1, 0)), c1], axis=1)
+
+
+def walk_schedule(live, page_table):
+    """The flat walk of :func:`_paged_kernel` over the live (slot, column)
+    pairs of a launch, slot-major and columns ascending. live: ``(S, 2)``
+    int32 :func:`live_columns`; page_table: ``(S, max_pages)`` int32.
+    Returns ``(page_of, cell_of, starts)``:
+
+    - ``starts`` ``(S + 1,)``: slot ``s`` owns the steps ``[starts[s],
+      starts[s + 1])``, as many as it has live columns — a slot with none
+      owns no step; ``starts[S]`` is the walk's extent;
+    - ``cell_of`` ``(S * max_pages + 1,)``: step ``t`` is column
+      ``cell_of[t] & _CELL_MASK`` of slot ``cell_of[t] >> _CELL_BITS``;
+    - ``page_of``, as long: the pool page that column names.
+
+    The two are as long as a walk can be and one more (the pipeline
+    evaluates the index maps of the step AFTER the one it runs); every
+    entry past the extent repeats the last step's, which asks for no new
+    copy. Compare-and-sum over the slots' boundaries and one gather of the
+    table: no loop, nothing a device trace would show beside the kernel."""
+    s_slots, max_pages = page_table.shape
+    count = live[:, 1] - live[:, 0]
+    slot_ids = jnp.arange(s_slots, dtype=jnp.int32)
+    starts = jnp.where(
+        jnp.arange(s_slots + 1, dtype=jnp.int32)[:, None] > slot_ids[None],
+        count[None], 0).sum(axis=1)
+    t = jnp.minimum(jnp.arange(s_slots * max_pages + 1, dtype=jnp.int32),
+                    jnp.maximum(starts[-1] - 1, 0))
+    # the slots whose first step lies at or before t: all up to t's own
+    # (the boundaries ascend), so its slot is their count and what its
+    # column lies off t is the telescoped sum of their differences
+    begun = t[:, None] >= starts[None, 1:-1]
+    slot = begun.sum(axis=1, dtype=jnp.int32)
+    off = starts[:-1] - live[:, 0]
+    col = t - off[0] - jnp.where(begun, (off[1:] - off[:-1])[None],
+                                 0).sum(axis=1)
+    page_of = page_table.ravel()[slot * max_pages + col]
+    return page_of, (slot << _CELL_BITS) | col, starts
 
 
 def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
@@ -483,6 +530,9 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
                          "got %d" % (who, width))
     groups = n_heads // n_kv
     max_pages = page_table.shape[1]
+    if max_pages > _CELL_MASK:
+        raise ValueError("%s: a page table of %d columns (a walk's cell "
+                         "holds %d)" % (who, max_pages, _CELL_MASK))
     causal = q_pos is not None
     if interpret is None:
         interpret = _interpret()
@@ -512,29 +562,24 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
         sl.reshape(s_slots, width),
         qpos.reshape(s_slots, width) if causal else None,
         max_pages, page_size, window=window, ring=ring)
-    # a column outside the slot's live range names the nearest live column's
-    # page: the block a grid step out there asks for is already resident, so
-    # the pipeline issues no copy for it (clamped here, not in the index
-    # map: its scalar work is paid once a column, dead ones too)
-    col = jnp.clip(jnp.arange(max_pages, dtype=jnp.int32)[None, :],
-                   live[:, :1], jnp.maximum(live[:, 1:] - 1, live[:, :1]))
-    pt = jnp.take_along_axis(page_table.astype(jnp.int32), col, axis=1)
-    # page table, lengths, query positions, live columns (+ the ring's
-    # block numbers): traced data all, so no length ever retraces
-    scalars = (pt.ravel(), sl, qpos, live.ravel())
+    # the walk's schedule, lengths, query positions (+ the ring's block
+    # numbers): traced data all, so no length ever retraces
+    page_of, cell_of, starts = walk_schedule(
+        live, page_table.astype(jnp.int32))
+    scalars = (page_of, cell_of, starts, sl, qpos)
     if ring:
         scalars += (ring_blocks(sl, max_pages, page_size).ravel(),)
 
-    def q_map(s, j, *_):
-        return (s, 0, 0)
+    def q_map(t, page_of, cell_of, *_):
+        return (cell_of[t] >> _CELL_BITS, 0, 0)
 
-    def page_map(s, j, pt, *_):
-        return (pt[s * max_pages + j], 0, 0, 0)
+    def page_map(t, page_of, *_):
+        return (page_of[t], 0, 0, 0)
 
     spec = dict(
-        # the page axis ends at the longest slot's last live column: a dead
-        # column beyond it would still cost its index maps (0.1 us each)
-        grid=(s_slots, jnp.maximum(live[:, 1].max(), 1)),
+        # one step a live (slot, column) pair (a launch with none still
+        # runs one step: nothing it leaves behind is kept, see below)
+        grid=(jnp.maximum(starts[-1], 1),),
         in_specs=[
             pl.BlockSpec((1, r_pad, d), q_map),
             pl.BlockSpec((1, page_size, n_kv, d), page_map),
@@ -547,18 +592,23 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
             pltpu.VMEM((r_pad, d), jnp.float32),
         ],
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=5, **spec) \
-        if ring else pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=4,
+    grid_spec = pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=6, **spec) \
+        if ring else pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=5,
                                                   **spec)
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((s_slots, r_pad, d), q.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="mx_paged_attn",  # what a device trace is searched for
     )(*scalars, qk, k_pool, v_pool)
+    # a slot with no live column was never visited: its rows are whatever
+    # the buffer held (one step an empty slot, writing zeros, was timed
+    # beside this on the chip: 10.6 us a launch more at OPT's widths with
+    # fifteen slots empty, 11.1 at Trinity's — PERF.md §6, PR 40)
+    out = jnp.where((live[:, 1] > live[:, 0])[:, None, None], out, 0)
     out = out[:, :rows, :d_q].reshape(s_slots, n_kv, width, groups, d_q)
     return out.transpose(0, 2, 1, 3, 4).reshape(s_slots, width, n_heads,
                                                 d_q)
@@ -570,9 +620,10 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, seq_lens,
     """Ragged paged-attention for decode: one query token per slot.
 
     q: (S, H, D); k_pool/v_pool: (P, page_size, KH, D) static pools;
-    page_table: (S, max_pages) int32 page ids (the walk ends at a
-    sequence's last page, so later entries are not read — but a slot with
-    no token still names its column 0: keep every entry a valid page id);
+    page_table: (S, max_pages) int32 page ids (the walk visits a
+    sequence's live pages, so later entries are not read — but a launch
+    with no live page at all still names one entry: keep every entry a
+    valid page id);
     seq_lens: (S,) int32 tokens live per slot (0 = inactive slot, output
     row is zeros).
     q_pos: optional (S,) int32 — when given, the causal bound: positions

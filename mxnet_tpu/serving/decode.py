@@ -170,6 +170,12 @@ _T_KV_COLS_GRID = telemetry.counter(
     "columns x slots x layers (live / grid = the share of a table a tick "
     "fetches and multiplies)",
     labels=("server", "group"))
+_T_KV_COLS_WALKED = telemetry.counter(
+    "mxnet_decode_kv_cols_walked_total",
+    "grid steps those walks' launches ran: a launch visits each slot's "
+    "live columns and no other's (at least one step a launch), so walked "
+    "- live = steps that fetched and multiplied nothing",
+    labels=("server", "group"))
 _T_OVERLAPPED = telemetry.counter(
     "mxnet_decode_steps_overlapped_total",
     "decode steps dispatched while the step before them was still "
@@ -501,6 +507,7 @@ class DecodeEngine:
                 if self._grouped else (("full", self._cache),)))
         self._kv_cols_live = 0
         self._kv_cols_grid = 0
+        self._kv_cols_walked = 0
         # a model with experts returns its load counters beside the tokens
         moe_shape = getattr(model, "moe_counters", None)
         self._moe_rows = (np.zeros(moe_shape, np.int64)
@@ -1113,9 +1120,11 @@ class DecodeEngine:
                 # steps dispatched while the one before was un-fetched
                 "steps_overlapped": self._steps_overlapped,
                 # page-table columns the ticks' attention walks ran, of
-                # the tables' columns x slots x layers (the share that ran)
+                # the tables' columns x slots x layers (the share that
+                # ran), and the grid steps their launches took to run them
                 "kv_cols_live": self._kv_cols_live,
                 "kv_cols_grid": self._kv_cols_grid,
+                "kv_cols_walked": self._kv_cols_walked,
                 "kv_pool_leaves": self._kv_pool_leaves,
                 "decode_step_temp_bytes": self._step_temp_bytes,
                 # per program, per part, the instructions the compiled
@@ -2251,22 +2260,31 @@ class DecodeEngine:
         ``ops.pallas_kernels.live_columns`` for a tick: the columns up to
         the slot's longest row, in a ring at most all of them) of the
         tables' columns x slots (``kv_cols_grid``), over the layers of
-        every cache group. ``row_lens``: the step operand's per-row
-        lengths."""
+        every cache group, and the grid steps the launches took
+        (``kv_cols_walked``, the extent of
+        ``ops.pallas_kernels.walk_schedule``: each slot's own live columns,
+        one step at the least a launch). ``row_lens``: the step operand's
+        per-row lengths."""
         cols = -(-row_lens.reshape(self.num_slots, -1).max(axis=1)
                  // self._cache.page_size)
-        live = grid = 0
+        live = grid = walked = 0
         for group, columns, layers in self._walk_groups:
-            n_live = layers * sum(min(int(c), columns) for c in cols)
+            launch = sum(min(int(c), columns) for c in cols)
+            n_live = layers * launch
             n_grid = layers * self.num_slots * columns
+            n_walked = layers * max(launch, 1)
             _T_KV_COLS_LIVE.inc(n_live, server=self._name, group=group)
             _T_KV_COLS_GRID.inc(n_grid, server=self._name, group=group)
+            _T_KV_COLS_WALKED.inc(n_walked, server=self._name, group=group)
             live += n_live
             grid += n_grid
+            walked += n_walked
         with self._cv:      # stats() reads them from caller threads
             self._kv_cols_live += live
             self._kv_cols_grid += grid
+            self._kv_cols_walked += walked
         return {"kv_cols_live": live, "kv_cols_grid": grid,
+                "kv_cols_walked": walked,
                 "kv_pool_leaves": self._kv_pool_leaves}
 
     def _layer_args(self, counters, live) -> dict:

@@ -317,7 +317,7 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
             "kv_rows_full", "kv_rows_window", "kv_window_pages",
             "kv_window_capacity"}
     walk = {"kv_cols_live", "kv_cols_grid",     # a decode tick's alone
-            "kv_pool_leaves"}
+            "kv_cols_walked", "kv_pool_leaves"}
     with _engine(tiny, num_slots=2) as eng:
         eng.warmup()
         opts = jax.profiler.ProfileOptions()

@@ -209,3 +209,176 @@ def test_precise_agrees_with_the_float32_reference(precise, tol):
         want = pk.paged_attention_reference(q, k_pool, v_pool, pt, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol,
                                rtol=0)
+
+
+# ---------------------------------------------------------------------------
+# the flat walk: one grid step a live (slot, column) pair (PR 40)
+# ---------------------------------------------------------------------------
+
+FLAT_KINDS = ["decode", "verify", "chunk", "ring", "window"]
+# tokens a slot; "full": as many as the table holds (a ring: wrapped twice)
+FLAT_FILLS = {
+    "empty_first": [0, 5, 20, 37, 48],
+    "empty_middle": [5, 20, 0, 37, 48],
+    "empty_last": [5, 20, 37, 48, 0],
+    "all_empty": [0, 0, 0, 0, 0],
+    "one_full_beside_empties": [0, 0, "full", 0, 0],
+}
+
+
+def _flat_launch(kind, tokens):
+    """One of ``_paged_call``'s five launches over 5 slots holding
+    ``tokens`` (the chunk: 5 rows of ONE sequence, a row real where its
+    entry is not 0): ``run(pt)`` the kernel in interpret mode, ``ref(pt)``
+    its dense oracle, and what the launch hands ``live_columns``."""
+    rng = np.random.RandomState(40)
+    ps, h, kh, d = PAGE, 4, 2, 8
+    cols, window, ring = (5, 32, True) if kind == "ring" else \
+        (6, 16 if kind == "window" else 0, False)
+    full = 2 * cols * ps + 11 if ring else cols * ps
+    n = np.asarray([full if t == "full" else t for t in tokens], np.int32)
+    s = n.size
+    qp = None
+    if kind == "verify":        # W = 3: a chain of rows, the last padded
+        sl = np.stack([np.maximum(n - 1, 0), n, np.zeros_like(n)], axis=1)
+    elif kind == "chunk":
+        start = cols * ps - s if "full" in tokens else ps + 3
+        qp = (start + np.arange(s, dtype=np.int32))[:, None]
+        sl = np.where(n[:, None] > 0, qp + 1, 0).astype(np.int32)
+    else:
+        sl = n[:, None]
+    w = sl.shape[1]
+    pool = 2 + s * cols         # page 0 the null page, the last one NaN
+    q = jnp.asarray(rng.randn(s, w, h, d).astype(np.float32))
+    kp = rng.randn(pool, ps, kh, d).astype(np.float32)
+    vp = rng.randn(pool, ps, kh, d).astype(np.float32)
+    kp[-1] = vp[-1] = np.nan
+    kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+    pt = 1 + rng.permutation(s * cols).reshape(s, cols).astype(np.int32)
+    if kind == "chunk":
+        pt[:] = pt[0]
+    flat = jnp.asarray(sl.ravel())
+    qflat = None if qp is None else jnp.asarray(qp.ravel())
+
+    def run(table):
+        return pk._paged_call(q, kp, vp, jnp.asarray(table), flat, qflat,
+                              None, True, "test", window=window, ring=ring)
+
+    def ref(table):
+        table = jnp.asarray(table)
+        if kind == "verify":
+            out = pk.paged_spec_attention_reference(
+                q.reshape(s * w, h, d), kp, vp, table, flat)
+        elif kind == "ring":
+            out = pk.paged_window_attention_reference(
+                q[:, 0], kp, vp, table, flat, window)
+        elif kind == "window":
+            pos = np.arange(cols * ps)[None, :]
+            out = pk._dense_paged(
+                q[:, 0], kp, vp, table,
+                jnp.asarray((pos < sl) & (pos >= sl - window)), None)
+        else:
+            out = pk.paged_attention_reference(q[:, 0], kp, vp, table, flat,
+                                               q_pos=qflat)
+        return out.reshape(s, w, h, d)
+
+    live = np.array(pk.live_columns(
+        jnp.asarray(sl), None if qp is None else jnp.asarray(qp), cols, ps,
+        window=window, ring=ring))
+    return dict(run=run, ref=ref, pt=pt, sl=sl, live=live, cols=cols,
+                nan_page=pool - 1)
+
+
+def _check_schedule(live, pt):
+    """``walk_schedule`` against the walk written out by hand: every live
+    (slot, column) once, slot-major and ascending."""
+    s, cols = pt.shape
+    page_of, cell_of, starts = (np.asarray(x) for x in pk.walk_schedule(
+        jnp.asarray(live), jnp.asarray(pt)))
+    want = [(slot, col) for slot in range(s)
+            for col in range(live[slot, 0], live[slot, 1])]
+    extent = int(starts[-1])
+    assert extent == len(want)
+    assert page_of.shape == cell_of.shape == (s * cols + 1,)
+    got = list(zip(cell_of[:extent] >> pk._CELL_BITS,
+                   cell_of[:extent] & pk._CELL_MASK))
+    assert got == want
+    assert [pt[c] for c in want] == list(page_of[:extent])
+    assert list(starts) == [sum(1 for c in want if c[0] < slot)
+                            for slot in range(s + 1)]
+    # past the extent every entry repeats the last step's (no new copy);
+    # a walk of nothing names a cell and a page of the table all the same
+    last = max(extent - 1, 0)
+    assert (cell_of[last:] == cell_of[last]).all()
+    assert (page_of[last:] == page_of[last]).all()
+    assert 0 <= cell_of[last] >> pk._CELL_BITS < s
+    assert 0 <= cell_of[last] & pk._CELL_MASK < cols
+    assert page_of[last] in pt
+    return extent
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("kind", FLAT_KINDS)
+def test_walk_schedule_is_the_live_pairs_slot_major_and_ascending(kind,
+                                                                  seed):
+    """Seeded ragged lengths, an empty slot among them: the schedule is the
+    brute-force walk, and its extent is what the engine counts as
+    ``kv_cols_walked`` a layer (each slot's own columns up to its longest
+    row; where no window cuts columns off the front)."""
+    rng = np.random.RandomState(400 + seed)
+    tokens = [int(t) for t in rng.randint(1, 110 if kind == "ring" else 49,
+                                          5)]
+    tokens[rng.randint(5)] = 0
+    launch = _flat_launch(kind, tokens)
+    extent = _check_schedule(launch["live"], launch["pt"])
+    if kind in ("decode", "verify", "ring"):
+        longest = launch["sl"].max(axis=1)
+        assert extent == sum(min(-(-int(n) // PAGE), launch["cols"])
+                             for n in longest)
+
+
+@pytest.mark.parametrize("kind", FLAT_KINDS)
+def test_walk_schedule_with_every_slot_full_stays_in_bounds(kind):
+    """Every column of every slot live: the walk is ``S * max_pages`` steps
+    and the entry behind its last one — whose index maps the pipeline
+    evaluates — exists and names the last step's block again."""
+    launch = _flat_launch(kind, ["full"] * 5)
+    if kind == "window":        # a window cuts columns off the front
+        launch["live"][:, 0] = 0
+    elif kind == "chunk":       # rows of one sequence: the last sees it all
+        launch["live"][:] = [0, launch["cols"]]
+    extent = _check_schedule(launch["live"], launch["pt"])
+    assert extent == launch["pt"].size
+
+
+@pytest.mark.parametrize("fill", sorted(FLAT_FILLS))
+@pytest.mark.parametrize("kind", FLAT_KINDS)
+def test_flat_walk_matches_the_dense_oracle_around_empty_slots(kind, fill):
+    launch = _flat_launch(kind, FLAT_FILLS[fill])
+    _check_schedule(launch["live"], launch["pt"])
+    got = np.asarray(launch["run"](launch["pt"]))
+    np.testing.assert_allclose(got, np.asarray(launch["ref"](launch["pt"])),
+                               atol=2e-5, rtol=2e-5)
+    assert not got[~(launch["sl"] > 0)].any()    # a row that sees nothing
+    if fill != "all_empty":
+        assert np.abs(got).sum() > 0
+
+
+@pytest.mark.parametrize("fill", sorted(FLAT_FILLS))
+@pytest.mark.parametrize("kind", FLAT_KINDS)
+def test_flat_walk_never_reads_a_column_it_does_not_walk(kind, fill):
+    """Every column outside a slot's ``[c0, c1)`` — every column of an
+    empty slot — points at a page of NaN: no step fetches or multiplies it
+    (a masked product would read ``0 * NaN``), so the output is finite and
+    equals the oracle's over the table whose un-walked columns hold the null
+    page (PR 28's test, for the walk that visits live pairs alone)."""
+    launch = _flat_launch(kind, FLAT_FILLS[fill])
+    col = np.arange(launch["cols"])[None, :]
+    dead = (col < launch["live"][:, :1]) | (col >= launch["live"][:, 1:])
+    assert dead.any()
+    got = np.asarray(launch["run"](
+        np.where(dead, launch["nan_page"], launch["pt"])))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(
+        got, np.asarray(launch["ref"](np.where(dead, 0, launch["pt"]))),
+        atol=2e-5, rtol=2e-5)

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 import mxnet_tpu as mx
 from mxnet_tpu import gluon, nd, serving, telemetry, trainplane
@@ -391,11 +392,12 @@ def _walk_model(kind):
 
 @pytest.mark.parametrize("kind", ["plain", "grouped"])
 def test_decode_commit_span_carries_the_page_walk(kind, tmp_path):
-    """``kv_cols_live`` / ``kv_cols_grid`` on every tick of every model: the
-    table columns the paged-attention walk ran (what
-    ``pallas_kernels.live_columns`` hands the kernel) of the tables'
-    columns x slots, over the layers; the two counters and ``stats()`` add
-    up to the spans."""
+    """``kv_cols_live`` / ``kv_cols_grid`` / ``kv_cols_walked`` on every
+    tick of every model: the table columns the paged-attention walk ran
+    (what ``pallas_kernels.live_columns`` hands the kernel) of the tables'
+    columns x slots, over the layers, and the grid steps the launches took
+    (the extent of ``pallas_kernels.walk_schedule``: the idle slot is not
+    walked); the three counters and ``stats()`` add up to the spans."""
     from mxnet_tpu.ops import pallas_kernels as pk
     from mxnet_tpu.serving import decode as decode_mod
 
@@ -419,22 +421,29 @@ def test_decode_commit_span_carries_the_page_walk(kind, tmp_path):
     for i, args in enumerate(commits):
         # one sequence of 41 + i tokens, the other slot idle
         lens = np.asarray([[41 + i], [0]], np.int32)
-        want = sum(
-            layers * int(np.diff(np.asarray(pk.live_columns(
-                lens, None, cols, 8, ring=ring))).sum())
-            for (cols, layers), ring in zip(tables, (False, True)))
+        want = walked = 0
+        for (cols, layers), ring in zip(tables, (False, True)):
+            live = pk.live_columns(lens, None, cols, 8, ring=ring)
+            want += layers * int(np.diff(np.asarray(live)).sum())
+            walked += layers * int(pk.walk_schedule(
+                live, jnp.zeros((2, cols), jnp.int32))[2][-1])
         assert args["kv_cols_live"] == want
         assert args["kv_cols_grid"] == grid
-        assert 0 < args["kv_cols_live"] <= args["kv_cols_grid"]
+        assert args["kv_cols_walked"] == walked
+        assert 0 < args["kv_cols_live"] <= args["kv_cols_walked"] \
+            <= args["kv_cols_grid"]
         # the arrays the step was handed as pools: K and V, one a layer
         assert args["kv_pool_leaves"] == 2 * sum(n for _c, n in tables)
     for key, counter in (("kv_cols_live", decode_mod._T_KV_COLS_LIVE),
-                         ("kv_cols_grid", decode_mod._T_KV_COLS_GRID)):
+                         ("kv_cols_grid", decode_mod._T_KV_COLS_GRID),
+                         ("kv_cols_walked", decode_mod._T_KV_COLS_WALKED)):
         total = sum(a[key] for a in commits)
         assert stats[key] - before[key] == total
         assert sum(counter.value(server=name, group=g)
                    for g in ("full", "window")[:len(tables)]) == stats[key]
     assert "mxnet_decode_kv_cols_live_total" in telemetry.render_prometheus()
+    assert "mxnet_decode_kv_cols_walked_total" in \
+        telemetry.render_prometheus()
     assert stats["kv_pool_leaves"] == commits[0]["kv_pool_leaves"]
     # warmup() read the compiled step's temporaries (what they must stay
     # under at real widths: tests/test_chip_compile.py)

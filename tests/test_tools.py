@@ -102,3 +102,30 @@ def test_im2rec_multithread(tmp_path):
         h, img = recordio.unpack_img(r.read_idx(k))
         assert min(img.shape[:2]) == 16
     r.close()
+
+
+def test_kernel_time_rehearses_off_the_chip(tmp_path):
+    """``tools/kernel_time.py --interpret``: the control flow at tiny shapes
+    (this tree's kernel beside a second copy of its own source, agreeing bit
+    for bit, one JSON line a run and no time in it); without ``--interpret``
+    and without a TPU it refuses to measure."""
+    import json
+
+    tool = str(REPO / "tools" / "kernel_time.py")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out_file = tmp_path / "kernel_time.jsonl"
+    out = subprocess.run(
+        [sys.executable, tool, "--interpret", "--cell", "trinity", "--fill",
+         "empty15", "--out", str(out_file), "--other",
+         str(REPO / "mxnet_tpu" / "ops" / "pallas_kernels.py")],
+        capture_output=True, text=True, timeout=300, env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = [json.loads(ln) for ln in out_file.read_text().splitlines()]
+    assert [ln["side"] for ln in lines] == ["other", "this", "this", "other"]
+    assert all(ln["launches"] == 5 and ln["live_columns"] > 0
+               and "us_per_launch" not in ln for ln in lines)
+    assert [ln["bitwise_equal"] for ln in lines[1:]] == [True] * 3
+    refused = subprocess.run([sys.executable, tool, "--cell", "opt"],
+                             capture_output=True, text=True, timeout=300,
+                             env=env)
+    assert refused.returncode != 0 and "no TPU" in refused.stderr
