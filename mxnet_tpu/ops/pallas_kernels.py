@@ -830,17 +830,44 @@ def paged_window_attention(q, k_pool, v_pool, page_table, seq_lens, window,
                                             seq_lens, window, scale=scale)
 
 
+# rows of a query block and columns of a kv block of the band kernel: ONE
+# constant for the chip, found there (tools/kernel_time.py --kernel band;
+# PERF.md section 6, PR 43: 128 / 256 rows x 128 / 256 / 512 columns timed)
+_BAND_BLOCK = 256
+
+
+def _band_first(qi, block, window, maximum=jnp.maximum):
+    """The first kv block query block ``qi`` sees — the one that holds its
+    first row's oldest key; the last is its own, the diagonal. ``qi`` a
+    traced int, or a Python one with ``maximum=max``."""
+    if not window:
+        return 0 * qi
+    return maximum(qi * block - window + 1, 0) // block
+
+
+def band_blocks(tokens, rows=None, window=0, block=_BAND_BLOCK):
+    """(query block, kv block) pairs ONE kv head's launch of
+    :func:`band_attention` multiplies when ``tokens`` of its ``rows``
+    (default: all) are real — host arithmetic over the kernel's own block
+    size (the engine's ``attn_blocks_*`` span arguments; pinned against the
+    kernel's live steps in tests/test_afmoe_decoder.py)."""
+    block = min(block, _pad_up(tokens if rows is None else rows, 8))
+    return sum(qi - _band_first(qi, block, window, max) + 1
+               for qi in range(max(-(-tokens // block), 1)))
+
+
 def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                 scale, window, block, n_band, groups, precision):
+                 scale, window, block, n_band, precision):
     """One (kv head, query block, band step) cell of causal (+ window)
     prefill attention. q_ref/o_ref: (1, G, B, D) — the G query heads of the
-    kv head; k_ref/v_ref: (1, B, D) — kv block ``first + j`` of the band
-    (``first = max(qi - n_band + 1, 0)``); steps past the diagonal are not
-    computed (their index map names the diagonal block again: no new
-    copy)."""
+    kv head, multiplied as ONE (G x B, D) matrix; k_ref/v_ref: (1, B, D) —
+    kv block ``first + j`` of the band (:func:`_band_first`); steps past
+    the diagonal are not computed (their index map names the diagonal block
+    again: no new copy). m/l/acc scratch: (G x B, ...), a row a query."""
     qi = pl.program_id(1)
     j = pl.program_id(2)
-    kb = jnp.maximum(qi - (n_band - 1), 0) + j
+    kb = _band_first(qi, block, window) + j
+    groups, _, d = q_ref.shape[1:]
 
     @pl.when(j == 0)
     def _init():
@@ -850,8 +877,11 @@ def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(kb <= qi)
     def _block():
+        q = q_ref[0].astype(jnp.float32).reshape(groups * block, d)
         k = k_ref[0].astype(jnp.float32)          # (B, D)
         v = v_ref[0].astype(jnp.float32)
+        # a row's position depends on its place in its head's block only:
+        # one (B, B) mask for the G heads
         rows = qi * block + lax.broadcasted_iota(jnp.int32,
                                                  (block, block), 0)
         cols = kb * block + lax.broadcasted_iota(jnp.int32,
@@ -859,45 +889,49 @@ def _band_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         valid = cols <= rows
         if window:
             valid = jnp.logical_and(valid, cols > rows - window)
-        for g in range(groups):
-            q = q_ref[0, g].astype(jnp.float32)   # (B, D)
-            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                precision=precision,
-                                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, _NEG_BIG)
-            m_prev = m_scr[g][:, :1]
-            l_prev = l_scr[g][:, :1]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-            acc_scr[g] = acc_scr[g] * alpha + lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())), precision=precision,
-                preferred_element_type=jnp.float32)
-            m_scr[g] = jnp.broadcast_to(m_new, (block, LANES))
-            l_scr[g] = jnp.broadcast_to(l_new, (block, LANES))
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=precision,
+                            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid[None], s.reshape(groups, block, block),
+                      _NEG_BIG).reshape(groups * block, block)
+        m_prev = m_scr[:, :1]
+        l_prev = l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + p.sum(axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
     @pl.when(j == n_band - 1)
     def _finish():
-        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :, :1], 1e-30)
-                    ).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+                    ).reshape(groups, block, d).astype(o_ref.dtype)
 
 
-def band_attention(q, k, v, scale=None, window=0, block=128,
-                   interpret=None, precise=False):
+def band_attention(q, k, v, scale=None, window=0, block=_BAND_BLOCK,
+                   interpret=None, precise=False, length=None):
     """Causal prefill attention of ONE sequence with grouped queries and an
     optional sliding window, never an ``(H, T, T)`` tensor.
 
     q: (T, H, D); k/v: (T, KH, D), ``H % KH == 0``; ``window`` (static) > 0
-    masks keys at or below ``query - window``. Rows of padding at the end
-    come back finite and meaningless (a causal row never sees what follows
-    it). Grid (KH, T/B, band): a query block visits the ``window / B + 1``
-    kv blocks of its band (all blocks up to the diagonal without a window)
-    — blocks outside are not visited. ``precise`` (static): float32
-    products where the compiler's default is one bfloat16 pass over float32
-    operands. Returns (T, H, D). On a TPU the
-    kernel ``mx_prefill_attn``; elsewhere :func:`band_attention_reference`
-    unless ``interpret`` asks for the kernel."""
+    masks keys at or below ``query - window``. ``length`` (a traced int32
+    scalar, or None: every row is real): the rows that hold the prompt's
+    tokens. Query blocks of padding behind them are not launched and come
+    back ZEROS; the padding rows of the last live block come back finite
+    and meaningless (a causal row never sees what follows it). Grid (KH,
+    live query blocks, band): a query block of ``block`` rows visits the
+    kv blocks of as many columns that its band crosses (all up to the
+    diagonal without a window) — blocks outside are not visited — and
+    multiplies each once for the ``H / KH`` query heads of the kv head.
+    ``precise`` (static): float32 products where the compiler's default is
+    one bfloat16 pass over float32 operands. Returns (T, H, D). On a TPU
+    the kernel ``mx_prefill_attn``; elsewhere
+    :func:`band_attention_reference` unless ``interpret`` asks for the
+    kernel."""
     if interpret is None:
         if _interpret():
             return band_attention_reference(q, k, v, scale=scale,
@@ -911,7 +945,10 @@ def band_attention(q, k, v, scale=None, window=0, block=128,
     block = min(block, _pad_up(t, 8))
     tp = _pad_up(t, block)
     n_q = tp // block
-    n_band = n_q if not window else min(n_q, -(-window // block) + 1)
+    n_band = max(qi - _band_first(qi, block, window, max) + 1
+                 for qi in range(n_q))
+    n_live = n_q if length is None else jnp.clip(
+        -(-jnp.asarray(length, jnp.int32) // block), 1, n_q)
     pad = ((0, tp - t), (0, 0), (0, 0))
     # (T, KH, G, D) -> (KH, G, T, D); k/v -> (KH, T, D)
     qg = jnp.pad(q, pad).reshape(tp, n_kv, groups, d).transpose(1, 2, 0, 3)
@@ -922,29 +959,35 @@ def band_attention(q, k, v, scale=None, window=0, block=128,
         return (h, 0, qi, 0)
 
     def kv_map(h, qi, j):
-        first = jnp.maximum(qi - (n_band - 1), 0)
-        return (h, jnp.minimum(first + j, qi), 0)
+        return (h, jnp.minimum(_band_first(qi, block, window) + j, qi), 0)
 
     out = pl.pallas_call(
         functools.partial(_band_kernel, scale=float(scale),
                           window=int(window), block=block, n_band=n_band,
-                          groups=groups,
                           precision=lax.Precision.HIGHEST if precise
                           else None),
-        grid=(n_kv, n_q, n_band),
+        # the query blocks behind the prompt's last are not launched (a
+        # traced extent; launched and skipped they cost 0.9-1.5 ms a long
+        # prefill more: PERF.md section 6, PR 43)
+        grid=(n_kv, n_live, n_band),
         in_specs=[pl.BlockSpec((1, groups, block, d), q_map),
                   pl.BlockSpec((1, block, d), kv_map),
                   pl.BlockSpec((1, block, d), kv_map)],
         out_specs=pl.BlockSpec((1, groups, block, d), q_map),
         out_shape=jax.ShapeDtypeStruct((n_kv, groups, tp, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((groups, block, LANES), jnp.float32),
-                        pltpu.VMEM((groups, block, LANES), jnp.float32),
-                        pltpu.VMEM((groups, block, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((groups * block, LANES), jnp.float32),
+                        pltpu.VMEM((groups * block, LANES), jnp.float32),
+                        pltpu.VMEM((groups * block, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="mx_prefill_attn",  # what a device trace is searched for
     )(qg, kk, vv)
+    if length is not None:
+        # a query block that was not launched is whatever the buffer held
+        live = lax.broadcasted_iota(jnp.int32, (1, 1, tp, 1), 2) \
+            < n_live * block
+        out = jnp.where(live, out, 0)
     return out.transpose(2, 0, 1, 3).reshape(tp, n_heads, d)[:t]
 
 

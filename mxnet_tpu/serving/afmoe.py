@@ -21,7 +21,11 @@ What it declares to :class:`~mxnet_tpu.serving.DecodeEngine`:
 ``moe_counters``
     ``decode`` and ``prefill`` return the rows routed to each held expert of
     each expert layer (last column: to experts held elsewhere) behind the
-    logits.
+    logits;
+``prefill_attn_blocks``
+    the block pairs :func:`~mxnet_tpu.ops.pallas_kernels.band_attention`
+    multiplies for a prompt, over the layers: the padding of a rung behind
+    the prompt's last block is not computed.
 
 Decode attends through :func:`~mxnet_tpu.ops.pallas_kernels.paged_attention`
 (full) and :func:`~mxnet_tpu.ops.pallas_kernels.paged_window_attention`
@@ -260,7 +264,7 @@ class AfmoeDecoder(PagedDecodeModel):
         def attend(sliding, q, k, v, _kp, _vp):
             return pallas_kernels.band_attention(
                 q, k, v, scale=self.scale, window=window if sliding else 0,
-                precise=True)
+                precise=True, length=length)
 
         x, k_pool, v_pool, counters = self._forward(
             params, tokens, positions, k_pool, v_pool, write_pages,
@@ -269,6 +273,17 @@ class AfmoeDecoder(PagedDecodeModel):
             last = _mm(self._rms(x[length - 1], params["ln_f"])[None],
                        params["head"])[0]
         return (last, k_pool, v_pool) + counters
+
+    def prefill_attn_blocks(self, tokens: int, rung: int) -> int:
+        """Block pairs :meth:`prefill`'s attention launches multiply for a
+        prompt of ``tokens`` on ``rung``, a kv head, over the layers."""
+        from ..ops import pallas_kernels
+
+        n_window = len(self.kv_groups["window"])
+        return n_window * pallas_kernels.band_blocks(
+            tokens, rung, self.cfg["sliding_window"]) \
+            + (self.num_layers - n_window) * pallas_kernels.band_blocks(
+                tokens, rung)
 
     def prefill_chunk(self, params, tokens, start, length, k_pool, v_pool,
                       page_table_row, write_pages, write_offsets):

@@ -187,6 +187,13 @@ _T_PREFILL_HELD = telemetry.counter(
     "each prefill's duration times the slots that were decoding when it "
     "was launched (over the tokens decoded: what a token loses to prefills)",
     labels=("server",))
+_T_PREFILL_ATTN_BLOCKS = telemetry.counter(
+    "mxnet_decode_prefill_attn_blocks_total",
+    "(query block, kv block) pairs of the prefills' blocked attention, a kv "
+    "head, over the model's layers: kind=rung what the band holds over the "
+    "padded rung, kind=live over the prompt's real tokens — what the kernel "
+    "multiplies (a model that declares prefill_attn_blocks)",
+    labels=("server", "kind"))
 _T_STEP_TEMP = telemetry.gauge(
     "mxnet_decode_step_temp_bytes",
     "temporaries of the compiled decode step (its memory_analysis), set by "
@@ -234,7 +241,7 @@ class PagedDecodeModel:
     Attributes the engine sizes the cache from: ``num_layers``,
     ``num_heads``, ``num_kv_heads``, ``head_dim``, ``vocab_size``.
 
-    Two optional declarations (a model without them, like
+    Three optional declarations (a model without them, like
     :class:`TinyDecoder`, is served exactly as before):
 
     ``kv_groups``
@@ -252,6 +259,11 @@ class PagedDecodeModel:
         pick) rows each held expert received in each expert layer and, in
         the last column, the rows routed to experts held elsewhere. It
         rides the tick's one fetch behind the sampled tokens.
+    ``prefill_attn_blocks(tokens, rung)``
+        the (query block, kv block) pairs its blocked prefill attention
+        multiplies for a prompt of ``tokens`` padded to ``rung``, a kv head,
+        over its layers (host arithmetic): the engine writes them on the
+        ``mx.decode.prefill`` span and sums them in ``stats()``.
     """
 
     num_layers: int
@@ -555,6 +567,8 @@ class DecodeEngine:
         #: worker-confined: the rows the live trace has (None: no trace)
         self._programs_traced: Optional[List[dict]] = None
         self._prefill_held_slot_ms = 0.0
+        self._attn_blocks_rung = 0
+        self._attn_blocks_live = 0
         pool_bytes = int(sum(x.nbytes for x in pools))
         self._governor.register_bound("serving.%s.kv_pool" % name,
                                       pool_bytes)
@@ -1138,6 +1152,11 @@ class DecodeEngine:
                 # decoding slots x the prefills they waited behind, counted
                 # while telemetry or a jax.profiler trace is on
                 "prefill_held_slot_ms": self._prefill_held_slot_ms,
+                # block pairs of the prefills' attention band: over the
+                # padded rungs, and over the prompts' real tokens (what
+                # the kernel multiplies)
+                "attn_blocks_rung": self._attn_blocks_rung,
+                "attn_blocks_live": self._attn_blocks_live,
                 "prefill_buckets": list(self._ladder),
                 "prefill_chunk": self._chunk,
                 "cow_copies": self._cow_copies,
@@ -1727,6 +1746,7 @@ class DecodeEngine:
         with self._prefill_span(rung=rung) as span:
             if matched == 0:
                 tok = self._run_full_prefill(req, slot, ring=ring)
+                span.set_args(**self._attn_blocks(rung, p))
             else:
                 tok = self._run_chunk(slot, req, req.filled, p, rung)
             self._finish_prefill(req, slot, tok, span)
@@ -2286,6 +2306,22 @@ class DecodeEngine:
         return {"kv_cols_live": live, "kv_cols_grid": grid,
                 "kv_cols_walked": walked,
                 "kv_pool_leaves": self._kv_pool_leaves}
+
+    def _attn_blocks(self, rung: int, tokens: int) -> dict:
+        """Span arguments of a whole-prompt prefill of a model whose
+        attention there is blocked (it declares ``prefill_attn_blocks``):
+        the band's block pairs over the rung and over the prompt's
+        ``tokens`` — host arithmetic — and their running sums."""
+        blocks = getattr(self._model, "prefill_attn_blocks", None)
+        if blocks is None:
+            return {}
+        n_rung, n_live = blocks(rung, rung), blocks(tokens, rung)
+        _T_PREFILL_ATTN_BLOCKS.inc(n_rung, server=self._name, kind="rung")
+        _T_PREFILL_ATTN_BLOCKS.inc(n_live, server=self._name, kind="live")
+        with self._cv:      # stats() reads them from caller threads
+            self._attn_blocks_rung += n_rung
+            self._attn_blocks_live += n_live
+        return {"attn_blocks_rung": n_rung, "attn_blocks_live": n_live}
 
     def _layer_args(self, counters, live) -> dict:
         """Span arguments of a prefill or a decode tick of a model that

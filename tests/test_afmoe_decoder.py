@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from mxnet_tpu import serving, telemetry
 from mxnet_tpu.base import MXNetError
+from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.serving import afmoe_reference as ref
 from mxnet_tpu.serving import decode as decode_mod
 from mxnet_tpu.serving.kvcache import (GroupedKVCache, OutOfPagesError,
@@ -308,6 +309,25 @@ def test_a_grouped_model_is_refused_the_prefix_cache_chunks_and_drafts(tiny):
         serving.AfmoeDecoder(**dict(TINY, held_experts=[14, 4]))
 
 
+def _traced_events(tmp_path, work):
+    """(name, arguments) of the ``mx.decode.*`` spans ``work()`` writes into
+    a ``jax.profiler`` trace."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    return [(ev.name, dict(ev.stats))
+            for plane in jax.profiler.ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("mx.decode.")]
+
+
 def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
     """``mx.decode.commit`` and ``mx.decode.prefill`` carry what the
     benchmark's per-layer readers read, on the trace's own clock — on the
@@ -320,22 +340,9 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
             "kv_cols_walked", "kv_pool_leaves"}
     with _engine(tiny, num_slots=2) as eng:
         eng.warmup()
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
-        try:
-            eng.generate(_prompt(50, 5), 6, timeout=300)
-            eng.close()
-        finally:
-            jax.profiler.stop_trace()
+        events = _traced_events(tmp_path, lambda: (
+            eng.generate(_prompt(50, 5), 6, timeout=300), eng.close()))
         stats = eng.stats()
-    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
-                                     "*", "*.xplane.pb"))
-    events = [(ev.name, dict(ev.stats))
-              for plane in jax.profiler.ProfileData.from_file(path).planes
-              if plane.name.startswith("/host:")
-              for line in plane.lines for ev in line.events
-              if ev.name.startswith("mx.decode.")]
     prefill = [a for n, a in events if n == "mx.decode.prefill"]
     commits = [a for n, a in events if n == "mx.decode.commit"]
     assert len(prefill) == 1 and len(commits) == 5
@@ -348,8 +355,12 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
     assert stats["steps_overlapped"] == 4 and stats["ticks"] == 5
     assert all(set(a) == {"active", "prefilling", "queued"}
                for n, a in events if n == "mx.decode.tick" and a)
-    assert set(prefill[0]) == keys | {"rung", "held"}
+    assert set(prefill[0]) == keys | {"rung", "held", "attn_blocks_rung",
+                                      "attn_blocks_live"}
     assert prefill[0]["held"] == 0          # nobody was decoding yet
+    # 50 tokens on the rung of 64: one block of 64 rows a layer, all live
+    assert prefill[0]["attn_blocks_rung"] == 5
+    assert prefill[0]["attn_blocks_live"] == 5
     assert prefill[0]["kv_rows_full"] == 50
     assert prefill[0]["kv_rows_window"] == WINDOW
     for i, args in enumerate(commits):
@@ -360,3 +371,106 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
         assert args["kv_window_capacity"] == 10
         assert args["kv_pool_leaves"] == 2 * len(TINY["layer_types"])
         assert 0 <= args["moe_load_max"] <= args["moe_rows_held"] <= 16
+
+
+# ---------------------------------------------------------------------------
+# the prefill's blocked attention: what it multiplies, and what is counted
+# ---------------------------------------------------------------------------
+def _band_live_pairs(t, window, length, block=pk._BAND_BLOCK):
+    """(query block, kv block) pairs a kv head's launch of the band kernel
+    multiplies (interpret mode), counted from outside: with kv block ``kb``'s
+    values NaN, the query blocks that come back NaN are those that
+    multiplied it (a masked column still reads ``0 * NaN``); a block that
+    was not launched comes back zeros."""
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rng.randn(t, n, 8).astype(np.float32))
+               for n in (6, 1, 1))
+    block = min(block, t)
+    run = jax.jit(lambda vv, n: pk.band_attention(
+        q, k, vv, window=window, block=block, interpret=True, length=n))
+    pairs = 0
+    for kb in range(-(-t // block)):
+        rows = jnp.arange(t)[:, None, None] // block == kb
+        out = np.asarray(run(jnp.where(rows, jnp.nan, v),
+                             jnp.asarray(length, jnp.int32)))
+        out = np.pad(out, ((0, -t % block), (0, 0), (0, 0)))
+        pairs += int(np.isnan(out.reshape(-1, block * 6 * 8)).any(1).sum())
+    return pairs
+
+
+@pytest.mark.parametrize("length", [200, 130, 65, 17])
+@pytest.mark.parametrize("window", [0, 72])
+def test_band_blocks_counts_the_kernels_live_steps(window, length):
+    """``band_blocks`` — the arithmetic behind ``attn_blocks_live`` — against
+    the kernel itself, 200 rows in thirteen blocks of 16, a window that cuts
+    one."""
+    assert _band_live_pairs(200, window, length, block=16) \
+        == pk.band_blocks(length, 200, window, block=16)
+    assert pk.band_blocks(200, 200, window, block=16) \
+        == pk.band_blocks(200, window=window, block=16) \
+        >= pk.band_blocks(length, 200, window, block=16)
+
+
+def test_the_spans_block_pairs_are_the_kernels_live_steps(tiny, tmp_path):
+    """A prompt of 200 tokens on the rung of 512: ``attn_blocks_live`` of
+    its ``mx.decode.prefill`` span is the live steps of the five launches
+    (four over the window, one over every key), ``attn_blocks_rung`` what
+    the whole rung takes; ``stats()`` and the Prometheus counter carry the
+    same pair, summed over the engine's prefills since it started."""
+    model, _params = tiny
+    with _engine(tiny, num_slots=2, max_seq_len=640,
+                 prefill_buckets=(64, 512)) as eng:
+        eng.warmup()
+        before = eng.stats()
+        events = _traced_events(tmp_path, lambda: (
+            eng.generate(_prompt(200, 1), 3, timeout=300),
+            eng.generate(_prompt(40, 2), 3, timeout=300), eng.close()))
+        stats = eng.stats()
+    live = 4 * _band_live_pairs(512, WINDOW, 200) \
+        + _band_live_pairs(512, 0, 200)
+    rung = 4 * _band_live_pairs(512, WINDOW, 512) \
+        + _band_live_pairs(512, 0, 512)
+    assert (live, rung) == (5, 15)
+    assert [(a["rung"], a["attn_blocks_live"], a["attn_blocks_rung"])
+            for n, a in events if n == "mx.decode.prefill"] \
+        == [(512, live, rung), (64, 5, 5)]
+    assert model.prefill_attn_blocks(200, 512) == live
+    for kind, n in (("live", live + 5), ("rung", rung + 5)):
+        key = "attn_blocks_" + kind
+        assert stats[key] - before[key] == n
+        assert decode_mod._T_PREFILL_ATTN_BLOCKS.value(
+            server=eng.name, kind=kind) == stats[key]
+    assert "mxnet_decode_prefill_attn_blocks_total" \
+        in telemetry.render_prometheus()
+
+
+def test_engine_serves_the_same_tokens_with_the_band_kernel_on_the_path(
+        tiny, monkeypatch):
+    """Off the TPU ``band_attention`` is its dense reference; with the
+    kernel itself (interpret mode, the prompt's traced ``length``, the
+    padding's query blocks zeros) under ``AfmoeDecoder.prefill`` the engine
+    serves the tokens it serves without, each the reference's best."""
+    model, params = tiny
+    sizes = [(5, 6), (40, 8), (70, 8), (100, 6)]
+    prompts = [_prompt(n, i) for i, (n, _m) in enumerate(sizes)]
+
+    def serve():
+        with _engine(tiny) as eng:
+            eng.warmup()
+            return [eng.generate(p, m, timeout=300)
+                    for p, (_n, m) in zip(prompts, sizes)]
+
+    plain = serve()
+    calls = []
+    real = pk.band_attention
+
+    def through_the_kernel(q, k, v, **kw):
+        calls.append(kw["length"])
+        return real(q, k, v, block=16, interpret=True, **kw)
+
+    monkeypatch.setattr(pk, "band_attention", through_the_kernel)
+    served = serve()
+    assert calls and all(n is not None for n in calls)
+    for prompt, got, want in zip(prompts, served, plain):
+        assert list(got) == list(want)
+        assert _gap_sd(model, params, prompt, got) <= GAP_TOL

@@ -137,12 +137,21 @@ def _case(name, spec, dtype):
             (spec((CHUNK, 32, 64), dtype), pool, pool,
              spec((CHUNK, 128), jnp.int32), spec((CHUNK,), jnp.int32),
              spec((CHUNK,), jnp.int32)))
-    if name in ("band_prefill_8192", "band_prefill_8192_window"):
+    # Trinity's prefill (48 / 8 heads x 128, float32 products) with the
+    # prompt's traced length: the largest rung over every key and over the
+    # window, the smallest rung, and a prompt of ONE token
+    if name.startswith("band_prefill_"):
+        rung = 512 if "512" in name else 8192
         window = 4096 if name.endswith("window") else 0
-        kv = spec((8192, 8, 128), dtype)
-        return (lambda q, k, v: pk.band_attention(
-            q, k, v, window=window, interpret=False),
-            (spec((8192, 48, 128), dtype), kv, kv))
+        kv = spec((rung, 8, 128), dtype)
+        operands = (spec((rung, 48, 128), dtype), kv, kv)
+        if name.endswith("length_1"):
+            return (lambda q, k, v: pk.band_attention(
+                q, k, v, window=window, interpret=False, precise=True,
+                length=jnp.asarray(1, jnp.int32)), operands)
+        return (lambda q, k, v, n: pk.band_attention(
+            q, k, v, window=window, interpret=False, precise=True, length=n),
+            operands + (spec((), jnp.int32),))
     if name in ("moe_gmm_decode", "moe_gmm_prefill"):
         rows = 64 if name.endswith("decode") else 8192 * 4
         return (lambda x, w, g: moe.grouped_matmul(x, w, g, interpret=False),
@@ -169,6 +178,8 @@ def _case(name, spec, dtype):
                                   "paged_window_decode_trinity",
                                   "band_prefill_8192",
                                   "band_prefill_8192_window",
+                                  "band_prefill_512",
+                                  "band_prefill_8192_length_1",
                                   "moe_gmm_decode", "moe_gmm_prefill"])
 def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
                                monkeypatch):
@@ -190,6 +201,7 @@ def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
     ("paged_prefill_chunk", "mx_paged_attn"),
     ("paged_decode_opt1p3b", "mx_paged_attn"),
     ("band_prefill_8192_window", "mx_prefill_attn"),
+    ("band_prefill_512", "mx_prefill_attn"),
     ("moe_gmm_decode", "mx_moe_gmm"),
 ])
 def test_kernel_keeps_its_name_in_the_compiled_program(

@@ -1359,20 +1359,68 @@ def test_bounded_walk_never_reads_a_dead_column(kind, n):
         atol=2e-5, rtol=2e-5)
 
 
+def _band_operands(t, h, kh, d=16, seed=9):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(t, n, d).astype(np.float32))
+                 for n in (h, kh, kh))
+
+
 @pytest.mark.parametrize("window", [0, 32, 40])
 @pytest.mark.parametrize("h,kh", [(4, 4), (12, 2)])
 def test_band_attention_kernel_matches_dense_oracle(window, h, kh):
     """Interpret mode, 16-row blocks over 100 rows (padded to 112): causal,
     a window of whole blocks and one that cuts a block."""
-    rng = np.random.RandomState(9)
-    t, d = 100, 16
-    q = jnp.asarray(rng.randn(t, h, d).astype(np.float32))
-    k = jnp.asarray(rng.randn(t, kh, d).astype(np.float32))
-    v = jnp.asarray(rng.randn(t, kh, d).astype(np.float32))
+    q, k, v = _band_operands(100, h, kh)
     got = pk.band_attention(q, k, v, window=window, block=16,
                             interpret=True)
     want = pk.band_attention_reference(q, k, v, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("length", [5, 48, 49, 100])
+@pytest.mark.parametrize("window", [0, 32, 40])
+@pytest.mark.parametrize("h,kh", [(12, 2), (6, 1)])
+def test_band_attention_kernel_stops_at_the_prompts_last_block(
+        window, h, kh, length):
+    """``length`` (traced) inside the first block, on a block's boundary,
+    one past it and the whole sequence: the prompt's rows are the dense
+    oracle's and, bit for bit, those of the call without ``length``; the
+    query blocks behind the prompt's last are not launched and come back
+    zeros; the padding rows between are finite."""
+    t, block = 100, 16
+    q, k, v = _band_operands(t, h, kh)
+    got = np.asarray(jax.jit(lambda n: pk.band_attention(
+        q, k, v, window=window, block=block, interpret=True, length=n))(
+            jnp.asarray(length, jnp.int32)))
+    whole = np.asarray(pk.band_attention(q, k, v, window=window,
+                                         block=block, interpret=True))
+    want = np.asarray(pk.band_attention_reference(q, k, v, window=window))
+    np.testing.assert_allclose(got[:length], want[:length], atol=2e-5)
+    np.testing.assert_array_equal(got[:length], whole[:length])
+    behind = -(-length // block) * block
+    assert np.isfinite(got[length:behind]).all()
+    assert not got[behind:].any()
+
+
+@pytest.mark.parametrize("length", [None, 150, 17])
+@pytest.mark.parametrize("window", [0, 72])
+def test_band_attention_kernel_at_its_own_block_size(window, length):
+    """The kept block (256 rows a query block, 256 columns a kv block) over
+    600 rows: three blocks, a window that cuts one. Keys and values behind
+    the prompt's last block are NaN: a block the kernel fetched and
+    multiplied for a live row would show (``0 * NaN``)."""
+    t, block = 600, pk._BAND_BLOCK
+    assert t > 2 * block
+    q, k, v = _band_operands(t, 12, 2)
+    n = t if length is None else length
+    dead = jnp.arange(t)[:, None, None] >= -(-n // block) * block
+    got = np.asarray(pk.band_attention(
+        q, jnp.where(dead, jnp.nan, k), jnp.where(dead, jnp.nan, v),
+        window=window, interpret=True,
+        length=None if length is None else jnp.asarray(length, jnp.int32)))
+    want = np.asarray(pk.band_attention_reference(q, k, v, window=window))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[:n], want[:n], atol=2e-5)
 
 
 def test_a_model_without_kv_groups_keeps_its_one_group_and_its_operands(tiny):

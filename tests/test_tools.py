@@ -129,3 +129,33 @@ def test_kernel_time_rehearses_off_the_chip(tmp_path):
                              capture_output=True, text=True, timeout=300,
                              env=env)
     assert refused.returncode != 0 and "no TPU" in refused.stderr
+
+
+def test_kernel_time_rehearses_the_band_kernel_off_the_chip(tmp_path):
+    """``--kernel band --interpret``: a prefill's chain of launches at tiny
+    shapes over one rung and three real lengths, this tree's kernel beside a
+    second copy of its own source (gap 0.0) and beside itself at other block
+    sizes (the same mathematics in another order of sums), no time in a
+    line."""
+    import json
+
+    tool = str(REPO / "tools" / "kernel_time.py")
+    out_file = tmp_path / "kernel_time.jsonl"
+    out = subprocess.run(
+        [sys.executable, tool, "--kernel", "band", "--interpret", "--out",
+         str(out_file), "--band-blocks", "16", "--other",
+         str(REPO / "mxnet_tpu" / "ops" / "pallas_kernels.py")],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-2000:]
+    lines = [json.loads(ln) for ln in out_file.read_text().splitlines()]
+    sides = ["other", "this", "this", "other", "this@16"]
+    assert [ln["side"] for ln in lines] == sides * 3
+    assert [ln["real"] for ln in lines[::5]] == [64, 47, 33]
+    assert all(ln["kernel"] == "band" and ln["rung"] == 64
+               and "ms_per_chain" not in ln for ln in lines)
+    for ln in lines:
+        if ln["side"] == "this":
+            assert ln["max_abs_gap"] == 0.0
+        elif ln["side"] != "other":
+            assert 0.0 < ln["max_abs_gap"] < 1e-5

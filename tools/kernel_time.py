@@ -1,6 +1,7 @@
-"""Time ``_paged_kernel`` ALONE on the chip, at the shapes of both decode
-cells, this tree's beside another ``pallas_kernels.py`` (the parent's, or a
-variant of this one) in ONE process — what PRs 28, 34 and 40 decided by.
+"""Time ``_paged_kernel`` — or, with ``--kernel band``, the prefill's
+``_band_kernel`` — ALONE on the chip, at the shapes of the decode cells,
+this tree's beside another ``pallas_kernels.py`` (the parent's, or a variant
+of this one) in ONE process — what PRs 28, 34, 40 and 43 decided by.
 
 A tick's launches are chained in one jitted function (launch ``i + 1`` takes
 launch ``i``'s output as its queries, so they run in order as the step's
@@ -17,6 +18,17 @@ Occupancies (``--fill``): ``cell`` what the benchmark's traffic leaves live
 (one slot of 16 at 304 tokens on OPT; two at 2,640 and 1,584 on Trinity),
 ``empty15`` one live slot LAST behind fifteen empty ones, ``ragged`` all
 sixteen live at seeded log-normal lengths, ``full`` every column live.
+
+``--kernel band``: a Trinity prefill's five launches of ``band_attention``
+(48 / 8 heads x 128, float32 products; four over the window of 4096, one
+over every key) chained the same way, at rungs 2048 / 4096 / 8192 with the
+prompt's real tokens the whole rung, 0.73 of it and one past its half
+(``--rungs``, ``--real``). A ``pallas_kernels.py`` whose ``band_attention``
+takes no ``length`` (PR 40's) computes the whole rung. ``--band-blocks``
+times this tree's kernel at other block sizes (rows of a query block =
+columns of a kv block) beside the one it keeps: how the constant was chosen
+(PERF.md section 6, PR 43). The two sides' largest absolute gap over the
+real rows rides each line.
 
 Run on the chip, from the repo's root:
 
@@ -140,6 +152,86 @@ def operands(cell, fill, seed, tiny):
     return (q, pools, tables, jnp.asarray(lens)), int(np.sum(live))
 
 
+BAND = dict(heads=48, kv_heads=8, head_dim=128,
+            launches=(4096, 4096, 4096, 4096, 0))   # a launch's window
+BAND_TINY = dict(heads=12, kv_heads=2, head_dim=16,
+                 launches=(32, 32, 0))
+
+
+def build_band(pk, shape, interpret, block=None):
+    """The chain of a prefill's launches over ``pk``'s kernel: ``fn(q, k, v,
+    length)`` -> the last launch's output. ``block``: another block size
+    than the kernel's own."""
+    import inspect
+
+    import jax
+
+    takes_length = "length" in inspect.signature(
+        pk.band_attention).parameters
+
+    def fn(q, k, v, length):
+        more = {"block": block} if block else {}
+        if takes_length:
+            more["length"] = length
+        for window in shape["launches"]:
+            q = pk.band_attention(q, k, v, window=window, precise=True,
+                                  interpret=interpret, **more)
+        return q
+
+    return jax.jit(fn)
+
+
+def band_main(args, device, sides):
+    import jax.numpy as jnp
+
+    shape = BAND_TINY if args.interpret else BAND
+    rungs = [64] if args.interpret else [int(r) for r in
+                                         args.rungs.split(",")]
+    chains = {name: build_band(pk, shape, args.interpret)
+              for name, pk in dict(sides).items()}
+    variants = [(name, chains[name]) for name, _pk in sides]
+    for spec in filter(None, args.band_blocks.split(",")):
+        variants.append(("this@" + spec, build_band(
+            dict(sides)["this"], shape, args.interpret, int(spec))))
+    for rung in rungs:
+        rng = np.random.RandomState(args.seed)
+        q = jnp.asarray(rng.randn(rung, shape["heads"], shape["head_dim"])
+                        .astype(np.float32))
+        k, v = (jnp.asarray(rng.randn(rung, shape["kv_heads"],
+                                      shape["head_dim"]).astype(np.float32))
+                for _ in range(2))
+        for real in args.real.split(","):
+            n = rung // 2 + 1 if real == "half+1" else \
+                int(round(float(real) * rung))
+            ops = (q, k, v, jnp.asarray(n, jnp.int32))
+            outs = {}
+            for name, fn in variants:
+                secs, out = time_chain(fn, ops,
+                                       1 if args.interpret else args.reps)
+                outs[name] = np.asarray(out)[:n]
+                line = {"kernel": "band", "rung": rung, "real": n,
+                        "side": name, "device": device.device_kind,
+                        "launches": len(shape["launches"])}
+                if not args.interpret:
+                    line["ms_per_chain"] = secs * 1e3
+                if name != "other" and "other" in outs:
+                    line["max_abs_gap"] = largest_gap(outs[name],
+                                                      outs["other"])
+                emit(line, args.out)
+
+
+def largest_gap(a, b):
+    """Largest absolute difference of two host arrays."""
+    return float(np.abs(a - b).max())
+
+
+def emit(line, path):
+    text = json.dumps(line)
+    print(text, flush=True)
+    with open(path, "a") as f:
+        f.write(text + "\n")
+
+
 def time_chain(fn, args, reps):
     """Seconds a call: ``reps`` calls enqueued back to back, one wait."""
     fn(*args).block_until_ready()
@@ -153,6 +245,7 @@ def time_chain(fn, args, reps):
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--kernel", choices=["paged", "band"], default="paged")
     ap.add_argument("--cell", choices=sorted(CELLS) + ["both"],
                     default="both")
     ap.add_argument("--fill", default="cell,empty15,ragged,full",
@@ -160,7 +253,17 @@ def main(argv=None):
     ap.add_argument("--other", default=None,
                     help="another pallas_kernels.py to time beside this "
                          "tree's (other / this / this / other)")
-    ap.add_argument("--reps", type=int, default=200)
+    ap.add_argument("--rungs", default="2048,4096,8192",
+                    help="band: the prefill rungs")
+    ap.add_argument("--real", default="1.0,0.73,half+1",
+                    help="band: the prompt's real tokens, shares of the "
+                         "rung (half+1: one past its half)")
+    ap.add_argument("--band-blocks", default="",
+                    help="band: other block sizes of this tree's kernel to "
+                         "time too, e.g. 128,512")
+    ap.add_argument("--reps", type=int, default=None,
+                    help="calls enqueued back to back (default 200; band: "
+                         "8)")
     ap.add_argument("--seed", type=int, default=40)
     ap.add_argument("--interpret", action="store_true",
                     help="tiny shapes in interpret mode (no chip: proves "
@@ -180,6 +283,10 @@ def main(argv=None):
         other = ("other", load_kernels(args.other))
         sides = [other, sides[0], sides[0], other]
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    if args.reps is None:
+        args.reps = 8 if args.kernel == "band" else 200
+    if args.kernel == "band":
+        return band_main(args, device, sides)
     cells = sorted(CELLS) if args.cell == "both" else [args.cell]
     for cell in cells:
         chains = {name: build(pk, cell, args.interpret)
@@ -201,10 +308,7 @@ def main(argv=None):
                 if len(outs) == 2:
                     line["bitwise_equal"] = bool(np.array_equal(
                         outs["this"], outs["other"], equal_nan=True))
-                text = json.dumps(line)
-                print(text, flush=True)
-                with open(args.out, "a") as f:
-                    f.write(text + "\n")
+                emit(line, args.out)
 
 
 if __name__ == "__main__":
