@@ -83,36 +83,46 @@ def _on_done(r, fut):
         r["error"] = repr(err)
 
 
+def _submit(cell, eng, r, now):
+    r["t_submit"] = now
+    try:
+        with cell.span("bench.submit"):
+            fut = eng.submit(r["prompt"], r["max_new"])
+    except Exception as exc:  # noqa: BLE001 - a refusal is a failed
+        # request, counted; the run goes on
+        r["error"] = repr(exc)
+        return
+    r["submitted"] = True
+    fut.add_done_callback(functools.partial(_on_done, r))
+
+
 def offer(cell, eng, reqs, seconds):
     """Offer ``reqs`` on their schedule for ``seconds`` from one thread;
     returns the window's clock and what was sampled inside it. Requests are
     marked in place (``t_submit``, ``submitted``, and by the engine's
-    callback ``t_done``, ``tokens`` or ``error``)."""
+    callback ``t_done``, ``tokens`` or ``error``). EVERY request of the
+    schedule is submitted: what a stall of this loop left over when the
+    window closed goes in late (``gen_late_p90_ms`` shows it), never
+    skipped. A traced run's span is the window's last seconds
+    (``trace_s`` of the traffic file, else the harness's default) and the
+    profiler is stopped here, after the loop: ``t_free`` is the instant that
+    stop returned, which the drain counts from."""
     for r in reqs:
         r.update(submitted=False, t_done=None, tokens=None, error=None)
     pages_peak, queue_mid, nxt = 0, None, 0
+    trace_s = cell.traffic.get("trace_s")
     t0 = cell.setup_done()
     end = t0 + seconds
     while True:
         now = time.perf_counter()
         if now >= end:
             break
-        cell.trace_tick(now - t0)
+        cell.trace_tick(now - t0, at_end=True, span=trace_s)
         if queue_mid is None and now - t0 >= seconds / 2.0:
             queue_mid = eng.queue_depth()
         if nxt < len(reqs) and now >= t0 + reqs[nxt]["due_s"]:
-            r = reqs[nxt]
+            _submit(cell, eng, reqs[nxt], now)
             nxt += 1
-            r["t_submit"] = now
-            try:
-                with cell.span("bench.submit"):
-                    fut = eng.submit(r["prompt"], r["max_new"])
-            except Exception as exc:  # noqa: BLE001 - a refusal is a failed
-                # request, counted; the run goes on
-                r["error"] = repr(exc)
-                continue
-            r["submitted"] = True
-            fut.add_done_callback(functools.partial(_on_done, r))
             continue
         pages_peak = max(pages_peak, eng.kvcache_stats()["pages_in_use"])
         wake = min(end, now + POLL_SECONDS)
@@ -120,10 +130,14 @@ def offer(cell, eng, reqs, seconds):
             wake = min(wake, t0 + reqs[nxt]["due_s"])
         with cell.span("bench.wait"):
             time.sleep(max(0.0, wake - time.perf_counter()))
+    for r in reqs[nxt:]:
+        _submit(cell, eng, r, time.perf_counter())
+    win = {"t0": t0, "end": end, "pages_peak": pages_peak,
+           "queue_mid": queue_mid, "queue_end": eng.queue_depth(),
+           "stats": eng.stats()}
     cell.trace_stop()
-    return {"t0": t0, "end": end, "pages_peak": pages_peak,
-            "queue_mid": queue_mid, "queue_end": eng.queue_depth(),
-            "stats": eng.stats()}
+    win["t_free"] = time.perf_counter()
+    return win
 
 
 def wait_for(reqs, deadline):
@@ -154,8 +168,10 @@ def run(cell):
                       and stats_mod.in_window(r["t_done"], t0, cell.seconds)]
     tokens_in_window = sum(len(r["tokens"]) for r in done_in_window)
 
-    # -- drain: requests still in flight get DRAIN_SECONDS more ----------
-    wait_for(reqs, end + float(cell.traffic.get("drain_s", DRAIN_SECONDS)))
+    # -- drain: requests still in flight get DRAIN_SECONDS more, counted
+    # from when the profiler's stop (a traced run's) gave the thread back
+    wait_for(reqs, win["t_free"]
+             + float(cell.traffic.get("drain_s", DRAIN_SECONDS)))
     t_cap = time.perf_counter()
     peak = cell.memory_peak(devs)
     unfinished = [r for r in reqs if r["tokens"] is None]
@@ -210,9 +226,10 @@ def run(cell):
         # K/V rows the decode ticks inside the traced span had to read: at a
         # tick a sequence holds its prompt and the tokens produced so far
         lo, hi = cell.trace_span
-        counters["traced_kv_token_reads"] = sum(
-            r["prompt"].size + idx for r in reqs
-            for t, idx in r.get("ticks", ()) if lo <= t <= hi)
+        in_span = [r["prompt"].size + idx for r in reqs
+                   for t, idx in r.get("ticks", ()) if lo <= t <= hi]
+        counters["traced_kv_token_reads"] = sum(in_span)
+        counters["traced_slot_ticks"] = len(in_span)
 
     # -- free the engine, then one plain pass per sampled request --------
     finished = [r for r in reqs if r["tokens"] is not None]

@@ -7,7 +7,8 @@ window layer with its window and every full layer without, over the peak
 bf16 rate; kernel time: the summed device time of the ``mx_prefill_attn``
 operations in the same trace (it computes the padded rung in float32, so
 the share is of what the algorithm needs, not of what the kernel does).
-A traced window in which no prompt was prefilled reads 0."""
+A traced span in which no prompt was prefilled reads nothing (a share of a
+peak is never reported as 0)."""
 import flops
 import flops_moe
 import trace_reduce
@@ -26,7 +27,7 @@ def read(run):
         run, ("mx.decode.prefill",)) or () if "kv_rows_full" in r]
     seconds, count = trace_reduce.time_matching(trace, KERNEL)
     if not rows or not count:
-        return 0.0
+        return None
     model = cell.config["model"]
     full, window, _e = flops_moe.layer_kinds(model)
     heads, dim = model["num_attention_heads"], model["head_dim"]

@@ -109,6 +109,7 @@ class Cell:
         self._tracing = False
         self.traced = False
         self.trace_span = None     # (start, stop) on perf_counter
+        self.stop_trace_s = None   # what jax.profiler.stop_trace() took
         self.t_setup_done = None
 
     # -- host spans -----------------------------------------------------
@@ -149,16 +150,22 @@ class Cell:
                                        "peak_bytes_reserved", "bytes_limit")})
         return peak
 
-    # -- profiler window: TRACE_SECONDS in the middle of the window ------
-    def trace_tick(self, elapsed):
+    # -- profiler window ------------------------------------------------
+    def trace_tick(self, elapsed, at_end=False, span=None):
         """Call from the measuring loop with the seconds since the window
-        opened; starts and stops the profiler around the window's middle."""
+        opened; starts the profiler where the traced span (``span`` seconds,
+        ``TRACE_SECONDS`` unless the driver says otherwise) opens. By default
+        the span is the window's middle and is closed here. With ``at_end``
+        it is the window's last ``span`` seconds and the driver closes it
+        with ``trace_stop()`` once its loop has ended: ``stop_trace`` writes
+        the file before it returns (seconds per hundred traced steps), and
+        nothing that is due in the window may wait for that."""
         if not self.trace or self.traced:
             return
         import jax
 
-        span = min(TRACE_SECONDS, self.seconds / 2.0)
-        lo = (self.seconds - span) / 2.0
+        span = min(float(span or TRACE_SECONDS), self.seconds / 2.0)
+        lo = self.seconds - span if at_end else (self.seconds - span) / 2.0
         if not self._tracing and elapsed >= lo:
             shutil.rmtree(self.trace_dir, ignore_errors=True)
             opts = jax.profiler.ProfileOptions()
@@ -166,17 +173,23 @@ class Cell:
             jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
             self._tracing = True
             self.trace_span = (time.perf_counter(), None)
-        elif self._tracing and elapsed >= lo + span:
+        elif self._tracing and not at_end and elapsed >= lo + span:
             self.trace_stop()
 
     def trace_stop(self):
+        """Close the traced span: its end is the instant BEFORE the profiler
+        is told to stop, so what runs while the file is written is not
+        counted among the span's ticks; ``stop_trace_s`` is what the stop
+        took."""
         if self._tracing:
             import jax
 
+            t_end = time.perf_counter()
             jax.profiler.stop_trace()
             self._tracing = False
             self.traced = True
-            self.trace_span = (self.trace_span[0], time.perf_counter())
+            self.trace_span = (self.trace_span[0], t_end)
+            self.stop_trace_s = time.perf_counter() - t_end
 
 
 def layer_metrics(manifest, run):
@@ -269,6 +282,11 @@ def main(argv=None):
         run.get("counters", {}),
         compile_cache_hits_setup=cell.cache_at_setup[0] - hits0,
         compile_cache_misses_setup=cell.cache_at_setup[1] - misses0)
+    if cell.traced:
+        run["counters"].update(
+            stop_trace_s=cell.stop_trace_s,
+            trace_span_s=cell.trace_span[1] - cell.trace_span[0],
+            trace_span_starts_at_s=cell.trace_span[0] - cell.t_setup_done)
     device = device_info(jax, run)
 
     unit_of = {m["name"]: m["unit"]
@@ -310,6 +328,9 @@ def main(argv=None):
 
     say(phase="end_to_end_traced_run", **end_to_end)
     reduced = trace_reduce.reduce(trace_reduce.find_xplane(cell.trace_dir))
+    if reduced["busy_s"] <= 0:
+        raise SystemExit("benchmark: no operation ran on the device in the "
+                         "traced window")
     run["trace"] = reduced
     full = {}
     for name, s_ns, e_ns in reduced["events"][reduced["lead_device"]]:
@@ -322,9 +343,6 @@ def main(argv=None):
     metrics = {n: {"value": v, "unit": unit_of[n]}
                for n, v in layer_metrics(manifest, run).items()
                if v is not None}
-    if reduced["busy_s"] <= 0:
-        raise SystemExit("benchmark: no operation ran on the device in the "
-                         "traced window")
     device["busy_s"] = reduced["busy_s"]
     device["window_s"] = reduced["window_s"]
     result["metrics"] = metrics

@@ -6,8 +6,9 @@
 One process, one engine: for each rate the mix's schedule is offered for
 ``--seconds``, then the engine drains completely before the next rate. The
 knee is the highest rate at which the queue at the window's end is no deeper
-than at its middle. The table goes into ``PERF.md``; 0.8 x the knee goes into
-the traffic file as ``rate_per_s``. Not part of a benchmark run.
+than at its middle. The table goes into ``PERF.md`` and the traffic file's
+``why``; a stated share of the knee goes into the traffic file as
+``rate_per_s`` (0.5 x since PR 44). Not part of a benchmark run.
 """
 from __future__ import annotations
 
@@ -74,6 +75,8 @@ def main(argv=None):
             "drain_s": time.perf_counter() - win["end"],
             "trace_sample": cell.config.get("trace_sample", 1.0)}),
             flush=True)
+    print(json.dumps({"memory_peak_bytes": cell.memory_peak(jax.devices())}),
+          flush=True)
     eng.close(drain=False)
     return 0
 

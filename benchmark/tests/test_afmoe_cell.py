@@ -218,11 +218,9 @@ def test_layer_metric_readers_on_real_spans_and_made_up_device_events(
     full = sum(c.args["kv_rows_full"] for c in commits)
     run = {"cell": cell, "trace": reduced,
            "counters": {"traced_kv_token_reads": full + 5}}
-    manifest = harness.load_json("BENCHMARK.json")
     names = ["moe_ms_per_tick", "moe_gmm_roofline",
              "paged_attn_window_roofline", "moe_load_max_over_mean",
              "kv_window_pages_peak_pct", "prefill_attn_roofline"]
-    assert [m["name"] for m in manifest["per_layer"]][-6:] == names
     got = {n: harness.load_module("layer_metrics", n).read(run)
            for n in names}
     assert got["moe_ms_per_tick"] == pytest.approx(0.03)   # 30 us a step
@@ -237,12 +235,12 @@ def test_layer_metric_readers_on_real_spans_and_made_up_device_events(
     run2.pop("_program_spans", None)
     assert harness.load_module(
         "layer_metrics", "paged_attn_window_roofline").read(run2) is None
-    # no prefill in the traced window reads 0, not nothing
+    # no prefill in the traced span reads nothing: a share is never 0
     reduced3 = dict(reduced, events={0: [e for e in events
                                          if "prefill_attn" not in e[0]]})
     run3 = dict(run, trace=reduced3)
     assert harness.load_module(
-        "layer_metrics", "prefill_attn_roofline").read(run3) == 0.0
+        "layer_metrics", "prefill_attn_roofline").read(run3) is None
     # a program without the spans (the parent): nothing, and no raise
     parent = dict(run, trace=dict(reduced))
     parent["_program_spans"] = {"spans": [], "by_name": {}, "idle_s": 0.0,

@@ -13,10 +13,40 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-@pytest.fixture(scope="module")
-def manifest():
+def _manifest():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def manifest():
+    return _manifest()
+
+
+def _reader_files():
+    return {f[:-3] for f in os.listdir(os.path.join(BENCH, "layer_metrics"))
+            if f.endswith(".py")}
+
+
+@pytest.mark.parametrize("name", sorted(
+    {m["name"] for m in _manifest()["per_layer"]} | _reader_files()))
+def test_every_per_layer_entry_has_its_reader_and_every_reader_its_entry(
+        name, manifest):
+    """The contract between the manifest and ``layer_metrics/``, whatever
+    the order of the entries: one entry, one file with one ``read(run)``,
+    in cells that report the end-to-end metric it moves."""
+    rows = [m for m in manifest["per_layer"] if m["name"] == name]
+    assert len(rows) == 1, "%s: %d per_layer entries" % (name, len(rows))
+    path = os.path.join(BENCH, "layer_metrics", name + ".py")
+    assert os.path.isfile(path), "no reader file for %s" % name
+    with open(path) as f:
+        assert re.search(r"^def read\(run\):", f.read(), re.M), path
+    (row,) = rows
+    moved = next(m for m in manifest["end_to_end"]
+                 if m["name"] == row["moves"])
+    cells = [w["name"] for w in manifest["workloads"]]
+    assert set(row.get("workloads", cells)) <= set(
+        moved.get("workloads", cells))
 
 
 def test_keys_and_limits(manifest):

@@ -19,7 +19,6 @@ import program_parts
 import program_spans
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ROOT = os.path.dirname(BENCH)
 NEW = ["step_attn_proj_ms_per_tick", "step_mlp_ms_per_tick",
        "step_route_ms_per_tick", "step_head_ms_per_tick", "step_unnamed_pct",
        "prefill_device_ms_p50", "prefill_attn_share_pct",
@@ -274,18 +273,3 @@ def test_new_readers_return_none_on_a_rehearsal(name):
            "cell": types.SimpleNamespace(trace_dir="/nonexistent",
                                          t_setup_done=0.0, config={})}
     assert _reader(name)(run) is None
-
-
-def test_manifest_names_the_new_readers_last_in_cells_that_report_them():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    rows = manifest["per_layer"][-len(NEW):]
-    assert [m["name"] for m in rows] == NEW
-    decode = next(m["workloads"] for m in manifest["end_to_end"]
-                  if m["name"] == "request_p50_ms")
-    for m in rows:
-        assert m["moves"] == "request_p50_ms"
-        assert set(m["workloads"]) <= set(decode)
-        assert m["source"] in ("device_trace", "program_span")
-        assert os.path.isfile(os.path.join(BENCH, "layer_metrics",
-                                           m["name"] + ".py"))

@@ -14,7 +14,6 @@ import pytest
 import program_spans
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ROOT = os.path.dirname(BENCH)
 NEW = ["train_shard_ms", "train_prologue_ms", "train_gather_ms",
        "train_dispatch_ms", "train_commit_ms", "train_step_self_ms",
        "idle_attributed_pct.train", "tick_host_ms", "tick_prefill_share_pct",
@@ -219,9 +218,3 @@ def test_new_readers_return_none_on_a_rehearsal(name):
            "cell": types.SimpleNamespace(trace_dir="/nonexistent",
                                          t_setup_done=0.0, config={})}
     assert _reader(name)(run) is None
-
-
-def test_manifest_names_every_new_reader_last():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        names = [m["name"] for m in json.load(f)["per_layer"]]
-    assert names[-len(NEW):] == NEW

@@ -1,5 +1,6 @@
 """The generator is a pure function of seed and parameters; the percentile
 and windowing arithmetic."""
+import collections
 import json
 import os
 
@@ -43,6 +44,75 @@ def test_every_seed_gets_the_same_sizes_in_another_order():
     assert [r["max_new"] for r in a] != [r["max_new"] for r in b]
     gaps = lambda rs: sorted(np.diff([r["due_s"] for r in rs] + [40.0]))
     assert np.allclose(gaps(a), gaps(b))
+
+
+DECODE_MIXES = [("chat_open_loop", 50272), ("short_long_open_loop", 25024)]
+
+
+def _pairs(reqs):
+    return collections.Counter((r["prompt"].size, r["max_new"]) for r in reqs)
+
+
+def _gaps(reqs, seconds):
+    return np.sort(np.diff([r["due_s"] for r in reqs] + [seconds]))
+
+
+@pytest.mark.parametrize("rehearse,seconds", [(False, 50.0), (True, 2.0)])
+@pytest.mark.parametrize("mix_name,vocab", DECODE_MIXES)
+def test_every_seed_offers_the_same_requests(mix_name, vocab, rehearse,
+                                             seconds):
+    """The multiset of (prompt length, max_new) PAIRS and of gaps is the
+    mix's; a seed orders whole requests and draws the token ids."""
+    mix = traffic.load(os.path.join(BENCH, "traffic", mix_name + ".json"),
+                       rehearse)
+    seeds = [0, 1, 3000039100, 2 ** 31 + 12345, 4294967301]
+    runs = [traffic.open_loop(mix, s, seconds, vocab) for s in seeds]
+    n = max(1, round(mix["rate_per_s"] * seconds))
+    assert all(len(r) == n for r in runs)
+    assert all(_pairs(r) == _pairs(runs[0]) for r in runs[1:])
+    assert all(np.allclose(_gaps(r, seconds), _gaps(runs[0], seconds))
+               for r in runs[1:])
+    # the marginals are the stated quantiles, each once
+    assert sorted(r["prompt"].size for r in runs[0]) == sorted(
+        traffic.length_quantiles(mix["prompt_len"], n))
+    assert sorted(r["max_new"] for r in runs[0]) == sorted(
+        traffic.length_quantiles(mix["output_len"], n))
+    if n > 3:
+        order = lambda rs: [(r["prompt"].size, r["max_new"]) for r in rs]
+        assert order(runs[1]) != order(runs[2])
+        assert not np.array_equal(runs[1][0]["prompt"][:8],
+                                  runs[2][0]["prompt"][:8]) \
+            or runs[1][0]["prompt"].size != runs[2][0]["prompt"].size
+    # still a pure function of (mix, seed, seconds)
+    again = traffic.open_loop(mix, seeds[2], seconds, vocab)
+    assert [r["due_s"] for r in again] == [r["due_s"] for r in runs[2]]
+    assert all(np.array_equal(a["prompt"], b["prompt"])
+               and a["max_new"] == b["max_new"]
+               for a, b in zip(again, runs[2]))
+
+
+@pytest.mark.parametrize("mix_name,vocab", DECODE_MIXES)
+def test_two_seeds_offer_one_cycle_from_two_starting_points(mix_name, vocab):
+    """The order of the requests and of the gaps is the mix's (``MIX_STREAM``
+    of seed 0): a seed rotates both together, so every request keeps its
+    neighbours and the gap behind it; the ids come from the seed's own
+    stream as before."""
+    mix = traffic.load(os.path.join(BENCH, "traffic", mix_name + ".json"))
+    a = traffic.open_loop(mix, 77, 50.0, vocab)
+    b = traffic.open_loop(mix, 3000039100, 50.0, vocab)
+    n = len(a)
+
+    def cycle(reqs):
+        due = [r["due_s"] for r in reqs] + [50.0]
+        return [(r["prompt"].size, r["max_new"], round(due[i + 1] - due[i], 9))
+                for i, r in enumerate(reqs)]
+
+    ca, cb = cycle(a), cycle(b)
+    shifts = [k for k in range(n) if ca[k:] + ca[:k] == cb]
+    assert shifts and shifts != [0], "not a rotation of one cycle"
+    ids = traffic._rng(77, 1)
+    first = ids.integers(1, vocab, a[0]["prompt"].size, dtype=np.int32)
+    assert np.array_equal(first, a[0]["prompt"])
 
 
 def test_lengths_follow_the_stated_distribution():

@@ -90,11 +90,17 @@ def reduce(path, top=10):
     the union of the operation intervals, per device, averaged over the
     devices that ran anything. ``ops`` sums durations by short name on the
     busiest device; ``idle_gaps`` are that device's longest gaps, each with
-    the host span that covered most of it."""
+    the host span that covered most of it. A trace in which no operation
+    ran on any device gives ``busy_s`` 0 and no events."""
     raw = load(path)
     devs = {d: ops for d, ops in raw["devices"].items() if ops}
     if not devs:
-        raise ValueError("no operation ran on a device in %s" % path)
+        # an empty traced span is a reading, not a crash: busy 0, no events;
+        # run.py ends the run with its own "no operation ran" message
+        return {"window_s": 0.0, "busy_s": 0.0, "devices": [],
+                "lead_device": None, "ops_s": {}, "device_ops": [],
+                "idle_gaps": [], "events": {}, "modules": [],
+                "host_spans": raw["host_spans"]}
     lo = min(s for ops in devs.values() for _n, s, _e in ops)
     hi = max(e for ops in devs.values() for _n, _s, e in ops)
     window_ns = hi - lo

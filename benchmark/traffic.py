@@ -5,10 +5,19 @@ this module turns it, with ``--seed`` and ``--seconds``, into the inputs the
 driver offers the system. Everything here is a pure function of
 (parameters, seed, seconds): same arguments, same inputs, bit for bit.
 
-Every seed gets the SAME multiset of sizes and of gaps between arrivals, in
-another order: the sizes are the quantiles of the stated distribution, not
-draws from it, so two seeds offer the same amount of work and differ only in
-how it is interleaved (and in the token ids / pixel values).
+Every seed of an open-loop mix gets the SAME traffic, starting elsewhere: the
+sizes are the quantiles of the stated distribution, not draws from it; which
+output length meets which prompt length, the order of the requests and the
+order of the gaps between them are functions of the mix alone (``MIX_STREAM``
+of seed 0): one cyclic schedule a mix. The seed picks where in that cycle the
+window starts (one rotation of requests and gaps together) and draws the token
+ids (pixel values). So two seeds offer the same multiset of (prompt length,
+output length) pairs and of gaps, each request behind the same neighbours, and
+differ in which of them meet the empty engine at the window's start. Seeds
+that permuted lengths and gaps independently (before PR 44), or whole requests
+and gaps (PR 44's first try), spread a median over the requests by 6-15 % of
+itself with nothing else changed, rotations of one cycle by 2-4 % at half the
+knee (near the knee nothing is steady): PERF.md section 2.
 
 Kinds:
 
@@ -18,7 +27,10 @@ Kinds:
     "sigma": s, "min": a, "max": b}`` | ``{"dist": "uniform", "min": a,
     "max": b}`` | ``{"dist": "fixed", "value": v}``;
     ``shared_prefix`` (optional): ``{"group_size": g, "tokens": n}`` — each
-    run of ``g`` consecutive requests starts with the same ``n`` tokens.
+    run of ``g`` consecutive requests starts with the same ``n`` tokens;
+    ``drain_s``, ``trace_s`` (optional, read by the driver and not here):
+    how long requests in flight are waited for once the window has closed,
+    and how long a traced run's span at the window's end is.
 ``train_feed`` a rotation of distinct host batches.
     ``batch_per_chip``; ``distinct_batches``.
 
@@ -34,6 +46,7 @@ import statistics
 import numpy as np
 
 SEED_MOD = 2 ** 32
+MIX_STREAM = 2       # of seed 0: a mix's pairing and cyclic order, no --seed
 
 
 def load(path, rehearse=False):
@@ -88,11 +101,17 @@ def open_loop(params, seed, seconds, vocab):
     ``prompt`` (int32 token ids) and ``max_new``, ordered by ``due_s``, all
     due inside ``[0, seconds)``."""
     n = max(1, int(round(params["rate_per_s"] * seconds)))
-    order = _rng(seed, 0)
-    gaps = order.permutation(gap_quantiles(n, params.get("arrival_cv", 1)))
+    # the mix's own cycle: pairs, their order and the gaps' order
+    mix = _rng(0, MIX_STREAM)
+    olens = mix.permutation(length_quantiles(params["output_len"], n))
+    pick = mix.permutation(n)
+    plens = length_quantiles(params["prompt_len"], n)[pick]
+    olens = olens[pick]
+    gaps = mix.permutation(gap_quantiles(n, params.get("arrival_cv", 1)))
+    # the seed: where in the cycle the window starts
+    start = int(_rng(seed, 0).integers(n))
+    plens, olens, gaps = (np.roll(a, -start) for a in (plens, olens, gaps))
     due = (np.cumsum(gaps) - gaps) * (seconds / gaps.sum())
-    plens = order.permutation(length_quantiles(params["prompt_len"], n))
-    olens = order.permutation(length_quantiles(params["output_len"], n))
     ids = _rng(seed, 1)
     shared = params.get("shared_prefix")
     prefix = None
