@@ -47,17 +47,32 @@ _ROW_TILE = 128   # rows of one grouped-product tile
 _COL_TILE = 256   # output columns of one tile
 
 
-def route(h, wr, expert_bias, top_k, route_norm=True, route_scale=1.0):
+def route(h, wr, expert_bias, top_k, route_norm=True, route_scale=1.0,
+          n_group=1, topk_group=1):
     """Router over all ``N`` experts. ``h``: ``(T, E)``; ``wr``: ``(E, N)``;
     ``expert_bias``: ``(N,)`` — added to the scores for SELECTION only.
     Returns ``(sel (T, top_k) int32, weights (T, top_k) float32)``:
     ``s = sigmoid(h wr)``, ``sel = top_k(s + bias)``, ``w = s[sel]``,
-    normalised to sum 1 (``route_norm``) and scaled by ``route_scale``."""
+    normalised to sum 1 (``route_norm``) and scaled by ``route_scale``.
+
+    ``n_group`` > 1: group-limited selection (DeepSeek-V3's): the experts are
+    ``n_group`` runs of ``N / n_group`` consecutive ones, a group's score is
+    the sum of its two largest ``s + bias``, and the top-k is taken over the
+    experts of the ``topk_group`` best groups only."""
     with jax.named_scope("mx_moe_route"):
         scores = jax.nn.sigmoid(jnp.dot(
             h.astype(jnp.float32), wr.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
-        _, sel = lax.top_k(scores + expert_bias.astype(jnp.float32), top_k)
+        biased = scores + expert_bias.astype(jnp.float32)
+        if n_group > 1:
+            t, n = biased.shape
+            best2, _ = lax.top_k(biased.reshape(t, n_group, n // n_group), 2)
+            _, groups = lax.top_k(best2.sum(axis=-1), topk_group)
+            kept = (groups[:, :, None] == jnp.arange(n_group)[None, None]
+                    ).any(axis=1)
+            biased = jnp.where(jnp.repeat(kept, n // n_group, axis=1),
+                               biased, -jnp.inf)
+        _, sel = lax.top_k(biased, top_k)
         w = jnp.take_along_axis(scores, sel, axis=-1)
         if route_norm:
             w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)

@@ -364,7 +364,8 @@ def _paged_kernel(pg_ref, cell_ref, st_ref, sl_ref, qp_ref, *rest, page_size,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     r_pad, d = acc_scr.shape
-    n_kv = k_ref.shape[2]
+    # (a latent pool's block has no head axis: (1, page_size, D))
+    n_kv = k_ref.shape[2] if len(k_ref.shape) == 4 else 1
     rows = width * groups           # the query rows of one kv head
     keys = page_size * n_kv
 
@@ -504,7 +505,8 @@ def walk_schedule(live, page_table):
 
 
 def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
-                interpret, who, window=0, ring=False, precise=False):
+                interpret, who, window=0, ring=False, precise=False,
+                name="mx_paged_attn"):
     """Shared launch of :func:`_paged_kernel`. q: (S, W, H, D); seq_lens
     (and q_pos, when not None): (S*W,) per query token. ``window`` (static)
     masks keys at or below ``query - window``; ``ring`` (static) reads
@@ -512,9 +514,13 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
     ``precise`` (static) asks for float32 products where the compiler's
     default is one bfloat16 pass. The pools may hold their rows wider than
     D (zero lanes: ``serving.kvcache.pool_row_width``): the query grows to
-    them with zeros and the result is cut back. Returns (S, W, H, D)."""
+    them with zeros and the result is cut back. A pool of THREE axes ``(P,
+    page_size, D)`` is a latent pool: one row a token, no head axis, read by
+    every query head. ``name``: the custom call's, what a device trace is
+    searched for. Returns (S, W, H, D)."""
     s_slots, width, n_heads, d_q = q.shape
-    _, page_size, n_kv, d = k_pool.shape
+    page_size, d = k_pool.shape[1], k_pool.shape[-1]
+    n_kv = k_pool.shape[2] if k_pool.ndim == 4 else 1
     if scale is None:
         scale = 1.0 / (d_q ** 0.5)
     if d != d_q:
@@ -574,7 +580,9 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
         return (cell_of[t] >> _CELL_BITS, 0, 0)
 
     def page_map(t, page_of, *_):
-        return (page_of[t], 0, 0, 0)
+        return (page_of[t],) + (0,) * (k_pool.ndim - 1)
+
+    page_block = (1,) + k_pool.shape[1:]
 
     spec = dict(
         # one step a live (slot, column) pair (a launch with none still
@@ -582,8 +590,8 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
         grid=(jnp.maximum(starts[-1], 1),),
         in_specs=[
             pl.BlockSpec((1, r_pad, d), q_map),
-            pl.BlockSpec((1, page_size, n_kv, d), page_map),
-            pl.BlockSpec((1, page_size, n_kv, d), page_map),
+            pl.BlockSpec(page_block, page_map),
+            pl.BlockSpec(page_block, page_map),
         ],
         out_specs=pl.BlockSpec((1, r_pad, d), q_map),
         scratch_shapes=[
@@ -602,7 +610,7 @@ def _paged_call(q, k_pool, v_pool, page_table, seq_lens, q_pos, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="mx_paged_attn",  # what a device trace is searched for
+        name=name,
     )(*scalars, qk, k_pool, v_pool)
     # a slot with no live column was never visited: its rows are whatever
     # the buffer held (one step an empty slot, writing zeros, was timed
@@ -720,6 +728,40 @@ def paged_attention(q, k_pool, v_pool, page_table, seq_lens, q_pos=None,
                                       interpret=False, precise=precise)
     return paged_attention_reference(q, k_pool, v_pool, page_table,
                                      seq_lens, q_pos=q_pos, scale=scale)
+
+
+def paged_latent_attention_reference(q, pool, page_table, seq_lens, rank,
+                                     scale):
+    """Dense jnp form of :func:`paged_latent_attention` (the CPU path and
+    the kernel's parity oracle)."""
+    pool = pool[:, :, None]         # one "kv head"
+    return paged_attention_reference(q, pool, pool, page_table, seq_lens,
+                                     scale=scale)[..., :rank]
+
+
+def paged_latent_attention(q, pool, page_table, seq_lens, rank, scale,
+                           interpret=None):
+    """Decode attention over a LATENT pool: one row a token and no head axis,
+    ``[c (rank); k_r]`` — every head's key is the whole row and every head's
+    value its first ``rank`` columns (latent attention in its absorbed form:
+    DeepSeek-V2, arXiv:2405.04434).
+
+    q: (S, H, W) absorbed queries ``[W_kvb^K^T q_nope; q_rope]``, ``W`` the
+    width of the row's content; pool: (P, page_size, Dw) — rows held ``Dw
+    >= W`` wide (zero lanes); page_table: (S, max_pages);
+    seq_lens: (S,). Returns ``(S, H, rank)``: ``sum_s softmax_s(q . row_s *
+    scale) row_s[:rank]``. The launch is :func:`_paged_kernel`'s own, the
+    SAME array as its K and its V operand and float32 products, under the
+    name ``mx_mla_attn``; what it computes past ``rank`` is dropped."""
+    if interpret is None:
+        if _interpret():
+            return paged_latent_attention_reference(
+                q, pool, page_table, seq_lens, rank, scale)
+        interpret = False
+    out = _paged_call(q[:, None], pool, pool, page_table, seq_lens, None,
+                      scale, interpret, "paged_latent_attention",
+                      precise=True, name="mx_mla_attn")
+    return out[:, 0, :, :rank]
 
 
 def ragged_spec_attention(q, k_pool, v_pool, page_table, seq_lens,

@@ -119,8 +119,8 @@ from ..resilience import hbm as _hbm
 from .batcher import (EngineUnavailableError, QueueFullError,
                       RequestTimeoutError, ServerClosedError)
 from .buckets import select_bucket
-from .kvcache import (GroupedKVCache, OutOfPagesError, PagedKVCache,
-                      PrefixMatch, write_kv)
+from .kvcache import (OutOfPagesError, PrefixMatch, layer_states,
+                      make_cache, write_kv)
 from .stats import ServingStats
 from .tenancy import (PRIORITY_CLASSES, SHARED_TENANT, Tenant,
                       TenantRegistry, TenantUnavailableError,
@@ -241,9 +241,25 @@ class PagedDecodeModel:
     Attributes the engine sizes the cache from: ``num_layers``,
     ``num_heads``, ``num_kv_heads``, ``head_dim``, ``vocab_size``.
 
-    Three optional declarations (a model without them, like
+    Four optional declarations (a model without them, like
     :class:`TinyDecoder`, is served exactly as before):
 
+    ``layer_state``
+        what each layer keeps between tokens, one entry a layer
+        (:func:`~mxnet_tpu.serving.kvcache.layer_states`: ``("paged",)``,
+        ``("ring", window)``, ``("latent", width)``, ``("slot", shapes)``);
+        the cache manager allocates per entry and for no layer that owns
+        nothing of a kind. A model of latent and slot-state layers is
+        handed, in the places of ``k_pool`` and ``v_pool`` below, the two
+        operands of its
+        :class:`~mxnet_tpu.serving.kvcache.LatentStateCache`: its latent
+        pools ``(P, page, row width)``, one a latent layer and no V pool,
+        and its slot state, a tuple a state layer of ``(num_slots,) +
+        shape`` float32 arrays (and returns them in those places); ``decode``
+        row ``s`` IS slot ``s`` and a row with ``seq_len`` 0 must leave its
+        slot's state bit for bit; ``prefill`` takes ``slot=`` (a traced
+        int32) and writes that slot's state whole. Served with
+        ``prefix_cache=False``, ``prefill_chunk=0``, ``spec_k=0``.
     ``kv_groups``
         ``{"full": [layer, ...], "window": [layer, ...], "window_tokens":
         n}`` — the model's layers are of two kinds. The engine then keeps a
@@ -486,37 +502,42 @@ class DecodeEngine:
             self._chunk_rungs = self._ladder
         else:
             self._chunk_rungs = ()
-        kv_groups = getattr(model, "kv_groups", None)
-        self._grouped = bool(kv_groups)
-        if self._grouped:
-            if self._prefix_cache or self._chunk or self._spec_k \
-                    or self._ring_len:
-                raise MXNetError(
-                    "a model that declares kv_groups is served with "
-                    "prefix_cache=False, prefill_chunk=0, spec_k=0 and no "
-                    "ring prefill: a window layer's pages are a ring that "
-                    "is rewritten in place (no page to share, no chunk or "
-                    "draft row to read back through it)")
-            self._cache = GroupedKVCache(
-                self.num_slots, self.max_seq_len, kv_groups,
-                model.num_kv_heads, model.head_dim, page_size=page_size,
-                num_pages=num_pages, dtype=dtype, name=name)
-        else:
-            self._cache = PagedKVCache(
-                self.num_slots, self.max_seq_len, model.num_layers,
-                model.num_kv_heads, model.head_dim, page_size=page_size,
-                num_pages=num_pages, dtype=dtype, name=name,
-                prefix_cache=self._prefix_cache)
-        # rows of the packed operands: a second group adds its write pages
+        kinds = {st[0] for st in layer_states(model)}
+        if kinds != {"paged"} and (self._prefix_cache or self._chunk
+                                   or self._spec_k or self._ring_len):
+            why = {
+                "ring": "a window layer's pages are a ring that is "
+                        "rewritten in place (no page to share, no chunk or "
+                        "draft row to read back through it)",
+                "slot": "a slot's state is no page to share, a chunk would "
+                        "have to hand it on and a rejected draft row cannot "
+                        "be taken out of a recurrence",
+                "latent": "its prefill attends in expanded form, not "
+                          "through the pool"}
+            raise MXNetError(
+                "a model that declares %s layers (layer_state, kv_groups) "
+                "is served with prefix_cache=False, prefill_chunk=0, "
+                "spec_k=0 and no ring prefill: %s"
+                % (" and ".join(sorted(kinds - {"paged"})),
+                   "; ".join(why[k] for k in sorted(kinds) if k in why)))
+        self._cache = make_cache(
+            model, self.num_slots, self.max_seq_len, page_size=page_size,
+            num_pages=num_pages, dtype=dtype, name=name,
+            prefix_cache=self._prefix_cache)
+        # what the cache declares a prefill's packed operand carries
+        # behind its three rows: a second group's write pages, or a
+        # slot-state model's slot (every column)
+        self._prefill_extra = self._cache.prefill_extra
+        self._prefill_rows = 3 + (self._prefill_extra is not None)
+        self._grouped = self._prefill_extra == "window_pages"
+        # rows of the step's packed operand: a second group adds its write
+        # pages
         self._extra_rows = 1 if self._grouped else 0
         # ... and the step's: `from_prev` last
         self._packed_rows = 5 + self._extra_rows + 1
         # the tables a decode tick's paged attention walks: (cache group,
         # columns, layers that walk it)
-        self._walk_groups = tuple(
-            (group, c.max_pages, c.num_layers) for group, c in (
-                (("full", self._cache.full), ("window", self._cache.window))
-                if self._grouped else (("full", self._cache),)))
+        self._walk_groups = self._cache.walk_groups()
         self._kv_cols_live = 0
         self._kv_cols_grid = 0
         self._kv_cols_walked = 0
@@ -554,8 +575,7 @@ class DecodeEngine:
         # every queued request may reserve up to max_seq_len of pages
         # (total_queued() reads one int, safe from any thread).
         self._governor = _hbm.governor()
-        pools = jax.tree_util.tree_leaves(
-            (self._cache.k_pool, self._cache.v_pool))
+        pools = jax.tree_util.tree_leaves(self._cache.operands)
         #: arrays a step is handed as the pools: one a layer for K and V
         self._kv_pool_leaves = len(pools)
         self._step_temp_bytes: Optional[int] = None  # set by warmup()
@@ -572,7 +592,8 @@ class DecodeEngine:
         pool_bytes = int(sum(x.nbytes for x in pools))
         self._governor.register_bound("serving.%s.kv_pool" % name,
                                       pool_bytes)
-        page_bytes = pool_bytes // max(1, self._cache.num_pages)
+        # (what is not paged, a slot's state, no reservation takes)
+        page_bytes = self._cache.paged_bytes // max(1, self._cache.num_pages)
         worst_pages = self._cache.pages_for(self.max_seq_len)
         self._governor.register_bound(
             "serving.%s.pending_prefill" % name,
@@ -601,7 +622,7 @@ class DecodeEngine:
         # the previous step's output. The page table rides a version-keyed
         # device cache (below), so a steady tick pays exactly one put +
         # one fetch
-        grouped = self._grouped
+        grouped, extra = self._grouped, self._prefill_extra
 
         def head():
             """The scope of a program's last operations (``mx_head``: what
@@ -652,16 +673,20 @@ class DecodeEngine:
         # the rung shape, so they travel as one (3, rung) array
         # (one jit, one program a rung: the scope names the rung's ops)
         def mx_prefill(params, packed, length, k_pool, v_pool):
+            more = {}
             with jax.named_scope("mx_head"):
                 if grouped:
                     tokens, full_pages, write_offsets, window_pages = packed
                     write_pages = (full_pages, window_pages)
+                elif extra == "slot":
+                    tokens, write_pages, write_offsets, slots = packed
+                    more["slot"] = slots[0]
                 else:
                     tokens, write_pages, write_offsets = packed
             with jax.named_scope("mx_prefill_%d" % tokens.shape[0]):
                 out = model.prefill(
                     params, tokens, length, k_pool, v_pool, write_pages,
-                    write_offsets)
+                    write_offsets, **more)
             last, k_pool, v_pool = out[:3]
             with head():
                 first = jnp.argmax(last).astype(jnp.int32)
@@ -764,8 +789,9 @@ class DecodeEngine:
         off). A retry must not re-pass dead buffers, and a prefill
         failure that killed the pools has destroyed EVERY live sequence's
         KV — the caller escalates to a full eviction + fresh pools."""
-        pool = self._cache.k_pool
-        dead = getattr((pool[0] if self._grouped else pool)[0],
+        import jax
+
+        dead = getattr(jax.tree_util.tree_leaves(self._cache.operands)[0],
                        "is_deleted", None)
         return bool(dead and dead())
 
@@ -993,8 +1019,7 @@ class DecodeEngine:
         packed[4] = self._cache.null_write_slots(s * self._spec_w)[1]
         def step_args():
             return (params, jnp.asarray(packed), self._no_prev,
-                    self._cache.k_pool, self._cache.v_pool,
-                    self._device_page_table())
+                    *self._cache.operands, self._device_page_table())
 
         # every model program is compiled ahead of its warm-up call, which
         # finds that lowering and that executable again (the jit holds one
@@ -1032,10 +1057,10 @@ class DecodeEngine:
             # chunked mode never dispatches the monolithic rungs — every
             # prompt runs through the one chunk rung compiled below
             for rung in self._ladder:
-                pre = np.zeros((3 + self._extra_rows, rung), np.int32)
+                pre = np.zeros((self._prefill_rows, rung), np.int32)
                 pre[2] = self._cache.null_write_slots(rung)[1]
                 args = (params, jnp.asarray(pre), jnp.asarray(1, jnp.int32),
-                        self._cache.k_pool, self._cache.v_pool)
+                        *self._cache.operands)
                 compiled = self._prefill_jit.lower(*args).compile()
                 _tok, kp, vp = self._prefill_jit(*args)
                 self._cache.swap_pools(kp, vp)
@@ -1046,7 +1071,7 @@ class DecodeEngine:
             pre[1], pre[2] = self._cache.null_write_slots(rung)
             args = (params, jnp.asarray(pre), jnp.asarray(0, jnp.int32),
                     jnp.asarray(1, jnp.int32), jnp.asarray(null_row),
-                    self._cache.k_pool, self._cache.v_pool)
+                    *self._cache.operands)
             compiled = self._chunk_jit.lower(*args).compile()
             _tok, kp, vp = self._chunk_jit(*args)
             self._cache.swap_pools(kp, vp)
@@ -1054,7 +1079,7 @@ class DecodeEngine:
         if self._prefix_cache:
             # null -> null: harmless, and the CoW copy is compiled
             kp, vp = self._cow_jit(
-                self._cache.k_pool, self._cache.v_pool,
+                *self._cache.operands,
                 jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32))
             self._cache.swap_pools(kp, vp)
         with self._cv:
@@ -1194,6 +1219,8 @@ class DecodeEngine:
             }
         out["tenants"] = self._tenants.snapshot()
         out["kvcache"] = self._cache.stats()
+        if "state" in out["kvcache"]:   # a cache that holds slot state
+            out["state"] = out["kvcache"].pop("state")
         # the governor's verdict rides every stats snapshot (the fleet's
         # replica rows and /debug/state read it from here)
         hv = self._governor.healthz_view()
@@ -1766,13 +1793,15 @@ class DecodeEngine:
                        ring=ring)
         # tokens, write pages, offsets (+ a second group's write pages);
         # the padding's pages stay 0, the null page
-        pre = np.zeros((3 + self._extra_rows, rung), np.int32)
+        pre = np.zeros((self._prefill_rows, rung), np.int32)
         pre[0, :p] = req.prompt
         wpg, woff = self._cache.write_slots(slot, 0, p)
         if self._grouped:
             pre[1, :p], pre[3, :p] = wpg
         else:
             pre[1, :p] = wpg
+        if self._prefill_extra == "slot":
+            pre[3] = slot
         pre[2] = np.concatenate(
             [woff, self._cache.null_write_slots(rung - p)[1]])
         policy = self._retry or resilience.default_policy()
@@ -1788,7 +1817,7 @@ class DecodeEngine:
             return telemetry.jit_call(
                 "serving.decode_prefill", self._prefill_jit, self._params,
                 jnp.asarray(pre), jnp.asarray(p, jnp.int32),
-                self._cache.k_pool, self._cache.v_pool)
+                *self._cache.operands)
 
         tok, kp, vp = policy.call(attempt, site="serving.decode.prefill")
         self._cache.swap_pools(kp, vp)
@@ -1842,7 +1871,7 @@ class DecodeEngine:
                 "serving.decode_prefill_chunk", self._chunk_jit,
                 self._params, jnp.asarray(pre),
                 jnp.asarray(start, jnp.int32), jnp.asarray(n, jnp.int32),
-                jnp.asarray(row), self._cache.k_pool, self._cache.v_pool)
+                jnp.asarray(row), *self._cache.operands)
 
         tok, kp, vp = policy.call(attempt, site="serving.decode.prefill")
         self._cache.swap_pools(kp, vp)
@@ -1856,8 +1885,8 @@ class DecodeEngine:
         sharers never observe each other's tokens."""
         jnp = self._jnp
         kp, vp = telemetry.jit_call(
-            "serving.decode_cow", self._cow_jit, self._cache.k_pool,
-            self._cache.v_pool, jnp.asarray(src, jnp.int32),
+            "serving.decode_cow", self._cow_jit, *self._cache.operands,
+            jnp.asarray(src, jnp.int32),
             jnp.asarray(dst, jnp.int32))
         self._cache.swap_pools(kp, vp)
         with self._cv:
@@ -1913,10 +1942,12 @@ class DecodeEngine:
         # rate, not token rate — outside the decode-host-sync budget)
         fetched = fetch_host([tok])[0].reshape(-1)
         first = int(fetched[0])    # a model's counters ride behind it
-        if span is not None and (self._grouped
-                                 or self._moe_rows is not None):
-            span.set_args(**self._layer_args(
-                fetched[1:] if self._moe_rows is not None else None, [p]))
+        if span is not None:
+            args = self._layer_args(
+                fetched[1:] if self._moe_rows is not None else None, [p],
+                prefill=True)
+            if args:
+                span.set_args(**args)
         now = time.perf_counter()
         ttft = (now - req.t_submit) * 1e3
         _tracing.event(req.trace, "first_token", ttft_ms=round(ttft, 3))
@@ -1964,8 +1995,8 @@ class DecodeEngine:
         use_ring = n_dev > 1 and tokens.shape[0] % n_dev == 0
         last, kp, vp = model.prefill(
             self._params, jnp.asarray(tokens),
-            jnp.asarray(length, jnp.int32), self._cache.k_pool,
-            self._cache.v_pool, jnp.asarray(wpg), jnp.asarray(woff),
+            jnp.asarray(length, jnp.int32), *self._cache.operands,
+            jnp.asarray(wpg), jnp.asarray(woff),
             attn=ring_attn if use_ring else None)
         return jnp.argmax(last).astype(jnp.int32), kp, vp
 
@@ -2021,8 +2052,8 @@ class DecodeEngine:
                     "eviction required")
             return telemetry.jit_call(
                 "serving.decode_step", self._step, self._params,
-                jnp.asarray(packed), fed, self._cache.k_pool,
-                self._cache.v_pool, self._device_page_table())
+                jnp.asarray(packed), fed, *self._cache.operands,
+                self._device_page_table())
 
         try:
             with telemetry.span("decode.dispatch", _SPAN_CAT,
@@ -2064,10 +2095,9 @@ class DecodeEngine:
             return
         with telemetry.span("decode.commit", _SPAN_CAT) as span:
             args = self._walk_args(rec.lens)
-            if self._grouped or counters is not None:
-                args.update(self._layer_args(
-                    counters, [int(rec.lens[slot * self._spec_w])
-                               for slot, _req in rec.active]))
+            args.update(self._layer_args(
+                counters, [int(rec.lens[slot * self._spec_w])
+                           for slot, _req in rec.active]))
             span.set_args(**args)
             self._commit_step(rec.active, toks, rec.drafts)
 
@@ -2323,13 +2353,15 @@ class DecodeEngine:
             self._attn_blocks_live += n_live
         return {"attn_blocks_rung": n_rung, "attn_blocks_live": n_live}
 
-    def _layer_args(self, counters, live) -> dict:
-        """Span arguments of a prefill or a decode tick of a model that
-        declares experts or cache groups (what the benchmark's per-layer
-        readers are handed), and the expert-load counters' bookkeeping.
+    def _layer_args(self, counters, live, prefill=False) -> dict:
+        """Span arguments of a prefill or a decode tick beyond the page
+        walk: what the cache says of its groups or its state
+        (``span_args``) and what a model that declares experts counted (what
+        the benchmark's per-layer readers are handed), and the counters'
+        bookkeeping.
         ``counters``: the model's flat ``moe_counters`` of this program run
         (or None); ``live``: tokens each sequence of the run holds."""
-        args = {}
+        args = self._cache.span_args(live, prefill)
         if counters is not None:
             rows = np.asarray(counters, np.int64).reshape(
                 self._moe_rows.shape)
@@ -2344,14 +2376,6 @@ class DecodeEngine:
             args.update(moe_rows_held=n_held,
                         moe_experts_hit=int(np.count_nonzero(held)),
                         moe_load_max=int(held.max()))
-        if self._grouped:
-            window = self._cache.window
-            args.update(
-                kv_rows_full=int(sum(live)),
-                kv_rows_window=int(sum(min(n, window.window_tokens)
-                                       for n in live)),
-                kv_window_pages=window.pages_in_use,
-                kv_window_capacity=window.num_pages - 1)
         return args
 
     def _propose(self, req: _DecodeRequest, slot: int, pos: int):

@@ -52,6 +52,7 @@ from __future__ import annotations
 
 import collections
 import hashlib
+import math
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -60,7 +61,8 @@ from .. import telemetry
 from ..base import MXNetError, get_env
 
 __all__ = ["PagedKVCache", "RingKVCache", "GroupedKVCache",
-           "OutOfPagesError", "PrefixMatch", "write_kv"]
+           "LatentStateCache", "OutOfPagesError", "PrefixMatch", "write_kv",
+           "write_rows", "layer_states", "make_cache"]
 
 _DEFAULT_PAGE_SIZE = 16
 
@@ -98,6 +100,19 @@ _T_PRESSURE_SHEDS = telemetry.counter(
     labels=("cache",))
 
 
+_T_STATE = telemetry.counter(
+    "mxnet_decode_state_total",
+    "a model with slot-state and latent layers, summed over decode ticks "
+    "and prefills: what=slots_live the rows whose recurrence ran, "
+    "what=bytes_moved the state bytes it read and wrote (a tick: every live "
+    "slot's state once each way; a prefill: its slot's state written), "
+    "what=latent_rows_read the latent rows the attention read",
+    labels=("server", "what"))
+_T_STATE_BYTES = telemetry.gauge(
+    "mxnet_decode_state_bytes",
+    "bytes of the per-slot state of the model's slot-state layers, every "
+    "slot (fixed at construction: it is not paged)",
+    labels=("server",))
 _T_GROUP_PAGES = telemetry.gauge(
     "mxnet_kvcache_group_pages_in_use",
     "KV pages in use in a further group of layers of a cache whose model "
@@ -126,30 +141,39 @@ def _page_size(page_size: Optional[int]) -> int:
     return max(1, int(page_size))
 
 
+def write_rows(pool, layer: int, new, pages, offsets):
+    """Scatter one batch of new rows into ONE pool of a layer: ``pool`` a
+    sequence of per-layer ``(P, page_size, KH, Dw)`` arrays (a latent pool:
+    ``(P, page_size, Dw)``), ``new`` ``(N, KH, D)`` (``(N, D)``) rows,
+    zero-padded here to the width ``Dw >= D`` the pool holds its rows at
+    (:func:`pool_row_width`). Returns the pool as a tuple with element
+    ``layer`` replaced — every other layer's array is the SAME object, so a
+    step donates and returns it untouched."""
+    import jax.numpy as jnp
+
+    pool = tuple(pool)
+    pad = pool[layer].shape[-1] - new.shape[-1]
+    if pad:
+        new = jnp.pad(new, ((0, 0),) * (new.ndim - 1) + ((0, pad),))
+    return pool[:layer] + (pool[layer].at[pages, offsets].set(new),) \
+        + pool[layer + 1:]
+
+
 def write_kv(k_pool, v_pool, layer: int, k_new, v_new, pages, offsets):
     """Scatter one batch of new K/V rows into the layer's pool pages.
 
     k_pool/v_pool: sequences of per-layer ``(P, page_size, KH, Dw)`` device
-    pools (traced); k_new/v_new: (N, KH, D) rows, zero-padded here to the
-    width ``Dw >= D`` the pools hold their rows at (:func:`pool_row_width`);
+    pools (traced); k_new/v_new: (N, KH, D) rows (:func:`write_rows` pads
+    them to the pools' width);
     pages/offsets: (N,) int32 destinations (host-computed by
     :meth:`PagedKVCache.write_slots`). Returns the pools as tuples with
-    element ``layer`` replaced — every other layer's array is the SAME
-    object, so a step donates and returns it untouched. Pure — trace it
+    element ``layer`` replaced. Pure — trace it
     inside the step jit; every shape is static, so membership churn never
     recompiles. Rows whose destination is the null page (inactive slots,
     prompt padding) overwrite garbage with garbage by design.
     """
-    import jax.numpy as jnp
-
-    def put(pool, new):
-        pad = pool[layer].shape[-1] - new.shape[-1]
-        if pad:
-            new = jnp.pad(new, ((0, 0), (0, 0), (0, pad)))
-        return pool[:layer] + (pool[layer].at[pages, offsets].set(new),) \
-            + pool[layer + 1:]
-
-    return put(tuple(k_pool), k_new), put(tuple(v_pool), v_new)
+    return write_rows(k_pool, layer, k_new, pages, offsets), \
+        write_rows(v_pool, layer, v_new, pages, offsets)
 
 
 def pool_row_width(shape, dtype, device) -> int:
@@ -701,11 +725,42 @@ class PagedKVCache:
         return (np.zeros(n_tokens, np.int32),
                 (pos % self.page_size).astype(np.int32))
 
+    #: the name its page table's walk is counted under
+    group = "full"
+    #: what a prefill's packed operand carries behind tokens, write pages
+    #: and offsets: nothing, a second group's ``"window_pages"`` or the
+    #: ``"slot"`` a stateful prefill writes
+    prefill_extra = None
+
+    @property
+    def operands(self):
+        """The two operands every program of the engine is handed, donates
+        and returns (:meth:`swap_pools` stores them back)."""
+        return self.k_pool, self.v_pool
+
     def swap_pools(self, k_pool, v_pool) -> None:
         """Store the pools returned by a jitted step (functional update
         discipline; with donation the old buffers are already dead)."""
         self.k_pool = k_pool
         self.v_pool = v_pool
+
+    @property
+    def paged_bytes(self) -> int:
+        """Bytes of what :attr:`num_pages` pages hold, over the layers (a
+        page's share of it is what a reservation takes)."""
+        return int(sum(x.nbytes for pool in (self.k_pool, self.v_pool)
+                       for x in pool))
+
+    def walk_groups(self):
+        """``((group, table columns, layers), ...)``: the page tables a
+        decode tick's attention walks."""
+        return ((self.group, self.max_pages, self.num_layers),)
+
+    def span_args(self, live, prefill: bool = False) -> dict:
+        """What a prefill's or a decode tick's span says of this cache
+        beyond the page walk (``live``: tokens each sequence of the run
+        holds): nothing for one group of paged K/V."""
+        return {}
 
     def reset_pools(self) -> None:
         """Fresh zeroed pools (same shapes). The eviction path calls this
@@ -934,6 +989,7 @@ class GroupedKVCache:
     """
 
     prefix_cache = False
+    prefill_extra = "window_pages"
 
     def __init__(self, num_slots: int, max_seq_len: int, kv_groups: dict,
                  num_kv_heads: int, head_dim: int,
@@ -987,9 +1043,26 @@ class GroupedKVCache:
     def v_pool(self):
         return (self.full.v_pool, self.window.v_pool)
 
+    operands = property(lambda self: (self.k_pool, self.v_pool))
+    paged_bytes = property(
+        lambda self: self.full.paged_bytes + self.window.paged_bytes)
+
     def swap_pools(self, k_pool, v_pool) -> None:
         self.full.swap_pools(k_pool[0], v_pool[0])
         self.window.swap_pools(k_pool[1], v_pool[1])
+
+    def walk_groups(self):
+        return (("full", self.full.max_pages, self.full.num_layers),
+                ("window", self.window.max_pages, self.window.num_layers))
+
+    def span_args(self, live, prefill: bool = False) -> dict:
+        window = self.window
+        return dict(
+            kv_rows_full=int(sum(live)),
+            kv_rows_window=int(sum(min(n, window.window_tokens)
+                                   for n in live)),
+            kv_window_pages=window.pages_in_use,
+            kv_window_capacity=window.num_pages - 1)
 
     def reset_pools(self) -> None:
         self.full.reset_pools()
@@ -1039,3 +1112,178 @@ class GroupedKVCache:
         out = self.full.stats()
         out["window"] = self.window.stats()
         return out
+
+
+class LatentStateCache(PagedKVCache):
+    """The cache of a model whose layers keep a LATENT row a token or a
+    fixed-size STATE a slot (:func:`layer_states`; both kinds in one model).
+
+    ``latent_pool``: one array a latent layer, ``(pages, page_size, row
+    width)`` — no head axis and NO V pool: a token's row ``[c; k_r]`` is key
+    and value at once, held as wide as :func:`pool_row_width` says (the lane
+    tile above its width on a TPU: zeros the products ignore). Paged exactly
+    as a :class:`PagedKVCache` pages K/V: the page table, the free list, the
+    reservation and ``write_slots`` are the base class's, over the latent
+    layers alone. ``slot_state``: a tuple a state layer of arrays
+    ``(num_slots,) + shape``, float32 zeros, which a prefill overwrites for
+    its slot and a decode tick updates in place for the rows that hold a
+    token. It is not paged, so nothing of it is reserved, shared or freed: a
+    slot's next prefill overwrites it whole. The two are the
+    :attr:`operands` the engine threads through its programs and donates.
+    No prefix index: a state is no page to hash."""
+
+    group = "latent"
+    prefill_extra = "slot"
+
+    def __init__(self, num_slots: int, max_seq_len: int, states,
+                 page_size: Optional[int] = None,
+                 num_pages: Optional[int] = None, dtype="float32",
+                 name: str = "decode"):
+        latent = [st[1] for st in states if st[0] == "latent"]
+        self._state_shapes = tuple(
+            tuple(tuple(int(n) for n in shape) for shape in st[1])
+            for st in states if st[0] == "slot")
+        if len(set(latent)) != 1 or not self._state_shapes:
+            raise MXNetError(
+                "LatentStateCache serves latent layers of one width beside "
+                "slot-state layers, got %s" % (list(states),))
+        #: bytes of the slot state (float32), every layer and slot
+        self.state_bytes = 4 * int(num_slots) * sum(
+            math.prod(shape) for layer in self._state_shapes
+            for shape in layer)
+        #: slots live, state bytes moved, latent rows read: summed over the
+        #: ticks and prefills :meth:`span_args` was asked about
+        self._moved = (0, 0, 0)
+        super().__init__(num_slots, max_seq_len, len(latent), 1,
+                         int(latent[0]), page_size=page_size,
+                         num_pages=num_pages or 0, dtype=dtype, name=name)
+        _T_STATE_BYTES.set(self.state_bytes, server=name)
+
+    operands = property(lambda self: (self.latent_pool, self.slot_state))
+
+    def swap_pools(self, latent_pool, slot_state) -> None:
+        self.latent_pool = latent_pool
+        self.slot_state = slot_state
+
+    @property
+    def paged_bytes(self) -> int:
+        return int(sum(x.nbytes for x in self.latent_pool))
+
+    def _zero_pools(self) -> None:
+        import jax.numpy as jnp
+
+        # the base class sized the rows with one head each; that axis goes
+        # (a TPU pads it to a sublane tile and the step then converts the
+        # pool on its way into the kernel, every tick), the rows keep the
+        # width it asked the device for
+        pages, page_size, _one, width = self._pool_shape
+        self.latent_pool = tuple(
+            jnp.zeros((pages, page_size, width), self._pool_dtype)
+            for _ in range(self.num_layers))
+        self.slot_state = tuple(
+            tuple(jnp.zeros((self.num_slots,) + shape, jnp.float32)
+                  for shape in layer) for layer in self._state_shapes)
+
+    def span_args(self, live, prefill: bool = False) -> dict:
+        """A tick's recurrence reads and writes each live slot's state once;
+        a prefill writes its slot's. The latent attention reads a row a
+        token a latent layer (a prefill: before the pool). Counted here too
+        (``mxnet_decode_state_total``, :meth:`stats`)."""
+        a_slot = self.state_bytes // self.num_slots
+        moved = (len(live), len(live) * a_slot * (1 if prefill else 2),
+                 int(sum(live)) * self.num_layers)
+        for what, n in zip(("slots_live", "bytes_moved",
+                            "latent_rows_read"), moved):
+            _T_STATE.inc(n, server=self.name, what=what)
+        # one new tuple: stats() reads it from caller threads
+        self._moved = tuple(a + b for a, b in zip(self._moved, moved))
+        return dict(state_slots_live=moved[0], state_bytes_moved=moved[1],
+                    latent_rows_read=moved[2])
+
+    def stats(self) -> dict:
+        out = super().stats()
+        # (its pages ARE the latent pool's: the two names of one number)
+        out["state"] = dict(
+            zip(("state_slots_live", "state_bytes_moved",
+                 "latent_rows_read"), self._moved),
+            state_bytes=self.state_bytes,
+            latent_pages=out["pages_in_use"],
+            latent_capacity=out["pages_capacity"])
+        return out
+
+
+#: what a layer may keep between tokens, and what each kind forbids
+STATE_KINDS = ("paged", "ring", "latent", "slot")
+
+
+def layer_states(model) -> list:
+    """What each layer of ``model`` keeps between tokens, one entry a layer:
+
+    ``("paged",)``
+        K and V rows a token in pages of the layer's own pools;
+    ``("ring", window_tokens)``
+        the same, in a ring of pages a slot (a sliding window);
+    ``("latent", width)``
+        ONE row a token of ``width`` floats, paged, no head axis, no V pool;
+    ``("slot", (shape, ...))``
+        float32 arrays of fixed shapes a slot, not paged.
+
+    A model says so as ``layer_state``; one that declares ``kv_groups`` has
+    paged (``full``) and ring (``window``) layers; one that declares neither
+    keeps paged K/V in every layer."""
+    declared = getattr(model, "layer_state", None)
+    if declared is not None:
+        states = [tuple(st) for st in declared]
+        bad = sorted({st[0] for st in states} - set(STATE_KINDS))
+        if bad or len(states) != model.num_layers:
+            raise MXNetError(
+                "layer_state: %d entries for %d layers, unknown kinds %s "
+                "(known: %s)" % (len(states), model.num_layers, bad,
+                                 list(STATE_KINDS)))
+        return states
+    groups = getattr(model, "kv_groups", None)
+    if groups:
+        ring = ("ring", int(groups["window_tokens"]))
+        return [ring if li in groups["window"] else ("paged",)
+                for li in range(model.num_layers)]
+    return [("paged",)] * model.num_layers
+
+
+def make_cache(model, num_slots: int, max_seq_len: int, page_size=None,
+               num_pages=None, dtype="float32", name: str = "decode",
+               prefix_cache: bool = False):
+    """The cache :func:`layer_states` of ``model`` asks for: pools for the
+    layers that own some and for no other. All paged: a
+    :class:`PagedKVCache`; paged and ring: a :class:`GroupedKVCache`; latent
+    and slot state: a :class:`LatentStateCache`. Another mix of kinds is not
+    served yet, and says so."""
+    states = layer_states(model)
+    kinds = {st[0] for st in states}
+    if kinds == {"paged"}:
+        return PagedKVCache(
+            num_slots, max_seq_len, model.num_layers, model.num_kv_heads,
+            model.head_dim, page_size=page_size, num_pages=num_pages,
+            dtype=dtype, name=name, prefix_cache=prefix_cache)
+    if prefix_cache:
+        raise MXNetError("no prefix index over %s layers" % sorted(kinds))
+    if kinds == {"paged", "ring"}:
+        windows = {st[1] for st in states if st[0] == "ring"}
+        if len(windows) != 1:
+            raise MXNetError("ring layers of one window, got %s"
+                             % sorted(windows))
+        groups = {"full": [li for li, st in enumerate(states)
+                           if st[0] == "paged"],
+                  "window": [li for li, st in enumerate(states)
+                             if st[0] == "ring"],
+                  "window_tokens": windows.pop()}
+        return GroupedKVCache(
+            num_slots, max_seq_len, groups, model.num_kv_heads,
+            model.head_dim, page_size=page_size, num_pages=num_pages,
+            dtype=dtype, name=name)
+    if kinds == {"latent", "slot"}:
+        return LatentStateCache(
+            num_slots, max_seq_len, states, page_size=page_size,
+            num_pages=num_pages, dtype=dtype, name=name)
+    raise MXNetError(
+        "no cache for layers of kinds %s together (served: paged; paged + "
+        "ring; latent + slot)" % sorted(kinds))

@@ -229,7 +229,7 @@ def jit_call(site: str, jitted, *args, **kwargs):
 PROGRAM_PARTS = frozenset((
     "mx_embed", "mx_qkv", "mx_kv_write", "mx_attn", "mx_attn_out", "mx_mlp",
     "mx_moe_route", "mx_moe_experts", "mx_moe_shared", "mx_moe_combine",
-    "mx_head"))
+    "mx_kda_proj", "mx_kda_state", "mx_mla_proj", "mx_head"))
 
 #: Which layout of those scopes a program was traced with. jax's persistent
 #: compile cache keys a program WITHOUT its ``op_name`` metadata, so two
@@ -243,7 +243,7 @@ PROGRAM_PARTS = frozenset((
 #: names still match), so ``tests/test_program_parts.py`` pins a digest of
 #: the programs' scope paths beside this value: a scope cannot move without
 #: that test asking for the bump.
-PROGRAM_PARTS_VERSION = "1"
+PROGRAM_PARTS_VERSION = "2"
 
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
 _HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")

@@ -158,6 +158,21 @@ def _case(name, spec, dtype):
                 (spec((rows, 3072), dtype),
                  spec((32, 3072, 3072), jnp.bfloat16),
                  spec((32,), jnp.int32)))
+    if name == "latent_decode_ling":
+        # the Ling cell's latent layer: 32 slots x 32 heads on rows of 512 +
+        # 64 held 640 wide, 352 columns a slot, a pool with no head axis
+        return (lambda q, pool, table, lens: pk.paged_latent_attention(
+            q, pool, table, lens, 512, 192 ** -0.5, interpret=False),
+            (spec((32, 32, 576), dtype), spec((32 * 352 + 1, PAGE, 640),
+                                              dtype),
+             spec((32, 352), jnp.int32), spec((32,), jnp.int32)))
+    if name == "band_prefill_4096_ling":
+        # its prefill attention, expanded: 32 heads of 192 (keys) and 128
+        # (values), all three operands at the lane tile above, 256
+        operands = (spec((4096, 32, 256), dtype),) * 3
+        return (lambda q, k, v, n: pk.band_attention(
+            q, k, v, scale=192 ** -0.5, interpret=False, precise=True,
+            length=n), operands + (spec((), jnp.int32),))
     if name == "nms":
         n = 1000
         return (lambda b, c, v: pk.nms_keep(b, c, v, 0.5, False),
@@ -180,7 +195,9 @@ def _case(name, spec, dtype):
                                   "band_prefill_8192_window",
                                   "band_prefill_512",
                                   "band_prefill_8192_length_1",
-                                  "moe_gmm_decode", "moe_gmm_prefill"])
+                                  "moe_gmm_decode", "moe_gmm_prefill",
+                                  "latent_decode_ling",
+                                  "band_prefill_4096_ling"])
 def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
                                monkeypatch):
     monkeypatch.setattr(pk, "_interpret", lambda: False)
@@ -203,6 +220,7 @@ def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
     ("band_prefill_8192_window", "mx_prefill_attn"),
     ("band_prefill_512", "mx_prefill_attn"),
     ("moe_gmm_decode", "mx_moe_gmm"),
+    ("latent_decode_ling", "mx_mla_attn"),
 ])
 def test_kernel_keeps_its_name_in_the_compiled_program(
         name, kernel, one_chip, compile_cache_off, monkeypatch):
@@ -317,3 +335,53 @@ def test_decode_step_updates_its_kv_pools_in_place(name, one_chip,
     for leaf in jax.tree_util.tree_leaves(
             (compiled.input_formats[0][3:5], compiled.output_formats[1:])):
         assert tuple(leaf.layout.major_to_minor) == (0, 1, 2, 3)
+
+
+def test_ling_step_updates_its_latent_pool_and_slot_state_in_place(
+        one_chip, compile_cache_off):
+    """The Ling cell's shapes: a row into the latent pool, the latent launch
+    on that pool (the same array as its K and its V operand), the one-token
+    update of two layers' per-slot state. The compiled step holds no copy
+    of the pool (a pool with a head axis of 1 was converted whole on its way
+    into the kernel, 461 MB a tick: PERF.md section 6, PR 46) and no second
+    state."""
+    from mxnet_tpu.ops import kda
+    from mxnet_tpu.serving import kvcache
+
+    slots, heads, dim = 32, 32, 128
+
+    def spec(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(q, row, pool, state, table, lens, pages, offs, x):
+        (pool,) = kvcache.write_rows((pool,), 0, row, pages, offs)
+        seen = pk.paged_latent_attention(q, pool, table, lens, 512,
+                                         192 ** -0.5, interpret=False)
+        live, new = lens > 0, []
+        for s_l, tail in state:
+            mixed, tail = kda.short_conv_step(x, tail, jnp.ones((4, 3 * heads
+                                                                 * dim)),
+                                              live)
+            qk = mixed[:, :heads * dim].reshape(slots, heads, dim)
+            out, s_l = kda.step(qk, qk, qk, -qk * qk, qk[..., 0], s_l, live)
+            new.append((s_l, tail))
+            x = x + out.sum()
+        return seen, x, pool, tuple(new)
+
+    state = tuple((spec((slots, heads, dim, dim)),
+                   spec((slots, 3, 3 * heads * dim))) for _ in range(2))
+    ints = spec((slots,), jnp.int32)
+    pool = spec((slots * 352 + 1, PAGE, 640))
+    compiled = jax.jit(step, donate_argnums=(2, 3)).lower(
+        spec((slots, heads, 576)), spec((slots, 576)), pool, state,
+        spec((slots, 352), jnp.int32), ints, ints, ints,
+        spec((slots, 3 * heads * dim))).compile()
+    text = compiled.as_text()
+    assert "mx_mla_attn" in text
+    pool_bytes = (slots * 352 + 1) * PAGE * 640 * 4
+    state_bytes = slots * heads * dim * dim * 4
+    # under one layer's state, far under the pool: nothing is held twice
+    assert compiled.memory_analysis().temp_size_in_bytes < state_bytes \
+        < pool_bytes
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes \
+        + 2 * state_bytes

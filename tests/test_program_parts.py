@@ -25,6 +25,7 @@ TINY_PARTS = {"mx_embed", "mx_qkv", "mx_kv_write", "mx_attn", "mx_attn_out",
               "mx_mlp", "mx_head"}
 MOE_PARTS = {"mx_moe_route", "mx_moe_experts", "mx_moe_shared",
              "mx_moe_combine"}
+LING_PARTS = {"mx_kda_proj", "mx_kda_state", "mx_mla_proj"}
 
 
 def _model(kind):
@@ -32,6 +33,18 @@ def _model(kind):
         return serving.TinyDecoder(vocab_size=32, num_layers=2, num_heads=4,
                                    head_dim=8, num_kv_heads=2), \
             dict(max_seq_len=48), TINY_PARTS
+    if kind == "ling":
+        # no mx_qkv / mx_attn_out: its two kinds of attention have parts of
+        # their own around the one launch under mx_attn
+        model = serving.LingDecoder(
+            vocab_size=96, hidden_size=48, num_attention_heads=4, head_dim=8,
+            kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, intermediate_size=96, moe_intermediate_size=32,
+            layer_types=["kda", "kda", "mla"], num_dense_layers=1,
+            num_experts=16, num_experts_per_tok=4, n_group=4, topk_group=2,
+            held_experts=[0, 8], routed_scaling_factor=2.5)
+        return model, dict(max_seq_len=128, prefill_chunk=0), \
+            (TINY_PARTS - {"mx_qkv", "mx_attn_out"}) | MOE_PARTS | LING_PARTS
     model = serving.AfmoeDecoder(
         vocab_size=96, hidden_size=48, num_attention_heads=12,
         num_key_value_heads=2, head_dim=8, intermediate_size=96,
@@ -44,7 +57,7 @@ def _model(kind):
         TINY_PARTS | MOE_PARTS
 
 
-@pytest.fixture(scope="module", params=["tiny", "afmoe"])
+@pytest.fixture(scope="module", params=["tiny", "afmoe", "ling"])
 def warmed(request):
     """``(stats()["program_parts"], the engine's rows, the parts the model
     has)`` of a warmed-up engine."""
@@ -58,7 +71,7 @@ def warmed(request):
 
 
 def test_vocabulary_is_closed():
-    assert telemetry.PROGRAM_PARTS == TINY_PARTS | MOE_PARTS
+    assert telemetry.PROGRAM_PARTS == TINY_PARTS | MOE_PARTS | LING_PARTS
 
 
 @pytest.mark.parametrize("program", ["step", "first rung", "last rung"])
@@ -135,8 +148,8 @@ def test_chunk_rungs_are_mapped_like_the_others():
 #: what ``telemetry.PROGRAM_PARTS_VERSION`` stands for: a digest of the scope
 #: paths (``jit(mx_prefill)/mx_qkv/dot_general``, each with the number of
 #: source lines that put an operation there) of the tiny models' programs as
-#: jax lowers them, before any cache is asked
-SCOPES_PINNED = {"1": "9a331db489e43cc8"}
+#: jax lowers them, before any cache is asked ("2": with the ling model's)
+SCOPES_PINNED = {"1": "9a331db489e43cc8", "2": "39e65f87636dace6"}
 
 
 def _scope_paths(lowered):
@@ -150,21 +163,21 @@ def test_the_version_tag_is_pinned_to_the_layout_of_the_scopes():
     a bump is a WRONG attribution, not a silent one. So a scope cannot move
     without this digest moving."""
     paths = collections.Counter()
-    for kind in ("tiny", "afmoe"):
+    for kind in ("tiny", "afmoe", "ling"):
         model, kw, _parts = _model(kind)
         kw.pop("prefill_chunk", None)
         with serving.DecodeEngine(
                 model, model.init_params(0), num_slots=2, page_size=8,
                 prefill_buckets=(16,), prefix_cache=False, timeout_ms=0,
                 name="parts_pin_%s" % kind, **kw) as eng:
-            k, v = eng._cache.k_pool, eng._cache.v_pool
+            k, v = eng._cache.operands
             one = jnp.asarray(1, jnp.int32)
             paths.update(_scope_paths(eng._step.lower(
                 eng._params, jnp.zeros((eng._packed_rows, 2), jnp.int32),
                 eng._no_prev, k, v, eng._device_page_table())))
             paths.update(_scope_paths(eng._prefill_jit.lower(
                 eng._params,
-                jnp.zeros((3 + eng._extra_rows, 16), jnp.int32), one, k, v)))
+                jnp.zeros((eng._prefill_rows, 16), jnp.int32), one, k, v)))
             if kind == "tiny":
                 paths.update(_scope_paths(eng._chunk_jit.lower(
                     eng._params, jnp.zeros((3, 8), jnp.int32), one, one,
