@@ -12,9 +12,16 @@ activations at ``highest`` precision: the state carries what it rounds):
     a whole prompt, ``chunk`` tokens at a time (a ``lax.scan`` over the
     chunks; inside one the recurrence is solved as a triangular system);
 :func:`step`
-    one token a slot, the state read twice and written once;
+    one token a slot, EVERY slot's state read twice and written once
+    whatever holds a token: the reference of the tick and its path off the
+    chip. On a TPU a decode step goes through
+    :func:`mxnet_tpu.ops.pallas_kernels.kda_state_step` instead (the kernel
+    ``mx_kda_state``: the same operations in the same order over the live
+    slots only, each one's state read once and written once, in place),
+    which is ``step`` itself everywhere else;
 :func:`serial_scan`
-    the recurrence as written, a token at a time: the oracle of the tests.
+    the recurrence as written, a token at a time through :func:`step`: the
+    oracle of the tests.
 
 Inside a chunk the decay between two of its tokens is ``exp(G_i - G_j)``, ``i
 >= j``, from the cumulative log-decays ``G``: an argument that is never

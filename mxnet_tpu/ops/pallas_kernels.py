@@ -1055,6 +1055,175 @@ def band_attention_reference(q, k, v, scale=None, window=0):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# The delta rule's one-token update over the live slots (ops/kda.py `step`)
+# ---------------------------------------------------------------------------
+#
+# A decode tick of a delta-rule layer reads and writes ONE STATE MATRIX ``(d_k,
+# d_v)`` a head and slot: 2 MB a slot at 32 heads of 128 x 128 float32, far
+# more than everything else the layer touches. :func:`mxnet_tpu.ops.kda.step`
+# (plain XLA, the reference and the path off the chip) reads every slot's
+# state twice and writes it once whatever is live; here one grid step holds
+# ONE LIVE SLOT's state in VMEM for both passes and writes it back once, and
+# a slot that holds no token is not visited at all. The state operand is
+# aliased to the state result, so what is not visited keeps its bits because
+# nothing touches it. The walk (:func:`live_slots`: the live slots packed to
+# the front and their count, scalar-prefetch operands built in XLA once a
+# step for all its layers) is :func:`walk_schedule`'s idea again: the state
+# block's index map names the slot, so it is DMA'd straight into VMEM.
+#
+# The arithmetic is `kda.step`'s in its order, float32 on the vector unit. The
+# state lies ``d_k`` on the sublanes and ``d_v`` on the lanes, so ``k``, ``q``
+# and ``exp(a)`` scale it as COLUMNS: their ``(heads, d_k)`` tiles are
+# transposed once a grid step (three small transposes a slot) and a head's
+# column is a lane of the result, broadcast along the lanes: never a relayout
+# of a state tile.
+
+
+def live_slots(valid):
+    """The walk of :func:`kda_state_step` over the slots that hold a token.
+    valid: ``(S,)`` bool. Returns ``(slot_of (S + 1,), count (1,))`` int32:
+    the live slots in ascending order packed to the front, every entry past
+    ``count`` repeating the last live slot (slot 0 with none live) — the
+    list is one entry longer than a walk can be because the pipeline
+    evaluates the index maps of the step AFTER the one it runs, and a
+    repeated entry asks for no new copy (as :func:`walk_schedule`'s).
+    Compare-and-sum, no loop, nothing a device trace would show beside the
+    kernels."""
+    n = valid.shape[0]
+    live = valid.astype(jnp.int32)
+    ids = jnp.arange(n, dtype=jnp.int32)
+    # a slot's place among the live: the live slots below it
+    place = jnp.where(ids[None, :] < ids[:, None], live[None, :], 0).sum(
+        axis=1)
+    count = live.sum()
+    t = jnp.minimum(jnp.arange(n + 1, dtype=jnp.int32),
+                    jnp.maximum(count - 1, 0))
+    mine = jnp.logical_and(valid[None, :], place[None, :] == t[:, None])
+    return jnp.where(mine, ids[None, :], 0).sum(axis=1), count.reshape(1)
+
+
+def _kda_state_kernel(slot_ref, n_ref, q_ref, k_ref, a_ref, v_ref, b_ref,
+                      s_ref, o_ref, s_out_ref):
+    """One live slot of the delta rule's one-token update, every head.
+    Grid step ``t`` is slot ``slot_ref[t]`` (the index maps' own) of
+    ``n_ref[0]`` live ones. q_ref/k_ref/a_ref: ``(1, H, d_k)`` (``a`` the log
+    decay); v_ref/o_ref: ``(1, H, d_v)``; b_ref: ``(1, 1, H)`` (beta);
+    s_ref/s_out_ref: ``(1, H, d_k, d_v)`` — ONE buffer in HBM. A head::
+
+        S' = exp(a) S;  u = beta (v - S'^T k)
+        o = S'^T q + (q . k) u;  S = S' + k u^T
+
+    A launch with no live slot still runs step 0: it copies slot 0's state
+    through, bit for bit (the block is written back whatever the body did)."""
+    del slot_ref
+    t = pl.program_id(0)
+    n = n_ref[0]
+    heads = s_ref.shape[1]
+
+    @pl.when(t < n)
+    def _live():
+        # (d_k, H): a head's k, q and decay are columns of these
+        qt = q_ref[0].T
+        kt = k_ref[0].T
+        et = jnp.exp(a_ref[0]).T
+        qk = (qt * kt).sum(axis=0, keepdims=True)            # (1, H)
+        beta = b_ref[0]                                      # (1, H)
+        for h in range(heads):
+            kc = kt[:, h:h + 1]
+            decayed = et[:, h:h + 1] * s_ref[0, h]
+            seen_k = (kc * decayed).sum(axis=0, keepdims=True)  # S'^T k
+            seen_q = (qt[:, h:h + 1] * decayed).sum(axis=0, keepdims=True)
+            u = beta[:, h:h + 1] * (v_ref[0, h:h + 1, :] - seen_k)
+            o_ref[0, h:h + 1, :] = seen_q + qk[:, h:h + 1] * u
+            s_out_ref[0, h] = decayed + kc * u
+
+    @pl.when(jnp.logical_and(n == 0, t == 0))
+    def _none_live():
+        s_out_ref[...] = s_ref[...]
+
+
+def kda_state_walk(valid, interpret=None):
+    """What :func:`kda_state_step` walks, built ONCE a step for all its
+    layers: :func:`live_slots` where the kernel runs (a TPU, or ``interpret``
+    given), ``None`` where the reference does."""
+    if interpret is None and _interpret():
+        return None
+    return live_slots(valid)
+
+
+def kda_state_step(q, k, v, log_decay, beta, state, valid=None,
+                   interpret=None, walk=None):
+    """The delta rule's one-token update (:func:`mxnet_tpu.ops.kda.step`:
+    same arguments, same result ``(o (B, H, d_v), state)``) over the LIVE
+    slots only, in place. Off a TPU it IS ``kda.step`` (the reference, and
+    the oracle of the tests); on a TPU, or with ``interpret`` given, the
+    kernel ``mx_kda_state``: one grid step a live slot (``valid``), its
+    ``(H, d_k, d_v)`` state read once, held in VMEM for both passes and
+    written once into the buffer it came from (the state operand is aliased
+    to the state result: donate it). A slot that holds no token is neither
+    read nor written and keeps its bits; its output row comes back zeros. A
+    launch with no live slot changes nothing.
+
+    ``walk``: :func:`kda_state_walk` of ``valid`` where the caller has built
+    it (a step hands ONE to all its layers), else built here. The platform is
+    the only gate: any ``(H, d_k, d_v)`` goes through the kernel by its
+    shapes — the blocks span the operands' minor dims whole, so a head of 16
+    x 8 lowers as one of 128 x 128 does (tests/test_chip_compile.py) — and
+    a compiler's refusal on a TPU propagates."""
+    if interpret is None:
+        if _interpret():
+            from . import kda
+
+            return kda.step(q, k, v, log_decay, beta, state, valid)
+        interpret = False
+    if valid is None:
+        valid = jnp.ones(k.shape[:1], bool)
+    slot_of, count = live_slots(valid) if walk is None else walk
+    out, state = _kda_state_call(slot_of, count, q, k, log_decay, v,
+                                 beta[:, None, :], state,
+                                 interpret=bool(interpret))
+    # a slot that was not visited is whatever the buffer held
+    return jnp.where(valid[:, None, None], out, 0), state
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kda_state_call(slot_of, count, q, k, log_decay, v, beta, state, *,
+                    interpret):
+    """The launch of :func:`_kda_state_kernel`, a jitted function of its own
+    so that the layers of a step share ONE trace and ONE lowering of the
+    kernel (its loop over the heads is unrolled: lowered a layer, six of
+    them cost a step program 1.9 s of set-up)."""
+    _, heads, d_k = k.shape
+    d_v = v.shape[-1]
+
+    def block(*shape):
+        return pl.BlockSpec((1,) + shape,
+                            lambda t, slot_of, count: (slot_of[t],)
+                            + (0,) * len(shape))
+
+    return pl.pallas_call(
+        _kda_state_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            # one step a live slot (none live: one step that changes
+            # nothing)
+            grid=(jnp.maximum(count[0], 1),),
+            in_specs=[block(heads, d_k), block(heads, d_k),
+                      block(heads, d_k), block(heads, d_v), block(1, heads),
+                      block(heads, d_k, d_v)],
+            out_specs=[block(heads, d_v), block(heads, d_k, d_v)]),
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # the state (operand 7, the scalars counted) IS the state result
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="mx_kda_state",    # what a device trace is searched for
+    )(slot_of, count, q, k, log_decay, v, beta, state)
+
+
 def _register_flash_attention_op():
     """Expose the kernel through the op registry:
     ``_contrib_flash_attention(query, key, value)`` on (B, H, S, D)."""

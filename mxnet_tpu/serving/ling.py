@@ -13,7 +13,9 @@ is one of two kinds (``layer_types``):
     rule over ONE STATE MATRIX ``(head_dim, head_dim)`` A HEAD, the output
     RMS-normalised a head and gated a head by ``sigmoid(x W_g)``. What it
     keeps between tokens is fixed in size: the state and the convolution's
-    last ``taps - 1`` inputs, a slot.
+    last ``taps - 1`` inputs, a slot. A prompt runs the chunked scan; a
+    decode tick updates the state of the slots that hold a token, in place
+    (:func:`~mxnet_tpu.ops.pallas_kernels.kda_state_step`).
 ``mla``
     latent attention (DeepSeek-V2, arXiv:2405.04434): a token is kept as ONE
     row ``[c (kv_lora_rank); k_r (qk_rope_head_dim)]``, ``c`` RMS-normalised,
@@ -443,6 +445,9 @@ class LingDecoder(PagedDecodeModel):
         nope, dv = cfg["qk_nope_head_dim"], cfg["v_head_dim"]
         with jax.named_scope("mx_embed"):
             valid = seq_lens > 0
+        with jax.named_scope("mx_kda_state"):
+            # the live slots, once for every kda layer's update
+            walk = pallas_kernels.kda_state_walk(valid)
 
         def kda(layer, hx, held):
             with jax.named_scope("mx_kda_proj"):
@@ -451,8 +456,8 @@ class LingDecoder(PagedDecodeModel):
                     qkv, held[1], layer["conv"], valid)
                 q, k, v = self._kda_heads(mixed)
             with jax.named_scope("mx_kda_state"):
-                out, s_new = kda_ops.step(q, k, v, decay, beta, held[0],
-                                          valid)
+                out, s_new = pallas_kernels.kda_state_step(
+                    q, k, v, decay, beta, held[0], valid, walk=walk)
                 out = self._kda_out(layer, hx, out)
             return out, (s_new, tail)
 

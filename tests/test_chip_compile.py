@@ -173,6 +173,19 @@ def _case(name, spec, dtype):
         return (lambda q, k, v, n: pk.band_attention(
             q, k, v, scale=192 ** -0.5, interpret=False, precise=True,
             length=n), operands + (spec((), jnp.int32),))
+    if name in ("kda_state_ling", "kda_state_tiny"):
+        # the Ling cell's recurrence: 32 slots x 32 heads of 128 x 128 (a
+        # slot's 2 MB a grid step), and tests/test_ling_decoder.py's 3 heads
+        # of 16 x 8, which the kernel takes by its shapes (always float32:
+        # the state carries what it rounds)
+        slots, heads, dk, dv = (32, 32, 128, 128) if name.endswith("ling") \
+            else (3, 3, 16, 8)
+        cols = spec((slots, heads, dk), jnp.float32)
+        return (lambda *a: pk.kda_state_step(*a, interpret=False),
+                (cols, cols, spec((slots, heads, dv), jnp.float32), cols,
+                 spec((slots, heads), jnp.float32),
+                 spec((slots, heads, dk, dv), jnp.float32),
+                 spec((slots,), jnp.bool_)))
     if name == "nms":
         n = 1000
         return (lambda b, c, v: pk.nms_keep(b, c, v, 0.5, False),
@@ -197,7 +210,8 @@ def _case(name, spec, dtype):
                                   "band_prefill_8192_length_1",
                                   "moe_gmm_decode", "moe_gmm_prefill",
                                   "latent_decode_ling",
-                                  "band_prefill_4096_ling"])
+                                  "band_prefill_4096_ling",
+                                  "kda_state_ling", "kda_state_tiny"])
 def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
                                monkeypatch):
     monkeypatch.setattr(pk, "_interpret", lambda: False)
@@ -221,6 +235,7 @@ def test_kernel_lowers_for_v5e(name, dtype, one_chip, compile_cache_off,
     ("band_prefill_512", "mx_prefill_attn"),
     ("moe_gmm_decode", "mx_moe_gmm"),
     ("latent_decode_ling", "mx_mla_attn"),
+    ("kda_state_ling", "mx_kda_state"),
 ])
 def test_kernel_keeps_its_name_in_the_compiled_program(
         name, kernel, one_chip, compile_cache_off, monkeypatch):
@@ -341,10 +356,12 @@ def test_ling_step_updates_its_latent_pool_and_slot_state_in_place(
         one_chip, compile_cache_off):
     """The Ling cell's shapes: a row into the latent pool, the latent launch
     on that pool (the same array as its K and its V operand), the one-token
-    update of two layers' per-slot state. The compiled step holds no copy
-    of the pool (a pool with a head axis of 1 was converted whole on its way
-    into the kernel, 461 MB a tick: PERF.md section 6, PR 46) and no second
-    state."""
+    update of two layers' per-slot state through ``kda_state_step``'s kernel,
+    one walk for both. The compiled step holds no copy of the pool (a pool
+    with a head axis of 1 was converted whole on its way into the kernel, 461
+    MB a tick: PERF.md section 6, PR 46) and no second state: the kernel's
+    state result IS its state operand, which is what keeps a slot that holds
+    no token untouched."""
     from mxnet_tpu.ops import kda
     from mxnet_tpu.serving import kvcache
 
@@ -358,12 +375,15 @@ def test_ling_step_updates_its_latent_pool_and_slot_state_in_place(
         seen = pk.paged_latent_attention(q, pool, table, lens, 512,
                                          192 ** -0.5, interpret=False)
         live, new = lens > 0, []
+        walk = pk.kda_state_walk(live, interpret=False)
         for s_l, tail in state:
             mixed, tail = kda.short_conv_step(x, tail, jnp.ones((4, 3 * heads
                                                                  * dim)),
                                               live)
             qk = mixed[:, :heads * dim].reshape(slots, heads, dim)
-            out, s_l = kda.step(qk, qk, qk, -qk * qk, qk[..., 0], s_l, live)
+            out, s_l = pk.kda_state_step(qk, qk, qk, -qk * qk, qk[..., 0],
+                                         s_l, live, interpret=False,
+                                         walk=walk)
             new.append((s_l, tail))
             x = x + out.sum()
         return seen, x, pool, tuple(new)
@@ -378,6 +398,8 @@ def test_ling_step_updates_its_latent_pool_and_slot_state_in_place(
         spec((slots, 3 * heads * dim))).compile()
     text = compiled.as_text()
     assert "mx_mla_attn" in text
+    assert len(re.findall(r"%mx_kda_state[.\d]* = [^\n]*tpu_custom_call",
+                          text)) == 2
     pool_bytes = (slots * 352 + 1) * PAGE * 640 * 4
     state_bytes = slots * heads * dim * dim * 4
     # under one layer's state, far under the pool: nothing is held twice

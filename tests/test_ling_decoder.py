@@ -216,6 +216,42 @@ def test_a_row_that_is_no_token_keeps_state_and_tail_bit_for_bit():
     assert np.array_equal(np.asarray(new_tail)[0, -1], np.asarray(x)[0])
 
 
+LIVE = {"none": [], "first": [0], "last": [4], "scattered": [1, 3],
+        "all": [0, 1, 2, 3, 4]}
+
+
+@pytest.mark.parametrize("live", sorted(LIVE))
+@pytest.mark.parametrize("heads,dk,dv", [(3, 16, 8), (2, 128, 128)])
+def test_state_kernel_in_interpret_mode_is_step_over_the_live_slots(
+        heads, dk, dv, live):
+    """``mx_kda_state`` against ``kda.step``: live rows' outputs and states
+    to 1e-5, and a slot that holds no token — every slot of a tick with none
+    — bit for bit what it was (the kernel never visits it)."""
+    slots = 5
+    q, k, v, a, b = _kda_inputs(slots, heads, dk, dv, seed=5)
+    state = jnp.asarray(np.random.RandomState(6).randn(slots, heads, dk, dv)
+                        .astype(np.float32))
+    valid = np.zeros(slots, bool)
+    valid[LIVE[live]] = True
+    want_o, want_s = kda.step(q, k, v, a, b, state, jnp.asarray(valid))
+    got_o, got_s = jax.jit(lambda *xs: pk.kda_state_step(
+        *xs, interpret=True))(q, k, v, a, b, state, jnp.asarray(valid))
+    got_o, got_s, state = (np.asarray(x) for x in (got_o, got_s, state))
+    np.testing.assert_allclose(got_o[valid], np.asarray(want_o)[valid],
+                               atol=1e-5)
+    np.testing.assert_allclose(got_s, want_s, atol=1e-5)
+    assert np.array_equal(got_s[~valid], state[~valid])
+    assert not got_o[~valid].any()
+    if valid.any():
+        assert not np.array_equal(got_s[valid], state[valid])
+    # the walk: the live slots packed to the front, the last one repeated
+    slot_of, count = (np.asarray(x) for x in pk.live_slots(
+        jnp.asarray(valid)))
+    assert count.tolist() == [valid.sum()] and slot_of.shape == (slots + 1,)
+    assert slot_of[:valid.sum()].tolist() == LIVE[live]
+    assert (slot_of[valid.sum():] == (LIVE[live] or [0])[-1]).all()
+
+
 def test_short_conv_step_continues_short_conv():
     rng = np.random.RandomState(4)
     x = jnp.asarray(rng.randn(9, 6).astype(np.float32))
@@ -506,6 +542,34 @@ def test_a_seq_len_0_row_leaves_its_slots_state_bit_for_bit(tiny):
         assert np.array_equal(old[1:], new[1:])
     assert any(not np.array_equal(old[0], new[0])
                for old, new in zip(before, after))
+
+
+def test_decode_through_the_state_kernel_is_decode_through_step(
+        tiny, monkeypatch):
+    """``LingDecoder.decode`` with ``mx_kda_state`` in interpret mode (one
+    walk handed to every kda layer) against the same tick through
+    ``kda.step``: logits of the live rows, every layer's state, and the
+    empty slot's state bit for bit."""
+    import functools
+
+    model, params = tiny
+    ticks = []
+    for kernel in (False, True):
+        if kernel:
+            for name in ("kda_state_walk", "kda_state_step"):
+                monkeypatch.setattr(pk, name, functools.partial(
+                    getattr(pk, name), interpret=True))
+        cache = _cache(model, slots=3)
+        _prefill(model, params, cache, 0, _prompt(20, 14), 64, 30)
+        _prefill(model, params, cache, 2, _prompt(9, 15), 64, 30)
+        before = np.asarray(cache.slot_state[0][0])
+        logits = _decode(model, params, cache, {0: (5, 20), 2: (7, 9)})
+        ticks.append((logits, [np.asarray(s) for s, _t in cache.slot_state]))
+        assert np.array_equal(ticks[-1][1][0][1], before[1])
+    (want, want_s), (got, got_s) = ticks
+    np.testing.assert_allclose(got[[0, 2]], want[[0, 2]], atol=LOGIT_TOL)
+    for a, b in zip(got_s, want_s):
+        np.testing.assert_allclose(a, b, atol=1e-5)
 
 
 # -- through the engine -------------------------------------------------
