@@ -9,8 +9,9 @@ Three pure functions, plain XLA, float32 throughout (every product of two
 activations at ``highest`` precision: the state carries what it rounds):
 
 :func:`chunked_scan`
-    a whole prompt, ``chunk`` tokens at a time (a ``lax.scan`` over the
-    chunks; inside one the recurrence is solved as a triangular system);
+    a whole prompt, ``chunk`` tokens at a time (a loop over the chunks that
+    hold the prompt, its trip count traced from the prompt's length; inside
+    one the recurrence is solved as a triangular system);
 :func:`step`
     one token a slot, EVERY slot's state read twice and written once
     whatever holds a token: the reference of the tick and its path off the
@@ -82,11 +83,15 @@ def step(q, k, v, log_decay, beta, state, valid=None):
     return out, new
 
 
-def chunked_scan(q, k, v, log_decay, beta, chunk=CHUNK):
+def chunked_scan(q, k, v, log_decay, beta, chunk=CHUNK, length=None):
     """:func:`serial_scan` a ``chunk`` of tokens at a time (same arguments
     and result). A row with ``log_decay`` 0 and ``beta`` 0 moves nothing:
     that is how a caller masks the padding of a rung. ``T`` is padded to a
-    whole number of chunks with such rows.
+    whole number of chunks with such rows. ``length`` (a traced int32 count,
+    or None: every row is a token): the rows from ``length`` on are padding
+    whatever they hold — they neither decay nor update — and the chunks
+    behind the one that holds row ``length - 1`` are not visited: their
+    outputs come back ZERO.
 
     A chunk, all heads at once, with ``S`` the state in front of it, ``G``
     the cumulative log-decays inside it, ``D_ij = exp(G_i - G_j)`` (a
@@ -116,8 +121,15 @@ def chunked_scan(q, k, v, log_decay, beta, chunk=CHUNK):
     def dot(a, b, spec):
         return jnp.einsum(spec, a, b, precision=_HIGHEST)
 
-    def one(s, xs):
-        qc, kc, vc, ac, bc = xs                 # (C, H, ...)
+    xs = tuple(chunks(x) for x in (q, k, v, log_decay, beta))
+
+    def one(i, carry):
+        s, outs = carry
+        qc, kc, vc, ac, bc = (x[i] for x in xs)     # (C, H, ...)
+        if length is not None:
+            token = i * chunk + jnp.arange(chunk) < length
+            ac = jnp.where(token[:, None, None], ac, 0.0)
+            bc = jnp.where(token[:, None], bc, 0.0)
         g = jnp.cumsum(ac, axis=0)              # (C, H, d_k)
         # (C, C, H, d_k): the decay from token j to token i, 0 above the
         # diagonal (where the argument would be positive)
@@ -136,10 +148,12 @@ def chunked_scan(q, k, v, log_decay, beta, chunk=CHUNK):
             + dot(b_qk, u, "ijh,hje->ihe")
         to_end = jnp.exp(g[-1][None] - g)
         s = into[-1][..., None] * s + dot(kc * to_end, u, "jhd,hje->hde")
-        return s, out
+        return s, outs.at[i].set(out)
 
-    state, out = lax.scan(one, state, tuple(
-        chunks(x) for x in (q, k, v, log_decay, beta)))
+    n_live = n if length is None else jnp.clip(
+        -(-jnp.asarray(length, jnp.int32) // chunk), 0, n)
+    state, out = lax.fori_loop(0, n_live, one, (
+        state, jnp.zeros((n, chunk, n_heads, d_v), jnp.float32)))
     return out.reshape((n * chunk, n_heads, d_v))[:t], state
 
 

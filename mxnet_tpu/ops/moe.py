@@ -38,6 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_kernels import _interpret, _pad_up
+from .row_blocks import row_blocks
 
 __all__ = ["route", "expert_layer", "grouped_matmul",
            "grouped_matmul_reference", "matmul", "split_terms"]
@@ -174,7 +175,8 @@ def _gmm_kernel(bounds_ref, grp_ref, tile_ref, lhs_ref, rhs_ref, out_ref,
                              ).astype(out_ref.dtype)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, name="mx_moe_gmm", interpret=None):
+def grouped_matmul(lhs, rhs, group_sizes, name="mx_moe_gmm", interpret=None,
+                   zero=True):
     """``out[r] = lhs[r] @ rhs[g]`` for the rows ``r`` of group ``g``:
     ``lhs`` ``(M, K)`` with its rows sorted by group, ``rhs`` ``(G, K, N)``,
     ``group_sizes`` ``(G,)`` int32 (sum <= M). Rows past the last group come
@@ -182,8 +184,12 @@ def grouped_matmul(lhs, rhs, group_sizes, name="mx_moe_gmm", interpret=None):
     type on the MXU (float32 rows against bfloat16 weights as two terms:
     :func:`split_terms`), float32 accumulation, float32 out; only tiles that hold
     a row are visited and only the weights of groups that have one are
-    read. Off the TPU :func:`grouped_matmul_reference`, unless
-    ``interpret`` asks for the kernel."""
+    read. ``zero=False`` leaves the rows past the last group as the buffer
+    held them (tiles no item visited are never written): for a caller whose
+    next pass over the rows zeroes them itself
+    (:func:`~mxnet_tpu.ops.row_blocks.row_blocks`). Off the TPU
+    :func:`grouped_matmul_reference`, unless ``interpret`` asks for the
+    kernel."""
     if interpret is None:
         if _interpret():
             return grouped_matmul_reference(lhs, rhs, group_sizes)
@@ -221,6 +227,8 @@ def grouped_matmul(lhs, rhs, group_sizes, name="mx_moe_gmm", interpret=None):
         interpret=interpret,
         name=name,
     )(bounds, grp, tile, lhs, rhs)
+    if not zero:
+        return out[:m]
     # tiles no item visited, and rows of a visited tile past the last
     # group, hold whatever the buffer held
     live = jnp.arange(mp, dtype=jnp.int32)[:, None] < bounds[-1]
@@ -241,16 +249,27 @@ def grouped_matmul_reference(lhs, rhs, group_sizes):
 # the layer
 # ---------------------------------------------------------------------------
 
-def _swiglu(x, weights, group_sizes, name):
+def _swiglu(x, weights, group_sizes, name, rows=None):
     """``(silu(x w1) * (x w3)) w2`` of each row under its group's weights
-    (``weights``: ``w1``/``w3`` ``(G, E, M)``, ``w2`` ``(G, M, E)``)."""
-    gate = grouped_matmul(x, weights["w1"], group_sizes, name=name)
-    up = grouped_matmul(x, weights["w3"], group_sizes, name=name)
-    return grouped_matmul(jax.nn.silu(gate) * up, weights["w2"],
-                          group_sizes, name=name)
+    (``weights``: ``w1``/``w3`` ``(G, E, M)``, ``w2`` ``(G, M, E)``), zero
+    in the rows past the last group. ``rows``: the groups' sum as a traced
+    count (a prefill's), or ``None`` — with it the activation and the result
+    are passes over the row blocks that hold a row
+    (:func:`~mxnet_tpu.ops.row_blocks.row_blocks`), and it is they that zero
+    what the products' tiles did not write."""
+    zero = rows is None
+    gate = grouped_matmul(x, weights["w1"], group_sizes, name=name, zero=zero)
+    up = grouped_matmul(x, weights["w3"], group_sizes, name=name, zero=zero)
+    act = row_blocks(lambda g, u: jax.nn.silu(g) * u, (gate, up), rows)
+    out = grouped_matmul(act, weights["w2"], group_sizes, name=name,
+                         zero=zero)
+    return row_blocks(lambda o: o, (out,), rows)
 
 
-def expert_layer(h, route, experts, held, shared=None, valid=None):
+# a jit of its own: a model's expert layers share one trace and one lowering
+@functools.partial(jax.jit, static_argnames=("held",))
+def expert_layer(h, route, experts, held, shared=None, valid=None,
+                 length=None):
     """What this chip's experts add to each token, plus the shared expert.
 
     ``h``: ``(T, E)``; ``route``: ``(sel, weights)`` of :func:`route` over
@@ -259,11 +278,19 @@ def expert_layer(h, route, experts, held, shared=None, valid=None):
     count - 1`` (``held = (first, count)``); ``shared``: ``{"w1", "w3": (E,
     M), "w2": (M, E)}`` or ``None``; ``valid``: ``(T,)`` bool — rows that
     are real tokens (padding of a prefill rung and idle decode slots are
-    routed nowhere and counted nowhere).
+    routed nowhere and counted nowhere). ``length``: a traced int32 count,
+    or ``None`` — where the real tokens are the first ``length`` rows (a
+    prompt on its rung), the row-wise passes visit only the row blocks that
+    hold work (:func:`~mxnet_tpu.ops.row_blocks.row_blocks`): the shared
+    expert and the combine those of the first ``length`` tokens, the gather
+    and the activation of the sorted (token, pick) rows those of the rows
+    routed to experts held here, which the sort puts first. A decode tick
+    hands none and runs straight-line code over every row.
 
     Returns ``(out (T, E) float32, rows (count + 1,) int32)``: ``out =
     shared(h) + sum over a token's picks that are held here of w_e *
-    expert_e(h)``; ``rows[e]`` the (token, pick) rows expert ``first + e``
+    expert_e(h)`` (with ``length``: zero for the tokens of the row blocks
+    behind it); ``rows[e]`` the (token, pick) rows expert ``first + e``
     received and ``rows[count]`` those routed to experts held elsewhere.
     """
     sel, weights = route
@@ -281,20 +308,25 @@ def expert_layer(h, route, experts, held, shared=None, valid=None):
         rows = jnp.zeros((count + 2,), jnp.int32).at[key].add(1)[:count + 1]
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         group_sizes = rows[:count]
-        x = h[order // top_k]
+        n_held = None if length is None else group_sizes.sum()
+        x = row_blocks(lambda o: h[o // top_k], (order,), n_held)
     with jax.named_scope("mx_moe_experts"):
-        y = _swiglu(x, experts, group_sizes, "mx_moe_gmm")
+        y = _swiglu(x, experts, group_sizes, "mx_moe_gmm", n_held)
     with jax.named_scope("mx_moe_combine"):
         # back to (token, pick) order; rows past the held groups are zero
         back = jnp.zeros((n_rows,), jnp.int32).at[order].set(
             jnp.arange(n_rows, dtype=jnp.int32))
-        y = y[back].reshape(t, top_k, -1)
-        out = jnp.einsum("tk,tke->te", weights.astype(jnp.float32), y)
+        out = row_blocks(
+            lambda w, at: jnp.einsum("tk,tke->te", w.astype(jnp.float32),
+                                     y[at.reshape(-1)].reshape(at.shape
+                                                               + (-1,))),
+            (weights, back.reshape(t, top_k)), length)
     if shared is not None:
         with jax.named_scope("mx_moe_shared"):
-            whole = jnp.asarray([t], jnp.int32)
+            whole = jnp.asarray([t], jnp.int32) if length is None \
+                else jnp.reshape(length, (1,)).astype(jnp.int32)
             alike = _swiglu(h, {k: v[None] for k, v in shared.items()},
-                            whole, "mx_moe_shared")
+                            whole, "mx_moe_shared", length)
         with jax.named_scope("mx_moe_combine"):
             out = out + alike
     return out, rows

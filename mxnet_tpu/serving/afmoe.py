@@ -25,7 +25,11 @@ What it declares to :class:`~mxnet_tpu.serving.DecodeEngine`:
 ``prefill_attn_blocks``
     the block pairs :func:`~mxnet_tpu.ops.pallas_kernels.band_attention`
     multiplies for a prompt, over the layers: the padding of a rung behind
-    the prompt's last block is not computed.
+    the prompt's last block is not computed;
+``prefill_rows``
+    the rows of a rung a prefill's row-wise passes (norms, projections,
+    router, shared expert, combine) compute for a prompt: those of the row
+    blocks the prompt reaches (:mod:`mxnet_tpu.ops.row_blocks`).
 
 Decode attends through :func:`~mxnet_tpu.ops.pallas_kernels.paged_attention`
 (full) and :func:`~mxnet_tpu.ops.pallas_kernels.paged_window_attention`
@@ -181,32 +185,51 @@ class AfmoeDecoder(PagedDecodeModel):
             q, k = self._rope(q, positions), self._rope(k, positions)
         return q, k, v
 
-    def _mlp(self, layer, hx, valid):
-        """``(mlp(hx), rows or None)``: dense SwiGLU or the expert layer
-        (whose parts :mod:`mxnet_tpu.ops.moe` names itself)."""
+    def _behind_attention(self, layer, x, hx, att):
+        """A row from its attention's output to where its MLP begins. A
+        dense layer: the whole rest of the layer, ``(x,)``; an expert layer:
+        ``(x, normed row, picks, weights)`` — the router is the last thing a
+        row decides alone."""
         import jax
 
         from ..ops import moe
 
-        if "router" not in layer:
-            with jax.named_scope("mx_mlp"):
-                return _mm(jax.nn.silu(_mm(hx, layer["w1"]))
-                           * _mm(hx, layer["w3"]), layer["w2"]), None
+        part = jax.named_scope
         cfg = self.cfg
-        picks = moe.route(hx, layer["router"], layer["expert_bias"],
-                          cfg["num_experts_per_tok"], cfg["route_norm"],
-                          cfg["route_scale"])
-        return moe.expert_layer(hx, picks, layer["experts"],
-                                tuple(cfg["held_experts"]),
-                                shared=layer["shared"], valid=valid)
+        with part("mx_attn_out"):
+            att = att.reshape(att.shape[0], -1) \
+                * jax.nn.sigmoid(_mm(hx, layer["wg"]))
+            x = x + self._rms(_mm(att, layer["wo"]), layer["ln_post_attn"])
+        # an expert layer's norm in front goes with its router, the norm
+        # behind and the residual with its combine
+        if "router" in layer:
+            with part("mx_moe_route"):
+                hm = self._rms(x, layer["ln_pre_mlp"])
+            return (x, hm) + moe.route(
+                hm, layer["router"], layer["expert_bias"],
+                cfg["num_experts_per_tok"], cfg["route_norm"],
+                cfg["route_scale"])
+        with part("mx_mlp"):
+            hm = self._rms(x, layer["ln_pre_mlp"])
+            m = _mm(jax.nn.silu(_mm(hm, layer["w1"])) * _mm(hm, layer["w3"]),
+                    layer["w2"])
+            return (x + self._rms(m, layer["ln_post_mlp"]),)
 
     def _forward(self, params, tokens, positions, k_pool, v_pool,
-                 write_pages, write_offsets, valid, attend):
+                 write_pages, write_offsets, valid, attend, length=None):
         """The layers over ``tokens`` rows, each piece under its part of the
         program (``telemetry.PROGRAM_PARTS``); ``attend(sliding, q, k, v,
-        pools)`` is the one thing prefill and decode do differently."""
+        pools)`` is one thing prefill and decode do differently. The other
+        is ``length``: a prefill hands the count of rows that hold its
+        prompt, and what a row computes alone then runs over the row blocks
+        under it (:func:`~mxnet_tpu.ops.row_blocks.row_blocks`; the rows
+        behind them come back zero, and nothing reads them); a decode tick
+        hands none and runs every row as straight-line code."""
         import jax
         import jax.numpy as jnp
+
+        from ..ops import moe
+        from ..ops.row_blocks import row_blocks
 
         part = jax.named_scope
         k_pool, v_pool = list(k_pool), list(v_pool)
@@ -216,9 +239,13 @@ class AfmoeDecoder(PagedDecodeModel):
         for li, layer in enumerate(params["layers"]):
             sliding = self.cfg["layer_types"][li] == "sliding_attention"
             grp, gi = self._place[li]
-            with part("mx_qkv"):
+
+            def qkv(x, positions):
                 hx = self._rms(x, layer["ln_in"])
-                q, k, v = self._qkv(layer, hx, positions, sliding)
+                return (hx,) + self._qkv(layer, hx, positions, sliding)
+
+            with part("mx_qkv"):
+                hx, q, k, v = row_blocks(qkv, (x, positions), length)
             with part("mx_kv_write"):
                 k_pool[grp], v_pool[grp] = write_kv(
                     k_pool[grp], v_pool[grp], gi, k, v, write_pages[grp],
@@ -227,19 +254,18 @@ class AfmoeDecoder(PagedDecodeModel):
                 att = attend(sliding, q, k, v, k_pool[grp][gi],
                              v_pool[grp][gi])
             with part("mx_attn_out"):
-                att = att.reshape(att.shape[0], -1) \
-                    * jax.nn.sigmoid(_mm(hx, layer["wg"]))
-                x = x + self._rms(_mm(att, layer["wo"]),
-                                  layer["ln_post_attn"])
-            # an expert layer's norm in front goes with its router, the
-            # norm behind and the residual with its combine
-            dense = "router" not in layer
-            with part("mx_mlp" if dense else "mx_moe_route"):
-                hm = self._rms(x, layer["ln_pre_mlp"])
-            m, n_rows = self._mlp(layer, hm, valid)
-            if n_rows is not None:
-                rows.append(n_rows)
-            with part("mx_mlp" if dense else "mx_moe_combine"):
+                x, *routed = row_blocks(
+                    lambda *row: self._behind_attention(layer, *row),
+                    (x, hx, att), length)
+            if not routed:
+                continue
+            hm, sel, weights = routed
+            m, n_rows = moe.expert_layer(
+                hm, (sel, weights), layer["experts"],
+                tuple(self.cfg["held_experts"]), shared=layer["shared"],
+                valid=valid, length=length)
+            rows.append(n_rows)
+            with part("mx_moe_combine"):
                 x = x + self._rms(m, layer["ln_post_mlp"])
         with part("mx_head"):
             counters = (jnp.stack(rows),) if rows else ()
@@ -261,14 +287,19 @@ class AfmoeDecoder(PagedDecodeModel):
             valid = positions < length
         window = self.cfg["sliding_window"]
 
-        def attend(sliding, q, k, v, _kp, _vp):
+        def attend(sliding, q, k, v, kp, vp):
+            # the rows are in the pools before the attention reads them:
+            # left to itself the scheduler writes every layer's at the
+            # program's end and keeps them all until then (336 MB at rung
+            # 8192; compile, PR 48)
+            q, k, v, _, _ = jax.lax.optimization_barrier((q, k, v, kp, vp))
             return pallas_kernels.band_attention(
                 q, k, v, scale=self.scale, window=window if sliding else 0,
                 precise=True, length=length)
 
         x, k_pool, v_pool, counters = self._forward(
             params, tokens, positions, k_pool, v_pool, write_pages,
-            write_offsets, valid, attend)
+            write_offsets, valid, attend, length=length)
         with jax.named_scope("mx_head"):
             last = _mm(self._rms(x[length - 1], params["ln_f"])[None],
                        params["head"])[0]
@@ -284,6 +315,13 @@ class AfmoeDecoder(PagedDecodeModel):
             tokens, rung, self.cfg["sliding_window"]) \
             + (self.num_layers - n_window) * pallas_kernels.band_blocks(
                 tokens, rung)
+
+    def prefill_rows(self, tokens: int, rung: int) -> int:
+        """Rows of ``rung`` that :meth:`prefill`'s row-wise passes compute
+        for a prompt of ``tokens``: those of the row blocks it reaches."""
+        from ..ops import row_blocks
+
+        return row_blocks.rows_visited(tokens, rung)
 
     def prefill_chunk(self, params, tokens, start, length, k_pool, v_pool,
                       page_table_row, write_pages, write_offsets):

@@ -194,6 +194,13 @@ _T_PREFILL_ATTN_BLOCKS = telemetry.counter(
     "padded rung, kind=live over the prompt's real tokens — what the kernel "
     "multiplies (a model that declares prefill_attn_blocks)",
     labels=("server", "kind"))
+_T_PREFILL_ROWS = telemetry.counter(
+    "mxnet_decode_prefill_rows_total",
+    "rows of the whole-prompt prefills' row-wise passes (norms, projections, "
+    "router, shared expert): kind=rung the padded rungs' rows, "
+    "kind=computed those of the row blocks the prompts reach — what is "
+    "computed (a model that declares prefill_rows)",
+    labels=("server", "kind"))
 _T_STEP_TEMP = telemetry.gauge(
     "mxnet_decode_step_temp_bytes",
     "temporaries of the compiled decode step (its memory_analysis), set by "
@@ -280,6 +287,11 @@ class PagedDecodeModel:
         multiplies for a prompt of ``tokens`` padded to ``rung``, a kv head,
         over its layers (host arithmetic): the engine writes them on the
         ``mx.decode.prefill`` span and sums them in ``stats()``.
+    ``prefill_rows(tokens, rung)``
+        the rows of ``rung`` its prefill's row-wise passes compute for a
+        prompt of ``tokens`` (host arithmetic: those of the row blocks the
+        prompt reaches, :mod:`mxnet_tpu.ops.row_blocks`): on the span as
+        ``rows_rung`` / ``rows_computed``, summed in ``stats()`` likewise.
     """
 
     num_layers: int
@@ -589,6 +601,8 @@ class DecodeEngine:
         self._prefill_held_slot_ms = 0.0
         self._attn_blocks_rung = 0
         self._attn_blocks_live = 0
+        self._rows_of_rungs = 0
+        self._rows_of_blocks = 0
         pool_bytes = int(sum(x.nbytes for x in pools))
         self._governor.register_bound("serving.%s.kv_pool" % name,
                                       pool_bytes)
@@ -1182,6 +1196,10 @@ class DecodeEngine:
                 # the kernel multiplies)
                 "attn_blocks_rung": self._attn_blocks_rung,
                 "attn_blocks_live": self._attn_blocks_live,
+                # rows of the prefills' row-wise passes: the padded rungs',
+                # and those of the row blocks the prompts reached
+                "prefill_rows_rung": self._rows_of_rungs,
+                "prefill_rows_computed": self._rows_of_blocks,
                 "prefill_buckets": list(self._ladder),
                 "prefill_chunk": self._chunk,
                 "cow_copies": self._cow_copies,
@@ -1773,7 +1791,8 @@ class DecodeEngine:
         with self._prefill_span(rung=rung) as span:
             if matched == 0:
                 tok = self._run_full_prefill(req, slot, ring=ring)
-                span.set_args(**self._attn_blocks(rung, p))
+                span.set_args(**self._attn_blocks(rung, p),
+                              **self._rows_computed(rung, p))
             else:
                 tok = self._run_chunk(slot, req, req.filled, p, rung)
             self._finish_prefill(req, slot, tok, span)
@@ -2352,6 +2371,22 @@ class DecodeEngine:
             self._attn_blocks_rung += n_rung
             self._attn_blocks_live += n_live
         return {"attn_blocks_rung": n_rung, "attn_blocks_live": n_live}
+
+    def _rows_computed(self, rung: int, tokens: int) -> dict:
+        """Span arguments of a whole-prompt prefill of a model whose
+        row-wise passes follow the prompt's length (it declares
+        ``prefill_rows``): the rung's rows and those computed — host
+        arithmetic — and their running sums."""
+        rows = getattr(self._model, "prefill_rows", None)
+        if rows is None:
+            return {}
+        computed = rows(tokens, rung)
+        _T_PREFILL_ROWS.inc(rung, server=self._name, kind="rung")
+        _T_PREFILL_ROWS.inc(computed, server=self._name, kind="computed")
+        with self._cv:      # stats() reads them from caller threads
+            self._rows_of_rungs += rung
+            self._rows_of_blocks += computed
+        return {"rows_rung": rung, "rows_computed": computed}
 
     def _layer_args(self, counters, live, prefill=False) -> dict:
         """Span arguments of a prefill or a decode tick beyond the page

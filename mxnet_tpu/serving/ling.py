@@ -36,7 +36,10 @@ What it declares to :class:`~mxnet_tpu.serving.DecodeEngine`:
 ``layer_state`` (a ``slot`` entry a kda layer, a ``latent`` entry a mla
 layer: the engine hands ``decode`` / ``prefill`` the cache's two operands,
 the latent pools and the slot state, where a K/V model is handed ``k_pool``
-and ``v_pool``) and ``moe_counters``.
+and ``v_pool``), ``moe_counters`` and ``prefill_rows`` (the rows of a rung a
+prefill's row-wise passes compute for a prompt: those of the row blocks the
+prompt reaches, :mod:`mxnet_tpu.ops.row_blocks`; the scan visits the chunks
+that hold the prompt).
 Activations are float32 for real (:func:`mxnet_tpu.ops.moe.matmul` against
 bfloat16 weights, float32 products in the recurrence and the attention): a
 router amplifies rounding and a recurrence carries it. No chunked prefill,
@@ -229,41 +232,22 @@ class LingDecoder(PagedDecodeModel):
         x1, x2 = x[..., :d // 2], x[..., d // 2:]
         return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
-    def _mlp(self, layer, hx, valid):
-        """``(mlp(hx), rows or None)``: dense SwiGLU or the expert layer
-        (whose parts :mod:`mxnet_tpu.ops.moe` names itself)."""
-        import jax
-
-        from ..ops import moe
-
-        if "router" not in layer:
-            with jax.named_scope("mx_mlp"):
-                return _mm(jax.nn.silu(_mm(hx, layer["w1"]))
-                           * _mm(hx, layer["w3"]), layer["w2"]), None
-        cfg = self.cfg
-        picks = moe.route(hx, layer["router"], layer["expert_bias"],
-                          cfg["num_experts_per_tok"], cfg["norm_topk_prob"],
-                          cfg["routed_scaling_factor"],
-                          n_group=cfg["n_group"],
-                          topk_group=cfg["topk_group"])
-        return moe.expert_layer(hx, picks, layer["experts"],
-                                tuple(cfg["held_experts"]),
-                                shared=layer["shared"], valid=valid)
-
-    def _kda_inputs(self, layer, hx):
-        """``(qkv (N, 3 H D) before the convolution, log decay (N, H, D),
-        beta (N, H))`` of a kda layer."""
+    def _kda_inputs(self, layer, x):
+        """A kda layer's rows from the residual stream: ``(normed row (N,
+        E), qkv (N, 3 H D) before the convolution, log decay (N, H, D), beta
+        (N, H))``."""
         import jax
         import jax.numpy as jnp
 
-        n = hx.shape[0]
+        n = x.shape[0]
         h, d = self.num_heads, self.head_dim
+        hx = self._rms(x, layer["ln_in"])
         qkv = jnp.concatenate([_mm(hx, layer["wq"]), _mm(hx, layer["wk"]),
                                _mm(hx, layer["wv"])], axis=-1)
         gate = (_mm(hx, layer["wf"]) + layer["dt_bias"]).reshape(n, h, d)
         decay = self.cfg["kda_lower_bound"] * jax.nn.sigmoid(
             jnp.exp(layer["a_log"])[None, :, None] * gate)
-        return qkv, decay, jax.nn.sigmoid(_mm(hx, layer["wb"]))
+        return hx, qkv, decay, jax.nn.sigmoid(_mm(hx, layer["wb"]))
 
     def _kda_heads(self, mixed):
         """The convolution's output ``(N, 3 H D)`` as the recurrence's ``q,
@@ -291,31 +275,69 @@ class LingDecoder(PagedDecodeModel):
             * jax.nn.sigmoid(_mm(hx, layer["wg"]))[..., None]
         return gated.reshape(out.shape[0], -1)
 
-    def _mla_rows(self, layer, hx, positions):
-        """``(q_nope (N, H, nope), q_rope (N, H, rope) rotated, row (N, rank
-        + rope))``: a latent layer's queries and the row it keeps a token."""
+    def _mla_rows(self, layer, x, positions):
+        """A latent layer's rows from the residual stream: ``(normed row (N,
+        E), q_nope (N, H, nope), q_rope (N, H, rope) rotated, row (N, rank +
+        rope))`` — its queries and the row it keeps a token."""
         import jax.numpy as jnp
 
         cfg = self.cfg
-        n = hx.shape[0]
+        n = x.shape[0]
         nope, rope = cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"]
         rank = cfg["kv_lora_rank"]
+        hx = self._rms(x, layer["ln_in"])
         q = _mm(hx, layer["wq"]).reshape(n, self.num_heads, nope + rope)
         kva = _mm(hx, layer["wkva"])
         row = jnp.concatenate(
             [self._rms(kva[:, :rank], layer["kv_norm"]),
              self._rope(kva[:, rank:], positions)], axis=-1)
-        return q[..., :nope], self._rope(q[..., nope:], positions), row
+        return hx, q[..., :nope], self._rope(q[..., nope:], positions), row
+
+    def _behind_attention(self, layer, kind, x, hx, att):
+        """A row from its attention's output (the recurrence's ``(N, H,
+        D)``, the latent attention's ``(N, H, dv)``) to where its MLP
+        begins. A dense layer: the whole rest of the layer, ``(x,)``; an
+        expert layer: ``(x, normed row)``, what its router and its experts
+        take."""
+        import jax
+
+        part = jax.named_scope
+        if kind == "kda":
+            with part("mx_kda_state"):
+                att = self._kda_out(layer, hx, att)
+            with part("mx_kda_proj"):
+                x = x + _mm(att, layer["wo"])
+        else:
+            with part("mx_mla_proj"):
+                att = att * jax.nn.sigmoid(_mm(hx, layer["wg"]))[..., None]
+                x = x + _mm(att.reshape(att.shape[0], -1), layer["wo"])
+        # an expert layer's norm in front goes with its router, the
+        # residual behind with its combine
+        if "router" in layer:
+            with part("mx_moe_route"):
+                return x, self._rms(x, layer["ln_mlp"])
+        with part("mx_mlp"):
+            hm = self._rms(x, layer["ln_mlp"])
+            return (x + _mm(jax.nn.silu(_mm(hm, layer["w1"]))
+                            * _mm(hm, layer["w3"]), layer["w2"]),)
 
     def _forward(self, params, tokens, positions, latent, state, write_pages,
-                 write_offsets, valid, kda, mla):
+                 write_offsets, valid, kda, mla, length=None):
         """The layers over ``tokens`` rows, each piece under its part of the
-        program (``telemetry.PROGRAM_PARTS``). ``kda(layer, hx, state of the
-        layer) -> (out (N, H D), state)`` and ``mla(layer, hx, q_nope,
-        q_rope, row, pool of the layer) -> out (N, H dv)`` are what prefill
-        and decode do differently."""
+        program (``telemetry.PROGRAM_PARTS``). ``kda(layer, qkv, decay,
+        beta, state of the layer) -> (out (N, H, D), state)`` and
+        ``mla(layer, q_nope, q_rope, row, pool of the layer) -> out (N, H,
+        dv)`` are what prefill and decode do differently, and ``length``: a
+        prefill hands the count of rows that hold its prompt, and what a row
+        computes alone then runs over the row blocks under it
+        (:func:`~mxnet_tpu.ops.row_blocks.row_blocks`; the rows behind them
+        come back zero, and nothing reads them); a decode tick hands none
+        and runs every row as straight-line code."""
         import jax
         import jax.numpy as jnp
+
+        from ..ops import moe
+        from ..ops.row_blocks import row_blocks
 
         part = jax.named_scope
         latent, state = tuple(latent), list(state)
@@ -324,34 +346,42 @@ class LingDecoder(PagedDecodeModel):
         rows = []
         for li, layer in enumerate(params["layers"]):
             at = self._place[li]
-            if self.cfg["layer_types"][li] == "kda":
+            kind = self.cfg["layer_types"][li]
+            if kind == "kda":
                 with part("mx_kda_proj"):
-                    hx = self._rms(x, layer["ln_in"])
-                att, state[at] = kda(layer, hx, state[at])
-                with part("mx_kda_proj"):
-                    x = x + _mm(att, layer["wo"])
+                    hx, qkv, decay, beta = row_blocks(
+                        lambda x: self._kda_inputs(layer, x), (x,), length)
+                att, state[at] = kda(layer, qkv, decay, beta, state[at])
             else:
                 with part("mx_mla_proj"):
-                    hx = self._rms(x, layer["ln_in"])
-                    q_nope, q_rope, row = self._mla_rows(layer, hx,
-                                                         positions)
+                    hx, q_nope, q_rope, row = row_blocks(
+                        lambda x, at: self._mla_rows(layer, x, at),
+                        (x, positions), length)
                 with part("mx_kv_write"):
                     latent = write_rows(latent, at, row, write_pages,
                                         write_offsets)
-                att = mla(layer, hx, q_nope, q_rope, row, latent[at])
-                with part("mx_mla_proj"):
-                    att = att * jax.nn.sigmoid(
-                        _mm(hx, layer["wg"]))[..., None]
-                    x = x + _mm(att.reshape(att.shape[0], -1), layer["wo"])
-            # an expert layer's norm in front goes with its router, the
-            # residual behind with its combine
-            dense = "router" not in layer
-            with part("mx_mlp" if dense else "mx_moe_route"):
-                hm = self._rms(x, layer["ln_mlp"])
-            m, n_rows = self._mlp(layer, hm, valid)
-            if n_rows is not None:
-                rows.append(n_rows)
-            with part("mx_mlp" if dense else "mx_moe_combine"):
+                att = mla(layer, q_nope, q_rope, row, latent[at])
+            with part("mx_kda_proj" if kind == "kda" else "mx_mla_proj"):
+                x, *routed = row_blocks(
+                    lambda *row: self._behind_attention(layer, kind, *row),
+                    (x, hx, att), length)
+            if not routed:
+                continue
+            # the router stays whole: a top-k over a block of rows lowers to
+            # a full sort, 2.2 ms a layer at rung 4096 (PERF.md section 6,
+            # PR 48)
+            (hm,), cfg = routed, self.cfg
+            picks = moe.route(hm, layer["router"], layer["expert_bias"],
+                              cfg["num_experts_per_tok"],
+                              cfg["norm_topk_prob"],
+                              cfg["routed_scaling_factor"],
+                              n_group=cfg["n_group"],
+                              topk_group=cfg["topk_group"])
+            m, n_rows = moe.expert_layer(
+                hm, picks, layer["experts"], tuple(cfg["held_experts"]),
+                shared=layer["shared"], valid=valid, length=length)
+            rows.append(n_rows)
+            with part("mx_moe_combine"):
                 x = x + m
         with part("mx_head"):
             counters = (jnp.stack(rows),) if rows else ()
@@ -366,6 +396,7 @@ class LingDecoder(PagedDecodeModel):
         from ..ops import kda as kda_ops
         from ..ops import pallas_kernels
         from ..ops.pallas_kernels import LANES
+        from ..ops.row_blocks import row_blocks
 
         if attn is not None or slot is None:
             raise MXNetError("LingDecoder prefills one slot's state: no ring "
@@ -379,37 +410,45 @@ class LingDecoder(PagedDecodeModel):
             positions = jnp.arange(t, dtype=jnp.int32)
             valid = positions < length
 
-        def kda(layer, hx, held):
+        def kda(layer, qkv, decay, beta, held):
+            # the slot's state is written where it is made, `soon`: left to
+            # itself the scheduler writes every layer's at the program's end
+            # and keeps what it is made from until then (1.2 GB of `qkv` at
+            # rung 4096; compile, PR 48)
+            soon = jax.lax.optimization_barrier
             with jax.named_scope("mx_kda_proj"):
-                qkv, decay, beta = self._kda_inputs(layer, hx)
-                q, k, v = self._kda_heads(
+                # the convolution reaches across rows: it stays whole
+                qkv_heads = self._kda_heads(
                     kda_ops.short_conv(qkv, layer["conv"]))
-                tail = kda_ops.conv_tail(qkv, length, taps)
+                tails = held[1].at[slot].set(
+                    kda_ops.conv_tail(qkv, length, taps))
+                (q, k, v), tails = soon((qkv_heads, tails))
             with jax.named_scope("mx_kda_state"):
-                # the padding behind the prompt neither decays nor updates
-                out, s_new = kda_ops.chunked_scan(
-                    q, k, v, jnp.where(valid[:, None, None], decay, 0.0),
-                    jnp.where(valid[:, None], beta, 0.0))
-                out = self._kda_out(layer, hx, out)
-                held = (held[0].at[slot].set(s_new),
-                        held[1].at[slot].set(tail))
-            return out, held
+                out, s_new = kda_ops.chunked_scan(q, k, v, decay, beta,
+                                                  length=length)
+                out, states = soon((out, held[0].at[slot].set(s_new)))
+            return out, (states, tails)
 
-        def mla(layer, hx, q_nope, q_rope, row, _pool):
-            with jax.named_scope("mx_mla_proj"):
-                # expanded: every head's keys and values from the row
+        def mla(layer, q_nope, q_rope, row, _pool):
+            def expand(q_nope, q_rope, row):
+                """Every head's keys and values from the row a token
+                keeps."""
+                n = row.shape[0]
                 kv = _mm(row[:, :cfg["kv_lora_rank"]], layer["wkvb"]
-                         ).reshape(t, self.num_heads, nope + dv)
+                         ).reshape(n, self.num_heads, nope + dv)
                 k_rope = jnp.broadcast_to(
                     row[:, None, cfg["kv_lora_rank"]:],
-                    (t, self.num_heads, q_rope.shape[-1]))
+                    (n, self.num_heads, q_rope.shape[-1]))
                 q = jnp.concatenate([q_nope, q_rope], axis=-1)
                 k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
                 # one head size for the three operands: the lane tile above
                 # the keys' (the values' zeros are cut off again)
-                q, k, v = (
+                return tuple(
                     jnp.pad(x, ((0, 0), (0, 0), (0, wide - x.shape[-1])))
                     for x in (q, k, kv[..., nope:]))
+
+            with jax.named_scope("mx_mla_proj"):
+                q, k, v = row_blocks(expand, (q_nope, q_rope, row), length)
             with jax.named_scope("mx_attn"):
                 out = pallas_kernels.band_attention(
                     q, k, v, scale=self.scale, precise=True, length=length)
@@ -417,11 +456,18 @@ class LingDecoder(PagedDecodeModel):
 
         x, latent, state, counters = self._forward(
             params, tokens, positions, latent, state, write_pages,
-            write_offsets, valid, kda, mla)
+            write_offsets, valid, kda, mla, length=length)
         with jax.named_scope("mx_head"):
             last = _mm(self._rms(x[length - 1], params["ln_f"])[None],
                        params["head"])[0]
         return (last, latent, state) + counters
+
+    def prefill_rows(self, tokens: int, rung: int) -> int:
+        """Rows of ``rung`` that :meth:`prefill`'s row-wise passes compute
+        for a prompt of ``tokens``: those of the row blocks it reaches."""
+        from ..ops import row_blocks
+
+        return row_blocks.rows_visited(tokens, rung)
 
     def prefill_chunk(self, params, tokens, start, length, latent, state,
                       page_table_row, write_pages, write_offsets):
@@ -449,19 +495,17 @@ class LingDecoder(PagedDecodeModel):
             # the live slots, once for every kda layer's update
             walk = pallas_kernels.kda_state_walk(valid)
 
-        def kda(layer, hx, held):
+        def kda(layer, qkv, decay, beta, held):
             with jax.named_scope("mx_kda_proj"):
-                qkv, decay, beta = self._kda_inputs(layer, hx)
                 mixed, tail = kda_ops.short_conv_step(
                     qkv, held[1], layer["conv"], valid)
                 q, k, v = self._kda_heads(mixed)
             with jax.named_scope("mx_kda_state"):
                 out, s_new = pallas_kernels.kda_state_step(
                     q, k, v, decay, beta, held[0], valid, walk=walk)
-                out = self._kda_out(layer, hx, out)
             return out, (s_new, tail)
 
-        def mla(layer, hx, q_nope, q_rope, _row, pool):
+        def mla(layer, q_nope, q_rope, _row, pool):
             with jax.named_scope("mx_mla_proj"):
                 # absorbed: the keys' expansion goes into the query ...
                 wkvb = layer["wkvb"].reshape(rank, self.num_heads, nope + dv)

@@ -243,7 +243,7 @@ PROGRAM_PARTS = frozenset((
 #: names still match), so ``tests/test_program_parts.py`` pins a digest of
 #: the programs' scope paths beside this value: a scope cannot move without
 #: that test asking for the bump.
-PROGRAM_PARTS_VERSION = "2"
+PROGRAM_PARTS_VERSION = "3"
 
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
 _HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")

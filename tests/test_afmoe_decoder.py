@@ -356,8 +356,11 @@ def test_spans_of_a_grouped_model_carry_the_layers_arguments(tiny, tmp_path):
     assert all(set(a) == {"active", "prefilling", "queued"}
                for n, a in events if n == "mx.decode.tick" and a)
     assert set(prefill[0]) == keys | {"rung", "held", "attn_blocks_rung",
-                                      "attn_blocks_live"}
+                                      "attn_blocks_live", "rows_rung",
+                                      "rows_computed"}
     assert prefill[0]["held"] == 0          # nobody was decoding yet
+    # ... and one block of rows for the row-wise passes, computed whole
+    assert (prefill[0]["rows_rung"], prefill[0]["rows_computed"]) == (64, 64)
     # 50 tokens on the rung of 64: one block of 64 rows a layer, all live
     assert prefill[0]["attn_blocks_rung"] == 5
     assert prefill[0]["attn_blocks_live"] == 5
@@ -442,6 +445,18 @@ def test_the_spans_block_pairs_are_the_kernels_live_steps(tiny, tmp_path):
             server=eng.name, kind=kind) == stats[key]
     assert "mxnet_decode_prefill_attn_blocks_total" \
         in telemetry.render_prometheus()
+    # the row-wise passes: 200 tokens reach one block of 256 rows of the
+    # rung of 512, 40 tokens the one block the rung of 64 is
+    assert [(a["rows_rung"], a["rows_computed"])
+            for n, a in events if n == "mx.decode.prefill"] \
+        == [(512, model.prefill_rows(200, 512)), (64, 64)] \
+        == [(512, 256), (64, 64)]
+    for kind, n in (("rung", 512 + 64), ("computed", 256 + 64)):
+        key = "prefill_rows_" + kind
+        assert stats[key] - before[key] == n
+        assert decode_mod._T_PREFILL_ROWS.value(
+            server=eng.name, kind=kind) == stats[key]
+    assert "mxnet_decode_prefill_rows_total" in telemetry.render_prometheus()
 
 
 def test_engine_serves_the_same_tokens_with_the_band_kernel_on_the_path(

@@ -11,7 +11,11 @@ inside a module-scoped fixture, never at import / in ``skipif`` / in
 xdist workers that each import every test file, and only the worker handed
 this file may load it. Nothing runs — a passing compile is not a chip run.
 """
+import importlib.util
+import json
+import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -280,7 +284,7 @@ def _pool_step_case(name, one_chip):
 
     # pools, tables and (group, layer in it) by cache group, as a model
     # with ``kv_groups`` receives them; group 1 is a window's ring
-    if name == "opt1p3b_32x64":         # the OPT cell: 385 pages, 32 x 64
+    if name == "opt1p3b_32x64":         # the OPT cell (769 pages), 32 x 64
         heads, kv_heads, dim = 32, 32, 64
         pool = (pools(LAYERS, (385, PAGE, kv_heads, dim)),)
         tables = (spec((SLOTS, 128), jnp.int32),)
@@ -407,3 +411,101 @@ def test_ling_step_updates_its_latent_pool_and_slot_state_in_place(
         < pool_bytes
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes \
         + 2 * state_bytes
+
+
+# ---------------------------------------------------------------------------
+# the served models' prefills: their row-wise passes loop over the row
+# blocks a prompt reaches, and the loops' carries cost no memory
+# ---------------------------------------------------------------------------
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+
+
+def _cell(config, reference):
+    """``(model, abstract parameters, the configuration's engine block)`` of
+    a benchmark configuration at its published widths: the tree its plain
+    reference states (``param_specs``), as shapes."""
+    from mxnet_tpu import serving
+
+    with open(os.path.join(BENCH, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    sys.path.insert(0, BENCH)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            reference + "_for_shapes",
+            os.path.join(BENCH, "reference", reference + ".py"))
+        ref = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(ref)
+    finally:
+        sys.path.remove(BENCH)
+    model = getattr(serving, cfg["factory"].rsplit(".", 1)[1])(
+        **cfg["factory_kwargs"])
+    specs = ref.param_specs(dict(model.cfg, param_dtype="bfloat16"))
+    return model, specs, cfg["engine"]
+
+
+@pytest.mark.parametrize("name,rung,most", [
+    ("trinity", 8192, 1.58e9), ("ling", 4096, 0.87e9)],
+    ids=["trinity_8192", "ling_4096"])
+def test_prefill_of_the_largest_rung_takes_no_more_temporaries(
+        name, rung, most, one_chip, compile_cache_off, monkeypatch):
+    """Trinity's rung 8192 and Ling's rung 4096 at the cells' widths and
+    pools, donated: the programs compile for the chip, hold the loops of the
+    row-wise passes (``mxnet_tpu.ops.row_blocks``) and the band kernel, and
+    take no more temporaries than before the passes followed the prompt's
+    length (1.58 GB and 0.86 GB; PERF.md section 6, PR 48: the loops'
+    carries are updated in place, and the pools' and the state's writes stay
+    where they are made — written at the program's end they kept 0.3 and
+    2.4 GB more alive)."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    moe.expert_layer.clear_cache()      # no trace made off the chip
+
+    def spec(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
+                                    sharding=one_chip)
+
+    model, specs, engine = _cell(*{
+        "trinity": ("trinity_large_preview_ep8", "afmoe_share"),
+        "ling": ("ling_3_flash_vl_ep4", "ling_share")}[name])
+    params = jax.tree_util.tree_map(
+        lambda s: spec(s.shape, s.dtype), specs,
+        is_leaf=lambda x: hasattr(x, "init"))
+    slots, page = engine["num_slots"], engine["page_size"]
+    rows, count = spec((rung,), jnp.int32), spec((), jnp.int32)
+    if name == "trinity":
+        pools = (tuple(spec((engine["num_pages"]["full"], page, 8, 128))
+                       for _ in model.kv_groups["full"]),
+                 tuple(spec((engine["num_pages"]["window"], page, 8, 128))
+                       for _ in model.kv_groups["window"]))
+        cache = (pools, pools)
+
+        def prefill(p, tokens, n, k, v, full, window, offs):
+            return model.prefill(p, tokens, n, k, v, (full, window), offs)
+
+        operands = (rows, count) + cache + (rows, rows, rows)
+    else:
+        pages = slots * (engine["max_seq_len"] // page) + 1
+        cache = ((spec((pages, page, 640)),),
+                 tuple((spec((slots, 32, 128, 128)),
+                        spec((slots, 3, 12288)))
+                       for kind in model.cfg["layer_types"] if kind == "kda"))
+
+        def prefill(p, tokens, n, latent, state, pages, offs, slot):
+            return model.prefill(p, tokens, n, latent, state, pages, offs,
+                                 slot=slot)
+
+        operands = (rows, count) + cache + (rows, rows, count)
+    try:
+        compiled = jax.jit(prefill, donate_argnums=(3, 4)).lower(
+            params, *operands).compile()
+    finally:
+        moe.expert_layer.clear_cache()
+    text = compiled.as_text()
+    assert "mx_prefill_attn" in text and "mx_moe_gmm" in text
+    assert text.count(" while(") >= 6 * model.num_layers
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < most
+    # the pools (and the slots' state) are updated in place
+    assert memory.alias_size_in_bytes >= sum(
+        int(np.prod(x.shape)) * 4 for x in jax.tree_util.tree_leaves(cache))
