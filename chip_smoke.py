@@ -264,8 +264,8 @@ def serve_phase(device, seed, size):
             packed = np.zeros(
                 (eng._packed_rows, size["slots"] * (spec_k + 1)), np.int32)
             step_text = eng._step.lower(
-                eng._params, packed, eng._no_prev, eng._cache.k_pool,
-                eng._cache.v_pool, eng._cache.page_table).as_text()
+                eng._params, packed, eng._no_prev, *eng._cache.operands,
+                eng._cache.page_table).as_text()
         finally:
             eng.close()
         exact = [bool(np.array_equal(g, w)) for g, w in zip(got, want)]

@@ -69,8 +69,8 @@ from .afmoe import AfmoeDecoder
 from .decode import DecodeEngine, PagedDecodeModel, TinyDecoder
 from .engine import BlockEngine, Engine, StableHLOEngine
 from .fleet import FleetRouter
-from .kvcache import (GroupedKVCache, LatentStateCache, OutOfPagesError,
-                      PagedKVCache, PrefixMatch, RingKVCache)
+from .kvcache import (OutOfPagesError, PagedKVCache, PrefixMatch,
+                      RingKVCache)
 from .ling import LingDecoder
 from .speculative import (DraftProposer, ModelDraft, PromptLookupDraft,
                           available_drafts, make_draft, register_draft)
@@ -87,8 +87,7 @@ __all__ = [
     "bucket_ladder", "select_bucket", "pad_to_bucket",
     "serve_block", "serve_stablehlo",
     "DecodeEngine", "PagedDecodeModel", "TinyDecoder", "AfmoeDecoder",
-    "LingDecoder", "GroupedKVCache", "RingKVCache", "LatentStateCache",
-    "FleetRouter",
+    "LingDecoder", "RingKVCache", "FleetRouter",
     "PagedKVCache", "OutOfPagesError", "PrefixMatch",
     "DraftProposer", "PromptLookupDraft", "ModelDraft",
     "register_draft", "make_draft", "available_drafts",

@@ -13,11 +13,13 @@ product over the ``held_experts`` that live here, one shared expert).
 
 What it declares to :class:`~mxnet_tpu.serving.DecodeEngine`:
 
-``kv_groups``
-    its full layers keep every page, its window layers a ring of
-    ``sliding_window / page_size + 1`` pages a slot
-    (:class:`~mxnet_tpu.serving.kvcache.GroupedKVCache`); pools, page tables
-    and write pages arrive as ``(full, window)`` pairs;
+``layer_state``
+    ``("paged",)`` for a full layer, which keeps every page, ``("ring",
+    sliding_window)`` for a window layer: a ring of ``sliding_window /
+    page_size + 1`` pages a slot. Two groups of pools
+    (:func:`~mxnet_tpu.serving.kvcache.make_cache`), so ``pools``, page
+    tables and write pages arrive as ``(full, window)`` pairs, a group's
+    pools as ``(k layers, v layers)``; ``state`` is ``()``;
 ``moe_counters``
     ``decode`` and ``prefill`` return the rows routed to each held expert of
     each expert layer (last column: to experts held elsewhere) behind the
@@ -53,7 +55,7 @@ import numpy as np
 
 from ..base import MXNetError
 from .decode import PagedDecodeModel
-from .kvcache import write_kv
+from .kvcache import place_layers, write_kv
 
 __all__ = ["AfmoeDecoder"]
 
@@ -125,18 +127,14 @@ class AfmoeDecoder(PagedDecodeModel):
         self.num_kv_heads = int(num_key_value_heads)
         self.head_dim = int(head_dim)
         self.scale = float(head_dim) ** -0.5
-        # layer -> (group, index inside the group's pools)
-        self._place = []
-        groups = {"full": [], "window": []}
-        for li, kind in enumerate(layer_types):
-            name = "window" if kind == "sliding_attention" else "full"
-            self._place.append((0 if name == "full" else 1,
-                                len(groups[name])))
-            groups[name].append(li)
-        if not groups["full"] or not groups["window"]:
+        if len(set(layer_types)) != 2:
             raise MXNetError("AfmoeDecoder needs layers of both kinds, got "
                              "%s" % list(layer_types))
-        self.kv_groups = dict(groups, window_tokens=int(sliding_window))
+        self.layer_state = [("ring", int(sliding_window))
+                            if kind == "sliding_attention" else ("paged",)
+                            for kind in layer_types]
+        # layer -> (group, index inside the group's pools)
+        self._place = place_layers(self.layer_state)
         n_expert_layers = self.num_layers - int(num_dense_layers)
         self.moe_counters = (n_expert_layers, held[1] + 1) \
             if n_expert_layers > 0 else None
@@ -215,11 +213,12 @@ class AfmoeDecoder(PagedDecodeModel):
                     layer["w2"])
             return (x + self._rms(m, layer["ln_post_mlp"]),)
 
-    def _forward(self, params, tokens, positions, k_pool, v_pool,
+    def _forward(self, params, tokens, positions, pools, state,
                  write_pages, write_offsets, valid, attend, length=None):
         """The layers over ``tokens`` rows, each piece under its part of the
         program (``telemetry.PROGRAM_PARTS``); ``attend(sliding, q, k, v,
-        pools)`` is one thing prefill and decode do differently. The other
+        the layer's k pool, its v pool)`` is one thing prefill and decode do
+        differently. The other
         is ``length``: a prefill hands the count of rows that hold its
         prompt, and what a row computes alone then runs over the row blocks
         under it (:func:`~mxnet_tpu.ops.row_blocks.row_blocks`; the rows
@@ -232,7 +231,7 @@ class AfmoeDecoder(PagedDecodeModel):
         from ..ops.row_blocks import row_blocks
 
         part = jax.named_scope
-        k_pool, v_pool = list(k_pool), list(v_pool)
+        pools = list(pools)     # a (k layers, v layers) a group
         with part("mx_embed"):
             x = self._embed(params, tokens)
         rows = []
@@ -247,12 +246,10 @@ class AfmoeDecoder(PagedDecodeModel):
             with part("mx_qkv"):
                 hx, q, k, v = row_blocks(qkv, (x, positions), length)
             with part("mx_kv_write"):
-                k_pool[grp], v_pool[grp] = write_kv(
-                    k_pool[grp], v_pool[grp], gi, k, v, write_pages[grp],
-                    write_offsets)
+                pools[grp] = k_pool, v_pool = write_kv(
+                    *pools[grp], gi, k, v, write_pages[grp], write_offsets)
             with part("mx_attn"):
-                att = attend(sliding, q, k, v, k_pool[grp][gi],
-                             v_pool[grp][gi])
+                att = attend(sliding, q, k, v, k_pool[gi], v_pool[gi])
             with part("mx_attn_out"):
                 x, *routed = row_blocks(
                     lambda *row: self._behind_attention(layer, *row),
@@ -269,10 +266,10 @@ class AfmoeDecoder(PagedDecodeModel):
                 x = x + self._rms(m, layer["ln_post_mlp"])
         with part("mx_head"):
             counters = (jnp.stack(rows),) if rows else ()
-        return x, tuple(k_pool), tuple(v_pool), counters
+        return x, tuple(pools), state, counters
 
     # -- contract -------------------------------------------------------
-    def prefill(self, params, tokens, length, k_pool, v_pool, write_pages,
+    def prefill(self, params, tokens, length, pools, state, write_pages,
                 write_offsets, attn=None):
         import jax
         import jax.numpy as jnp
@@ -297,20 +294,20 @@ class AfmoeDecoder(PagedDecodeModel):
                 q, k, v, scale=self.scale, window=window if sliding else 0,
                 precise=True, length=length)
 
-        x, k_pool, v_pool, counters = self._forward(
-            params, tokens, positions, k_pool, v_pool, write_pages,
+        x, pools, state, counters = self._forward(
+            params, tokens, positions, pools, state, write_pages,
             write_offsets, valid, attend, length=length)
         with jax.named_scope("mx_head"):
             last = _mm(self._rms(x[length - 1], params["ln_f"])[None],
                        params["head"])[0]
-        return (last, k_pool, v_pool) + counters
+        return (last, pools, state) + counters
 
     def prefill_attn_blocks(self, tokens: int, rung: int) -> int:
         """Block pairs :meth:`prefill`'s attention launches multiply for a
         prompt of ``tokens`` on ``rung``, a kv head, over the layers."""
         from ..ops import pallas_kernels
 
-        n_window = len(self.kv_groups["window"])
+        n_window = self.cfg["layer_types"].count("sliding_attention")
         return n_window * pallas_kernels.band_blocks(
             tokens, rung, self.cfg["sliding_window"]) \
             + (self.num_layers - n_window) * pallas_kernels.band_blocks(
@@ -323,13 +320,13 @@ class AfmoeDecoder(PagedDecodeModel):
 
         return row_blocks.rows_visited(tokens, rung)
 
-    def prefill_chunk(self, params, tokens, start, length, k_pool, v_pool,
+    def prefill_chunk(self, params, tokens, start, length, pools, state,
                       page_table_row, write_pages, write_offsets):
         raise MXNetError(
             "AfmoeDecoder offers no chunked prefill: serve it with "
             "prefix_cache=False, prefill_chunk=0")
 
-    def decode(self, params, tokens, positions, k_pool, v_pool, page_tables,
+    def decode(self, params, tokens, positions, pools, state, page_tables,
                seq_lens, write_pages, write_offsets):
         import jax
 
@@ -351,9 +348,9 @@ class AfmoeDecoder(PagedDecodeModel):
 
         with jax.named_scope("mx_embed"):
             valid = seq_lens > 0
-        x, k_pool, v_pool, counters = self._forward(
-            params, tokens, positions, k_pool, v_pool, write_pages,
+        x, pools, state, counters = self._forward(
+            params, tokens, positions, pools, state, write_pages,
             write_offsets, valid, attend)
         with jax.named_scope("mx_head"):
             logits = _mm(self._rms(x, params["ln_f"]), params["head"])
-        return (logits, k_pool, v_pool) + counters
+        return (logits, pools, state) + counters

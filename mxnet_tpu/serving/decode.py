@@ -248,6 +248,33 @@ class PagedDecodeModel:
     Attributes the engine sizes the cache from: ``num_layers``,
     ``num_heads``, ``num_kv_heads``, ``head_dim``, ``vocab_size``.
 
+    What every method is handed as ``pools`` and ``state``, and returns in
+    those places, is the two operands of the model's cache
+    (:class:`~mxnet_tpu.serving.kvcache.DecodeCache`):
+
+    ``pools``
+        what is paged, one GROUP of pools a paged kind among the model's
+        layers. A K/V group is ``(k layers, v layers)``: sequences of
+        per-layer arrays ``(P, page, KH, Dw)``, ``k[layer]`` the array that
+        layer's kernel reads; :func:`~mxnet_tpu.serving.kvcache.write_kv`
+        replaces exactly that element. ``Dw`` is ``head_dim`` or, where the
+        device holds narrow rows otherwise (a TPU, head_dim under 128), the
+        lane tile above it: ``write_kv`` zero-pads the rows it is handed and
+        the ``ops.pallas_kernels.paged_*`` functions take ``(.., head_dim)``
+        queries and return ``(.., head_dim)``. A latent group is one
+        sequence of per-layer arrays ``(P, page, row width)``, no V pool.
+        A model with ONE group (:class:`TinyDecoder`: every layer paged;
+        ``LingDecoder``: its latent layers) is handed that group's pools,
+        its page table and its write pages bare; a model with several
+        (``AfmoeDecoder``: full, then window) a tuple a group of each, in
+        the cache's order (:func:`~mxnet_tpu.serving.kvcache.place_layers`).
+    ``state``
+        what a slot keeps whole: a tuple a ``slot`` layer of ``(num_slots,)
+        + shape`` float32 arrays, ``()`` for a model with no such layer.
+        ``decode`` row ``s`` IS slot ``s`` and a row with ``seq_len`` 0
+        must leave its slot's state bit for bit; ``prefill`` takes ``slot=``
+        (a traced int32) and writes that slot's state whole.
+
     Four optional declarations (a model without them, like
     :class:`TinyDecoder`, is served exactly as before):
 
@@ -255,27 +282,10 @@ class PagedDecodeModel:
         what each layer keeps between tokens, one entry a layer
         (:func:`~mxnet_tpu.serving.kvcache.layer_states`: ``("paged",)``,
         ``("ring", window)``, ``("latent", width)``, ``("slot", shapes)``);
-        the cache manager allocates per entry and for no layer that owns
-        nothing of a kind. A model of latent and slot-state layers is
-        handed, in the places of ``k_pool`` and ``v_pool`` below, the two
-        operands of its
-        :class:`~mxnet_tpu.serving.kvcache.LatentStateCache`: its latent
-        pools ``(P, page, row width)``, one a latent layer and no V pool,
-        and its slot state, a tuple a state layer of ``(num_slots,) +
-        shape`` float32 arrays (and returns them in those places); ``decode``
-        row ``s`` IS slot ``s`` and a row with ``seq_len`` 0 must leave its
-        slot's state bit for bit; ``prefill`` takes ``slot=`` (a traced
-        int32) and writes that slot's state whole. Served with
-        ``prefix_cache=False``, ``prefill_chunk=0``, ``spec_k=0``.
-    ``kv_groups``
-        ``{"full": [layer, ...], "window": [layer, ...], "window_tokens":
-        n}`` — the model's layers are of two kinds. The engine then keeps a
-        :class:`~mxnet_tpu.serving.kvcache.GroupedKVCache` and every
-        ``k_pool`` / ``v_pool`` / ``page_tables`` / ``write_pages``
-        argument below is a ``(full, window)`` PAIR: a group's pools in
-        the order of its layers, the window table a ring of ``n /
-        page_size + 1`` columns. Such a model is served with
-        ``prefix_cache=False``, ``prefill_chunk=0``, ``spec_k=0``.
+        :func:`~mxnet_tpu.serving.kvcache.make_cache` allocates per entry
+        and for no layer that owns nothing of a kind. A model whose layers
+        are not all ``paged`` is served with ``prefix_cache=False``,
+        ``prefill_chunk=0``, ``spec_k=0``.
     ``moe_counters``
         ``(expert layers, held experts + 1)`` — ``decode`` and ``prefill``
         return a fourth value, an int32 array of that shape: the (token,
@@ -300,16 +310,7 @@ class PagedDecodeModel:
     head_dim: int
     vocab_size: int
 
-    # ``k_pool`` / ``v_pool`` are SEQUENCES of per-layer arrays ``(P, page,
-    # KH, Dw)``: ``k_pool[layer]`` is the array that layer's kernel reads,
-    # :func:`~mxnet_tpu.serving.kvcache.write_kv` replaces exactly that
-    # element, and the methods return the sequences whole. ``Dw`` is
-    # ``head_dim`` or, where the device holds narrow rows otherwise (a TPU,
-    # head_dim under 128), the lane tile above it: ``write_kv`` zero-pads
-    # the rows it is handed and the ``ops.pallas_kernels.paged_*`` functions
-    # take ``(.., head_dim)`` queries and return ``(.., head_dim)``.
-
-    def decode(self, params, tokens, positions, k_pool, v_pool,
+    def decode(self, params, tokens, positions, pools, state,
                page_tables, seq_lens, write_pages, write_offsets):
         """One query ROW per table row: ``tokens``/``positions``/
         ``write_*``/``seq_lens`` are ``(S*W,)`` where ``page_tables`` is
@@ -324,20 +325,20 @@ class PagedDecodeModel:
         rows, written before attention reads). Inactive/padded rows
         carry ``seq_len 0`` and the null write page; their logits are
         garbage the engine ignores. Returns
-        ``(logits (S*W, vocab), k_pool, v_pool)``."""
+        ``(logits (S*W, vocab), pools, state)``."""
         raise NotImplementedError
 
-    def prefill(self, params, tokens, length, k_pool, v_pool,
+    def prefill(self, params, tokens, length, pools, state,
                 write_pages, write_offsets, attn=None):
         """Whole prompt in one pass: ``tokens`` ``(T,)`` padded to a
         ladder rung, ``length`` the real token count (traced — one
         compile per rung, not per length), ``write_*`` ``(T,)`` (padding
         rows target the null page). ``attn`` overrides the in-graph
         causal attention (the ring-attention long-context path). Returns
-        ``(last_token_logits (vocab,), k_pool, v_pool)``."""
+        ``(last_token_logits (vocab,), pools, state)``."""
         raise NotImplementedError
 
-    def prefill_chunk(self, params, tokens, start, length, k_pool, v_pool,
+    def prefill_chunk(self, params, tokens, start, length, pools, state,
                       page_table_row, write_pages, write_offsets):
         """One prefill chunk of one sequence, attending THROUGH the page
         table: ``tokens`` ``(C,)`` padded to the chunk rung at absolute
@@ -348,7 +349,7 @@ class PagedDecodeModel:
         already-cached positions target the null page), then attends
         each chunk query over the sequence's pages — the prefix written
         by earlier chunks or mapped from the prefix cache included.
-        Returns ``(last_real_token_logits (vocab,), k_pool, v_pool)``.
+        Returns ``(last_real_token_logits (vocab,), pools, state)``.
         Both chunked prefill and the prefix-cache tail/recompute path
         run through this."""
         raise NotImplementedError
@@ -527,7 +528,7 @@ class DecodeEngine:
                 "latent": "its prefill attends in expanded form, not "
                           "through the pool"}
             raise MXNetError(
-                "a model that declares %s layers (layer_state, kv_groups) "
+                "a model that declares %s layers (layer_state) "
                 "is served with prefix_cache=False, prefill_chunk=0, "
                 "spec_k=0 and no ring prefill: %s"
                 % (" and ".join(sorted(kinds - {"paged"})),
@@ -536,17 +537,14 @@ class DecodeEngine:
             model, self.num_slots, self.max_seq_len, page_size=page_size,
             num_pages=num_pages, dtype=dtype, name=name,
             prefix_cache=self._prefix_cache)
-        # what the cache declares a prefill's packed operand carries
-        # behind its three rows: a second group's write pages, or a
-        # slot-state model's slot (every column)
-        self._prefill_extra = self._cache.prefill_extra
-        self._prefill_rows = 3 + (self._prefill_extra is not None)
-        self._grouped = self._prefill_extra == "window_pages"
-        # rows of the step's packed operand: a second group adds its write
-        # pages
-        self._extra_rows = 1 if self._grouped else 0
-        # ... and the step's: `from_prev` last
-        self._packed_rows = 5 + self._extra_rows + 1
+        n_groups = self._cache.num_groups
+        # rows of a prefill's packed operand: tokens, the first group's
+        # write pages, offsets, a row of write pages a further group and,
+        # for a cache that holds slot state, the slot (every column)
+        self._prefill_rows = 2 + n_groups + bool(self._cache.state)
+        # ... and of the step's: tokens, positions, seq_lens, the first
+        # group's write pages, offsets, a row a further group, `from_prev`
+        self._packed_rows = 4 + n_groups + 1
         # the tables a decode tick's paged attention walks: (cache group,
         # columns, layers that walk it)
         self._walk_groups = self._cache.walk_groups()
@@ -631,12 +629,13 @@ class DecodeEngine:
         # seq_lens, write pages, write offsets; W = spec_k+1 query rows
         # per slot, 1 when speculation is off) travel as ONE packed
         # array — one host->device put per tick instead of five — with a
-        # second group's write pages and, last, `from_prev`: the rows
+        # further group's write pages and, last, `from_prev`: the rows
         # whose token the host has not seen yet, taken on the device from
-        # the previous step's output. The page table rides a version-keyed
+        # the previous step's output. The page tables ride a version-keyed
         # device cache (below), so a steady tick pays exactly one put +
-        # one fetch
-        grouped, extra = self._grouped, self._prefill_extra
+        # one fetch. What a program hands its model a group, the cache
+        # shapes (one group's bare, several as a tuple)
+        per_group = self._cache.per_group
 
         def head():
             """The scope of a program's last operations (``mx_head``: what
@@ -660,51 +659,43 @@ class DecodeEngine:
             return jnp.concatenate([sampled.reshape(-1),
                                     out[3].reshape(-1).astype(jnp.int32)])
 
-        def mx_decode_step(params, packed, prev, k_pool, v_pool,
-                           page_tables):
+        def mx_decode_step(params, packed, prev, pools, state, page_tables):
             # `prev`: the previous step's own output, whole (or zeros of
             # its shape before any step); its first S*W values are tokens
             with jax.named_scope("mx_head"):
                 from_prev = packed[-1]
-                if grouped:
-                    tokens, positions, seq_lens, full_pages, \
-                        write_offsets, window_pages = packed[:-1]
-                    write_pages = (full_pages, window_pages)
-                else:
-                    tokens, positions, seq_lens, write_pages, \
-                        write_offsets = packed[:-1]
+                tokens, positions, seq_lens, first_pages, write_offsets, \
+                    *further_pages = packed[:-1]
+                write_pages = per_group((first_pages, *further_pages))
                 tokens = jnp.where(from_prev != 0, prev[:tokens.shape[0]],
                                    tokens)
             out = model.decode(
-                params, tokens, positions, k_pool, v_pool, page_tables,
+                params, tokens, positions, pools, state, page_tables,
                 seq_lens, write_pages, write_offsets)
-            logits, k_pool, v_pool = out[:3]
+            logits, pools, state = out[:3]
             with head():
                 sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return with_counters(sampled, out), k_pool, v_pool
+                return with_counters(sampled, out), pools, state
 
         # same packing for prefill: tokens + write pages + offsets share
         # the rung shape, so they travel as one (3, rung) array
         # (one jit, one program a rung: the scope names the rung's ops)
-        def mx_prefill(params, packed, length, k_pool, v_pool):
+        def mx_prefill(params, packed, length, pools, state):
             more = {}
             with jax.named_scope("mx_head"):
-                if grouped:
-                    tokens, full_pages, write_offsets, window_pages = packed
-                    write_pages = (full_pages, window_pages)
-                elif extra == "slot":
-                    tokens, write_pages, write_offsets, slots = packed
+                tokens, first_pages, write_offsets, *further_pages = packed
+                if state:   # its prefill writes one slot's: the last row
+                    *further_pages, slots = further_pages
                     more["slot"] = slots[0]
-                else:
-                    tokens, write_pages, write_offsets = packed
+                write_pages = per_group((first_pages, *further_pages))
             with jax.named_scope("mx_prefill_%d" % tokens.shape[0]):
                 out = model.prefill(
-                    params, tokens, length, k_pool, v_pool, write_pages,
+                    params, tokens, length, pools, state, write_pages,
                     write_offsets, **more)
-            last, k_pool, v_pool = out[:3]
+            last, pools, state = out[:3]
             with head():
                 first = jnp.argmax(last).astype(jnp.int32)
-                return with_counters(first, out), k_pool, v_pool
+                return with_counters(first, out), pools, state
 
         # one prefill CHUNK: same (3, rung) packing plus the absolute
         # start position and the slot's page-table row — the chunk
@@ -712,21 +703,22 @@ class DecodeEngine:
         # KV included), so start/length are traced and one compile
         # serves every chunk of a rung
         def mx_prefill_chunk(params, packed, start, length, page_row,
-                             k_pool, v_pool):
+                             pools, state):
             tokens, write_pages, write_offsets = packed
-            last, k_pool, v_pool = model.prefill_chunk(
-                params, tokens, start, length, k_pool, v_pool, page_row,
+            last, pools, state = model.prefill_chunk(
+                params, tokens, start, length, pools, state, page_row,
                 write_pages, write_offsets)
             with head():
-                return jnp.argmax(last).astype(jnp.int32), k_pool, v_pool
+                return jnp.argmax(last).astype(jnp.int32), pools, state
 
         # the copy-on-write copy: duplicate one page's K/V (all layers)
         # into a fresh page so a sequence diverging inside a shared page
         # writes into its own copy; src/dst are traced scalars — ONE
-        # compile, pre-warmed against the null page
-        def mx_kv_cow(k_pool, v_pool, src, dst):
+        # compile, pre-warmed against the null page. Pages are what is
+        # paged: `state` passes through
+        def mx_kv_cow(pools, state, src, dst):
             return jax.tree_util.tree_map(
-                lambda pool: pool.at[dst].set(pool[src]), (k_pool, v_pool))
+                lambda pool: pool.at[dst].set(pool[src]), pools), state
 
         # pools are donated through the jits (they are dead the moment
         # the step returns — swap_pools rebinds to the outputs), so the
@@ -757,9 +749,8 @@ class DecodeEngine:
         #: the step dispatched and not yet fetched (worker-confined)
         self._inflight: Optional[_StepInFlight] = None
         self._steps_overlapped = 0
-        self._pt_dev = None  # version-keyed device page table
-        self._pt_version = -1
-        self._pt_groups = [[-1, None], [-1, None]]  # the same, by group
+        # version-keyed device page tables: [version, table] a group
+        self._pt_dev = [[-1, None] for _ in range(n_groups)]
 
         self._warm_compiles: Optional[int] = None
         self._slots: List[Optional[_DecodeRequest]] = \
@@ -794,7 +785,7 @@ class DecodeEngine:
         from .. import fastpath
 
         if fastpath.donation_argnums_ok():
-            return (3, 4)  # k_pool, v_pool in the prefill signature
+            return (3, 4)  # pools, state in the prefill signature
         return ()
 
     def _pools_dead(self) -> bool:
@@ -810,21 +801,14 @@ class DecodeEngine:
         return bool(dead and dead())
 
     def _device_page_table(self):
-        """The page table's device copy, re-put only when the allocator
-        mutated it (admission/free) — steady ticks with stable membership
-        skip the transfer entirely."""
-        if self._grouped:
-            # one copy a group, each re-put when ITS allocator moved
-            for held, (ver, table) in zip(self._pt_groups,
-                                          self._cache.tables):
-                if held[0] != ver:
-                    held[:] = [ver, self._jnp.asarray(table)]
-            return tuple(held[1] for held in self._pt_groups)
-        ver = self._cache.version
-        if self._pt_dev is None or self._pt_version != ver:
-            self._pt_dev = self._jnp.asarray(self._cache.page_table)
-            self._pt_version = ver
-        return self._pt_dev
+        """The page tables' device copies as the model is handed them, one
+        a group, each re-put only when ITS allocator mutated it
+        (admission/free) — steady ticks with stable membership skip the
+        transfer entirely."""
+        for held, (ver, table) in zip(self._pt_dev, self._cache.tables):
+            if held[0] != ver:
+                held[:] = [ver, self._jnp.asarray(table)]
+        return self._cache.per_group([held[1] for held in self._pt_dev])
 
     def _prefill_ladder(self, buckets):
         if buckets is None:
@@ -1082,7 +1066,7 @@ class DecodeEngine:
         null_row = np.zeros((self._cache.max_pages,), np.int32)
         for rung in self._chunk_rungs:
             pre = np.zeros((3, rung), np.int32)
-            pre[1], pre[2] = self._cache.null_write_slots(rung)
+            pre[2] = self._cache.null_write_slots(rung)[1]
             args = (params, jnp.asarray(pre), jnp.asarray(0, jnp.int32),
                     jnp.asarray(1, jnp.int32), jnp.asarray(null_row),
                     *self._cache.operands)
@@ -1810,17 +1794,16 @@ class DecodeEngine:
         rung = select_bucket(p, self._ladder)
         _tracing.event(req.trace, "prefill", rung=rung, tokens=p,
                        ring=ring)
-        # tokens, write pages, offsets (+ a second group's write pages);
-        # the padding's pages stay 0, the null page
+        # tokens, write pages, offsets (+ a further group's write pages,
+        # + the slot whose state it writes); the padding's pages stay 0,
+        # the null page
         pre = np.zeros((self._prefill_rows, rung), np.int32)
         pre[0, :p] = req.prompt
         wpg, woff = self._cache.write_slots(slot, 0, p)
-        if self._grouped:
-            pre[1, :p], pre[3, :p] = wpg
-        else:
-            pre[1, :p] = wpg
-        if self._prefill_extra == "slot":
-            pre[3] = slot
+        pre[1, :p] = wpg[0]
+        pre[3:2 + len(wpg), :p] = wpg[1:]
+        if self._cache.state:
+            pre[-1] = slot
         pre[2] = np.concatenate(
             [woff, self._cache.null_write_slots(rung - p)[1]])
         policy = self._retry or resilience.default_policy()
@@ -1875,7 +1858,7 @@ class DecodeEngine:
             npg, noff = self._cache.null_write_slots(rung - n)
             pages.append(npg)
             offs.append(noff)
-        pre[1] = np.concatenate(pages)
+        pre[1] = np.concatenate(pages, axis=-1)[0]   # (the one group's)
         pre[2] = np.concatenate(offs)
         row = np.ascontiguousarray(self._cache.page_table[slot])
         policy = self._retry or resilience.default_policy()
@@ -2138,16 +2121,20 @@ class DecodeEngine:
         w = self._spec_w
         ps = self._cache.page_size
         # rows: tokens, positions, seq_lens, write pages, write offsets
-        # (a second group's write pages), from_prev — ONE packed put per
+        # (a further group's write pages), from_prev — ONE packed put per
         # tick, W = spec_k+1 query rows per slot (slot s owns rows s*W ..
         # s*W+W-1: row 0 the committed token, rows 1..k its draft guesses
         # at the next positions). W is static — draft depth, acceptance
         # and per-tenant caps vary only the data, so speculation can never
         # retrace the step. Inactive slots and unused draft rows keep
-        # seq_len 0 and the null write page (row 3 stays 0); their offsets
-        # cycle the page so scatter indices stay in range.
+        # seq_len 0 and the null write page (rows of pages stay 0); their
+        # offsets cycle the page so scatter indices stay in range.
         packed = np.zeros((self._packed_rows, s * w), np.int32)
         packed[4] = np.arange(s * w) % ps
+        # the groups' page lookups, resolved once a tick: the first group's,
+        # and (row of the operand, lookup) of each further group
+        first_page_at, *further_pages_at = self._cache.page_lookups()
+        further_pages_at = list(enumerate(further_pages_at, 5))
         drafts: dict = {}
         for slot, req in active:
             # a token in flight is one position the host has not seen:
@@ -2167,16 +2154,14 @@ class DecodeEngine:
                 # in the same tick write their KV before attention reads,
                 # so draft rows see each other causally. Admission's
                 # worst-case reserve() plus the _propose clamp guarantee
-                # pos+j is covered, so index the page table directly.
+                # pos+j is covered, so the page tables are indexed directly.
                 packed[0, base + j] = row_tok
                 packed[1, base + j] = pos + j
                 packed[2, base + j] = pos + j + 1
-                packed[3, base + j] = \
-                    self._cache.page_table[slot, (pos + j) // ps]
+                packed[3, base + j] = first_page_at(slot, pos + j)
                 packed[4, base + j] = (pos + j) % ps
-                if self._grouped:
-                    packed[5, base + j] = \
-                        self._cache.window.page_at(slot, pos + j)
+                for row, page_at in further_pages_at:
+                    packed[row, base + j] = page_at(slot, pos + j)
         # black box: the in-flight set BEFORE the step executes, so a
         # mid-tick death's dump names the failing tick's sequences and
         # their tenants (the post-mortem acceptance contract). One event
@@ -2624,15 +2609,18 @@ class TinyDecoder(PagedDecodeModel):
 
         return jax.nn.relu(x @ layer["w1"]) @ layer["w2"]
 
-    def _forward(self, params, tokens, positions, k_pool, v_pool,
+    def _forward(self, params, tokens, positions, pools, state,
                  write_pages, write_offsets, attend):
         """The layers over ``tokens`` rows, each piece under its part of the
         program (``telemetry.PROGRAM_PARTS``); ``attend(li, q, k, v, k_pool,
         v_pool)`` is the one thing prefill, chunk and decode do
-        differently. Returns the logits of every row and the pools."""
+        differently. Returns the logits of every row and the two operands
+        (``pools``: its one group's ``(k layers, v layers)``; ``state``: as
+        handed, ``()``)."""
         import jax
 
         part = jax.named_scope
+        k_pool, v_pool = pools
         n = tokens.shape[0]
         h, kh, d = self.num_heads, self.num_kv_heads, self.head_dim
         with part("mx_embed"):
@@ -2658,21 +2646,21 @@ class TinyDecoder(PagedDecodeModel):
                 x = x + self._mlp(self._norm(x, layer["ln2"]), layer)
         with part("mx_head"):
             logits = self._norm(x, params["lnf"]) @ params["unembed"]
-        return logits, k_pool, v_pool
+        return logits, (k_pool, v_pool), state
 
     # -- contract -------------------------------------------------------
-    def prefill(self, params, tokens, length, k_pool, v_pool,
+    def prefill(self, params, tokens, length, pools, state,
                 write_pages, write_offsets, attn=None):
         import jax.numpy as jnp
 
         attn = attn or self._dense_causal
-        logits, k_pool, v_pool = self._forward(
+        logits, pools, state = self._forward(
             params, tokens, jnp.arange(tokens.shape[0], dtype=jnp.int32),
-            k_pool, v_pool, write_pages, write_offsets,
+            pools, state, write_pages, write_offsets,
             lambda li, q, k, v, kp, vp: attn(q, k, v, self.scale))
-        return logits[length - 1], k_pool, v_pool
+        return logits[length - 1], pools, state
 
-    def prefill_chunk(self, params, tokens, start, length, k_pool, v_pool,
+    def prefill_chunk(self, params, tokens, start, length, pools, state,
                       page_table_row, write_pages, write_offsets):
         import jax
         import jax.numpy as jnp
@@ -2682,16 +2670,16 @@ class TinyDecoder(PagedDecodeModel):
         with jax.named_scope("mx_embed"):
             positions = start.astype(jnp.int32) \
                 + jnp.arange(tokens.shape[0], dtype=jnp.int32)
-        logits, k_pool, v_pool = self._forward(
-            params, tokens, positions, k_pool, v_pool, write_pages,
+        logits, pools, state = self._forward(
+            params, tokens, positions, pools, state, write_pages,
             write_offsets,
             lambda li, q, k, v, kp, vp:
             pallas_kernels.paged_prefill_attention(
                 q, kp[li], vp[li], page_table_row, start, length,
                 scale=self.scale))
-        return logits[length - 1], k_pool, v_pool
+        return logits[length - 1], pools, state
 
-    def decode(self, params, tokens, positions, k_pool, v_pool,
+    def decode(self, params, tokens, positions, pools, state,
                page_tables, seq_lens, write_pages, write_offsets):
         from ..ops import pallas_kernels
 
@@ -2702,7 +2690,7 @@ class TinyDecoder(PagedDecodeModel):
         paged = pallas_kernels.paged_spec_attention if w > 1 \
             else pallas_kernels.paged_attention
         return self._forward(
-            params, tokens, positions, k_pool, v_pool, write_pages,
+            params, tokens, positions, pools, state, write_pages,
             write_offsets,
             lambda li, q, k, v, kp, vp: paged(
                 q, kp[li], vp[li], page_tables, seq_lens, scale=self.scale))

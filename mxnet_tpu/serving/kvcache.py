@@ -12,10 +12,20 @@ table — so allocation is a host-side free-list operation that never
 touches a device shape. Nothing recompiles as sequences come, grow and
 go.
 
-Split of responsibilities:
+What a model's layers keep between tokens is decided HERE and nowhere else:
+the model declares it (``layer_state``, :func:`layer_states`),
+:func:`make_cache` composes one :class:`DecodeCache` from the declaration —
+a group of pools with its allocator (:class:`PagedKVCache`, a sliding
+window's :class:`RingKVCache`) a distinct paged kind, the per-slot state of
+the layers that are not paged — and the decode engine asks that cache for
+everything, whatever it is made of (docs/serving.md, "What a layer keeps
+between tokens").
 
-* **host side (this class)** — the free list, per-page refcounts, the
-  per-slot page tables and lengths (numpy, static shapes), admission
+Split of responsibilities inside a group:
+
+* **host side (:class:`PagedKVCache`)** — the free list, per-page
+  refcounts, the per-slot page tables and lengths (numpy, static shapes),
+  admission
   accounting, the prefix index, and the ``mxnet_kvcache_*`` gauges;
 * **device side (pure helpers)** — :func:`write_kv` scatters one step's
   new K/V rows into the pools at host-computed (page, offset) slots;
@@ -60,9 +70,9 @@ import numpy as np
 from .. import telemetry
 from ..base import MXNetError, get_env
 
-__all__ = ["PagedKVCache", "RingKVCache", "GroupedKVCache",
-           "LatentStateCache", "OutOfPagesError", "PrefixMatch", "write_kv",
-           "write_rows", "layer_states", "make_cache"]
+__all__ = ["PagedKVCache", "RingKVCache", "DecodeCache", "OutOfPagesError",
+           "PrefixMatch", "write_kv", "write_rows", "layer_states",
+           "place_layers", "make_cache"]
 
 _DEFAULT_PAGE_SIZE = 16
 
@@ -116,7 +126,7 @@ _T_STATE_BYTES = telemetry.gauge(
 _T_GROUP_PAGES = telemetry.gauge(
     "mxnet_kvcache_group_pages_in_use",
     "KV pages in use in a further group of layers of a cache whose model "
-    "declares kv_groups (the first, full-attention group keeps "
+    "declares layers of several paged kinds (the first group keeps "
     "mxnet_kvcache_pages_in_use)",
     labels=("cache", "group"))
 _T_GROUP_CAPACITY = telemetry.gauge(
@@ -177,8 +187,8 @@ def write_kv(k_pool, v_pool, layer: int, k_new, v_new, pages, offsets):
 
 
 def pool_row_width(shape, dtype, device) -> int:
-    """The width a ``(P, page_size, KH, head_dim)`` pool's rows are HELD at
-    on ``device``: ``head_dim`` where the device's own default layout for
+    """The width a ``(P, page_size, KH, head_dim)`` pool's rows (a latent
+    pool's: ``(P, page_size, width)``) are HELD at on ``device``: ``head_dim`` where the device's own default layout for
     that shape is row-major (every CPU array; a TPU pool whose head_dim
     fills the 128 lanes), else ``head_dim`` rounded up to the lanes of the
     device's tile.
@@ -262,16 +272,23 @@ def _common_prefix_len(a: np.ndarray, b: np.ndarray) -> int:
 
 
 class PagedKVCache:
-    """Fixed-size paged KV pools for ``num_slots`` concurrent sequences.
+    """Fixed-size paged KV pools for ``num_slots`` concurrent sequences: one
+    GROUP of a model's layers (:func:`make_cache` composes a
+    :class:`DecodeCache` of one such group a paged kind).
 
-    Device state: ``k_pool``/``v_pool``, each a tuple of ``num_layers``
-    arrays ``(num_pages, page_size, num_kv_heads, row width)`` — ONE ARRAY
-    A LAYER, the operand the layer's scatter writes and its kernel reads,
-    its rows as wide as the device holds row-major (:func:`pool_row_width`:
-    ``head_dim``, or the lane tile above it), allocated once and
-    shape-stable for the cache's lifetime. The decode engine threads the
-    pools through its jitted step (functional update, donated) and stores
-    the returned arrays back via :meth:`swap_pools`.
+    Device state: ``pools``, ``(k layers, v layers)``, each a tuple of
+    ``num_layers`` arrays ``(num_pages, page_size, num_kv_heads, row
+    width)`` — ONE ARRAY A LAYER, the operand the layer's scatter writes and
+    its kernel reads, its rows as wide as the device holds row-major
+    (:func:`pool_row_width`: ``head_dim``, or the lane tile above it),
+    allocated once and shape-stable for the cache's lifetime. With
+    ``num_kv_heads=None`` the layers are LATENT: ``pools`` is one tuple of
+    ``(num_pages, page_size, row width)`` arrays, no head axis (a TPU pads
+    one head to a sublane tile and the step then converts the pool on its
+    way into the kernel, every tick) and NO V pool — a token's row is key
+    and value at once. The decode engine threads the pools through its
+    jitted step (functional update, donated) and stores the returned arrays
+    back (:meth:`DecodeCache.swap_pools`).
 
     Host state per slot: a fixed-width page-table row (``max_pages``
     entries, unused entries = the null page 0) and a token count. The
@@ -283,9 +300,13 @@ class PagedKVCache:
     the free list.
     """
 
+    #: the name its page table's walk is counted under
+    group = "full"
+
     def __init__(self, num_slots: int, max_seq_len: int, num_layers: int,
-                 num_kv_heads: int, head_dim: int, page_size: Optional[int]
-                 = None, num_pages: Optional[int] = None, dtype="float32",
+                 num_kv_heads: Optional[int], head_dim: int,
+                 page_size: Optional[int] = None,
+                 num_pages: Optional[int] = None, dtype="float32",
                  name: str = "decode", prefix_cache: bool = False):
         import jax.numpy as jnp
 
@@ -306,8 +327,12 @@ class PagedKVCache:
         self.num_pages = int(num_pages)
         self.name = name
         self.num_layers = int(num_layers)
-        shape = (self.num_pages, self.page_size, int(num_kv_heads),
-                 int(head_dim))
+        if num_kv_heads is None:
+            self.group = "latent"
+            shape = (self.num_pages, self.page_size, int(head_dim))
+        else:
+            shape = (self.num_pages, self.page_size, int(num_kv_heads),
+                     int(head_dim))
         self._pool_dtype = np_dtype(dtype)
         (device,) = jnp.zeros((), self._pool_dtype).devices()
         #: one layer's pool: rows as wide as ``device`` holds row-major
@@ -346,7 +371,6 @@ class PagedKVCache:
         # static resource-lifecycle pass
         self.audit = bool(get_env("MXNET_KVCACHE_AUDIT", 0, int,
                                   cache=False))
-        _T_CAPACITY.set(self.num_pages - 1, cache=self.name)
         self._publish()
 
     # -- accounting --------------------------------------------------------
@@ -725,41 +749,24 @@ class PagedKVCache:
         return (np.zeros(n_tokens, np.int32),
                 (pos % self.page_size).astype(np.int32))
 
-    #: the name its page table's walk is counted under
-    group = "full"
-    #: what a prefill's packed operand carries behind tokens, write pages
-    #: and offsets: nothing, a second group's ``"window_pages"`` or the
-    #: ``"slot"`` a stateful prefill writes
-    prefill_extra = None
-
-    @property
-    def operands(self):
-        """The two operands every program of the engine is handed, donates
-        and returns (:meth:`swap_pools` stores them back)."""
-        return self.k_pool, self.v_pool
-
-    def swap_pools(self, k_pool, v_pool) -> None:
-        """Store the pools returned by a jitted step (functional update
-        discipline; with donation the old buffers are already dead)."""
-        self.k_pool = k_pool
-        self.v_pool = v_pool
+    def page_at(self, slot, pos):
+        """The page that holds position ``pos`` of ``slot`` (the decode
+        tick's one write a row)."""
+        return self.page_table[slot, pos // self.page_size]
 
     @property
     def paged_bytes(self) -> int:
         """Bytes of what :attr:`num_pages` pages hold, over the layers (a
         page's share of it is what a reservation takes)."""
-        return int(sum(x.nbytes for pool in (self.k_pool, self.v_pool)
-                       for x in pool))
+        import jax
 
-    def walk_groups(self):
-        """``((group, table columns, layers), ...)``: the page tables a
-        decode tick's attention walks."""
-        return ((self.group, self.max_pages, self.num_layers),)
+        return int(sum(x.nbytes for x in jax.tree_util.tree_leaves(
+            self.pools)))
 
-    def span_args(self, live, prefill: bool = False) -> dict:
-        """What a prefill's or a decode tick's span says of this cache
+    def span_args(self, live) -> dict:
+        """What a prefill's or a decode tick's span says of this group
         beyond the page walk (``live``: tokens each sequence of the run
-        holds): nothing for one group of paged K/V."""
+        holds): nothing for pages a sequence keeps all of."""
         return {}
 
     def reset_pools(self) -> None:
@@ -774,11 +781,15 @@ class PagedKVCache:
     def _zero_pools(self) -> None:
         import jax.numpy as jnp
 
-        self.k_pool, self.v_pool = (
-            tuple(jnp.zeros(self._pool_shape, self._pool_dtype)
-                  for _ in range(self.num_layers)) for _ in range(2))
+        def layers():
+            return tuple(jnp.zeros(self._pool_shape, self._pool_dtype)
+                         for _ in range(self.num_layers))
+
+        self.pools = layers() if self.group == "latent" \
+            else (layers(), layers())
 
     def _publish(self) -> None:
+        _T_CAPACITY.set(self.num_pages - 1, cache=self.name)
         _T_PAGES.set(self.pages_in_use, cache=self.name)
         _T_CACHED.set(self.pages_cached, cache=self.name)
         _T_SHARED.set(self.shared_pages, cache=self.name)
@@ -916,22 +927,11 @@ class RingKVCache(PagedKVCache):
                 "kvcache %r: the window group needs at least one whole "
                 "ring (%d pages + the null page), got %d pages"
                 % (name, self.max_pages, self.num_pages))
-        _T_GROUP_CAPACITY.set(self.num_pages - 1, cache=self.name,
-                              group=self.group)
 
-    def ring_pages_for(self, n_tokens: int) -> int:
-        """Pages a sequence of ``n_tokens`` holds in this group."""
-        return self.pages_for(min(int(n_tokens), self.ring_tokens))
-
-    def can_admit(self, n_tokens: int) -> bool:
-        return self.ring_pages_for(n_tokens) <= self.pages_available
-
-    def reserve(self, slot: int, n_tokens: int, _pin=()) -> None:
-        if n_tokens > self.max_seq_len:
-            raise MXNetError(
-                "sequence of %d tokens exceeds max_seq_len %d"
-                % (n_tokens, self.max_seq_len))
-        super().reserve(slot, min(int(n_tokens), self.ring_tokens))
+    def pages_for(self, n_tokens: int) -> int:
+        """Pages a sequence of ``n_tokens`` holds in this group: the ring's
+        at the most (what ``can_admit`` counts and ``reserve`` takes)."""
+        return super().pages_for(min(int(n_tokens), self.ring_tokens))
 
     def reserved_tokens(self, slot: int) -> int:
         owned = self._owned[int(slot)]
@@ -954,13 +954,22 @@ class RingKVCache(PagedKVCache):
         keep = block > block[-1] - self.max_pages
         return np.where(keep, pages, 0).astype(np.int32), offsets
 
-    def page_at(self, slot: int, pos: int) -> int:
-        """The page that holds position ``pos`` of ``slot`` (the decode
-        tick's one write a slot)."""
-        return int(self.page_table[
-            slot, (pos // self.page_size) % self.max_pages])
+    def page_at(self, slot, pos):
+        return self.page_table[slot, (pos // self.page_size) % self.max_pages]
+
+    def span_args(self, live) -> dict:
+        """The rows a window layer reads beside those a full layer does,
+        and the ring pages taken."""
+        return dict(
+            kv_rows_full=int(sum(live)),
+            kv_rows_window=int(sum(min(n, self.window_tokens)
+                                   for n in live)),
+            kv_window_pages=self.pages_in_use,
+            kv_window_capacity=self.num_pages - 1)
 
     def _publish(self) -> None:
+        _T_GROUP_CAPACITY.set(self.num_pages - 1, cache=self.name,
+                              group=self.group)
         _T_GROUP_PAGES.set(self.pages_in_use, cache=self.name,
                            group=self.group)
         if self.audit:
@@ -972,248 +981,12 @@ class RingKVCache(PagedKVCache):
         return out
 
 
-class GroupedKVCache:
-    """The cache of a model that declares ``kv_groups``: its full-attention
-    layers in a :class:`PagedKVCache` (``full``) and its sliding-window
-    layers in a :class:`RingKVCache` (``window``), side by side — two sets
-    of pools, two page tables, two free lists, one slot numbering.
-
-    What the decode engine asks of a cache it asks of this one; pools,
-    tables and write pages come back as ``(full, window)`` pairs, which is
-    how the model's ``decode`` / ``prefill`` receive them. Page counts
-    without a group's name (``pages_in_use``, ``num_pages``,
-    ``pages_for``, a tenant's page budget) are the FULL group's: it is the
-    one that grows with the sequence and gates admission first; the window
-    group reports under ``stats()["window"]`` and its own gauges. A
-    reservation takes pages in both groups or in neither.
-    """
-
-    prefix_cache = False
-    prefill_extra = "window_pages"
-
-    def __init__(self, num_slots: int, max_seq_len: int, kv_groups: dict,
-                 num_kv_heads: int, head_dim: int,
-                 page_size: Optional[int] = None, num_pages=None,
-                 dtype="float32", name: str = "decode"):
-        pages = dict(num_pages) if isinstance(num_pages, dict) \
-            else {"full": num_pages}
-        self.full = PagedKVCache(
-            num_slots, max_seq_len, len(kv_groups["full"]), num_kv_heads,
-            head_dim, page_size=page_size, num_pages=pages.get("full"),
-            dtype=dtype, name=name)
-        self.window = RingKVCache(
-            num_slots, max_seq_len, len(kv_groups["window"]), num_kv_heads,
-            head_dim, int(kv_groups["window_tokens"]),
-            page_size=self.full.page_size, num_pages=pages.get("window"),
-            dtype=dtype, name=name)
-        # the ring's base class published ITS capacity under this name
-        _T_CAPACITY.set(self.full.num_pages - 1, cache=name)
-        self.name = name
-        self.page_size = self.full.page_size
-        self.num_slots = self.full.num_slots
-        self.max_seq_len = self.full.max_seq_len
-        self.max_pages = self.full.max_pages
-        self.num_pages = self.full.num_pages
-        self.audit = self.full.audit
-        self.seq_lens = self.full.seq_lens
-        self.pressure_sheds = 0
-
-    # -- what a PagedKVCache answers, for the full group -----------------
-    pages_in_use = property(lambda self: self.full.pages_in_use)
-    page_table = property(lambda self: self.full.page_table)
-
-    def pages_for(self, n_tokens: int) -> int:
-        return self.full.pages_for(n_tokens)
-
-    def exclusive_pages(self, slot: int) -> int:
-        return self.full.exclusive_pages(slot)
-
-    # -- both groups -----------------------------------------------------
-    @property
-    def tables(self):
-        """``((version, host table), ...)``, the full group's first."""
-        return ((self.full.version, self.full.page_table),
-                (self.window.version, self.window.page_table))
-
-    @property
-    def k_pool(self):
-        return (self.full.k_pool, self.window.k_pool)
-
-    @property
-    def v_pool(self):
-        return (self.full.v_pool, self.window.v_pool)
-
-    operands = property(lambda self: (self.k_pool, self.v_pool))
-    paged_bytes = property(
-        lambda self: self.full.paged_bytes + self.window.paged_bytes)
-
-    def swap_pools(self, k_pool, v_pool) -> None:
-        self.full.swap_pools(k_pool[0], v_pool[0])
-        self.window.swap_pools(k_pool[1], v_pool[1])
-
-    def walk_groups(self):
-        return (("full", self.full.max_pages, self.full.num_layers),
-                ("window", self.window.max_pages, self.window.num_layers))
-
-    def span_args(self, live, prefill: bool = False) -> dict:
-        window = self.window
-        return dict(
-            kv_rows_full=int(sum(live)),
-            kv_rows_window=int(sum(min(n, window.window_tokens)
-                                   for n in live)),
-            kv_window_pages=window.pages_in_use,
-            kv_window_capacity=window.num_pages - 1)
-
-    def reset_pools(self) -> None:
-        self.full.reset_pools()
-        self.window.reset_pools()
-
-    def can_admit_prefix(self, n_tokens: int, match=None) -> bool:
-        return self.full.can_admit(n_tokens) \
-            and self.window.can_admit(n_tokens)
-
-    def reserve(self, slot: int, n_tokens: int) -> None:
-        if self.window.ring_pages_for(n_tokens) \
-                - self.window.pages_owned(slot) > self.window.pages_free:
-            raise OutOfPagesError(
-                "kvcache %r: the window group cannot cover %d tokens "
-                "(%d pages free)" % (self.name, n_tokens,
-                                     self.window.pages_free))
-        self.full.reserve(slot, n_tokens)   # raises before any mutation
-        self.window.reserve(slot, n_tokens)
-
-    def free(self, slot: int) -> None:
-        self.full.free(slot)
-        self.window.free(slot)
-
-    def shed_cached(self, n=None) -> int:
-        return 0  # no prefix index, so no cached page to shed
-
-    def insert_prefix(self, slot: int, prompt) -> None:
-        """No prefix index: a ring page is rewritten in place."""
-
-    def write_slots(self, slot: int, start: int, n_tokens: int):
-        """``(pages (2, n), offsets (n,))``: one row of destination pages a
-        group, the full group's first; a page's offsets are the same in
-        both (one page size)."""
-        pages, offsets = self.full.write_slots(slot, start, n_tokens)
-        ring, _ = self.window.write_slots(slot, start, n_tokens)
-        return np.stack([pages, ring]), offsets
-
-    def null_write_slots(self, n_tokens: int):
-        pages, offsets = self.full.null_write_slots(n_tokens)
-        return np.stack([pages, pages]), offsets
-
-    def audit_check(self) -> None:
-        self.full.audit_check()
-        self.window.audit_check()
-
-    def stats(self) -> dict:
-        out = self.full.stats()
-        out["window"] = self.window.stats()
-        return out
-
-
-class LatentStateCache(PagedKVCache):
-    """The cache of a model whose layers keep a LATENT row a token or a
-    fixed-size STATE a slot (:func:`layer_states`; both kinds in one model).
-
-    ``latent_pool``: one array a latent layer, ``(pages, page_size, row
-    width)`` — no head axis and NO V pool: a token's row ``[c; k_r]`` is key
-    and value at once, held as wide as :func:`pool_row_width` says (the lane
-    tile above its width on a TPU: zeros the products ignore). Paged exactly
-    as a :class:`PagedKVCache` pages K/V: the page table, the free list, the
-    reservation and ``write_slots`` are the base class's, over the latent
-    layers alone. ``slot_state``: a tuple a state layer of arrays
-    ``(num_slots,) + shape``, float32 zeros, which a prefill overwrites for
-    its slot and a decode tick updates in place for the rows that hold a
-    token. It is not paged, so nothing of it is reserved, shared or freed: a
-    slot's next prefill overwrites it whole. The two are the
-    :attr:`operands` the engine threads through its programs and donates.
-    No prefix index: a state is no page to hash."""
-
-    group = "latent"
-    prefill_extra = "slot"
-
-    def __init__(self, num_slots: int, max_seq_len: int, states,
-                 page_size: Optional[int] = None,
-                 num_pages: Optional[int] = None, dtype="float32",
-                 name: str = "decode"):
-        latent = [st[1] for st in states if st[0] == "latent"]
-        self._state_shapes = tuple(
-            tuple(tuple(int(n) for n in shape) for shape in st[1])
-            for st in states if st[0] == "slot")
-        if len(set(latent)) != 1 or not self._state_shapes:
-            raise MXNetError(
-                "LatentStateCache serves latent layers of one width beside "
-                "slot-state layers, got %s" % (list(states),))
-        #: bytes of the slot state (float32), every layer and slot
-        self.state_bytes = 4 * int(num_slots) * sum(
-            math.prod(shape) for layer in self._state_shapes
-            for shape in layer)
-        #: slots live, state bytes moved, latent rows read: summed over the
-        #: ticks and prefills :meth:`span_args` was asked about
-        self._moved = (0, 0, 0)
-        super().__init__(num_slots, max_seq_len, len(latent), 1,
-                         int(latent[0]), page_size=page_size,
-                         num_pages=num_pages or 0, dtype=dtype, name=name)
-        _T_STATE_BYTES.set(self.state_bytes, server=name)
-
-    operands = property(lambda self: (self.latent_pool, self.slot_state))
-
-    def swap_pools(self, latent_pool, slot_state) -> None:
-        self.latent_pool = latent_pool
-        self.slot_state = slot_state
-
-    @property
-    def paged_bytes(self) -> int:
-        return int(sum(x.nbytes for x in self.latent_pool))
-
-    def _zero_pools(self) -> None:
-        import jax.numpy as jnp
-
-        # the base class sized the rows with one head each; that axis goes
-        # (a TPU pads it to a sublane tile and the step then converts the
-        # pool on its way into the kernel, every tick), the rows keep the
-        # width it asked the device for
-        pages, page_size, _one, width = self._pool_shape
-        self.latent_pool = tuple(
-            jnp.zeros((pages, page_size, width), self._pool_dtype)
-            for _ in range(self.num_layers))
-        self.slot_state = tuple(
-            tuple(jnp.zeros((self.num_slots,) + shape, jnp.float32)
-                  for shape in layer) for layer in self._state_shapes)
-
-    def span_args(self, live, prefill: bool = False) -> dict:
-        """A tick's recurrence reads and writes each live slot's state once;
-        a prefill writes its slot's. The latent attention reads a row a
-        token a latent layer (a prefill: before the pool). Counted here too
-        (``mxnet_decode_state_total``, :meth:`stats`)."""
-        a_slot = self.state_bytes // self.num_slots
-        moved = (len(live), len(live) * a_slot * (1 if prefill else 2),
-                 int(sum(live)) * self.num_layers)
-        for what, n in zip(("slots_live", "bytes_moved",
-                            "latent_rows_read"), moved):
-            _T_STATE.inc(n, server=self.name, what=what)
-        # one new tuple: stats() reads it from caller threads
-        self._moved = tuple(a + b for a, b in zip(self._moved, moved))
-        return dict(state_slots_live=moved[0], state_bytes_moved=moved[1],
-                    latent_rows_read=moved[2])
-
-    def stats(self) -> dict:
-        out = super().stats()
-        # (its pages ARE the latent pool's: the two names of one number)
-        out["state"] = dict(
-            zip(("state_slots_live", "state_bytes_moved",
-                 "latent_rows_read"), self._moved),
-            state_bytes=self.state_bytes,
-            latent_pages=out["pages_in_use"],
-            latent_capacity=out["pages_capacity"])
-        return out
-
-
-#: what a layer may keep between tokens, and what each kind forbids
+#: what a layer may keep between tokens; the paged kinds in the order of a
+#: cache's groups, with the name each group's page walk is counted under
 STATE_KINDS = ("paged", "ring", "latent", "slot")
+_GROUP_OF = {"paged": "full", "ring": "window", "latent": "latent"}
+#: the mixes of kinds a model is served with today (one has each)
+_SERVED = ({"paged"}, {"paged", "ring"}, {"latent", "slot"})
 
 
 def layer_states(model) -> list:
@@ -1228,62 +1001,275 @@ def layer_states(model) -> list:
     ``("slot", (shape, ...))``
         float32 arrays of fixed shapes a slot, not paged.
 
-    A model says so as ``layer_state``; one that declares ``kv_groups`` has
-    paged (``full``) and ring (``window``) layers; one that declares neither
-    keeps paged K/V in every layer."""
+    A model says so as ``layer_state``; one that declares nothing keeps
+    paged K/V in every layer."""
     declared = getattr(model, "layer_state", None)
-    if declared is not None:
-        states = [tuple(st) for st in declared]
-        bad = sorted({st[0] for st in states} - set(STATE_KINDS))
-        if bad or len(states) != model.num_layers:
-            raise MXNetError(
-                "layer_state: %d entries for %d layers, unknown kinds %s "
-                "(known: %s)" % (len(states), model.num_layers, bad,
-                                 list(STATE_KINDS)))
-        return states
-    groups = getattr(model, "kv_groups", None)
-    if groups:
-        ring = ("ring", int(groups["window_tokens"]))
-        return [ring if li in groups["window"] else ("paged",)
-                for li in range(model.num_layers)]
-    return [("paged",)] * model.num_layers
+    if declared is None:
+        return [("paged",)] * model.num_layers
+    states = [tuple(st) for st in declared]
+    bad = sorted({st[0] for st in states} - set(STATE_KINDS))
+    if bad or len(states) != model.num_layers:
+        raise MXNetError(
+            "layer_state: %d entries for %d layers, unknown kinds %s "
+            "(known: %s)" % (len(states), model.num_layers, bad,
+                             list(STATE_KINDS)))
+    return states
+
+
+def _paged_entries(states) -> list:
+    """The distinct paged entries of ``states`` in the order of a cache's
+    groups (:data:`STATE_KINDS`: full before window)."""
+    return sorted({st for st in states if st[0] != "slot"},
+                  key=lambda st: (STATE_KINDS.index(st[0]), st[1:]))
+
+
+def place_layers(states) -> list:
+    """Where a model finds each layer's arrays in the operands its cache
+    hands it, one ``(group, index)`` a layer of ``states`` (a model's
+    ``layer_state``): a paged layer's pools are layer ``index`` of group
+    ``group`` of ``pools``; a slot layer's state is entry ``index`` of
+    ``state`` (``group`` None)."""
+    states = [tuple(st) for st in states]
+    entries = _paged_entries(states)
+    seen = collections.Counter()
+    places = []
+    for st in states:
+        group = None if st[0] == "slot" else entries.index(st)
+        places.append((group, seen[group]))
+        seen[group] += 1
+    return places
+
+
+class DecodeCache:
+    """Everything a model's layers keep between tokens, composed entry by
+    entry from its :func:`layer_states` (:func:`make_cache`): what
+    :class:`~mxnet_tpu.serving.DecodeEngine` asks of its cache it asks of
+    this one, whatever the kinds.
+
+    ``groups``
+        one allocator with its pools (:class:`PagedKVCache`, a ring:
+        :class:`RingKVCache`) a distinct paged kind, over that kind's
+        layers: one slot numbering, a page table, a free list and a
+        reservation each. What spans them is answered here: a reservation
+        takes pages in every group or in none, ``free`` drops them in every
+        group, ``write_slots`` and :meth:`page_lookups` give one row of
+        pages (one lookup) a group, ``tables`` one table a group. Everything else — page
+        counts without a group's name (``pages_in_use``, ``num_pages``,
+        ``pages_for``, a tenant's page budget), the prefix index, ``audit``
+        — is the FIRST group's: the one that grows with the sequence and
+        gates admission first. A further group reports under
+        ``stats()[its name]`` and its own gauges.
+    ``state``
+        a tuple a ``slot`` layer of arrays ``(num_slots,) + shape``, float32
+        zeros, which a prefill overwrites for its slot and a decode tick
+        updates in place for the rows that hold a token; ``()`` for a model
+        with no such layer. It is not paged, so nothing of it is reserved,
+        shared or freed: a slot's next prefill overwrites it whole.
+
+    The two :attr:`operands` the engine threads through its programs and
+    donates are ``(pools, state)``; a model is handed ``pools``,
+    ``page_tables`` and ``write_pages`` as ONE group's bare or as a tuple a
+    group (:meth:`per_group`)."""
+
+    def __init__(self, groups, state_shapes=(), name: str = "decode"):
+        self.groups = tuple(groups)
+        self.num_groups = len(self.groups)
+        self.name = name
+        self._state_shapes = tuple(
+            tuple(tuple(int(n) for n in shape) for shape in layer)
+            for layer in state_shapes)
+        #: bytes of the slot state (float32), every layer and slot
+        self.state_bytes = 4 * self.num_slots * sum(
+            math.prod(shape) for layer in self._state_shapes
+            for shape in layer)
+        #: the groups whose rows a latent attention reads (`latent_*` below)
+        self._latent = [g for g in self.groups if g.group == "latent"]
+        #: slots live, state bytes moved, latent rows read: summed over the
+        #: ticks and prefills :meth:`span_args` was asked about
+        self._moved = (0, 0, 0)
+        self._zero_state()
+        if self.state:
+            _T_STATE_BYTES.set(self.state_bytes, server=name)
+
+    def __getattr__(self, name):
+        # what has no group's name is the first group's (see the class)
+        if name == "groups" or name.startswith("__"):
+            raise AttributeError(name)
+        return getattr(self.groups[0], name)
+
+    # -- the operands ------------------------------------------------------
+    def per_group(self, items):
+        """What a model is handed for ``items``, one a group: the one
+        group's own, or the tuple of several groups'."""
+        return items[0] if self.num_groups == 1 else tuple(items)
+
+    @property
+    def operands(self):
+        """``(pools, state)``: what every program of the engine is handed,
+        donates and returns (:meth:`swap_pools` stores them back)."""
+        return self.per_group([g.pools for g in self.groups]), self.state
+
+    def swap_pools(self, pools, state) -> None:
+        """Store what a jitted step returned in the operands' places
+        (functional update discipline; with donation the old buffers are
+        already dead)."""
+        for group, its in zip(self.groups, (pools,) if self.num_groups == 1
+                              else pools):
+            group.pools = its
+        self.state = state
+
+    @property
+    def paged_bytes(self) -> int:
+        return sum(g.paged_bytes for g in self.groups)
+
+    def _zero_state(self) -> None:
+        import jax.numpy as jnp
+
+        self.state = tuple(
+            tuple(jnp.zeros((self.num_slots,) + shape, jnp.float32)
+                  for shape in layer) for layer in self._state_shapes)
+
+    def reset_pools(self) -> None:
+        for group in self.groups:
+            group.reset_pools()
+        self._zero_state()
+
+    # -- every group -------------------------------------------------------
+    @property
+    def tables(self):
+        """``((version, host table), ...)``, one a group."""
+        return tuple((g.version, g.page_table) for g in self.groups)
+
+    def walk_groups(self):
+        """``((group, table columns, layers), ...)``: the page tables a
+        decode tick's attention walks."""
+        return tuple((g.group, g.max_pages, g.num_layers)
+                     for g in self.groups)
+
+    def can_admit_prefix(self, n_tokens: int, match=None) -> bool:
+        first, *further = self.groups
+        return first.can_admit_prefix(n_tokens, match) \
+            and all(g.can_admit(n_tokens) for g in further)
+
+    def reserve(self, slot: int, n_tokens: int) -> None:
+        for g in self.groups:
+            if g.pages_for(n_tokens) - g.pages_owned(slot) \
+                    > g.pages_available:
+                raise OutOfPagesError(
+                    "kvcache %r: the %s group cannot cover %d tokens (%d "
+                    "pages free)" % (self.name, g.group, n_tokens,
+                                     g.pages_available))
+        for g in self.groups:   # the first raises before any mutation
+            g.reserve(slot, n_tokens)
+
+    def free(self, slot: int) -> None:
+        for group in self.groups:
+            group.free(slot)
+
+    def write_slots(self, slot: int, start: int, n_tokens: int):
+        """``(pages (groups, n), offsets (n,))``: one row of destination
+        pages a group; a page's offsets are the same in all (one page
+        size)."""
+        rows = [g.write_slots(slot, start, n_tokens) for g in self.groups]
+        return np.stack([pages for pages, _ in rows]), rows[0][1]
+
+    def null_write_slots(self, n_tokens: int):
+        pages, offsets = self.groups[0].null_write_slots(n_tokens)
+        return np.stack([pages] * self.num_groups), offsets
+
+    def page_lookups(self):
+        """One ``page_at(slot, pos)`` a group — the page of that group that
+        holds position ``pos`` of ``slot``: a decode tick's one write a row,
+        resolved once a tick and asked once a row."""
+        return tuple(g.page_at for g in self.groups)
+
+    def audit_check(self) -> None:
+        for group in self.groups:
+            group.audit_check()
+
+    # -- what it tells -----------------------------------------------------
+    def span_args(self, live, prefill: bool = False) -> dict:
+        """What a prefill's or a decode tick's span says of this cache
+        beyond the page walk (``live``: tokens each sequence of the run
+        holds): what each group says and, of a cache that holds slot state,
+        what moved. A tick's recurrence reads and writes each live slot's
+        state once; a prefill writes its slot's. A latent attention reads a
+        row a token a latent layer (a prefill: before the pool). Counted
+        here too (``mxnet_decode_state_total``, :meth:`stats`)."""
+        args = {}
+        for group in self.groups:
+            args.update(group.span_args(live))
+        if not self.state:
+            return args
+        a_slot = self.state_bytes // self.num_slots
+        moved = (len(live), len(live) * a_slot * (1 if prefill else 2),
+                 int(sum(live)) * sum(g.num_layers for g in self._latent))
+        for what, n in zip(("slots_live", "bytes_moved",
+                            "latent_rows_read"), moved):
+            _T_STATE.inc(n, server=self.name, what=what)
+        # one new tuple: stats() reads it from caller threads
+        self._moved = tuple(a + b for a, b in zip(self._moved, moved))
+        args.update(state_slots_live=moved[0], state_bytes_moved=moved[1],
+                    latent_rows_read=moved[2])
+        return args
+
+    def stats(self) -> dict:
+        first, *further = self.groups
+        out = first.stats()
+        for group in further:
+            out[group.group] = group.stats()
+        if self.state:
+            out["state"] = dict(
+                zip(("state_slots_live", "state_bytes_moved",
+                     "latent_rows_read"), self._moved),
+                state_bytes=self.state_bytes,
+                latent_pages=sum(g.pages_in_use for g in self._latent),
+                latent_capacity=sum(g.num_pages - 1 for g in self._latent))
+        return out
 
 
 def make_cache(model, num_slots: int, max_seq_len: int, page_size=None,
                num_pages=None, dtype="float32", name: str = "decode",
-               prefix_cache: bool = False):
-    """The cache :func:`layer_states` of ``model`` asks for: pools for the
-    layers that own some and for no other. All paged: a
-    :class:`PagedKVCache`; paged and ring: a :class:`GroupedKVCache`; latent
-    and slot state: a :class:`LatentStateCache`. Another mix of kinds is not
-    served yet, and says so."""
+               prefix_cache: bool = False) -> DecodeCache:
+    """The cache :func:`layer_states` of ``model`` asks for, composed entry
+    by entry: one group of pools with its allocator a distinct paged kind
+    (``paged``: ``full``; ``ring``: ``window``; ``latent``), over that
+    kind's layers and no other, and the ``slot`` layers' state.
+    ``num_pages``: the first group's, or ``{group: pages}``. A mix of kinds
+    that no served model has is refused by name."""
     states = layer_states(model)
     kinds = {st[0] for st in states}
-    if kinds == {"paged"}:
-        return PagedKVCache(
-            num_slots, max_seq_len, model.num_layers, model.num_kv_heads,
-            model.head_dim, page_size=page_size, num_pages=num_pages,
-            dtype=dtype, name=name, prefix_cache=prefix_cache)
-    if prefix_cache:
+    entries = _paged_entries(states)
+    if kinds not in _SERVED or len(entries) != len(kinds - {"slot"}):
+        raise MXNetError(
+            "no served model keeps layers of %s together (served: %s): "
+            "serving a further mix wants its reference and its cell beside "
+            "this condition"
+            % (" + ".join(" ".join(map(str, e)) for e in entries
+                          + [("slot",)] * ("slot" in kinds)),
+               "; ".join(" + ".join(sorted(mix)) for mix in _SERVED)))
+    if prefix_cache and kinds != {"paged"}:
         raise MXNetError("no prefix index over %s layers" % sorted(kinds))
-    if kinds == {"paged", "ring"}:
-        windows = {st[1] for st in states if st[0] == "ring"}
-        if len(windows) != 1:
-            raise MXNetError("ring layers of one window, got %s"
-                             % sorted(windows))
-        groups = {"full": [li for li, st in enumerate(states)
-                           if st[0] == "paged"],
-                  "window": [li for li, st in enumerate(states)
-                             if st[0] == "ring"],
-                  "window_tokens": windows.pop()}
-        return GroupedKVCache(
-            num_slots, max_seq_len, groups, model.num_kv_heads,
-            model.head_dim, page_size=page_size, num_pages=num_pages,
-            dtype=dtype, name=name)
-    if kinds == {"latent", "slot"}:
-        return LatentStateCache(
-            num_slots, max_seq_len, states, page_size=page_size,
-            num_pages=num_pages, dtype=dtype, name=name)
-    raise MXNetError(
-        "no cache for layers of kinds %s together (served: paged; paged + "
-        "ring; latent + slot)" % sorted(kinds))
+    page_size = _page_size(page_size)
+    pages = dict(num_pages) if isinstance(num_pages, dict) \
+        else {_GROUP_OF[entries[0][0]]: num_pages}
+    groups = []
+    for entry in entries:
+        kind, layers = entry[0], states.count(entry)
+        common = dict(page_size=page_size, dtype=dtype, name=name,
+                      num_pages=pages.get(_GROUP_OF[kind]))
+        if kind == "ring":
+            groups.append(RingKVCache(
+                num_slots, max_seq_len, layers, model.num_kv_heads,
+                model.head_dim, entry[1], **common))
+        elif kind == "latent":
+            # (like a ring's, its pages are never MXNET_KVCACHE_PAGES')
+            common["num_pages"] = common["num_pages"] or 0
+            groups.append(PagedKVCache(
+                num_slots, max_seq_len, layers, None, entry[1], **common))
+        else:
+            groups.append(PagedKVCache(
+                num_slots, max_seq_len, layers, model.num_kv_heads,
+                model.head_dim, prefix_cache=prefix_cache, **common))
+    return DecodeCache(
+        groups, [st[1] for st in states if st[0] == "slot"], name=name)

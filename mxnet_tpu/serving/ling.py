@@ -34,9 +34,9 @@ expert layer after them (:mod:`mxnet_tpu.ops.moe`: a sigmoid router over all
 
 What it declares to :class:`~mxnet_tpu.serving.DecodeEngine`:
 ``layer_state`` (a ``slot`` entry a kda layer, a ``latent`` entry a mla
-layer: the engine hands ``decode`` / ``prefill`` the cache's two operands,
-the latent pools and the slot state, where a K/V model is handed ``k_pool``
-and ``v_pool``), ``moe_counters`` and ``prefill_rows`` (the rows of a rung a
+layer: of the two operands every model is handed, ``pools`` is its one
+group's, the latent layers' pools bare, and ``state`` a pair of arrays a kda
+layer), ``moe_counters`` and ``prefill_rows`` (the rows of a rung a
 prefill's row-wise passes compute for a prompt: those of the row blocks the
 prompt reaches, :mod:`mxnet_tpu.ops.row_blocks`; the scan visits the chunks
 that hold the prompt).
@@ -53,7 +53,7 @@ import numpy as np
 
 from ..base import MXNetError
 from .decode import PagedDecodeModel
-from .kvcache import write_rows
+from .kvcache import place_layers, write_rows
 
 __all__ = ["LingDecoder"]
 
@@ -144,12 +144,8 @@ class LingDecoder(PagedDecodeModel):
         self.layer_state = [("slot", state) if kind == "kda"
                             else ("latent", self.latent_width)
                             for kind in layer_types]
-        # layer -> its index among the layers of its kind
-        seen = {"kda": 0, "mla": 0}
-        self._place = []
-        for kind in layer_types:
-            self._place.append(seen[kind])
-            seen[kind] += 1
+        # layer -> its index among the latent pools, or in the slot state
+        self._place = [at for _group, at in place_layers(self.layer_state)]
         n_expert_layers = self.num_layers - int(num_dense_layers)
         self.moe_counters = (n_expert_layers, held[1] + 1) \
             if n_expert_layers > 0 else None

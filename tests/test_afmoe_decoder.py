@@ -30,8 +30,8 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.serving import afmoe_reference as ref
 from mxnet_tpu.serving import decode as decode_mod
-from mxnet_tpu.serving.kvcache import (GroupedKVCache, OutOfPagesError,
-                                       RingKVCache)
+from mxnet_tpu.serving.kvcache import (OutOfPagesError, RingKVCache,
+                                       make_cache)
 
 LOGIT_TOL = 2e-4
 GAP_TOL = 1e-3
@@ -70,10 +70,9 @@ def _prompt(n, seed):
 
 def _direct(model, params, prompt, steps, rung=64):
     """Prefill then ``steps`` teacher-forced decode steps through a
-    ``GroupedKVCache``, as the engine drives them; the logits of every
+    the model's cache, as the engine drives them; the logits of every
     position from the prompt's last on."""
-    cache = GroupedKVCache(2, 128, model.kv_groups, model.num_kv_heads,
-                           model.head_dim, page_size=PAGE)
+    cache = make_cache(model, 2, 128, page_size=PAGE)
     slot, p = 1, prompt.size
     seq = np.concatenate([prompt, _prompt(steps, 99)])
     cache.reserve(slot, p + steps)
@@ -84,9 +83,9 @@ def _direct(model, params, prompt, steps, rung=64):
                for g in pages)
     wo = jnp.asarray(np.concatenate([offs, np.zeros(pad, np.int32)]))
     out = model.prefill(params, jnp.asarray(tokens), jnp.asarray(p),
-                        cache.k_pool, cache.v_pool, wp, wo)
+                        *cache.operands, wp, wo)
     logits = [np.asarray(out[0])]
-    k_pool, v_pool = out[1], out[2]
+    pools, state = out[1], out[2]
     tables = tuple(jnp.asarray(t) for _v, t in cache.tables)
     for i in range(steps):
         pos = p + i
@@ -99,9 +98,9 @@ def _direct(model, params, prompt, steps, rung=64):
         wo = jnp.asarray(np.where(np.arange(2) == slot, offs[0], 0)
                          .astype(np.int32))
         out = model.decode(params, jnp.asarray(toks), jnp.asarray(poss),
-                           k_pool, v_pool, tables, jnp.asarray(lens), wp, wo)
+                           pools, state, tables, jnp.asarray(lens), wp, wo)
         logits.append(np.asarray(out[0])[slot])
-        k_pool, v_pool = out[1], out[2]
+        pools, state = out[1], out[2]
     return seq, np.stack(logits)
 
 
@@ -148,13 +147,13 @@ def test_engine_serves_what_the_reference_puts_first_under_churn(
              (64, 8), (90, 12)]
     ring_pages = WINDOW // PAGE + 1
     with _engine(tiny) as eng:
-        assert eng._cache.audit and eng._cache.window.audit
+        assert all(group.audit for group in eng._cache.groups)
         assert eng.warmup() == 4      # the step and three prefill rungs
         prompts = [_prompt(n, i) for i, (n, _m) in enumerate(sizes)]
         futs = [eng.submit(p, m) for p, (_n, m) in zip(prompts, sizes)]
         peak = 0
         while not all(f.done() for f in futs):
-            win = eng._cache.window
+            _full, win = eng._cache.groups
             assert max(win.pages_owned(s) for s in range(3)) <= ring_pages
             peak = max(peak, eng.kvcache_stats()["window"]["pages_in_use"])
             time.sleep(0.01)
@@ -258,17 +257,20 @@ def test_ring_cache_reserves_a_ring_and_writes_the_last_window():
 
 def test_grouped_cache_takes_pages_in_both_groups_or_in_neither(tiny):
     model, _params = tiny
-    cache = GroupedKVCache(3, 128, model.kv_groups, 2, 8, page_size=PAGE,
-                           num_pages={"full": 40, "window": 8})
-    assert [x.shape for x in cache.k_pool[0]] == [(40, PAGE, 2, 8)]
-    assert [x.shape for x in cache.k_pool[1]] == [(8, PAGE, 2, 8)] * 4
+    cache = make_cache(model, 3, 128, page_size=PAGE,
+                       num_pages={"full": 40, "window": 8})
+    full, window = cache.groups
+    (full_k, full_v), (window_k, window_v) = cache.operands[0]
+    assert [x.shape for x in full_k] == [(40, PAGE, 2, 8)]
+    assert [x.shape for x in window_v] == [(8, PAGE, 2, 8)] * 4
+    assert cache.operands[1] == ()
     cache.reserve(0, 100)
-    assert cache.full.pages_owned(0) == 13
-    assert cache.window.pages_owned(0) == 5
+    assert full.pages_owned(0) == 13
+    assert window.pages_owned(0) == 5
     assert cache.can_admit_prefix(16) and not cache.can_admit_prefix(24)
-    with pytest.raises(OutOfPagesError):
+    with pytest.raises(OutOfPagesError, match="window"):
         cache.reserve(1, 24)          # 3 window pages, 2 free
-    assert cache.full.pages_owned(1) == 0 and cache.pages_in_use == 13
+    assert full.pages_owned(1) == 0 and cache.pages_in_use == 13
     cache.reserve(1, 16)
     stats = cache.stats()
     assert stats["pages_in_use"] == 15 and stats["pages_capacity"] == 39
@@ -277,9 +279,16 @@ def test_grouped_cache_takes_pages_in_both_groups_or_in_neither(tiny):
     cache.free(0)
     cache.free(1)
     cache.audit_check()
-    assert cache.pages_in_use == 0 and cache.window.pages_in_use == 0
+    assert cache.pages_in_use == 0 and window.pages_in_use == 0
     pages, offs = cache.null_write_slots(9)
     assert pages.shape == (2, 9) and not pages.any() and offs.max() == 7
+    # a tick's write pages, one lookup a group: the ring's column wraps
+    cache.reserve(2, 100)
+    full_at, window_at = cache.page_lookups()
+    assert [full_at(2, 3), full_at(2, 99)] \
+        == [full.page_table[2, 0], full.page_table[2, 12]]
+    assert [window_at(2, 3), window_at(2, 99)] \
+        == [window.page_table[2, 0], window.page_table[2, 12 % 5]]
 
 
 def test_engine_admission_waits_for_pages_of_the_window_group(tiny):
@@ -299,10 +308,10 @@ def test_engine_admission_waits_for_pages_of_the_window_group(tiny):
 def test_a_grouped_model_is_refused_the_prefix_cache_chunks_and_drafts(tiny):
     model, params = tiny
     for kw in ({"prefix_cache": True}, {"prefill_chunk": 8}, {"spec_k": 2}):
-        with pytest.raises(MXNetError, match="kv_groups"):
+        with pytest.raises(MXNetError, match=r"ring layers \(layer_state\)"):
             _engine(tiny, **kw)
     with pytest.raises(MXNetError, match="chunked prefill"):
-        model.prefill_chunk(params, None, 0, 1, None, None, None, None, None)
+        model.prefill_chunk(params, None, 0, 1, None, (), None, None, None)
     with pytest.raises(MXNetError):
         serving.AfmoeDecoder(**dict(TINY, layer_types=["full_attention"]))
     with pytest.raises(MXNetError):

@@ -111,7 +111,7 @@ def _case(name, spec, dtype):
     # the two benchmark cells' own tables: the widest scalar-prefetch
     # operands (table, lengths, live columns) the decode step hands SMEM
     if name == "paged_decode_opt1p3b":      # 16 slots x 128 columns, 32 x 64
-        pool = spec((385, PAGE, 32, 64), dtype)
+        pool = spec((769, PAGE, 32, 64), dtype)
         return (lambda *a: pk.ragged_paged_attention(*a, interpret=False),
                 (spec((SLOTS, 32, 64), dtype), pool, pool,
                  spec((SLOTS, 128), jnp.int32), spec((SLOTS,), jnp.int32)))
@@ -126,7 +126,7 @@ def _case(name, spec, dtype):
     # heads x 64 in rows 128 wide (the key matrix of a page is 512 x 128),
     # the decode tick, the verify tick (width 4) and the chunk program
     if name.startswith("opt1p3b_wide_"):
-        pool = spec((385, PAGE, 32, 128), dtype)
+        pool = spec((769, PAGE, 32, 128), dtype)
         table = spec((SLOTS, 128), jnp.int32)
         if name.endswith("decode"):
             return (lambda *a: pk.ragged_paged_attention(*a, interpret=False),
@@ -282,14 +282,15 @@ def _pool_step_case(name, one_chip):
         assert width == 128, width      # 64 grows to the lanes, 128 stays
         return tuple(spec(shape[:-1] + (width,)) for _ in range(layers))
 
-    # pools, tables and (group, layer in it) by cache group, as a model
-    # with ``kv_groups`` receives them; group 1 is a window's ring
+    # pools (a (k layers, v layers) a group), tables and (group, layer in
+    # it) by cache group, as a model whose ``layer_state`` has several paged
+    # kinds receives them; group 1 is a window's ring
     if name == "opt1p3b_32x64":         # the OPT cell (769 pages), 32 x 64
         heads, kv_heads, dim = 32, 32, 64
-        pool = (pools(LAYERS, (385, PAGE, kv_heads, dim)),)
+        pool = (pools(LAYERS, (769, PAGE, kv_heads, dim)),)
         tables = (spec((SLOTS, 128), jnp.int32),)
         place = [(0, li) for li in range(LAYERS)]
-        smallest = 385 * PAGE * kv_heads * 128
+        smallest = 769 * PAGE * kv_heads * 128
     else:                               # Trinity's: 8 x 128, a window group
         heads, kv_heads, dim = 48, 8, 128
         ring = 4096 // PAGE + 1
@@ -302,23 +303,23 @@ def _pool_step_case(name, one_chip):
         place = [(1, 0), (1, 1), (1, 2), (0, 0)]
         smallest = 4097 * PAGE * kv_heads * dim
 
-    def step(q, k_new, v_new, k_pool, v_pool, tables, lens, pages, offs):
-        k_pool, v_pool = list(k_pool), list(v_pool)
+    def step(q, k_new, v_new, pools, state, tables, lens, pages, offs):
+        pools = list(pools)
         for grp, li in place:
-            k_pool[grp], v_pool[grp] = kvcache.write_kv(
-                k_pool[grp], v_pool[grp], li, k_new, v_new, pages, offs)
-            operands = (q, k_pool[grp][li], v_pool[grp][li], tables[grp],
-                        lens)
+            pools[grp] = k_pool, v_pool = kvcache.write_kv(
+                *pools[grp], li, k_new, v_new, pages, offs)
+            operands = (q, k_pool[li], v_pool[li], tables[grp], lens)
             q = q + (pk.ragged_window_attention(*operands, 4096,
                                                 interpret=False) if grp
                      else pk.ragged_paged_attention(*operands,
                                                     interpret=False))
             k_new, v_new = k_new + 1.0, v_new + 1.0
-        return q, tuple(k_pool), tuple(v_pool)
+        return q, tuple(pools), state
 
     rows = spec((SLOTS, kv_heads, dim))
     ints = spec((SLOTS,), jnp.int32)
-    return (step, (spec((SLOTS, heads, dim)), rows, rows, pool, pool, tables,
+    return (step, (spec((SLOTS, heads, dim)), rows, rows,
+                   tuple((group, group) for group in pool), (), tables,
                    ints, ints, ints), (3, 4), smallest)
 
 
@@ -474,14 +475,16 @@ def test_prefill_of_the_largest_rung_takes_no_more_temporaries(
     slots, page = engine["num_slots"], engine["page_size"]
     rows, count = spec((rung,), jnp.int32), spec((), jnp.int32)
     if name == "trinity":
-        pools = (tuple(spec((engine["num_pages"]["full"], page, 8, 128))
-                       for _ in model.kv_groups["full"]),
-                 tuple(spec((engine["num_pages"]["window"], page, 8, 128))
-                       for _ in model.kv_groups["window"]))
-        cache = (pools, pools)
+        def group(name, kind):
+            layers = tuple(spec((engine["num_pages"][name], page, 8, 128))
+                           for st in model.layer_state if st[0] == kind)
+            return layers, layers       # its K layers, its V layers
 
-        def prefill(p, tokens, n, k, v, full, window, offs):
-            return model.prefill(p, tokens, n, k, v, (full, window), offs)
+        cache = ((group("full", "paged"), group("window", "ring")), ())
+
+        def prefill(p, tokens, n, pools, state, full, window, offs):
+            return model.prefill(p, tokens, n, pools, state, (full, window),
+                                 offs)
 
         operands = (rows, count) + cache + (rows, rows, rows)
     else:

@@ -232,7 +232,7 @@ def test_write_kv_scatters_rows():
     rows = jnp.asarray(np.arange(2 * 1 * 4, dtype=np.float32)
                        .reshape(2, 1, 4))
     pages, offs = c.write_slots(0, 7, 2)  # straddles the page edge
-    kp, vp = write_kv(c.k_pool, c.v_pool, 1, rows, rows * 2.0,
+    kp, vp = write_kv(*c.pools, 1, rows, rows * 2.0,
                       jnp.asarray(pages), jnp.asarray(offs))
     got_k = np.asarray(kp[1][np.asarray(pages), np.asarray(offs)])
     np.testing.assert_array_equal(got_k, np.asarray(rows))
@@ -1423,16 +1423,18 @@ def test_band_attention_kernel_at_its_own_block_size(window, length):
     np.testing.assert_allclose(got[:n], want[:n], atol=2e-5)
 
 
-def test_a_model_without_kv_groups_keeps_its_one_group_and_its_operands(tiny):
-    """TinyDecoder declares neither ``kv_groups`` nor ``moe_counters``: one
-    PagedKVCache, a (6, S) step operand (five rows and ``from_prev``), a
-    (3, rung) prefill operand, no counters behind the tokens, no new key in
-    its stats."""
+def test_a_model_that_declares_nothing_keeps_its_one_group_and_its_operands(
+        tiny):
+    """TinyDecoder declares neither ``layer_state`` nor ``moe_counters``: one
+    group of paged K/V, a (6, S) step operand (five rows and ``from_prev``),
+    a (3, rung) prefill operand, no counters behind the tokens, no new key
+    in its stats."""
     from mxnet_tpu.serving.kvcache import PagedKVCache
 
     with _engine(tiny, prefix_cache=False) as eng:
-        assert type(eng._cache) is PagedKVCache
-        assert eng._extra_rows == 0 and eng._moe_rows is None
+        (group,) = eng._cache.groups
+        assert type(group) is PagedKVCache and eng._cache.state == ()
+        assert eng._packed_rows == 6 and eng._moe_rows is None
         eng.warmup()
         shapes = []
         real = eng._jnp.asarray

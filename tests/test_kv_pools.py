@@ -1,8 +1,10 @@
 """The KV pools as the decode step holds them (tier-1, CPU): one array a
-layer, in a tuple the cache, the engine's four jits and ``write_kv`` thread
-whole — for a :class:`PagedKVCache` and for both groups of a
-:class:`GroupedKVCache`. What the pools cost on the chip (no whole-pool copy,
-no per-layer slice) is ``tests/test_chip_compile.py``'s to hold.
+layer, in the pytree the cache, the engine's four jits and ``write_kv``
+thread whole — for the one group of a model that declares nothing, for both
+groups of a window model and for the latent pools and the slot state of a
+recurrent one, each built by ``kvcache.make_cache``. What the pools cost on
+the chip (no whole-pool copy, no per-layer slice) is
+``tests/test_chip_compile.py``'s to hold.
 """
 import numpy as np
 import pytest
@@ -13,7 +15,7 @@ import jax.numpy as jnp
 from mxnet_tpu import serving
 from mxnet_tpu.resilience import hbm
 from mxnet_tpu.serving import kvcache
-from mxnet_tpu.serving.kvcache import GroupedKVCache, PagedKVCache, write_kv
+from mxnet_tpu.serving.kvcache import make_cache, write_kv
 
 PAGE, WINDOW = 8, 32
 AFMOE = dict(vocab_size=96, hidden_size=48, num_attention_heads=12,
@@ -23,34 +25,45 @@ AFMOE = dict(vocab_size=96, hidden_size=48, num_attention_heads=12,
              num_dense_layers=1, num_experts=16, num_experts_per_tok=4,
              sliding_window=WINDOW, held_experts=[4, 4], route_scale=2.448,
              mup_enabled=True)
-KINDS = ["paged", "grouped"]
+LING = dict(vocab_size=96, hidden_size=48, num_attention_heads=4, head_dim=8,
+            kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, intermediate_size=96, moe_intermediate_size=32,
+            layer_types=["kda", "kda", "kda", "mla"], num_dense_layers=1,
+            num_experts=16, num_experts_per_tok=4, n_group=4, topk_group=2,
+            held_experts=[0, 8], routed_scaling_factor=2.5)
+KINDS = ["paged", "grouped"]        # K/V groups
+ALL_KINDS = KINDS + ["latent"]
+
+
+def _model(kind):
+    if kind == "paged":
+        return serving.TinyDecoder(vocab_size=32, num_layers=3, num_heads=4,
+                                   head_dim=8, num_kv_heads=2)
+    if kind == "grouped":
+        return serving.AfmoeDecoder(**AFMOE)
+    return serving.LingDecoder(**LING)
 
 
 def _cache(kind):
     """``(cache, [(layers, pages) of each group])``."""
     name = "pools-%d" % np.random.randint(1 << 30)
     if kind == "paged":
-        return PagedKVCache(3, 64, 3, 2, 8, page_size=PAGE, num_pages=20,
-                            name=name), [(3, 20)]
-    groups = {"full": [4], "window": [0, 1, 2, 3], "window_tokens": WINDOW}
-    return GroupedKVCache(3, 128, groups, 2, 8, page_size=PAGE,
-                          num_pages={"full": 40, "window": 12},
-                          name=name), [(1, 40), (4, 12)]
-
-
-def _groups(cache, pool):
-    """The per-group tuples of ``pool`` (a plain cache has one)."""
-    return pool if isinstance(cache, GroupedKVCache) else (pool,)
+        return make_cache(_model(kind), 3, 64, page_size=PAGE, num_pages=20,
+                          name=name), [(3, 20)]
+    if kind == "latent":
+        return make_cache(_model(kind), 3, 64, page_size=PAGE, num_pages=20,
+                          name=name), [(1, 20)]
+    return make_cache(_model(kind), 3, 128, page_size=PAGE,
+                      num_pages={"full": 40, "window": 12},
+                      name=name), [(1, 40), (4, 12)]
 
 
 def _engine(kind, **kw):
+    model = _model(kind)
     if kind == "paged":
-        model = serving.TinyDecoder(vocab_size=32, num_layers=2, num_heads=4,
-                                    head_dim=8, num_kv_heads=2)
         kw.setdefault("max_seq_len", 48)
         kw.setdefault("prefill_buckets", (8, 16))
     else:
-        model = serving.AfmoeDecoder(**AFMOE)
         kw.update(max_seq_len=128, page_size=PAGE, prefill_buckets=(16, 64),
                   prefix_cache=False, prefill_chunk=0)
     kw.setdefault("num_slots", 3)
@@ -59,14 +72,24 @@ def _engine(kind, **kw):
     return serving.DecodeEngine(model, model.init_params(0), **kw)
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", ALL_KINDS)
 def test_a_pool_is_one_array_a_layer(kind):
     cache, groups = _cache(kind)
-    for pool in (cache.k_pool, cache.v_pool):
-        for leaves, (layers, pages) in zip(_groups(cache, pool), groups):
+    for group, (layers, pages) in zip(cache.groups, groups):
+        # a latent group: the layers themselves, no head axis, no V pool
+        pools, row = ((group.pools,), (20,)) if kind == "latent" \
+            else (group.pools, (2, 8))
+        assert len(pools) == (1 if kind == "latent" else 2)
+        for leaves in pools:
             assert isinstance(leaves, tuple)
-            assert [x.shape for x in leaves] == [(pages, PAGE, 2, 8)] * layers
+            assert [x.shape for x in leaves] \
+                == [(pages, PAGE) + row] * layers
             assert len({id(x) for x in leaves}) == layers
+    # what a model is handed: one group's pools bare, several as a tuple
+    pools, state = cache.operands
+    assert pools is cache.groups[0].pools if len(groups) == 1 \
+        else pools == tuple(g.pools for g in cache.groups)
+    assert (state == ()) == (kind != "latent")
 
 
 @pytest.mark.parametrize("kind", KINDS)
@@ -76,9 +99,8 @@ def test_write_kv_replaces_one_layers_array_and_no_other(kind):
                        .reshape(3, 2, 8) + 1.0)
     pages = jnp.asarray([1, 1, 2], jnp.int32)
     offs = jnp.asarray([6, 7, 0], jnp.int32)
-    for k_old, v_old, (layers, _p) in zip(_groups(cache, cache.k_pool),
-                                          _groups(cache, cache.v_pool),
-                                          groups):
+    for group, (layers, _p) in zip(cache.groups, groups):
+        k_old, v_old = group.pools
         layer = layers - 1
         k_new, v_new = write_kv(k_old, v_old, layer, rows, rows * 2.0,
                                 pages, offs)
@@ -94,61 +116,66 @@ def test_write_kv_replaces_one_layers_array_and_no_other(kind):
         assert float(jnp.abs(k_old[layer]).sum()) == 0.0   # functional
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", ALL_KINDS)
 def test_swap_and_reset_pools_take_the_pytree(kind):
     cache, groups = _cache(kind)
-    ones = jax.tree_util.tree_map(lambda x: x + 1.0, cache.k_pool)
-    twos = jax.tree_util.tree_map(lambda x: x + 2.0, cache.v_pool)
-    cache.swap_pools(ones, twos)
-    assert jax.tree_util.tree_structure(cache.k_pool) \
-        == jax.tree_util.tree_structure(ones)
-    for held, given in zip(jax.tree_util.tree_leaves(
-            (cache.k_pool, cache.v_pool)),
-            jax.tree_util.tree_leaves((ones, twos))):
-        assert held is given
+    given = jax.tree_util.tree_map(lambda x: x + 1.0, cache.operands)
+    cache.swap_pools(*given)
+    assert jax.tree_util.tree_structure(cache.operands) \
+        == jax.tree_util.tree_structure(given)
+    for held, its in zip(jax.tree_util.tree_leaves(cache.operands),
+                         jax.tree_util.tree_leaves(given)):
+        assert held is its
     cache.reset_pools()
-    leaves = jax.tree_util.tree_leaves((cache.k_pool, cache.v_pool))
-    assert len(leaves) == 2 * sum(layers for layers, _p in groups)
-    assert all(float(jnp.abs(x).sum()) == 0.0 for x in leaves)
-    assert [x.shape for x in jax.tree_util.tree_leaves(cache.k_pool)] \
-        == [x.shape for x in jax.tree_util.tree_leaves(ones)]
+    pools, state = cache.operands
+    assert len(jax.tree_util.tree_leaves(pools)) \
+        == (1 if kind == "latent" else 2) * sum(n for n, _p in groups)
+    assert all(float(jnp.abs(x).sum()) == 0.0
+               for x in jax.tree_util.tree_leaves((pools, state)))
+    assert [x.shape for x in jax.tree_util.tree_leaves(cache.operands)] \
+        == [x.shape for x in jax.tree_util.tree_leaves(given)]
+    assert cache.paged_bytes == sum(
+        x.nbytes for x in jax.tree_util.tree_leaves(pools))
+    assert cache.state_bytes == sum(
+        x.nbytes for x in jax.tree_util.tree_leaves(state))
 
 
-@pytest.mark.parametrize("kind", KINDS)
-def test_cow_copies_the_page_in_every_layers_array(kind):
+@pytest.mark.parametrize("kind", ALL_KINDS)
+def test_cow_copies_the_page_in_every_layers_array_and_no_state(kind):
     with _engine(kind) as eng:
-        k_pool = jax.tree_util.tree_map(
-            lambda x: x.at[3].set(7.0), eng._cache.k_pool)
-        kp, vp = eng._cow_jit(k_pool, eng._cache.v_pool,
-                              jnp.asarray(3, jnp.int32),
-                              jnp.asarray(5, jnp.int32))
-        assert jax.tree_util.tree_structure(kp) \
-            == jax.tree_util.tree_structure(eng._cache.k_pool)
-        for leaf in jax.tree_util.tree_leaves(kp):
+        pools, state = eng._cache.operands
+        k_marked = jax.tree_util.tree_map(lambda x: x.at[3].set(7.0), pools)
+        copied, kept = eng._cow_jit(k_marked, state,
+                                    jnp.asarray(3, jnp.int32),
+                                    jnp.asarray(5, jnp.int32))
+        assert jax.tree_util.tree_structure(copied) \
+            == jax.tree_util.tree_structure(pools)
+        for leaf in jax.tree_util.tree_leaves(copied):
             got = np.asarray(leaf)
             assert (got[5] == 7.0).all() and (got[3] == 7.0).all()
             assert (got[4] == 0.0).all()
+        # what is not paged is not a page to copy
+        assert jax.tree_util.tree_structure(kept) \
+            == jax.tree_util.tree_structure(state)
         assert all(float(jnp.abs(x).sum()) == 0.0
-                   for x in jax.tree_util.tree_leaves(vp))
+                   for x in jax.tree_util.tree_leaves(kept))
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", ALL_KINDS)
 def test_pools_dead_reads_one_leaf(kind):
     with _engine(kind) as eng:
         assert eng._pools_dead() is False
-        pool = eng._cache.k_pool
-        first = (pool[0] if kind == "grouped" else pool)[0]
+        first = jax.tree_util.tree_leaves(eng._cache.operands)[0]
         first.delete()      # what a failed step does to a donated buffer
         assert eng._pools_dead() is True
         eng._cache.reset_pools()
         assert eng._pools_dead() is False
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", ALL_KINDS)
 def test_governor_bound_and_stats_count_every_leaf(kind):
     with _engine(kind) as eng:
-        leaves = jax.tree_util.tree_leaves(
-            (eng._cache.k_pool, eng._cache.v_pool))
+        leaves = jax.tree_util.tree_leaves(eng._cache.operands)
         bound = hbm.governor().oom_report()["bounds_bytes"][
             "serving.%s.kv_pool" % eng._name]
         assert bound == sum(x.nbytes for x in leaves) > 0
@@ -160,7 +187,7 @@ def test_governor_bound_and_stats_count_every_leaf(kind):
         assert eng.stats()["steady_state_recompiles"] == 0
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", ALL_KINDS)
 def test_served_tokens_survive_a_pool_reset(kind):
     """Tokens through tuple pools equal the oracle's, before and after the
     eviction path's ``reset_pools``."""
@@ -173,6 +200,85 @@ def test_served_tokens_survive_a_pool_reset(kind):
         np.testing.assert_array_equal(first, again)
         assert eng.stats()["steady_state_recompiles"] == 0
         assert eng.kvcache_stats()["pages_in_use"] == 0
+
+
+@pytest.mark.parametrize("kind,groups,leaves", [
+    ("paged", 1, 6), ("grouped", 2, 10), ("latent", 1, 7)])
+def test_the_seam_between_the_cache_the_engine_and_the_model(kind, groups,
+                                                            leaves):
+    """What the three served models are handed, by what they declare and by
+    nothing else: the step's packed operand has ``4 + groups + 1`` rows, a
+    prefill's ``2 + groups`` and one for the slot where a layer keeps slot
+    state; ``pools``, ``page_tables`` and ``write_pages`` have one entry a
+    group, one group's bare; ``state`` is ``()`` exactly where no layer is
+    ``slot``; and ``stats()`` counts the leaves it counted."""
+    with _engine(kind) as eng:
+        cache, model = eng._cache, eng._model
+        slot_layers = [st for st in kvcache.layer_states(model)
+                       if st[0] == "slot"]
+        assert cache.num_groups == groups == len(cache.tables) \
+            == len(cache.walk_groups())
+        packed, _drafts = eng._pack_step([])
+        assert packed.shape == (4 + groups + 1, eng.num_slots) \
+            and eng._packed_rows == 4 + groups + 1
+        assert eng._prefill_rows == 2 + groups + bool(slot_layers)
+        pools, state = cache.operands
+        tables = eng._device_page_table()
+        cache.reserve(0, 40)
+        pages, offs = cache.write_slots(0, 0, 40)
+        at = [page_at(0, 39) for page_at in cache.page_lookups()]
+        cache.free(0)
+        assert pages.shape == (groups, 40) and offs.shape == (40,)
+        assert at == list(pages[:, 39])
+        if groups == 1:     # bare: the group's own, no tuple around it
+            assert pools is cache.groups[0].pools
+            assert tables.shape == cache.groups[0].page_table.shape
+            assert cache.per_group(list(pages)).shape == (40,)
+        else:
+            assert isinstance(pools, tuple) and len(pools) == groups
+            assert [t.shape for t in tables] \
+                == [g.page_table.shape for g in cache.groups]
+            assert len(cache.per_group(list(pages))) == groups
+        assert (state == ()) == (not slot_layers)
+        assert len(state) == len(slot_layers)
+        # the programs take exactly these operands, and hand them back
+        out = jax.eval_shape(eng._step, eng._params, jnp.asarray(packed),
+                             eng._no_prev, pools, state, tables)
+        assert jax.tree_util.tree_structure(out[1:]) \
+            == jax.tree_util.tree_structure((pools, state))
+        assert eng.stats()["kv_pool_leaves"] == leaves == len(
+            jax.tree_util.tree_leaves((pools, state)))
+
+
+@pytest.mark.parametrize("states,said", [
+    ([("paged",), ("latent", 20)], "paged + latent 20"),
+    ([("paged",), ("slot", ((2, 2),))], "paged + slot"),
+    ([("latent", 20), ("latent", 20)], "latent 20"),
+    ([("ring", 16), ("ring", 32), ("paged",)], "paged + ring 16 + ring 32"),
+    ([("ring", 16), ("latent", 20), ("slot", ((2, 2),))],
+     "ring 16 + latent 20 + slot")])
+def test_a_mix_of_kinds_no_served_model_has_is_refused_by_name(states, said):
+    class Mixed:
+        num_layers = len(states)
+        num_kv_heads, head_dim = 2, 8
+        layer_state = states
+
+    assert kvcache.layer_states(Mixed()) == states
+    with pytest.raises(kvcache.MXNetError, match=said.replace("+", r"\+")
+                       + " together"):
+        make_cache(Mixed(), 2, 64, page_size=PAGE)
+
+
+def test_layers_are_placed_in_the_order_of_the_caches_groups():
+    """``place_layers`` is where a model learns its layers' places: full
+    before window whatever the layers' order, a slot layer in ``state``."""
+    assert kvcache.place_layers(
+        [("ring", 32), ("paged",), ("ring", 32), ("paged",)]) \
+        == [(1, 0), (0, 0), (1, 1), (0, 1)]
+    assert kvcache.place_layers(
+        [("slot", ((2, 2),)), ("latent", 20), ("slot", ((2, 2),))]) \
+        == [(None, 0), (0, 0), (None, 1)]
+    assert kvcache.place_layers([("paged",)] * 2) == [(0, 0), (0, 1)]
 
 
 def test_row_width_is_head_dim_where_the_device_is_row_major():
@@ -198,7 +304,7 @@ def test_pools_with_wider_rows_serve_the_same_tokens(kind, wide_rows):
     prompts = [np.arange(1, 12, dtype=np.int32),
                np.arange(5, 45, dtype=np.int32)]
     with _engine(kind) as eng:
-        leaf = jax.tree_util.tree_leaves(eng._cache.k_pool)[0]
+        leaf = jax.tree_util.tree_leaves(eng._cache.operands)[0]
         assert leaf.shape[-1] == 16
         eng.warmup()
         got = [eng.generate(p, 6, timeout=300) for p in prompts]
@@ -224,7 +330,7 @@ def test_pools_with_wider_rows_serve_the_same_tokens(kind, wide_rows):
 def test_write_kv_pads_rows_to_the_pools_width(wide_rows):
     cache, _groups_ = _cache("paged")
     rows = jnp.ones((2, 2, 8), jnp.float32)
-    k_new, _v = write_kv(cache.k_pool, cache.v_pool, 1, rows, rows,
+    k_new, _v = write_kv(*cache.operands[0], 1, rows, rows,
                          jnp.asarray([1, 2], jnp.int32),
                          jnp.asarray([0, 3], jnp.int32))
     got = np.asarray(k_new[1])

@@ -30,7 +30,6 @@ from mxnet_tpu.ops import kda, moe
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.serving import decode as decode_mod
 from mxnet_tpu.serving import kvcache
-from mxnet_tpu.serving.kvcache import LatentStateCache
 
 LOGIT_TOL = 2e-4
 GAP_TOL = 1e-3
@@ -113,8 +112,7 @@ def _engine(tiny, **kw):
 
 
 def _cache(model, slots=2):
-    return LatentStateCache(slots, 128, kvcache.layer_states(model),
-                            page_size=PAGE)
+    return kvcache.make_cache(model, slots, 128, page_size=PAGE)
 
 
 def _prefill(model, params, cache, slot, prompt, rung, total):
@@ -125,7 +123,7 @@ def _prefill(model, params, cache, slot, prompt, rung, total):
     tokens = np.zeros(rung, np.int32)
     tokens[:p] = prompt
     wp = np.zeros(rung, np.int32)
-    wp[:p] = pages
+    (wp[:p],) = pages      # one group: one row of pages
     wo = np.concatenate([offs, cache.null_write_slots(rung - p)[1]])
     out = model.prefill(params, jnp.asarray(tokens), jnp.asarray(p, jnp.int32),
                         *cache.operands, jnp.asarray(wp),
@@ -411,10 +409,10 @@ def test_cache_holds_pools_for_the_layers_that_own_some(tiny):
     assert kvcache.layer_states(model) == [
         ("slot", ((4, 8, 8), (3, 96)))] * 3 + [("latent", 20)]
     cache = kvcache.make_cache(model, 3, 128, page_size=PAGE)
-    assert isinstance(cache, LatentStateCache)
+    pools, state = cache.operands
     # one latent pool, no head axis, no V pool; three layers of slot state
-    assert [x.shape for x in cache.latent_pool] == [(3 * 16 + 1, PAGE, 20)]
-    assert [[x.shape for x in layer] for layer in cache.slot_state] == \
+    assert [x.shape for x in pools] == [(3 * 16 + 1, PAGE, 20)]
+    assert [[x.shape for x in layer] for layer in state] == \
         [[(3, 4, 8, 8), (3, 3, 96)]] * 3
     st = cache.stats()["state"]
     assert st["state_bytes"] == 3 * 3 * (4 * 8 * 8 + 3 * 96) * 4
@@ -422,10 +420,11 @@ def test_cache_holds_pools_for_the_layers_that_own_some(tiny):
     cache.reserve(1, 20)
     assert cache.stats()["state"]["latent_pages"] == 3
     # what the engine asks of it by declaration, not by its class
-    assert cache.prefill_extra == "slot"
     assert cache.walk_groups() == (("latent", 128 // PAGE, 1),)
     assert cache.paged_bytes == (3 * 16 + 1) * PAGE * 20 * 4
-    assert cache.operands == (cache.latent_pool, cache.slot_state)
+    assert cache.state_bytes == st["state_bytes"]
+    ((_version, table),) = cache.tables
+    assert table.shape == (3, 128 // PAGE)
     with pytest.raises(MXNetError, match="no prefix index"):
         kvcache.make_cache(model, 3, 128, prefix_cache=True)
 
@@ -435,14 +434,15 @@ def test_declarations_of_the_other_models_give_the_caches_they_had():
                                head_dim=8, num_kv_heads=2)
     assert kvcache.layer_states(tiny) == [("paged",)] * 2
     paged = kvcache.make_cache(tiny, 2, 64, page_size=8)
-    assert type(paged) is kvcache.PagedKVCache
+    (group,) = paged.groups
+    assert type(group) is kvcache.PagedKVCache
     # what the engine asks a cache by declaration: one group of K/V pages,
-    # nothing behind a prefill's three rows, nothing more on a span
-    assert paged.prefill_extra is None and paged.span_args([5, 9]) == {}
+    # no state, nothing more on a span
+    assert paged.span_args([5, 9]) == {} and paged.state_bytes == 0
     assert paged.walk_groups() == (("full", 8, 2),)
-    assert paged.operands == (paged.k_pool, paged.v_pool)
-    assert paged.paged_bytes == sum(
-        x.nbytes for x in paged.k_pool + paged.v_pool)
+    (k_pool, v_pool), state = paged.operands
+    assert state == () and len(k_pool) == len(v_pool) == 2
+    assert paged.paged_bytes == sum(x.nbytes for x in k_pool + v_pool)
     afmoe = serving.AfmoeDecoder(
         vocab_size=96, hidden_size=48, num_attention_heads=12,
         num_key_value_heads=2, head_dim=8, intermediate_size=96,
@@ -452,21 +452,21 @@ def test_declarations_of_the_other_models_give_the_caches_they_had():
         sliding_window=32, held_experts=[4, 4])
     assert kvcache.layer_states(afmoe) == [("ring", 32)] * 4 + [("paged",)]
     cache = kvcache.make_cache(afmoe, 2, 128, page_size=8)
-    assert type(cache) is kvcache.GroupedKVCache
-    assert (cache.full.num_layers, cache.window.num_layers) == (1, 4)
-    assert cache.prefill_extra == "window_pages"
+    full, window = cache.groups
+    assert (type(full), type(window)) == (kvcache.PagedKVCache,
+                                          kvcache.RingKVCache)
+    assert (full.num_layers, window.num_layers) == (1, 4)
     assert cache.walk_groups() == (("full", 16, 1), ("window", 5, 4))
-    assert cache.paged_bytes == cache.full.paged_bytes \
-        + cache.window.paged_bytes
+    assert cache.paged_bytes == full.paged_bytes + window.paged_bytes
     assert cache.span_args([40, 9]) == dict(
         kv_rows_full=49, kv_rows_window=41, kv_window_pages=0,
-        kv_window_capacity=cache.window.num_pages - 1)
+        kv_window_capacity=window.num_pages - 1)
 
     class Mixed:
         num_layers = 2
         layer_state = [("paged",), ("slot", ((2, 2),))]
 
-    with pytest.raises(MXNetError, match="no cache for layers of kinds"):
+    with pytest.raises(MXNetError, match=r"layers of paged \+ slot together"):
         kvcache.make_cache(Mixed(), 2, 64)
 
 
@@ -517,12 +517,12 @@ def test_prefill_writes_its_slots_state_whole(tiny):
     want = _prefill(model, params, clean, 1, seq, 64, 40)
     dirty = _cache(model)
     marked = tuple(tuple(x.at[1].set(jnp.nan).at[0].set(7.0) for x in layer)
-                   for layer in dirty.slot_state)
-    dirty.swap_pools(dirty.latent_pool, marked)
+                   for layer in dirty.state)
+    dirty.swap_pools(dirty.operands[0], marked)
     got = _prefill(model, params, dirty, 1, seq, 64, 40)
     assert np.array_equal(got, want)
-    for mine, theirs in zip(jax.tree_util.tree_leaves(dirty.slot_state),
-                            jax.tree_util.tree_leaves(clean.slot_state)):
+    for mine, theirs in zip(jax.tree_util.tree_leaves(dirty.state),
+                            jax.tree_util.tree_leaves(clean.state)):
         assert np.array_equal(np.asarray(mine)[1], np.asarray(theirs)[1])
         assert (np.asarray(mine)[0] == 7.0).all()
     a = _decode(model, params, dirty, {1: (3, 30)})[1]
@@ -535,9 +535,9 @@ def test_a_seq_len_0_row_leaves_its_slots_state_bit_for_bit(tiny):
     cache = _cache(model, slots=3)
     _prefill(model, params, cache, 0, _prompt(20, 14), 64, 30)
     _prefill(model, params, cache, 2, _prompt(9, 15), 64, 30)
-    before = [np.asarray(x) for x in jax.tree_util.tree_leaves(cache.slot_state)]
+    before = [np.asarray(x) for x in jax.tree_util.tree_leaves(cache.state)]
     _decode(model, params, cache, {0: (5, 20)})      # slots 1, 2: seq_len 0
-    after = [np.asarray(x) for x in jax.tree_util.tree_leaves(cache.slot_state)]
+    after = [np.asarray(x) for x in jax.tree_util.tree_leaves(cache.state)]
     for old, new in zip(before, after):
         assert np.array_equal(old[1:], new[1:])
     assert any(not np.array_equal(old[0], new[0])
@@ -562,9 +562,9 @@ def test_decode_through_the_state_kernel_is_decode_through_step(
         cache = _cache(model, slots=3)
         _prefill(model, params, cache, 0, _prompt(20, 14), 64, 30)
         _prefill(model, params, cache, 2, _prompt(9, 15), 64, 30)
-        before = np.asarray(cache.slot_state[0][0])
+        before = np.asarray(cache.state[0][0])
         logits = _decode(model, params, cache, {0: (5, 20), 2: (7, 9)})
-        ticks.append((logits, [np.asarray(s) for s, _t in cache.slot_state]))
+        ticks.append((logits, [np.asarray(s) for s, _t in cache.state]))
         assert np.array_equal(ticks[-1][1][0][1], before[1])
     (want, want_s), (got, got_s) = ticks
     np.testing.assert_allclose(got[[0, 2]], want[[0, 2]], atol=LOGIT_TOL)

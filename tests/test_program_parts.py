@@ -210,7 +210,7 @@ def test_programs_carry_the_layout_of_their_parts_where_the_cache_keys_it():
             prefill_buckets=(16,), prefix_cache=True, prefill_chunk=8,
             timeout_ms=0, name="parts_tag", **kw) as eng:
         s = eng.num_slots
-        k, v = eng._cache.k_pool, eng._cache.v_pool
+        k, v = eng._cache.operands
         packed = jnp.zeros((eng._packed_rows, s), jnp.int32)
         one = jnp.asarray(1, jnp.int32)
         lowered = {

@@ -231,10 +231,9 @@ def _prefill(model, length):
         out[:length] = x
         return jnp.asarray(out)
 
-    if pages.ndim == 2:         # a row of pages a cache group
-        pages, more = tuple(padded(p) for p in pages), {}
-    else:
-        pages, more = padded(pages), {"slot": jnp.asarray(1, jnp.int32)}
+    # a row of pages a cache group, as the cache hands a model its groups
+    pages = cache.per_group([padded(p) for p in pages])
+    more = {"slot": jnp.asarray(1, jnp.int32)} if cache.state else {}
     k, v = cache.operands
     out = jax.jit(lambda n: model.prefill(
         params, jnp.asarray(tokens), n, k, v, pages, padded(offs), **more))(
